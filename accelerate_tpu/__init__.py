@@ -34,99 +34,56 @@ from .utils.dataclasses import (
     TensorParallelConfig,
 )
 
-# Populated as modules land; guarded so partial builds stay importable.
-try:
-    from .accelerator import Accelerator
-except ImportError:  # pragma: no cover
-    pass
-try:
-    from .data_loader import prepare_data_loader, skip_first_batches
-except ImportError:  # pragma: no cover
-    pass
-try:
-    from .big_modeling import (
-        abstract_init,
-        cpu_offload,
-        disk_offload,
-        dispatch_model,
-        infer_auto_device_map,
-        infer_auto_placement,
-        init_empty_weights,
-        load_checkpoint_and_dispatch,
-        load_checkpoint_in_model,
-        offload_state_dict,
-        offload_store_params,
-        offloaded_apply,
-    )
-except ImportError:  # pragma: no cover
-    pass
-try:
-    from .utils.memory import find_executable_batch_size
-except ImportError:  # pragma: no cover
-    pass
-try:
-    from .utils.random import set_seed, synchronize_rng_states
-except ImportError:  # pragma: no cover
-    pass
-try:
-    from .launchers import debug_launcher, notebook_launcher
-except ImportError:  # pragma: no cover
-    pass
-try:
-    from .parallel.pipeline_parallel import PipelinedModel, prepare_pipeline
-except ImportError:  # pragma: no cover
-    pass
-try:
-    from .local_sgd import LocalSGD
-except ImportError:  # pragma: no cover
-    pass
-try:
-    from .utils.other import extract_model_from_parallel
-except ImportError:  # pragma: no cover
-    pass
-try:
-    from .hooks import (
-        AlignDevicesHook,
-        ModelHook,
-        SequentialHook,
-        add_hook_to_apply,
-        attach_align_device_hook,
-        remove_hook_from_apply,
-    )
-except ImportError:  # pragma: no cover
-    pass
-try:
-    from .utils.quantization import (
-        QuantizationConfig,
-        load_and_quantize_model,
-        quantize_params,
-        quantized_apply,
-    )
-except ImportError:  # pragma: no cover
-    pass
-try:
-    from .generation import (
-        GenerationConfig,
-        beam_search,
-        generate,
-        generate_seq2seq,
-        generate_streamed,
-        place_params_host,
-        sample_logits,
-    )
-except ImportError:  # pragma: no cover
-    pass
-try:
-    from .ops.streaming import LayerPrefetcher, StreamStats
-except ImportError:  # pragma: no cover
-    pass
-try:
-    from .telemetry import (
-        SLOMonitor,
-        SpanRecorder,
-        TrainTimeline,
-        TwinRegistry,
-        twin_registry,
-    )
-except ImportError:  # pragma: no cover
-    pass
+from .accelerator import Accelerator
+from .data_loader import prepare_data_loader, skip_first_batches
+from .big_modeling import (
+    abstract_init,
+    cpu_offload,
+    disk_offload,
+    dispatch_model,
+    infer_auto_device_map,
+    infer_auto_placement,
+    init_empty_weights,
+    load_checkpoint_and_dispatch,
+    load_checkpoint_in_model,
+    offload_state_dict,
+    offload_store_params,
+    offloaded_apply,
+)
+from .utils.memory import find_executable_batch_size
+from .utils.random import set_seed, synchronize_rng_states
+from .launchers import debug_launcher, notebook_launcher
+from .parallel.pipeline_parallel import PipelinedModel, prepare_pipeline
+from .local_sgd import LocalSGD
+from .utils.other import extract_model_from_parallel
+from .hooks import (
+    AlignDevicesHook,
+    ModelHook,
+    SequentialHook,
+    add_hook_to_apply,
+    attach_align_device_hook,
+    remove_hook_from_apply,
+)
+from .utils.quantization import (
+    QuantizationConfig,
+    load_and_quantize_model,
+    quantize_params,
+    quantized_apply,
+)
+from .generation import (
+    GenerationConfig,
+    beam_search,
+    generate,
+    generate_seq2seq,
+    generate_streamed,
+    place_params_host,
+    sample_logits,
+)
+from .ops.streaming import LayerPrefetcher, StreamStats
+from .telemetry import (
+    SLOMonitor,
+    SpanRecorder,
+    TrainTimeline,
+    TwinRegistry,
+    twin_registry,
+)

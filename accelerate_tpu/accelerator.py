@@ -1486,14 +1486,7 @@ class Accelerator:
             psgd_rank = self.grad_sync_kwargs.rank
             axes = tuple(self._compression_axes())
             err_spec = PartitionSpec(axes)
-            try:
-                from jax import shard_map as _shard_map
-
-                _no_check = {"check_vma": False}
-            except ImportError:  # older jax: check_vma was still check_rep
-                from jax.experimental.shard_map import shard_map as _shard_map
-
-                _no_check = {"check_rep": False}
+            from jax import shard_map as _shard_map
 
             def _psgd_local(params, mb, use_rng, qs, errs):
                 def loss_only(p):
@@ -1527,7 +1520,7 @@ class Accelerator:
                     in_specs=(PartitionSpec(), batch_specs, PartitionSpec(),
                               PartitionSpec(), err_spec),
                     out_specs=(PartitionSpec(), PartitionSpec(), PartitionSpec(), err_spec),
-                    **_no_check,
+                    check_vma=False,
                 )
                 loss, g_hat, new_qs, new_errs = fn(state.params, batch, use_rng, qs, errs)
                 new_state, metrics = apply_update(
@@ -1546,14 +1539,7 @@ class Accelerator:
                               if int(self.mesh.shape.get(a, 1)) > 1)
             ici_axes = tuple(a for a in hier_axes if a != "dcn")
             err_spec = PartitionSpec(hier_axes)
-            try:
-                from jax import shard_map as _shard_map
-
-                _no_check = {"check_vma": False}
-            except ImportError:  # older jax: check_vma was still check_rep
-                from jax.experimental.shard_map import shard_map as _shard_map
-
-                _no_check = {"check_rep": False}
+            from jax import shard_map as _shard_map
 
             def _hier_grads(params, mb, use_rng, qs, errs):
                 """Per-rank loss/grad + the three-phase reduction.  ``qs``/
@@ -1601,7 +1587,7 @@ class Accelerator:
                                   PartitionSpec(), err_spec),
                         out_specs=(PartitionSpec(), PartitionSpec(),
                                    PartitionSpec(), err_spec),
-                        **_no_check,
+                        check_vma=False,
                     )
                     loss, g_hat, new_qs, new_errs = fn(
                         state.params, batch, use_rng, qs, errs
@@ -1626,7 +1612,7 @@ class Accelerator:
                         _hier_dense, mesh=self.mesh,
                         in_specs=(PartitionSpec(), batch_specs, PartitionSpec()),
                         out_specs=(PartitionSpec(), PartitionSpec()),
-                        **_no_check,
+                        check_vma=False,
                     )
                     loss, g_hat = fn(state.params, batch, use_rng)
                     new_state, metrics = apply_update(state.replace(rng=rng), g_hat, loss)

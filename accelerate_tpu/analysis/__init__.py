@@ -12,8 +12,7 @@ device):
 - :mod:`.ast_rules` — repo-wide source linter for hazards only the caller's
   source shows: donated-name reuse after a ``donate_argnums`` call site
   (GL201, the PR 2 async-checkpoint race shape), host syncs in jitted code
-  (GL202), ``jax.experimental.shard_map`` outside the compat shims (GL203),
-  wall-clock/stdlib randomness under trace (GL204), non-atomic checkpoint
+  (GL202), wall-clock/stdlib randomness under trace (GL204), non-atomic checkpoint
   writes (GL205), shape-dependent traces (GL305), jit-in-hot-loop (GL306).
 - :mod:`.compiled_audit` — AOT ``lower().compile()`` every production
   program and read XLA's decisions off the executable: donation that did

@@ -317,45 +317,6 @@ def _rule_host_sync(index: _ModuleIndex, path: str) -> list[Finding]:
     return findings
 
 
-def _rule_shard_map_compat(index: _ModuleIndex, path: str) -> list[Finding]:
-    """GL203: jax.experimental.shard_map outside the ImportError fallback."""
-
-    def in_import_error_handler(node) -> bool:
-        cur = index._parent.get(id(node))
-        while cur is not None:
-            if isinstance(cur, ast.ExceptHandler):
-                names = []
-                t = cur.type
-                for e in t.elts if isinstance(t, ast.Tuple) else ([t] if t else []):
-                    names.append(_dotted(e))
-                if any(n in ("ImportError", "ModuleNotFoundError") for n in names):
-                    return True
-            cur = index._parent.get(id(cur))
-        return False
-
-    findings = []
-    for node in ast.walk(index.tree):
-        hit = None
-        if isinstance(node, ast.ImportFrom) and node.module and \
-                node.module.startswith("jax.experimental.shard_map"):
-            hit = f"from {node.module} import ..."
-        elif isinstance(node, ast.Import) and any(
-                a.name.startswith("jax.experimental.shard_map") for a in node.names):
-            hit = "import jax.experimental.shard_map"
-        elif isinstance(node, ast.Attribute) and \
-                _dotted(node) == "jax.experimental.shard_map":
-            hit = "jax.experimental.shard_map"
-        if hit and not in_import_error_handler(node):
-            findings.append(
-                _finding(
-                    "GL203",
-                    f"{hit} outside an `except ImportError` compat fallback",
-                    path, node.lineno,
-                )
-            )
-    return findings
-
-
 def _rule_impure_in_jit(index: _ModuleIndex, path: str) -> list[Finding]:
     """GL204: wall-clock / stdlib-random calls inside jit contexts."""
     findings = []
@@ -874,7 +835,6 @@ def _rule_snapshot_donation_race(index: _ModuleIndex, path: str) -> list[Finding
 _ALL_RULES = (
     _rule_donated_reuse,
     _rule_host_sync,
-    _rule_shard_map_compat,
     _rule_impure_in_jit,
     _rule_checkpoint_atomicity,
     _rule_shape_dependent_trace,

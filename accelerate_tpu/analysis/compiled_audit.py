@@ -80,30 +80,16 @@ def fresh_compile_context():
     that memo (and the cache's in-memory LRU; the on-disk store is
     untouched) so the flag is actually re-read, here and again on exit.
     """
-    try:
-        prev = jax.config.jax_enable_compilation_cache
-    except AttributeError:  # pragma: no cover - much older jax
-        yield
-        return
-    try:
-        from jax._src import compilation_cache as _cc
-    except Exception:  # pragma: no cover - private-API drift
-        _cc = None
+    from jax.experimental.compilation_cache import compilation_cache as _cc
 
-    def _drop_memo():
-        if _cc is not None:
-            try:
-                _cc.reset_cache()
-            except Exception:  # pragma: no cover - never initialized
-                pass
-
+    prev = jax.config.jax_enable_compilation_cache
     try:
         jax.config.update("jax_enable_compilation_cache", False)
-        _drop_memo()
+        _cc.reset_cache()
         yield
     finally:
         jax.config.update("jax_enable_compilation_cache", prev)
-        _drop_memo()
+        _cc.reset_cache()
 
 
 class CompileCounter:

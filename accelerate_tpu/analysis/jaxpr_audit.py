@@ -57,14 +57,13 @@ from typing import Any, Iterable, Optional
 
 import jax
 import numpy as np
+from jax.extend import core as jex_core
 
 from .report import Finding, Report, apply_suppressions
 from .rules import RULES
 
-try:  # jaxpr equations carry their user-code provenance here
-    from jax._src import source_info_util as _src_info
-except Exception:  # pragma: no cover - private-API drift
-    _src_info = None
+# jaxpr equations carry their user-code provenance here
+from jax._src import source_info_util as _src_info
 
 
 # random primitives that CONSUME a key (produce bits or a derived stream).
@@ -103,12 +102,9 @@ def _aval_bytes(aval) -> int:
 
 def _eqn_location(eqn):
     """``(path, line)`` of the user frame that emitted ``eqn``, if any."""
-    if _src_info is None or eqn is None:
+    if eqn is None:
         return None, None
-    try:
-        frame = _src_info.user_frame(eqn.source_info)
-    except Exception:  # pragma: no cover - private-API drift
-        return None, None
+    frame = _src_info.user_frame(eqn.source_info.traceback)
     if frame is None:
         return None, None
     return frame.file_name, frame.start_line
@@ -123,7 +119,7 @@ def _sub_jaxprs(eqn) -> list:
             if hasattr(item, "jaxpr") and hasattr(item, "consts"):
                 subs.append(item)  # ClosedJaxpr
             elif hasattr(item, "eqns") and hasattr(item, "invars"):
-                subs.append(jax.core.ClosedJaxpr(item, ()))
+                subs.append(jex_core.ClosedJaxpr(item, ()))
     return subs
 
 
@@ -163,7 +159,7 @@ def _audit_donation(jaxpr, donated: list[bool], path_hint) -> list[Finding]:
     the same viability criterion XLA's buffer-donation aliasing applies
     (an input buffer can only be reused by an output of equal size)."""
     findings = []
-    out_vars = [v for v in jaxpr.outvars if not isinstance(v, jax.core.Literal)]
+    out_vars = [v for v in jaxpr.outvars if not isinstance(v, jex_core.Literal)]
     # a donated input returned unchanged IS its own output buffer
     passthrough = {id(v) for v in jaxpr.invars} & {id(v) for v in out_vars}
     out_sizes: dict[int, int] = {}
@@ -199,7 +195,7 @@ def _audit_donation_promotion(jaxpr, donated: list[bool], path_hint) -> list[Fin
     python/numpy scalar mixed into the donated tree.  The drifted result
     re-keys the jit cache when fed back (a recompile every step) and can no
     longer alias the donated buffer."""
-    out_vars = [v for v in jaxpr.outvars if not isinstance(v, jax.core.Literal)]
+    out_vars = [v for v in jaxpr.outvars if not isinstance(v, jex_core.Literal)]
     passthrough = {id(v) for v in jaxpr.invars} & {id(v) for v in out_vars}
 
     def _sig(aval):
@@ -252,7 +248,7 @@ def _audit_consts(closed, threshold: int, path_hint) -> list[Finding]:
     const_first_use = {}
     for eqn in closed.jaxpr.eqns:
         for v in eqn.invars:
-            if not isinstance(v, jax.core.Literal) and id(v) not in const_first_use:
+            if not isinstance(v, jex_core.Literal) and id(v) not in const_first_use:
                 const_first_use[id(v)] = eqn
     for var, const in zip(closed.jaxpr.constvars, closed.consts):
         nbytes = getattr(const, "nbytes", None)
@@ -334,7 +330,7 @@ def _audit_key_reuse(closed) -> list[Finding]:
         for eqn in jaxpr.eqns:
             if eqn.primitive.name in _KEY_CONSUMERS:
                 for v in eqn.invars:
-                    if isinstance(v, jax.core.Literal) or not _is_key_aval(v.aval):
+                    if isinstance(v, jex_core.Literal) or not _is_key_aval(v.aval):
                         continue
                     # report at the outermost enclosing call site: inner
                     # jaxprs are deduplicated across identical calls, so an
@@ -348,7 +344,7 @@ def _audit_key_reuse(closed) -> list[Finding]:
                 sub_env: dict = {}
                 if len(operands) == len(inner.invars):
                     for outer, v in zip(operands, inner.invars):
-                        if not isinstance(outer, jax.core.Literal):
+                        if not isinstance(outer, jex_core.Literal):
                             sub_env[v] = root_of(outer, env)
                 walk(inner, sub_env, loc_eqn or eqn)
 
@@ -390,7 +386,7 @@ def _audit_collective_matmul(closed) -> list[Finding]:
         dots = []
         for eqn in jaxpr.eqns:
             for v in eqn.invars:
-                if not isinstance(v, jax.core.Literal):
+                if not isinstance(v, jex_core.Literal):
                     consumers.setdefault(id(v), []).append(eqn)
             if eqn.primitive.name == "all_gather":
                 gathers.append(eqn)
@@ -398,7 +394,7 @@ def _audit_collective_matmul(closed) -> list[Finding]:
                 dots.append(eqn)
             for sub in _sub_jaxprs(eqn):
                 scan(sub.jaxpr)
-        escaped = {id(v) for v in jaxpr.outvars if not isinstance(v, jax.core.Literal)}
+        escaped = {id(v) for v in jaxpr.outvars if not isinstance(v, jex_core.Literal)}
         for g in gathers:
             out = g.outvars[0]
             cons = consumers.get(id(out), [])
@@ -519,17 +515,17 @@ def _audit_fp8_scaling(closed) -> list[Finding]:
         fp8_dots = []
         for eqn in jaxpr.eqns:
             for v in eqn.invars:
-                if not isinstance(v, jax.core.Literal):
+                if not isinstance(v, jex_core.Literal):
                     consumers.setdefault(id(v), []).append(eqn)
             if eqn.primitive.name == "dot_general" and any(
                 _is_fp8_aval(v.aval) for v in eqn.invars
-                if not isinstance(v, jax.core.Literal)
+                if not isinstance(v, jex_core.Literal)
             ):
                 fp8_dots.append(eqn)
             for sub in _sub_jaxprs(eqn):
                 scan(sub.jaxpr)
         escaped = {id(v) for v in jaxpr.outvars
-                   if not isinstance(v, jax.core.Literal)}
+                   if not isinstance(v, jex_core.Literal)}
 
         def chain_is_scaled(var, depth=0) -> Optional[bool]:
             """True: a mul/div consumes the value (possibly through
@@ -561,7 +557,7 @@ def _audit_fp8_scaling(closed) -> list[Finding]:
             path, line = _eqn_location(d)
             dts = "x".join(
                 str(getattr(v.aval, "dtype", "?")) for v in d.invars
-                if not isinstance(v, jax.core.Literal)
+                if not isinstance(v, jex_core.Literal)
             )
             findings.append(
                 _finding(
@@ -588,7 +584,7 @@ def _audit_output_sharding(jaxpr, threshold: int, path_hint) -> list[Finding]:
     findings = []
     seen: set = set()
     for v in jaxpr.outvars:
-        if isinstance(v, jax.core.Literal) or id(v) in invar_ids or id(v) in seen:
+        if isinstance(v, jex_core.Literal) or id(v) in invar_ids or id(v) in seen:
             continue  # literals / pass-throughs keep their committed layout
         seen.add(id(v))
         size = _aval_bytes(v.aval)

@@ -181,15 +181,6 @@ RULES: dict[str, Rule] = {
             "the jit",
         ),
         Rule(
-            "GL203", "shard-map-compat", Severity.WARNING, "ast",
-            "jax.experimental.shard_map referenced outside an "
-            "`except ImportError` compat fallback: the experimental path "
-            "is removed in newer jax and must only appear as the shim's "
-            "fallback branch",
-            "use `try: from jax import shard_map` with the experimental "
-            "import only in the except ImportError handler",
-        ),
-        Rule(
             "GL204", "impure-in-jit", Severity.ERROR, "ast",
             "a call to time.time()/perf_counter()/random.*/np.random.* "
             "inside jitted code: the value is baked in at trace time, so "

@@ -196,9 +196,9 @@ def get_attention_impl(name: str) -> Callable:
     if name == "native":
         return native_attention
     if name == "flash":
-        from ..ops.flash_attention import flash_attention
+        from ..ops.flash_attention import mesh_flash_attention
 
-        return flash_attention
+        return mesh_flash_attention
     if name == "ring":
         from ..parallel.context_parallel import ring_attention
 

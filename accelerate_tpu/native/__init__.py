@@ -24,6 +24,7 @@ Surface:
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -34,6 +35,7 @@ import numpy as np
 
 _HERE = Path(__file__).parent
 _LIB_PATH = _HERE / "libaccel_native.so"
+_HASH_PATH = _HERE / "libaccel_native.so.srchash"  # sha256 of the sources it was built from
 _SRCS = sorted((_HERE / "src").glob("*.cc"))
 
 _lib = None
@@ -41,8 +43,23 @@ _load_lock = threading.Lock()
 _load_attempted = False
 
 
+def _source_hash() -> str:
+    """sha256 over the tracked sources (names + bytes) — what the binary
+    must have been built from."""
+    h = hashlib.sha256()
+    for src in _SRCS:
+        h.update(src.name.encode() + b"\0" + src.read_bytes() + b"\0")
+    return h.hexdigest()
+
+
 def _build() -> bool:
-    """(Re)build the shared library if sources are newer than the binary.
+    """(Re)build the shared library unless the hash recorded beside it
+    matches the sources'.
+
+    The binary is git-ignored and sits in the working tree, so a copied tree
+    can carry one that git would not commit; mtimes do not survive a copy,
+    a content hash does.  A binary with no (or another) recorded hash is
+    rebuilt from ``src/*.cc``.
 
     Multi-process safe (the launcher starts one process per host-rank and all
     of them race here on first use): the compile goes to a per-pid temp file
@@ -50,12 +67,12 @@ def _build() -> bool:
     compiles.
     """
     if not _SRCS:
-        return _LIB_PATH.exists()
+        return False
+    want = _source_hash()
 
     def _fresh() -> bool:
-        return _LIB_PATH.exists() and _LIB_PATH.stat().st_mtime >= max(
-            s.stat().st_mtime for s in _SRCS
-        )
+        return (_LIB_PATH.exists() and _HASH_PATH.exists()
+                and _HASH_PATH.read_text().strip() == want)
 
     if _fresh():
         return True
@@ -75,10 +92,12 @@ def _build() -> bool:
                 proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
                 if proc.returncode != 0 or not tmp.exists():
                     return False
+                _HASH_PATH.unlink(missing_ok=True)  # never a new .so under an old hash
                 os.replace(tmp, _LIB_PATH)  # atomic: loaders never see a partial .so
+                _HASH_PATH.write_text(want + "\n")
             finally:
                 tmp.unlink(missing_ok=True)
-            return _LIB_PATH.exists()
+            return True
     except OSError:
         return _fresh()
 

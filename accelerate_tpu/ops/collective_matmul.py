@@ -30,10 +30,8 @@ streams, halving ring depth to ``ceil((p-1)/2)`` hops (both ICI directions of
 the ring link carry traffic concurrently).
 
 Fallbacks: the XLA monolithic path is used whenever the ring axis is trivial
-(size 1), shapes do not divide the ring, or the old-``jax.experimental``
-``shard_map`` would degrade partial-manual semantics (it manualizes the whole
-mesh, which is only exact when every non-ring axis is trivial — the CPU test
-meshes).  The knob rides ``FullyShardedDataParallelPlugin.collective_matmul``
+(size 1) or shapes do not divide the ring.  The knob rides
+``FullyShardedDataParallelPlugin.collective_matmul``
 / env ``ACCELERATE_COLLECTIVE_MATMUL`` / ``bench.py --collective-matmul`` and
 is resolved at **trace time** (like ``ops/precision.fp8_autocast``): set it
 before the step compiles.
@@ -51,10 +49,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..parallel.collectives import (
     axis_index,
@@ -117,18 +112,11 @@ def collective_matmul(mode: str):
 def ring_supported(mesh: Optional[Mesh], axis_name: str) -> bool:
     """Whether the explicit ring path is usable on ``mesh`` over ``axis_name``.
 
-    Trivial ring axes fall back to the monolithic path (nothing to hide).  On
-    old jax the compat ``shard_map`` manualizes the WHOLE mesh, which is only
-    equivalent to partial-manual-over-the-ring when every other axis is
-    trivial — otherwise fall back rather than ship best-effort numerics.
+    Trivial ring axes fall back to the monolithic path (nothing to hide).
     """
     if mesh is None or axis_name not in getattr(mesh, "shape", {}):
         return False
-    if mesh.shape[axis_name] <= 1:
-        return False
-    if hasattr(jax, "shard_map"):
-        return True
-    return all(size == 1 for name, size in mesh.shape.items() if name != axis_name)
+    return mesh.shape[axis_name] > 1
 
 
 # ---------------------------------------------------------------------------
@@ -263,8 +251,7 @@ def make_collective_dense(mesh: Mesh, axis_name: str = "tp", kind: str = "column
 
     ``mode``: 'ring' | 'bidir' | 'monolithic' (the A/B baseline through the
     same specs).  Partial-manual over only the ring axis — dp/sp stay under
-    GSPMD; run under a cached jit like ``make_ulysses_attention`` (old-jax
-    eager shard_map validators reject multi-axis meshes spuriously).
+    GSPMD; run under a cached jit like ``make_ulysses_attention``.
     """
     if kind not in ("column", "row"):
         raise ValueError(f"kind must be 'column' or 'row', got {kind!r}")
@@ -290,17 +277,6 @@ def make_collective_dense(mesh: Mesh, axis_name: str = "tp", kind: str = "column
     )
 
 
-def _ambient_mesh() -> Optional[Mesh]:
-    from ..state import AcceleratorState, is_initialized
-
-    if not is_initialized():
-        return None
-    try:
-        return AcceleratorState().mesh
-    except Exception:  # pragma: no cover - half-built state
-        return None
-
-
 def _shapes_divide(x, w, kind: str, p: int) -> bool:
     if x.ndim != 3 or w.ndim != 2 or x.shape[-1] != w.shape[0]:
         return False
@@ -318,7 +294,7 @@ def dense_collective_matmul(x, w, kind: str, *, axis_name: str = "tp",
     ``None`` when the caller should take its ordinary (XLA monolithic) path.
 
     Falls back (returns ``None``) when the mode is off, no mesh is ambient,
-    the ring axis is trivial/unsupported (old-jax compat degradation), or the
+    the ring axis is trivial, or the
     sequence/feature/contraction dims don't divide the ring.  A fallback is
     always semantics-preserving: the global values are identical either way,
     only the collective schedule differs.
@@ -326,7 +302,9 @@ def dense_collective_matmul(x, w, kind: str, *, axis_name: str = "tp",
     mode = collective_matmul_mode()
     if mode == "off" or kind not in ("column", "row"):
         return None
-    mesh = _ambient_mesh()
+    from ..state import ambient_mesh
+
+    mesh = ambient_mesh()
     if not ring_supported(mesh, axis_name):
         return None
     if not _shapes_divide(x, w, kind, mesh.shape[axis_name]):
@@ -348,7 +326,9 @@ def ulysses_sp_boundary(num_heads: int, num_kv_heads: int, seq_len: int,
     """
     if collective_matmul_mode() == "off":
         return False
-    mesh = _ambient_mesh()
+    from ..state import ambient_mesh
+
+    mesh = ambient_mesh()
     if not ring_supported(mesh, axis_name):
         return False
     if mesh.shape.get("tp", 1) > 1:

@@ -46,17 +46,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # pragma: no cover - exercised through the public entry points
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover - pallas-less jax build
-    _HAS_PLTPU = False
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+from .flash_attention import _on_tpu
 
 
 # ---------------------------------------------------------------------------
@@ -100,7 +93,7 @@ def lora_kernel(mode: str):
 
 def _resolve_kernel(mode: str, t: int) -> str:
     if mode == "auto":
-        return "bgmv" if (_on_tpu() and t == 1 and _HAS_PLTPU) else "native"
+        return "bgmv" if (_on_tpu() and t == 1) else "native"
     return mode
 
 
@@ -180,10 +173,10 @@ def _bgmv_kernel(ids_ref, x_ref, a_ref, b_ref, o_ref):
     body is two small matmuls with fp32 accumulation."""
     del ids_ref  # consumed by the index_maps
     h = jax.lax.dot_general(
-        x_ref[...].astype(jnp.float32), a_ref[0].astype(jnp.float32),
+        x_ref[0].astype(jnp.float32), a_ref[0].astype(jnp.float32),
         (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
     )                                                    # [1, r]
-    o_ref[...] = jax.lax.dot_general(
+    o_ref[0] = jax.lax.dot_general(
         h, b_ref[0].astype(jnp.float32),
         (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
     ).astype(o_ref.dtype)                                # [1, d_out]
@@ -200,8 +193,6 @@ def bgmv(x, a_stack, b_stack, ids, *, interpret: Optional[bool] = None):
     HBM (the BGMV trick; id-0 rows read the null slot's zeros and the
     caller's ``where`` keeps them bitwise-clean).
     """
-    if not _HAS_PLTPU:  # pragma: no cover - pallas-less jax build
-        raise RuntimeError("pallas tpu backend unavailable")
     if interpret is None:
         interpret = not _on_tpu()
     s_slots, d_in = x.shape
@@ -211,19 +202,22 @@ def bgmv(x, a_stack, b_stack, ids, *, interpret: Optional[bool] = None):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(s_slots,),
+        # x / out carry a unit middle axis: a one-row block of [S, 1, d]
+        # has its last two dims equal to the array's, which the TPU
+        # lowering's block-shape rule requires ((1, d) over [S, d] is refused)
         in_specs=[
-            pl.BlockSpec((1, d_in), lambda s, ids: (s, 0)),
+            pl.BlockSpec((1, 1, d_in), lambda s, ids: (s, 0, 0)),
             pl.BlockSpec((1, d_in, r), lambda s, ids: (ids[s], 0, 0)),
             pl.BlockSpec((1, r, d_out), lambda s, ids: (ids[s], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, d_out), lambda s, ids: (s, 0)),
+        out_specs=pl.BlockSpec((1, 1, d_out), lambda s, ids: (s, 0, 0)),
     )
     return pl.pallas_call(
         _bgmv_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_slots, d_out), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((s_slots, 1, d_out), x.dtype),
         interpret=interpret,
-    )(ids.astype(jnp.int32), x, a_stack, b_stack)
+    )(ids.astype(jnp.int32), x[:, None, :], a_stack, b_stack)[:, 0]
 
 
 # ---------------------------------------------------------------------------

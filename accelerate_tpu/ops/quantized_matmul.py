@@ -28,14 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-    # renamed TPUCompilerParams -> CompilerParams around jax 0.7
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-except ImportError:  # pragma: no cover
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import _on_tpu
 from ..utils.quantization import QuantizedTensor, dequantize
@@ -263,10 +256,10 @@ def quantized_matmul(x, qt: QuantizedTensor, *, block_m: int = 128, block_k: Opt
             ],
             out_specs=pl.BlockSpec((bm, f), lambda i, k: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((m, f), out_dtype),
-            scratch_shapes=[pltpu.VMEM((bm, f), jnp.float32)] if _HAS_PLTPU else [],
-            compiler_params=_CompilerParams(
+            scratch_shapes=[pltpu.VMEM((bm, f), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")
-            ) if _HAS_PLTPU else None,
+            ),
             interpret=interpret,
         )(x2, codes, scales)
         return out.reshape(*lead, f)
@@ -291,10 +284,10 @@ def quantized_matmul(x, qt: QuantizedTensor, *, block_m: int = 128, block_k: Opt
         ],
         out_specs=pl.BlockSpec((bm, bf), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, f), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bf), jnp.float32)] if _HAS_PLTPU else [],
-        compiler_params=_CompilerParams(
+        scratch_shapes=[pltpu.VMEM((bm, bf), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
-        ) if _HAS_PLTPU else None,
+        ),
         interpret=interpret,
     )(x2, codes, scales)
     return out.reshape(*lead, f)

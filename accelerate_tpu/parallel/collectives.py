@@ -61,31 +61,15 @@ def ppermute(x, axis_name: str, perm: Sequence[tuple[int, int]]):
     return lax.ppermute(x, axis_name, perm)
 
 
-def _axis_size(axis_name: str):
-    """lax.axis_size across jax versions (older jax spells it psum(1, axis))."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
 def partial_manual_kwargs(axis_names) -> dict:
-    """shard_map kwargs for a region manual over only ``axis_names`` with
-    the replication check off, across the jax API generations.  New jax
-    (jax.shard_map) takes ``axis_names``/``check_vma``; old jax
-    (jax.experimental.shard_map) has neither — there the region degrades to
-    fully-manual over the whole mesh with ``check_rep`` off, which is
-    equivalent whenever the remaining mesh axes are trivial (the CPU test
-    meshes) and best-effort otherwise."""
-    import jax as _jax
-
-    if hasattr(_jax, "shard_map"):
-        return {"axis_names": set(axis_names), "check_vma": False}
-    return {"check_rep": False}
+    """``jax.shard_map`` kwargs for a region manual over only
+    ``axis_names`` with the replication check off."""
+    return {"axis_names": set(axis_names), "check_vma": False}
 
 
 def ring_permute(x, axis_name: str, shift: int = 1):
     """Rotate shards around the ring by ``shift`` (ICI-neighbor traffic)."""
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis_name, perm)
 
@@ -101,7 +85,7 @@ def axis_index(axis_name: str):
 
 
 def axis_size(axis_name: str):
-    return _axis_size(axis_name)
+    return lax.axis_size(axis_name)
 
 
 def broadcast_from(x, axis_name: str, src: int = 0):
@@ -114,7 +98,7 @@ def broadcast_from(x, axis_name: str, src: int = 0):
     multiply-by-mask so non-finite values on non-source ranks cannot poison
     the sum; bools ride as int32 through the reduction.
     """
-    n = _axis_size(axis_name)  # static int (axis extents are trace-time)
+    n = lax.axis_size(axis_name)  # static int (axis extents are trace-time)
     if isinstance(n, int) and not 0 <= src < n:
         # the old gather-then-index form raised at trace time on a bad src;
         # an unmatched one-hot would instead psum to silent zeros

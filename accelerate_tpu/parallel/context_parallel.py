@@ -30,10 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .collectives import axis_size, partial_manual_kwargs

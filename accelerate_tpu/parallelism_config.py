@@ -216,20 +216,16 @@ class ParallelismConfig:
         # Auto axis types = classic GSPMD propagation from in_shardings.
         # (jax>=0.9 make_mesh defaults to the new Explicit sharding-in-types
         # mode, which changes jit semantics — not what a prepare()-style
-        # framework wants.  Older jax has no AxisType at all — Auto is the
-        # only behavior there, so omitting the kwarg is equivalent.)
-        try:
-            type_kwargs = {"axis_types": (jax.sharding.AxisType.Auto,) * len(MESH_AXIS_ORDER)}
-        except AttributeError:  # pragma: no cover - jax < 0.5
-            type_kwargs = {}
-        try:
-            # Topology-aware assignment (ICI-ring friendly) when available.
-            if self.devices is None and devices == list(jax.devices()):
-                return jax.make_mesh(shape, MESH_AXIS_ORDER, devices=devices, **type_kwargs)
-        except Exception:
-            pass
+        # framework wants.)
+        axis_types = (jax.sharding.AxisType.Auto,) * len(MESH_AXIS_ORDER)
+        if self.devices is None and devices == list(jax.devices()):
+            # Topology-aware assignment (ICI-ring friendly) over the whole
+            # backend; a mesh jax cannot build is an error, never a silent
+            # plain reshape.
+            return jax.make_mesh(shape, MESH_AXIS_ORDER, devices=devices,
+                                 axis_types=axis_types)
         mesh_devices = np.asarray(devices).reshape(shape)
-        return Mesh(mesh_devices, MESH_AXIS_ORDER, **type_kwargs)
+        return Mesh(mesh_devices, MESH_AXIS_ORDER, axis_types=axis_types)
 
     # -- convenience specs -------------------------------------------------
 

@@ -518,3 +518,15 @@ class GradientState:
 
 def is_initialized() -> bool:
     return AcceleratorState._shared_state != {}
+
+
+def ambient_mesh() -> Optional[jax.sharding.Mesh]:
+    """The live :class:`AcceleratorState`'s mesh — what trace-time code
+    (ring collective matmuls, the per-shard Mosaic kernel wrap) partitions
+    over — or ``None`` when no Accelerator has been built."""
+    if not is_initialized():
+        return None
+    try:
+        return AcceleratorState().mesh
+    except Exception:  # pragma: no cover - half-built state
+        return None

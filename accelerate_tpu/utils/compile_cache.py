@@ -1,32 +1,28 @@
-"""Scoped JAX persistent compilation-cache management.
+"""JAX persistent compilation-cache placement — one rule, one setter.
 
-The suite and the bench are compile-dominated on CPU hosts, so both lean on
-``jax_compilation_cache_dir`` — but one flat ``/tmp`` directory shared by
-every process proved fragile: **concurrent jax processes corrupt the shared
-cache** (documented segfault/garbage flakes on this rig), and entries from a
-different jax build are dead weight at best.  This module gives every run a
-**scoped** cache directory instead (the first slice of ROADMAP item 4's
-compilation-cache management):
+Every entry point (``chip_smoke.py``, ``bench.py``, the fleet router, the
+fabric scripts, ``tests/conftest.py``) places its cache through
+:func:`enable_scoped_compilation_cache`, and the rule lives here only:
 
-- keyed by ``jax``/Python version, so an upgraded toolchain never reads a
-  stale cache;
-- keyed by a **tag** per harness (``tests``, ``bench``, ...), so the suite
-  and bench subprocesses never share a directory;
-- optionally keyed by a **scope** for concurrent runs: the
-  ``ACCELERATE_JAX_CACHE_SCOPE`` env var, or — automatically — the
-  pytest-xdist worker id, so parallel test workers each get a private cache
-  (the exact shape of the documented corruption).
+- ``JAX_COMPILATION_CACHE_DIR`` **set**: that directory is the cache.  jax
+  reads the variable itself; this module sets no other path in code, so
+  whoever runs the program decides where compiled programs persist.
+- **unset**: one fixed, git-ignored directory inside the checkout,
+  ``<repo>/.jax_cache`` — never a temporary name, a pid or a time: the
+  directory is part of the cache key, so a path that moves never hits.
+  Below it the cache is keyed by toolchain (``jax``/Python version — an
+  upgraded toolchain never reads a stale cache), by a **tag** per harness
+  (``tests``, ``bench``, ``smoke`` ...) and, for concurrent runs, by a
+  scope: ``ACCELERATE_JAX_CACHE_SCOPE``, the pytest-xdist worker id, and
+  the launched process id — concurrent jax processes never share a leaf.
 
-``ACCELERATE_JAX_CACHE_ROOT`` moves the whole tree off ``/tmp``.
-
-**Prewarm distribution** (the remaining slice of ROADMAP item 4):
-:func:`export_prewarm` packs a warmed scoped cache into one
-toolchain-keyed archive, and :func:`load_prewarm` unpacks it on a deploy
-host BEFORE the preflight/warmup — so production startup pays zero cold
-compiles even on a fresh machine.  Loads are **version-keyed**: a pack
-from a different jax/Python build is refused (its entries could never
-hit), and every stale-version directory under the cache root is swept on
-load, so upgraded toolchains never accumulate dead weight.
+**Prewarm distribution**: :func:`export_prewarm` packs the directory in
+force into one toolchain-keyed archive, and :func:`load_prewarm` unpacks it
+on a deploy host BEFORE the preflight/warmup — so production startup pays
+zero cold compiles even on a fresh machine.  Loads are **version-keyed**: a
+pack from a different jax/Python build is refused (its entries could never
+hit), and every stale-version directory under the in-checkout root is swept
+on load, so upgraded toolchains never accumulate dead weight.
 """
 
 from __future__ import annotations
@@ -37,9 +33,11 @@ import shutil
 import sys
 import tarfile
 from pathlib import Path
-from typing import Optional
 
 PREWARM_MANIFEST = "prewarm_manifest.json"
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+# the fixed in-checkout root used when CACHE_DIR_ENV is unset (.gitignore'd)
+CACHE_ROOT = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
 def toolchain_version_key() -> str:
@@ -48,12 +46,6 @@ def toolchain_version_key() -> str:
     import jax
 
     return f"jax{jax.__version__}-py{sys.version_info.major}.{sys.version_info.minor}"
-
-
-def _cache_root(root: Optional[str] = None) -> Path:
-    return Path(root or os.environ.get(
-        "ACCELERATE_JAX_CACHE_ROOT", "/tmp/accelerate_tpu_jax_cache"
-    ))
 
 
 def _process_scope() -> str:
@@ -80,27 +72,31 @@ def _process_scope() -> str:
     return ""
 
 
-def scoped_cache_dir(tag: str = "run", root: Optional[str] = None) -> str:
-    """The scoped cache directory for this (toolchain, tag, scope, process)
-    — created if missing, returned as a string path."""
-    scope = os.environ.get("ACCELERATE_JAX_CACHE_SCOPE") or os.environ.get(
-        "PYTEST_XDIST_WORKER", ""
-    )
-    proc = _process_scope()
-    leaf = "-".join(part for part in (tag, scope, proc) if part)
-    path = _cache_root(root) / toolchain_version_key() / leaf
+def scoped_cache_dir(tag: str = "run") -> str:
+    """The cache directory in force — created if missing.
+    ``JAX_COMPILATION_CACHE_DIR`` verbatim when set; otherwise the
+    (toolchain, tag, scope, process) leaf under the in-checkout root."""
+    env = os.environ.get(CACHE_DIR_ENV)
+    if env:
+        path = Path(env)
+    else:
+        scope = os.environ.get("ACCELERATE_JAX_CACHE_SCOPE") or os.environ.get(
+            "PYTEST_XDIST_WORKER", ""
+        )
+        leaf = "-".join(part for part in (tag, scope, _process_scope()) if part)
+        path = CACHE_ROOT / toolchain_version_key() / leaf
     path.mkdir(parents=True, exist_ok=True)
     return str(path)
 
 
-def export_prewarm(dest: str, tag: str = "run", *, root: Optional[str] = None) -> str:
-    """Pack the scoped compilation cache into one distributable archive.
+def export_prewarm(dest: str, tag: str = "run") -> str:
+    """Pack the compilation cache in force into one distributable archive.
 
     The archive carries a manifest keyed by :func:`toolchain_version_key`
     and ``tag``; ship it to deploy hosts and :func:`load_prewarm` it before
     ``preflight``/``warmup`` — the whole bucket ladder then compiles from
     cache hits.  Returns the archive path."""
-    src = Path(scoped_cache_dir(tag, root))
+    src = Path(scoped_cache_dir(tag))
     dest_path = Path(dest)
     dest_path.parent.mkdir(parents=True, exist_ok=True)
     entries = sorted(p.name for p in src.iterdir() if p.is_file())
@@ -121,32 +117,31 @@ def export_prewarm(dest: str, tag: str = "run", *, root: Optional[str] = None) -
     return str(dest_path)
 
 
-def sweep_stale_versions(root: Optional[str] = None) -> list[str]:
-    """Remove every cache-root subdirectory keyed by a DIFFERENT toolchain
-    than the current one (the version-keyed eviction: an upgraded jax or
-    Python never reads — or pays disk for — a stale cache).  Returns the
-    swept directory names."""
-    root_path = _cache_root(root)
+def sweep_stale_versions() -> list[str]:
+    """Remove every subdirectory of the in-checkout cache root keyed by a
+    DIFFERENT toolchain than the current one (the version-keyed eviction: an
+    upgraded jax or Python never reads — or pays disk for — a stale cache).
+    A ``JAX_COMPILATION_CACHE_DIR`` placed from outside has no toolchain
+    leaves and is not this module's to sweep.  Returns the swept names."""
     current = toolchain_version_key()
     swept = []
-    if not root_path.is_dir():
+    if os.environ.get(CACHE_DIR_ENV) or not CACHE_ROOT.is_dir():
         return swept
-    for child in sorted(root_path.iterdir()):
+    for child in sorted(CACHE_ROOT.iterdir()):
         if child.is_dir() and child.name != current:
             shutil.rmtree(child, ignore_errors=True)
             swept.append(child.name)
     return swept
 
 
-def load_prewarm(archive: str, tag: str = "run", *,
-                 root: Optional[str] = None) -> dict:
-    """Unpack a prewarm archive into this host's scoped cache directory.
+def load_prewarm(archive: str, tag: str = "run") -> dict:
+    """Unpack a prewarm archive into the cache directory in force.
 
     Version-keyed: an archive built by a different toolchain is REFUSED
     (``{"loaded": 0, "stale": True}`` — its entries could never hit and a
     deserialized foreign executable is exactly the corruption class the
     scoped dirs retired).  Either way, stale-version directories under the
-    cache root are swept.  Never raises on a bad archive — a broken
+    in-checkout root are swept.  Never raises on a bad archive — a broken
     prewarm pack degrades to a cold start, not a failed deploy."""
     report = {"loaded": 0, "stale": False, "swept": [], "version_key": toolchain_version_key()}
     try:
@@ -159,7 +154,7 @@ def load_prewarm(archive: str, tag: str = "run", *,
             if manifest.get("version_key") != toolchain_version_key():
                 report["stale"] = True
             else:
-                dest = Path(scoped_cache_dir(tag, root))
+                dest = Path(scoped_cache_dir(tag))
                 for m in tar.getmembers():
                     name = m.name
                     if not (m.isfile() and name.startswith("cache/")):
@@ -173,27 +168,25 @@ def load_prewarm(archive: str, tag: str = "run", *,
     except (OSError, tarfile.TarError, json.JSONDecodeError) as e:
         report["stale"] = True
         report["error"] = str(e)
-    report["swept"] = sweep_stale_versions(root)
+    report["swept"] = sweep_stale_versions()
     return report
 
 
 def enable_scoped_compilation_cache(
     tag: str = "run",
     *,
-    root: Optional[str] = None,
     min_compile_time_secs: float = 0.5,
     min_entry_size_bytes: int = 0,
-) -> Optional[str]:
-    """Point jax's persistent compilation cache at the scoped directory.
-    Returns the directory, or ``None`` when this jax build lacks the knobs
-    (older releases — the run proceeds uncached, never fails)."""
+) -> str:
+    """Turn jax's persistent compilation cache on for the directory in
+    force and return it.  The one place the path is set in code — and only
+    when ``JAX_COMPILATION_CACHE_DIR`` is unset (jax reads the variable
+    itself; a placement made from outside is never overridden)."""
     import jax
 
-    try:
-        d = scoped_cache_dir(tag, root)
+    d = scoped_cache_dir(tag)
+    if not os.environ.get(CACHE_DIR_ENV):
         jax.config.update("jax_compilation_cache_dir", d)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", min_compile_time_secs)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", min_entry_size_bytes)
-        return d
-    except Exception:  # pragma: no cover - older jax without the knobs
-        return None
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", min_compile_time_secs)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", min_entry_size_bytes)
+    return d

@@ -73,6 +73,11 @@ def _base_env(args, config) -> dict[str, str]:
         p for p in (pkg_root, env.get("PYTHONPATH")) if p
     )
     env.update(config_env(config))
+    if config.use_cpu:
+        # a chip belongs to one process: CPU workers must never reach for it
+        # (the launching process, or a sibling, may hold it), not even before
+        # their PartialState switches the platform
+        env["JAX_PLATFORMS"] = "cpu"
     return env
 
 
@@ -147,6 +152,11 @@ def prepare_tpu_pod_env(args, config) -> Optional[dict[str, str]]:
     if worker_id is None or not hostnames:
         return None
     hosts = [h.strip() for h in hostnames.split(",") if h.strip()]
+    if len(hosts) < 2:
+        # a one-host TPU VM exports the same variables: nothing to
+        # coordinate, and a worker handed a coordinator address must call
+        # jax.distributed.initialize before its first jax call
+        return None
     config.num_processes = len(hosts)
     config.machine_rank = int(worker_id)
     config.main_process_ip = hosts[0]

@@ -1,5 +1,5 @@
 """Corrected twins of ``planted_ast_rules.py`` — graft-lint must stay
-quiet on every one of these (GL202 host syncs, GL203 shard_map import,
+quiet on every one of these (GL202 host syncs,
 GL204 impure calls under trace)."""
 
 import time
@@ -34,8 +34,3 @@ def make_inputs(x):
     # impurity lives outside the trace, threaded in per call
     return x, time.time(), jax.random.key(0)
 
-
-try:
-    from jax import shard_map  # noqa: F401
-except ImportError:  # older jax — the sanctioned compat fallback shape
-    from jax.experimental.shard_map import shard_map  # noqa: F401

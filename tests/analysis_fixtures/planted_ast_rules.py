@@ -1,4 +1,4 @@
-"""PLANTED BUGS — one per AST rule (GL202/GL203/GL204).
+"""PLANTED BUGS — one per AST rule (GL202/GL204).
 
 Linted as source only, never imported.  Each planted call sits inside a
 function the engine must recognize as a jit context (decorated, passed to
@@ -36,4 +36,3 @@ def step_with_impurity(x, seed):
 
 jitted_impure = jax.jit(step_with_impurity, static_argnums=(1,))
 
-from jax.experimental.shard_map import shard_map  # noqa: E402,F401  GL203: no compat fallback

@@ -162,7 +162,7 @@ def test_every_emitted_rule_is_in_the_catalog():
     # all three engines draw severities/hints from rules.RULES; ids must resolve
     for rule_id in ("GL001", "GL002", "GL101", "GL102", "GL103", "GL104",
                     "GL105", "GL106", "GL107", "GL108", "GL110", "GL201",
-                    "GL202", "GL203", "GL204", "GL205", "GL301", "GL302",
+                    "GL202", "GL204", "GL205", "GL301", "GL302",
                     "GL303", "GL304", "GL305", "GL306", "GL401", "GL402",
                     "GL403", "GL404"):
         assert rule_id in RULES
@@ -406,18 +406,6 @@ def test_ast_float_only_flagged_on_traced_parameters():
     assert [(f.rule, f.line) for f in findings] == [("GL202", 4)]
 
 
-def test_ast_shard_map_compat_fallback_is_allowed():
-    good = (
-        "try:\n"
-        "    from jax import shard_map\n"
-        "except ImportError:\n"
-        "    from jax.experimental.shard_map import shard_map\n"
-    )
-    assert lint_source(good, "m.py") == []
-    bad = "from jax.experimental.shard_map import shard_map\n"
-    assert _rules_of(lint_source(bad, "m.py")) == {"GL203"}
-
-
 def test_ast_impure_in_jit_variants():
     src = (
         "import time, random\n"
@@ -493,12 +481,8 @@ def test_lint_paths_reports_missing_explicit_target(tmp_path):
 
 def test_directory_sweeps_prune_vendored_dirs(tmp_path):
     (tmp_path / ".venv" / "lib").mkdir(parents=True)
-    (tmp_path / ".venv" / "lib" / "vendored.py").write_text(
-        "from jax.experimental.shard_map import shard_map\n"
-    )
-    (tmp_path / "mine.py").write_text(
-        "from jax.experimental.shard_map import shard_map\n"
-    )
+    (tmp_path / ".venv" / "lib" / "vendored.py").write_text("import jax\n@jax.jit\ndef f(x):\n    return x.item()\n")
+    (tmp_path / "mine.py").write_text("import jax\n@jax.jit\ndef f(x):\n    return x.item()\n")
     rep = lint_paths([tmp_path])
     assert [Path(f.path).name for f in rep.unsuppressed()] == ["mine.py"]
 
@@ -526,7 +510,7 @@ def test_fixture_snapshot_race_planted_vs_clean():
 
 def test_fixture_ast_planted_all_rules_fire():
     rep = lint_paths([FIXTURES / "planted_ast_rules.py"], excludes=())
-    assert _rules_of(rep) == {"GL202", "GL203", "GL204"}, rep.render()
+    assert _rules_of(rep) == {"GL202", "GL204"}, rep.render()
     # every planted host-sync variant is individually caught
     gl202 = [f for f in rep.unsuppressed() if f.rule == "GL202"]
     assert len(gl202) == 4  # .item / np.asarray / float(param) / .tolist

@@ -238,6 +238,21 @@ def test_tpu_pod_env_autodetect(monkeypatch):
     assert env["ACCELERATE_COORDINATOR_ADDRESS"].startswith("host0:")
 
 
+def test_tpu_pod_env_one_host_is_not_a_pod(monkeypatch):
+    """A one-host TPU VM exports the pod variables too: no coordinator is
+    handed out (a worker given one must call jax.distributed.initialize
+    before its first jax call — a script that probes jax.devices() first,
+    as chip_smoke.py did on the chip, would die in the launcher's env)."""
+    from accelerate_tpu.utils.launch import prepare_tpu_pod_env
+
+    monkeypatch.setenv("TPU_WORKER_ID", "0")
+    monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "localhost")
+    args = _parse_launch(["script.py"])
+    config = _merge_args_into_config(args, LaunchConfig())
+    assert prepare_tpu_pod_env(args, config) is None
+    assert config.num_processes == 1 and config.main_process_ip is None
+
+
 def test_estimate_param_sizes():
     total, largest, per_module = abstract_param_sizes(
         "llama",

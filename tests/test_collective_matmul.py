@@ -18,7 +18,7 @@ import optax
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from shard_map_compat import NO_CHECK, shard_map
+from jax import shard_map
 
 from accelerate_tpu.ops.collective_matmul import (
     all_gather_matmul_monolithic,
@@ -48,7 +48,7 @@ def _col_run(body, mesh, x, w):
     f = shard_map(
         body, mesh=mesh,
         in_specs=(P(None, "tp", None), P(None, "tp")),
-        out_specs=P(None, None, "tp"), **NO_CHECK,
+        out_specs=P(None, None, "tp"), check_vma=False,
     )
     return np.asarray(jax.jit(f)(x, w))
 
@@ -57,7 +57,7 @@ def _row_run(body, mesh, x, w):
     f = shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None, "tp"), P("tp", None)),
-        out_specs=P(None, "tp", None), **NO_CHECK,
+        out_specs=P(None, "tp", None), check_vma=False,
     )
     return np.asarray(jax.jit(f)(x, w))
 
@@ -213,11 +213,8 @@ def test_ring_supported_gating(tp_mesh):
     assert not ring_supported(None, "tp")
     one = Mesh(np.asarray(jax.devices()[:1]).reshape(1), ("tp",))
     assert not ring_supported(one, "tp")           # trivial ring
-    if not hasattr(jax, "shard_map"):
-        # old-jax compat: fully-manual degradation only exact when every
-        # other axis is trivial — multi-axis meshes must fall back
-        multi = Mesh(np.asarray(jax.devices()).reshape(4, 2), ("dp_shard", "tp"))
-        assert not ring_supported(multi, "tp")
+    multi = Mesh(np.asarray(jax.devices()).reshape(4, 2), ("dp_shard", "tp"))
+    assert ring_supported(multi, "tp")             # partial-manual over tp only
 
 
 def test_dense_hook_fallbacks(monkeypatch):

@@ -29,14 +29,9 @@ from accelerate_tpu.utils.dataclasses import (
     ShardingStrategy,
 )
 
-try:
-    from jax import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
-    _NO_CHECK = {"check_vma": False}
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _NO_CHECK = {"check_rep": False}
+_NO_CHECK = {"check_vma": False}
 
 
 def _fresh():

@@ -165,7 +165,7 @@ def test_ulysses_in_jitted_train_step(sp_mesh):
 
 
 def test_cross_rank_token_mean(sp_mesh):
-    from shard_map_compat import NO_CHECK, shard_map
+    from jax import shard_map
 
     from accelerate_tpu.parallel.sequence_parallel import cross_rank_token_mean
 
@@ -176,7 +176,7 @@ def test_cross_rank_token_mean(sp_mesh):
         return cross_rank_token_mean(loss, mask, ("sp",))
 
     f = shard_map(body, mesh=sp_mesh, in_specs=(P(None, "sp"), P(None, "sp")),
-                  out_specs=P(), **NO_CHECK)
+                  out_specs=P(), check_vma=False)
     out = float(f(loss, mask))
     assert out == pytest.approx(float(jnp.mean(loss)))
 
@@ -516,3 +516,126 @@ def test_sp_composes_with_scanned_offload_ladder():
         losses.append(float(metrics["loss"]))
     assert np.isfinite(losses).all(), losses
     assert losses[-1] < losses[0], losses
+
+
+# ---------------------------------------------------------------------------
+# flash under a mesh: the per-shard wrap.  GSPMD cannot partition a Mosaic
+# call, so `attn_implementation="flash"` goes manual over whatever axes the
+# enclosing region still leaves Auto (tests/test_tpu_compile.py holds the TPU
+# compiler's side of this; here: the regions it must nest in, on the CPU mesh)
+# ---------------------------------------------------------------------------
+
+
+def _shard_map_axes(jaxpr):
+    """The manual-axes set of every shard_map in ``jaxpr``, outermost first."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "shard_map":
+            found.append(frozenset(eqn.params["manual_axes"]))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.extend(_shard_map_axes(sub))
+    return found
+
+
+@pytest.mark.parametrize("segmented", [False, True], ids=["plain", "segment_ids"])
+def test_mesh_flash_runs_per_shard_and_equals_the_bare_kernel(segmented):
+    from accelerate_tpu import Accelerator
+    from accelerate_tpu.ops.flash_attention import mesh_flash_attention
+
+    acc = Accelerator(parallelism_config=ParallelismConfig(dp_shard_size=4, tp_size=2))
+    q, k, v = _qkv(b=4)
+    kw = dict(causal=True, block_q=8, block_k=8, interpret=True)
+    if segmented:
+        kw["segment_ids"] = jnp.asarray(np.repeat([[0, 1]], 16, axis=1).repeat(4, 0))
+    # out and its three cotangents (the scalar loss itself sums across shards)
+    both = lambda attn: lambda q, k, v: (attn(q, k, v, **kw), jax.grad(
+        lambda q, k, v: jnp.sum(attn(q, k, v, **kw) ** 2), argnums=(0, 1, 2))(q, k, v))
+    wrapped = jax.jit(both(mesh_flash_attention))
+    for got, want in zip(jax.tree.leaves(wrapped(q, k, v)),
+                         jax.tree.leaves(jax.jit(both(flash_attention))(q, k, v))):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert set(_shard_map_axes(wrapped.trace(q, k, v).jaxpr.jaxpr)) == {
+        frozenset(acc.mesh.axis_names)}
+
+
+@pytest.mark.parametrize("outer,inner", [
+    ({"pp"}, {"dcn", "dp_replicate", "dp_shard", "cp", "sp", "tp", "ep"}),
+    (None, None),  # outer region fully manual: nothing left to wrap
+], ids=["pipeline_stage", "fully_manual"])
+def test_mesh_flash_nests_in_a_region_that_is_already_manual(outer, inner):
+    """jax rejects a concrete-mesh shard_map inside a manual region: the wrap
+    must read the CONTEXT mesh, take only the axes still Auto, and be the
+    bare kernel where the region (PowerSGD / hierarchical grad sync) left
+    none."""
+    from accelerate_tpu import Accelerator
+    from accelerate_tpu.ops.flash_attention import mesh_flash_attention
+
+    acc = Accelerator(parallelism_config=ParallelismConfig(
+        pp_size=2, dp_shard_size=2, tp_size=2))
+    q, k, v = _qkv(b=4)
+    kw = dict(causal=True, block_q=8, block_k=8, interpret=True)
+    batch = P(None if outer else "dp_shard")
+    region = jax.jit(jax.shard_map(
+        lambda q, k, v: mesh_flash_attention(q, k, v, **kw), mesh=acc.mesh,
+        in_specs=batch, out_specs=batch, check_vma=False,
+        **({"axis_names": outer} if outer else {})))
+    np.testing.assert_array_equal(
+        np.asarray(region(q, k, v)), np.asarray(flash_attention(q, k, v, **kw)))
+    nested = _shard_map_axes(region.trace(q, k, v).jaxpr.jaxpr)[1:]
+    assert nested == ([frozenset(inner)] if inner else [])
+
+
+@pytest.mark.parametrize("region", ["pipeline_stage", "powersgd", "hierarchical"])
+def test_flash_model_inside_manual_regions_matches_native(region):
+    """The supported combinations that trace the model inside a shard_map:
+    a GPipe stage (manual over pp), and the compressed / hierarchical
+    grad-sync steps (manual over every axis)."""
+    import optax
+
+    from accelerate_tpu import (
+        Accelerator,
+        FullyShardedDataParallelPlugin,
+        GradSyncKwargs,
+    )
+    from accelerate_tpu.models import LlamaConfig, LlamaForCausalLM, make_llama_loss_fn
+    from accelerate_tpu.parallel.pipeline_parallel import prepare_pipeline
+    from accelerate_tpu.state import AcceleratorState, GradientState
+    from accelerate_tpu.utils.dataclasses import ShardingStrategy
+
+    models = {impl: LlamaForCausalLM(LlamaConfig.tiny(
+        num_hidden_layers=2, attn_implementation=impl, dtype=jnp.float32))
+        for impl in ("flash", "native")}
+    ids = jnp.asarray(np.random.default_rng(0).integers(0, 256, (8, 16)), jnp.int32)
+    params = models["native"].init(jax.random.key(0), ids[:, :8])
+
+    if region == "pipeline_stage":
+        acc = Accelerator(parallelism_config=ParallelismConfig(
+            pp_size=2, dp_shard_size=2, tp_size=2))
+        got = prepare_pipeline(models["flash"], params, acc.mesh, num_microbatches=4)(ids)
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(models["native"].apply(params, ids)),
+            atol=2e-4, rtol=2e-4)
+        return
+
+    ddp = FullyShardedDataParallelPlugin(sharding_strategy=ShardingStrategy.NO_SHARD)
+    setup = {
+        "powersgd": (ParallelismConfig(dp_shard_size=8),
+                     GradSyncKwargs(compression="powersgd", rank=2)),
+        "hierarchical": (ParallelismConfig(dcn_size=2, dp_shard_size=4),
+                         GradSyncKwargs(hierarchical=True)),
+    }[region]
+    losses = {}
+    for impl, model in models.items():
+        AcceleratorState._reset_state(reset_partial_state=True)
+        GradientState._reset_state()
+        acc = Accelerator(parallelism_config=setup[0], fsdp_plugin=ddp,
+                          kwargs_handlers=[setup[1]])
+        state = acc.create_train_state(  # the step donates its state: a copy each
+            jax.tree.map(jnp.copy, params), acc.prepare(optax.sgd(0.05)))
+        step = acc.prepare_train_step(make_llama_loss_fn(model))
+        losses[impl] = []
+        for _ in range(2):
+            state, metrics = step(state, {"input_ids": ids, "labels": ids})
+            losses[impl].append(float(metrics["loss"]))
+    assert losses["flash"][1] < losses["flash"][0]
+    np.testing.assert_allclose(losses["flash"], losses["native"], atol=1e-4)

@@ -115,7 +115,7 @@ def test_listify():
 
 
 def test_psum_over_mesh(mesh8):
-    from shard_map_compat import shard_map
+    from jax import shard_map
 
     x = jnp.arange(8.0)
 
@@ -128,20 +128,20 @@ def test_psum_over_mesh(mesh8):
 
 
 def test_all_gather_over_mesh(mesh8):
-    from shard_map_compat import NO_CHECK, shard_map
+    from jax import shard_map
 
     x = jnp.arange(8.0)
 
     def body(x):
         return collectives.all_gather(x, "dp_shard", axis=0, tiled=True)
 
-    f = shard_map(body, mesh=mesh8, in_specs=P("dp_shard"), out_specs=P(None), **NO_CHECK)
+    f = shard_map(body, mesh=mesh8, in_specs=P("dp_shard"), out_specs=P(None), check_vma=False)
     out = f(x)
     np.testing.assert_allclose(np.asarray(out), np.arange(8.0))
 
 
 def test_ring_permute(mesh8):
-    from shard_map_compat import shard_map
+    from jax import shard_map
 
     x = jnp.arange(8.0)
 
@@ -154,7 +154,7 @@ def test_ring_permute(mesh8):
 
 
 def test_reduce_scatter(mesh8):
-    from shard_map_compat import shard_map
+    from jax import shard_map
 
     x = jnp.ones((64, 8))
 
@@ -170,7 +170,7 @@ def test_reduce_scatter(mesh8):
 
 
 def test_all_to_all(mesh8):
-    from shard_map_compat import shard_map
+    from jax import shard_map
 
     x = jnp.arange(64.0).reshape(8, 8)
 
@@ -184,7 +184,7 @@ def test_all_to_all(mesh8):
 
 
 def test_ring_permute_larger_and_negative_shift(mesh8):
-    from shard_map_compat import shard_map
+    from jax import shard_map
 
     x = jnp.arange(8.0)
 
@@ -203,7 +203,7 @@ def test_ring_permute_larger_and_negative_shift(mesh8):
 
 
 def test_all_to_all_values(mesh8):
-    from shard_map_compat import shard_map
+    from jax import shard_map
 
     x = jnp.arange(64.0).reshape(8, 8)
 
@@ -220,7 +220,7 @@ def test_all_to_all_values(mesh8):
 
 
 def test_broadcast_from_nonzero_src(mesh8):
-    from shard_map_compat import NO_CHECK, shard_map
+    from jax import shard_map
 
     x = jnp.arange(8.0) * 10.0
 
@@ -229,7 +229,7 @@ def test_broadcast_from_nonzero_src(mesh8):
             return collectives.broadcast_from(x, "dp_shard", src=src)
 
         return shard_map(inner, mesh=mesh8, in_specs=P("dp_shard"),
-                         out_specs=P("dp_shard"), **NO_CHECK)
+                         out_specs=P("dp_shard"), check_vma=False)
 
     for src in (0, 3, 7):
         out = np.asarray(body(src)(x))
@@ -239,11 +239,11 @@ def test_broadcast_from_nonzero_src(mesh8):
 def test_broadcast_from_rejects_out_of_range_src(mesh8):
     # the old gather-then-index form raised at trace time on a bad src; the
     # one-hot+psum rewrite must not degrade that into silent zeros
-    from shard_map_compat import NO_CHECK, shard_map
+    from jax import shard_map
 
     f = shard_map(
         lambda x: collectives.broadcast_from(x, "dp_shard", src=8),
-        mesh=mesh8, in_specs=P("dp_shard"), out_specs=P("dp_shard"), **NO_CHECK,
+        mesh=mesh8, in_specs=P("dp_shard"), out_specs=P("dp_shard"), check_vma=False,
     )
     with pytest.raises(ValueError, match="out of range"):
         f(jnp.arange(8.0))
@@ -252,7 +252,7 @@ def test_broadcast_from_rejects_out_of_range_src(mesh8):
 def test_broadcast_from_pins_old_gather_select_behavior(mesh8):
     """The O(n) one-hot+psum broadcast must be drop-in for the previous
     all-gather-then-index implementation, including 2-D payloads and bools."""
-    from shard_map_compat import NO_CHECK, shard_map
+    from jax import shard_map
     from jax import lax
 
     def old_broadcast(x, axis_name, src):
@@ -265,19 +265,19 @@ def test_broadcast_from_pins_old_gather_select_behavior(mesh8):
         new = shard_map(
             lambda x: collectives.broadcast_from(x, "dp_shard", src=src),
             mesh=mesh8, in_specs=P("dp_shard", None), out_specs=P("dp_shard", None),
-            **NO_CHECK,
+            check_vma=False,
         )(x2d)
         old = shard_map(
             lambda x: old_broadcast(x, "dp_shard", src),
             mesh=mesh8, in_specs=P("dp_shard", None), out_specs=P("dp_shard", None),
-            **NO_CHECK,
+            check_vma=False,
         )(x2d)
         np.testing.assert_array_equal(np.asarray(new), np.asarray(old))
 
     flags = jnp.asarray([True, False] * 4)
     got = shard_map(
         lambda x: collectives.broadcast_from(x, "dp_shard", src=2),
-        mesh=mesh8, in_specs=P("dp_shard"), out_specs=P("dp_shard"), **NO_CHECK,
+        mesh=mesh8, in_specs=P("dp_shard"), out_specs=P("dp_shard"), check_vma=False,
     )(flags)
     assert got.dtype == jnp.bool_
     np.testing.assert_array_equal(np.asarray(got), np.full(8, True))

@@ -45,14 +45,6 @@ def test_stack_unstack_roundtrip():
     assert all(np.allclose(a, b) for a, b in zip(orig, new))
 
 
-@pytest.mark.xfail(
-    condition=not hasattr(jax, "shard_map"),
-    reason="old jax (no jax.shard_map): partial_manual_kwargs degrades the "
-           "pipeline region to fully-manual, and the bf16 forward drifts "
-           "~1.5% of elements just past the 2e-2 parity tolerance — the "
-           "schedule itself still runs and differentiates (tests below)",
-    strict=False,
-)
 @pytest.mark.parametrize("num_microbatches", [2, 4, 8])
 def test_pipeline_matches_plain_forward(num_microbatches):
     cfg, model, params, ids = _tiny_model(num_layers=4)

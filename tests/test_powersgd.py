@@ -135,13 +135,13 @@ def test_powersgd_exact_when_rank_spans_gradient():
         )
         return g_hat, jax.tree_util.tree_map(lambda e: e[None], new_errs)
 
-    from shard_map_compat import NO_CHECK, shard_map
+    from jax import shard_map
 
     P = jax.sharding.PartitionSpec
     g_hat, new_errs = jax.jit(shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(("dp_shard",))), out_specs=(P(), P(("dp_shard",))),
-        **NO_CHECK,
+        check_vma=False,
     ))(qs, errs)
     np.testing.assert_allclose(np.asarray(g_hat["w"]), np.asarray(g_global), rtol=1e-4, atol=1e-4)
     assert float(jnp.abs(new_errs["w"]).max()) < 1e-4

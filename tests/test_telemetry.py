@@ -146,7 +146,7 @@ def test_all_standard_twins_register_from_their_accounting_sites():
     params = {"w": np.ones((8, 8), np.float32)}
     dcn_comm_accounting(params, ici_size=2, dcn_size=2)
     # measured side via a tiny traced psum over a dcn mesh axis
-    from tests.shard_map_compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.array(jax.devices()[:2]).reshape(2), ("dcn",))

@@ -1,0 +1,220 @@
+"""AOT compiles of the main path's kernels for a DESCRIBED TPU v5e chip.
+
+The TPU compiler is installed here and compiles for a chip that is described,
+not attached (``topologies.get_topology_desc``): what it refuses here — a
+block shape off the (8, 128) tiling, a shape cast Mosaic cannot lay out, too
+much scoped VMEM — it would refuse on the chip, where the green
+interpret-mode tests say nothing.  Llama-2-7B widths (32 MHA heads x 128) and
+the 513M control cell's (16/8 GQA heads x 96), at the smoke's serving
+geometry (16 slots, page 64, 256 pages), each kernel on one described chip;
+the flash kernel also over the four of ``topo.devices`` as a mesh, where
+GSPMD refuses a bare Mosaic call.  A compile that passes is a compile, never
+a chip run.
+
+All in this ONE file, the topology described inside a module-scoped fixture
+(never at import, in a skipif or in parametrize arguments): only one process
+may hold the TPU library, so only the xdist worker that is handed this file
+loads it.  The compilation cache is off around these compiles (an entry
+written for a described chip cannot be read back without one).
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from accelerate_tpu.ops import flash_attention as fa
+from accelerate_tpu.ops.fused_xent import fused_causal_lm_loss
+from accelerate_tpu.ops.lora import bgmv
+from accelerate_tpu.ops.quantized_matmul import quantized_matmul
+from accelerate_tpu.utils.quantization import QuantizedTensor
+
+SLOTS, PAGE, PAGES, PAGES_PER_SLOT = 16, 64, 256, 16
+RANK, ADAPTERS = 16, 4
+BF16 = jnp.bfloat16
+# (q heads, kv heads, head_dim, hidden)
+LLAMA2_7B = (32, 32, 128, 4096)
+CONTROL_513M = (16, 8, 96, 1536)
+WIDTHS = pytest.mark.parametrize(
+    "h,hkv,d,hidden", [LLAMA2_7B, CONTROL_513M], ids=["llama2_7b", "control_513m"])
+KV_DTYPES = pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def compile_for_chip(one_chip):
+    """``compile_for_chip(fn, *(shape, dtype))`` -> compiled HLO text, with
+    the persistent compilation cache off for the module."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+
+    def run(fn, *specs):
+        args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                for shape, dtype in specs]
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+    yield run
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _pool_specs(hkv, d, kv_dtype):
+    """(k_pages, v_pages[, k_scales, v_scales]) operand specs."""
+    page = ((hkv, PAGES, PAGE, d), jnp.int8 if kv_dtype == "int8" else BF16)
+    scale = ((hkv, PAGES), jnp.float32)
+    return [page, page] + ([scale, scale] if kv_dtype == "int8" else [])
+
+
+def _scales(rest):
+    return dict(k_scales=rest[0], v_scales=rest[1]) if rest else {}
+
+
+@WIDTHS
+def test_flash_forward_and_backward_compile(compile_for_chip, h, hkv, d, hidden):
+    def fwd_bwd(q, k, v):
+        loss = lambda q, k, v: jnp.sum(fa.flash_attention(
+            q, k, v, causal=True, interpret=False).astype(jnp.float32))
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    text = compile_for_chip(fwd_bwd, ((2, 2048, h, d), BF16),
+                            ((2, 2048, hkv, d), BF16), ((2, 2048, hkv, d), BF16))
+    assert text.count("tpu_custom_call") >= 3  # fwd, dq, dk/dv
+
+
+@pytest.mark.parametrize("region", ["gspmd", "pipeline_stage", "bare_kernel"])
+def test_flash_under_a_four_chip_mesh(topo, compile_for_chip, region):
+    """The sharded step's attention over ``topo.devices`` with NamedSharding
+    operands, Llama-2-7B widths.  GSPMD cannot partition a Mosaic call (the
+    bare kernel is refused), so ``mesh_flash_attention`` goes manual over the
+    axes still Auto: all of them under plain GSPMD, the rest inside a GPipe
+    stage that is already manual over ``pp``.  Invisible on the CPU mesh,
+    where interpret-mode kernels are plain XLA."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from accelerate_tpu import Accelerator, ParallelismConfig
+
+    staged = region == "pipeline_stage"
+    acc = Accelerator(parallelism_config=ParallelismConfig(
+        tp_size=2, devices=list(topo.devices),
+        **({"pp_size": 2} if staged else {"dp_shard_size": 2})))
+    attn = fa.flash_attention if region == "bare_kernel" else fa.mesh_flash_attention
+
+    def fwd_bwd(q, k, v):
+        loss = lambda q, k, v: jnp.sum(attn(
+            q, k, v, causal=True, interpret=False).astype(jnp.float32))
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    if staged:
+        fwd_bwd = jax.shard_map(fwd_bwd, mesh=acc.mesh, in_specs=P(), out_specs=P(),
+                                axis_names={"pp"}, check_vma=False)
+    sharding = NamedSharding(acc.mesh, P(None if staged else "dp_shard", None, "tp", None))
+    h, hkv, d, _ = LLAMA2_7B
+    lowered = lambda: jax.jit(fwd_bwd).lower(*(
+        jax.ShapeDtypeStruct((4, 2048, heads, d), BF16, sharding=sharding)
+        for heads in (h, hkv, hkv)))
+    if region == "bare_kernel":
+        with pytest.raises(Exception, match="Mosaic kernels cannot be automatically partitioned"):
+            lowered().compile()
+    else:
+        assert lowered().compile().as_text().count("tpu_custom_call") >= 3
+
+
+@WIDTHS
+@KV_DTYPES
+def test_paged_decode_attention_compiles(compile_for_chip, h, hkv, d, hidden, kv_dtype):
+    def decode(q, bt, pos, k, v, *rest):
+        return fa.paged_decode_attention(q, k, v, bt, pos, interpret=False, **_scales(rest))
+
+    text = compile_for_chip(
+        decode, ((SLOTS, h, d), BF16), ((SLOTS, PAGES_PER_SLOT), jnp.int32),
+        ((SLOTS,), jnp.int32), *_pool_specs(hkv, d, kv_dtype))
+    assert "tpu_custom_call" in text
+
+
+@WIDTHS
+@KV_DTYPES
+@pytest.mark.parametrize("slots,width", [(SLOTS, 5), (1, 512)],
+                         ids=["verify_k4", "prefill_512"])
+def test_paged_multitoken_attention_compiles(compile_for_chip, h, hkv, d, hidden,
+                                             kv_dtype, slots, width):
+    def multi(q, bt, pos, k, v, *rest):
+        return fa.paged_multitoken_attention(q, k, v, bt, pos, interpret=False,
+                                             **_scales(rest))
+
+    text = compile_for_chip(
+        multi, ((slots, width, h, d), BF16), ((slots, PAGES_PER_SLOT), jnp.int32),
+        ((slots, width), jnp.int32), *_pool_specs(hkv, d, kv_dtype))
+    assert "tpu_custom_call" in text
+
+
+@WIDTHS
+def test_bgmv_compiles(compile_for_chip, h, hkv, d, hidden):
+    text = compile_for_chip(
+        lambda x, a, b, ids: bgmv(x, a, b, ids, interpret=False),
+        ((SLOTS, hidden), BF16), ((ADAPTERS, hidden, RANK), BF16),
+        ((ADAPTERS, RANK, h * d), BF16), ((SLOTS,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+@WIDTHS
+@KV_DTYPES
+def test_fused_bgmv_paged_decode_compiles(compile_for_chip, h, hkv, d, hidden, kv_dtype):
+    def fused(x, q, a, b, ids, cos, sin, bt, pos, k, v, *rest):
+        return fa.fused_bgmv_paged_decode(x, q, a, b, ids, cos, sin, k, v, bt, pos,
+                                          interpret=False, **_scales(rest))
+
+    rope = ((4096, d // 2), jnp.float32)
+    text = compile_for_chip(
+        fused, ((SLOTS, hidden), BF16), ((SLOTS, h, d), BF16),
+        ((ADAPTERS, hidden, RANK), BF16), ((ADAPTERS, RANK, h * d), BF16),
+        ((SLOTS,), jnp.int32), rope, rope, ((SLOTS, PAGES_PER_SLOT), jnp.int32),
+        ((SLOTS,), jnp.int32), *_pool_specs(hkv, d, kv_dtype))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("m,rows,cols", [
+    (512, 4096, 11008),   # tiled kernel, gate/up projection
+    (512, 11008, 4096),   # tiled kernel, masked partial K tile (11008)
+    (1, 4096, 11008),     # whole-F decode kernel
+    (8, 11008, 4096),     # whole-F decode kernel, masked K
+], ids=["tiled_up", "tiled_down", "wholef_up", "wholef_down"])
+def test_int8_quantized_matmul_compiles(compile_for_chip, m, rows, cols):
+    block = 128
+
+    def qmm(x, codes, scales):
+        qt = QuantizedTensor(codes, scales, (rows, cols), BF16, "int8", block, layout="k2d")
+        return quantized_matmul(x, qt, interpret=False)
+
+    text = compile_for_chip(qmm, ((m, rows), BF16), ((rows, cols), jnp.int8),
+                            ((cols // block, rows), jnp.float32))
+    assert "tpu_custom_call" in text  # the kernel, not the dequantize+matmul route
+
+
+def test_fused_cross_entropy_compiles(compile_for_chip):
+    """The fused linear+CE is XLA dots under a custom_vjp (no Pallas): the
+    chip's compiler must take it at the smoke's widths, fwd and bwd."""
+    def loss_and_grads(hidden, weight, labels):
+        return jax.value_and_grad(
+            lambda hid, w: fused_causal_lm_loss(hid, w, labels, vocab_major=False,
+                                                num_chunks=4), argnums=(0, 1))(hidden, weight)
+
+    text = compile_for_chip(loss_and_grads, ((1, 2048, 4096), BF16),
+                            ((4096, 32000), BF16), ((1, 2048), jnp.int32))
+    assert "fusion" in text
