@@ -1164,6 +1164,7 @@ class Accelerator:
                     # grad_dtype="bf16" keeps out of HBM)
                     grads = jax.tree_util.tree_map(lambda g: g * clip.astype(g.dtype), grads)
 
+            @jax.named_scope("optimizer_update")
             def run_update(grads, opt_state, params, finite):
                 updates, new_opt = state.tx.update(grads, opt_state, params)
                 new_params = optax.apply_updates(params, updates)

@@ -44,6 +44,7 @@ class GenerationConfig:
     pad_token_id: int = 0
 
 
+@jax.named_scope("sample")
 def sample_logits(logits, rng, config: GenerationConfig):
     """Next-token selection from [B, V] logits.
 

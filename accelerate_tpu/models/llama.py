@@ -347,6 +347,7 @@ def paged_gather_kv(k_pages, v_pages, block_tables, k_scales=None,
     return lin(k_pages, k_scales), lin(v_pages, v_scales), kv_positions
 
 
+@jax.named_scope("paged_write_kv")
 def paged_write_kv(pages, values, page_ids, offsets):
     """Scatter per-token K or V rows into the page pool.
 
@@ -360,6 +361,7 @@ def paged_write_kv(pages, values, page_ids, offsets):
     )
 
 
+@jax.named_scope("paged_write_kv")
 def paged_write_kv_quantized(pages, scales, values, page_ids, offsets,
                              kv_dtype: str):
     """Quantize-on-write into int8/fp8 pages with per-(kv-head, page) scales.

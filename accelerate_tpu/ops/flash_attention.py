@@ -272,6 +272,7 @@ def _flash_fwd(q, k, v, seg_q, seg_kv, pos_q, pos_kv, causal: bool, sm_scale: fl
     row_kv = pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b // n_heads, 0, j))
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -456,6 +457,7 @@ def _flash_bwd(q, k, v, seg_q, seg_kv, pos_q, pos_kv, out, lse, g, g_lse, causal
             _dq_kernel, causal=causal, sm_scale=sm_scale, block_q=block_q, block_k=block_k,
             t_len=t, s_len=s_len, segmented=segmented, positioned=positioned,
         ),
+        name="flash_bwd_dq",
         grid=(bh, q_blocks, pl.cdiv(s_len, block_k)),
         in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec, seg_q_spec, seg_kv_spec,
                   seg_q_spec, seg_kv_spec],
@@ -486,6 +488,7 @@ def _flash_bwd(q, k, v, seg_q, seg_kv, pos_q, pos_kv, out, lse, g, g_lse, causal
             _dkv_kernel, causal=causal, sm_scale=sm_scale, block_q=block_q, block_k=block_k,
             t_len=t, s_len=s_len, q_blocks=q_blocks, segmented=segmented, positioned=positioned,
         ),
+        name="flash_bwd_dkv",
         grid=(bhkv, pl.cdiv(s_len, block_k), n_rep * q_blocks),
         in_specs=[qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2, seg_q_spec2, seg_kv_spec2,
                   seg_q_spec2, seg_kv_spec2],
@@ -747,6 +750,7 @@ def paged_decode_attention(
         operands = (bt_flat, pos, qg, k_pages, v_pages)
     out = pl.pallas_call(
         kernel,
+        name="paged_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_slots, hkv, group, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -899,6 +903,7 @@ def paged_multitoken_attention(
         operands = (bt_flat, pos0, qg, k_pages, v_pages)
     out = pl.pallas_call(
         kernel,
+        name="paged_multitoken",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_slots, hkv, rows, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -1110,6 +1115,7 @@ def fused_bgmv_paged_decode(
                     sin, k_pages, v_pages)
     out = pl.pallas_call(
         kernel,
+        name="fused_bgmv_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_slots, hkv, group, d), q_base.dtype),
         compiler_params=pltpu.CompilerParams(

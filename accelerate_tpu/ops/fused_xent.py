@@ -66,6 +66,7 @@ def _pad_vocab(weight, num_chunks, vocab_major):
     return weight, v, chunk
 
 
+@jax.named_scope("fused_xent")
 def _fwd(hidden, weight, labels, mask, num_chunks, vocab_major):
     n = hidden.shape[0]
     weight_p, v, chunk = _pad_vocab(weight, num_chunks, vocab_major)
@@ -95,6 +96,7 @@ def _fwd(hidden, weight, labels, mask, num_chunks, vocab_major):
     return loss, (hidden, weight, labels, mask, lse, n_valid)
 
 
+@jax.named_scope("fused_xent")
 def _bwd(num_chunks, vocab_major, res, gbar):
     hidden, weight, labels, mask, lse, n_valid = res
     weight_p, v, chunk = _pad_vocab(weight, num_chunks, vocab_major)

@@ -214,6 +214,7 @@ def bgmv(x, a_stack, b_stack, ids, *, interpret: Optional[bool] = None):
     )
     return pl.pallas_call(
         _bgmv_kernel,
+        name="bgmv",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_slots, 1, d_out), x.dtype),
         interpret=interpret,

@@ -248,6 +248,7 @@ def quantized_matmul(x, qt: QuantizedTensor, *, block_m: int = 128, block_k: Opt
         out = pl.pallas_call(
             functools.partial(_qmm_wholef_kernel, qblock=qblock,
                               out_dtype=out_dtype, k_len=h, masked_k=masked_k),
+            name="qmm_wholef",
             grid=(pl.cdiv(m, bm), pl.cdiv(h, bk)),
             in_specs=[
                 pl.BlockSpec((bm, bk), lambda i, k: (i, k)),
@@ -276,6 +277,7 @@ def quantized_matmul(x, qt: QuantizedTensor, *, block_m: int = 128, block_k: Opt
     out = pl.pallas_call(
         functools.partial(_qmm_kernel, qblock=qblock, out_dtype=out_dtype,
                           k_len=h, masked_k=masked_k),
+        name="qmm",
         grid=(pl.cdiv(m, bm), pl.cdiv(f, bf), pl.cdiv(h, bk)),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),

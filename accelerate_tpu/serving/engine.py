@@ -25,8 +25,10 @@ Execution contract:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
+from collections import deque
 from functools import lru_cache
 from typing import Optional
 
@@ -45,6 +47,18 @@ from .paged_cache import allocate, pages_for, push_pages, release
 from .prefix_cache import PrefixCache
 from .scheduler import ContinuousBatchingScheduler, Request, SlotState
 from .speculate import Speculator, make_draft_provider, speculative_page_need
+
+
+# latency samples kept for the harness's percentiles (the newest; the
+# whole-run sums are in ``ServingEngine.metrics``)
+_SAMPLE_WINDOW = 4096
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _no_phase(name, **args):
+    """``ServingEngine.step``'s ``phase`` with tracing off."""
+    return _NO_SPAN
 
 
 def _layer_view(layer, block_tables):
@@ -488,6 +502,7 @@ class ServingEngine:
         # identical on or off (pinned by tests + the dryrun telemetry leg).
         # A single attribute check per hook when off.
         self.telemetry = telemetry or TelemetryPlugin()
+        self._clock = time.perf_counter   # the scheduler's stamps share it
         self.trace: Optional[RequestTracer] = None
         if self.telemetry.trace_requests:
             self.enable_tracing()
@@ -510,6 +525,7 @@ class ServingEngine:
         self._arrival_wall: dict[int, float] = {}
         self._last_token_wall: dict[int, float] = {}
         self._ttft_seen: set[int] = set()
+        self._dispatch_seen: set[int] = set()
         self.metrics = {
             "decode_steps": 0, "prefill_steps": 0, "idle_steps": 0,
             "scheduled_decode_slots": 0, "useful_decode_tokens": 0,
@@ -527,14 +543,20 @@ class ServingEngine:
             # pages out of / into this engine — serving/transfer.py)
             "page_transfers": 0, "page_transfer_pages": 0,
             "page_transfer_bytes": 0,
+            # always-on latency counters, from the stamps add_request and
+            # the first dispatch / first token take (seconds on the
+            # engine's clock, once per request): submit -> first prefill
+            # dispatch, and submit -> first token on the host
+            "queue_wait_s_sum": 0.0, "queue_wait_n": 0,
+            "ttft_s_sum": 0.0, "ttft_n": 0,
         }
-        self.ttft_s: list[float] = []
+        self.ttft_s: deque[float] = deque(maxlen=_SAMPLE_WINDOW)
         # TTFT in VIRTUAL engine ticks (arrival -> first token), the
         # deterministic twin of the wall-clock ttft_s samples: the prefix
         # cache's with/without-reuse comparison pins on these (wall clocks
         # flake on CPU; tick counts replay identically)
         self.ttft_ticks: list[int] = []
-        self.token_gaps_s: list[float] = []
+        self.token_gaps_s: deque[float] = deque(maxlen=_SAMPLE_WINDOW)
 
     # -- telemetry -----------------------------------------------------------
 
@@ -549,16 +571,20 @@ class ServingEngine:
             self.trace = RequestTracer(
                 capacity=capacity or self.telemetry.ring_capacity, clock=clock,
             )
+            # one clock for the spans and for the stamps they are built from
+            self._clock = self.sched.clock = self.trace.recorder.clock
         return self.trace
 
     def disable_tracing(self) -> None:
         self.trace = None
+        self._clock = self.sched.clock = time.perf_counter
 
     # -- request lifecycle ---------------------------------------------------
 
     def add_request(self, request: Request) -> None:
-        self.sched.submit(request)
-        self._arrival_wall[request.uid] = time.perf_counter()
+        now = self._clock()
+        self.sched.submit(request, now)
+        self._arrival_wall[request.uid] = now
 
     def cancel(self, uid: int) -> None:
         """Request cancellation of ``uid`` at whatever lifecycle stage it is
@@ -609,9 +635,11 @@ class ServingEngine:
         sched.free_pages -= n_pages
         sched.events.append(("admit", request.uid, slot))
         # the prefill engine delivered the first token — TTFT is its story
-        self._arrival_wall[request.uid] = time.perf_counter()
-        self._last_token_wall[request.uid] = time.perf_counter()
+        now = self._clock()
+        self._arrival_wall[request.uid] = now
+        self._last_token_wall[request.uid] = now
         self._ttft_seen.add(request.uid)
+        self._dispatch_seen.add(request.uid)
         return slot
 
     def release_held(self, slot: int) -> None:
@@ -802,117 +830,170 @@ class ServingEngine:
     def step(self) -> dict:
         """One scheduler decision + at most one device program.
 
-        With tracing on (:attr:`trace`) the tick records its phase spans —
-        ``schedule`` (admission + the scheduler decision), ``dispatch:*``
-        (the async device-program call) and ``host_sync`` (the token fetch)
-        — plus the per-request lifecycle spans derived from the scheduler's
-        event log.  All host-side: the device programs are identical."""
+        With tracing on (:attr:`trace`) the tick is partitioned into
+        sibling phase spans that cover it from entry to return —
+        ``control``, ``schedule``, ``plan``, ``stage:*``, ``dispatch:*``,
+        ``host_sync``, ``commit``, ``trace`` (``RequestTracer``'s docstring
+        says what each holds) — plus the per-request lifecycle spans derived
+        from the scheduler's event log.  All host-side: the device programs
+        are identical."""
         tr = self.trace
-        for ev in _faults.fault_point("serve_step"):
-            if ev.kind == "preempt":
-                # drain: stop taking work, hand every in-flight request back
-                # (the serving analog of the trainer's SIGTERM-at-step-
-                # boundary stop; resilience/preemption.py discipline)
-                self.interrupted = True
+        phase = tr.phase if tr is not None else _no_phase
+        step = self.steps
+        with phase("control", step=step):
+            for ev in _faults.fault_point("serve_step"):
+                if ev.kind == "preempt":
+                    # drain: stop taking work, hand every in-flight request
+                    # back (the serving analog of the trainer's SIGTERM-at-
+                    # step-boundary stop; resilience/preemption.py discipline)
+                    self.interrupted = True
+                    self._drain_prefix_frees()
+                    return {"type": "preempted", "step": self.steps}
+                if ev.kind == "cancel":
+                    # cancellation storm: the oldest live request cancels —
+                    # deterministic, so the event-log pin covers the storm
+                    self._inject_cancel_oldest()
+                elif ev.kind == "deadline":
+                    # deadline storm: every live request expires NOW, and the
+                    # overload signal escalates the degradation ladder one
+                    # stage
+                    self.sched.force_expire_all()
+                    self.ladder.escalate()
+                elif ev.kind == "prefix":
+                    # cache-invalidation storm: every index hold drops — live
+                    # slots keep their shared refcounts (their pages free
+                    # later through the normal release path), future
+                    # admissions miss.  Tokens stay bitwise: a flush only
+                    # changes WHERE K/V gets computed, never what it holds.
+                    if self.prefix is not None:
+                        freed = self.prefix.flush()
+                        self.sched.free_pages += freed
+                        self.sched.events.append(("prefix_flush", freed))
+            self.sched.tick = self.steps
+            self._process_control()
+        with phase("schedule", step=step):
+            admitted = self.sched.admit()
+            if self.prefix is not None:
+                # push refcount-death / LRU-reclaim pages BEFORE any
+                # allocating dispatch (the host mirror counted them at
+                # decision time), then write each adopted prefix into its
+                # slot's block-table row
                 self._drain_prefix_frees()
-                return {"type": "preempted", "step": self.steps}
-            if ev.kind == "cancel":
-                # cancellation storm: the oldest live request cancels —
-                # deterministic, so the event-log pin covers the storm
-                self._inject_cancel_oldest()
-            elif ev.kind == "deadline":
-                # deadline storm: every live request expires NOW, and the
-                # overload signal escalates the degradation ladder one stage
-                self.sched.force_expire_all()
-                self.ladder.escalate()
-            elif ev.kind == "prefix":
-                # cache-invalidation storm: every index hold drops — live
-                # slots keep their shared refcounts (their pages free later
-                # through the normal release path), future admissions miss.
-                # Tokens stay bitwise: a flush only changes WHERE K/V gets
-                # computed, never what it holds.
-                if self.prefix is not None:
-                    freed = self.prefix.flush()
-                    self.sched.free_pages += freed
-                    self.sched.events.append(("prefix_flush", freed))
-        self.sched.tick = self.steps
-        self._process_control()
-        t_sched = tr.stamp() if tr is not None else 0.0
-        admitted = self.sched.admit()
-        if self.prefix is not None:
-            # push refcount-death / LRU-reclaim pages BEFORE any allocating
-            # dispatch (the host mirror counted them at decision time), then
-            # write each adopted prefix into its slot's block-table row
-            self._drain_prefix_frees()
-            for s in admitted:
-                st = self.sched.slots[s]
-                if st.shared_pages:
-                    pps = self.plugin.pages_per_slot
-                    ids = np.zeros((pps,), np.int32)
-                    ids[:len(st.shared_pages)] = st.shared_pages
-                    self.cache = self._adopt(
-                        self.cache, jnp.asarray(s, jnp.int32),
-                        jnp.asarray(ids),
-                        jnp.asarray(len(st.shared_pages), jnp.int32),
-                    )
-        action = self.sched.next_action()
-        if tr is not None:
-            tr.phase("schedule", t_sched, action=action[0], step=self.steps)
+                for s in admitted:
+                    st = self.sched.slots[s]
+                    if st.shared_pages:
+                        pps = self.plugin.pages_per_slot
+                        ids = np.zeros((pps,), np.int32)
+                        ids[:len(st.shared_pages)] = st.shared_pages
+                        self.cache = self._adopt(
+                            self.cache, jnp.asarray(s, jnp.int32),
+                            jnp.asarray(ids),
+                            jnp.asarray(len(st.shared_pages), jnp.int32),
+                        )
+            action = self.sched.next_action()
         window = None
         event: dict = {"type": action[0], "step": self.steps}
         if action[0] == "prefill":
-            _, slot, start, chunk, bucket = action
+            window = self._prefill_tick(action, phase, event)
+        elif action[0] == "decode" and self._spec is not None \
+                and not self.despeculated:
+            event["type"] = "verify"
+            window = self._verify_tick(action[1], phase, event)
+            if self.interrupted:  # preempt-mid-verify fault: nothing ran
+                self._drain_prefix_frees()
+                return {"type": "preempted", "step": self.steps}
+        elif action[0] == "decode":
+            window = self._decode_tick(action[1], phase, event)
+        else:
+            self.metrics["idle_steps"] += 1
+        with phase("commit", step=step):
+            used = self.sched.used_pages
+            self.metrics["page_step_sum"] += used
+            self.metrics["peak_used_pages"] = max(
+                self.metrics["peak_used_pages"], used)
+            # the tick boundary owes the device every refcount-death push
+            # the host counted this tick (mirror exact at every boundary —
+            # the refcounted invariant checker runs between ticks)
+            self._drain_prefix_frees()
+        if tr is not None:
+            # lifecycle spans off the scheduler's deterministic event log
+            # (submit/admit/swap/bypass/prefill/evict/finish this tick); the
+            # tracer's own cost shows as the tick's last span
+            with phase("trace", step=step):
+                tr.consume_scheduler_events(
+                    self.sched.events, step, window=window,
+                    stamps=self.sched.stamps)
+        self.steps += 1
+        return event
+
+    def _prefill_tick(self, action, phase, event):
+        """One bucket-padded prefill chunk (and, when it completes the
+        prompt, the first token's sampling).  Returns the tracing window —
+        ``stage`` start to the last sync's end — or None."""
+        _, slot, start, chunk, bucket = action
+        step = self.steps
+        with phase("plan", step=step):
             survived, evicted = self.sched.plan_prefill_evictions(slot, chunk)
             self._release_evicted(evicted)
             if survived:
                 st = self.sched.slots[slot]
                 ids = np.zeros((bucket,), np.int32)
                 ids[:chunk] = st.request.prompt[start:start + chunk]
-                t_disp = tr.stamp() if tr is not None else 0.0
-                cache, last = self._run_prefill(
-                    jnp.asarray(slot, jnp.int32),
-                    jnp.asarray(ids), jnp.asarray(start, jnp.int32),
-                    jnp.asarray(chunk, jnp.int32),
-                    jnp.asarray(st.adapter_slot, jnp.int32),
-                )
-                if tr is not None:
-                    tr.phase("dispatch:prefill", t_disp, slot=slot,
-                             chunk=chunk, bucket=bucket, step=self.steps)
-                self.cache = cache
-                self.sched.note_prefill(slot, chunk)
-                m = self.metrics
-                m["prefill_steps"] += 1
-                m["prefill_scheduled_tokens"] += bucket
-                m["prefill_useful_tokens"] += chunk
-                m["prompt_tokens"] += chunk
-                event.update(slot=slot, chunk=chunk, bucket=bucket)
-                if self.prefix is not None and st.prefill_done:
-                    # the completed prompt's NEW full pages register in the
-                    # content index (one small block-row fetch; the engine
-                    # syncs a token this tick anyway)
-                    self._insert_prefix(slot, st)
-                if st.prefill_done:
-                    # the prompt's last-token logits seed the decode loop —
-                    # the first generated token, exactly like generate()
-                    t_sync = tr.stamp() if tr is not None else 0.0
-                    tok = int(self._sample(last, self._step_rng()))
-                    if tr is not None:
-                        tr.phase("host_sync", t_sync, step=self.steps)
-                    m["generated_tokens"] += 1
-                    self._record_token(slot, tok)
-                if tr is not None:
-                    window = (t_disp, tr.recorder.clock())
-            else:
-                event["cancelled"] = True
-        elif action[0] == "decode" and self._spec is not None \
-                and not self.despeculated:
-            event["type"] = "verify"
-            window = self._verify_tick(action[1], tr, event)
-            if self.interrupted:  # preempt-mid-verify fault: nothing ran
-                self._drain_prefix_frees()
-                return {"type": "preempted", "step": self.steps}
-        elif action[0] == "decode":
-            active_slots, evicted = self.sched.plan_evictions(action[1])
+        if not survived:
+            event["cancelled"] = True
+            return None
+        m = self.metrics
+        with phase("stage:prefill", step=step) as opened:
+            uid = st.request.uid
+            if uid not in self._dispatch_seen:
+                # once per request, like the TTFT sample: how long it waited
+                # for its first chunk to be handed to the device
+                self._dispatch_seen.add(uid)
+                m["queue_wait_s_sum"] += self._clock() - self._arrival_wall[uid]
+                m["queue_wait_n"] += 1
+            args = (
+                jnp.asarray(slot, jnp.int32),
+                jnp.asarray(ids), jnp.asarray(start, jnp.int32),
+                jnp.asarray(chunk, jnp.int32),
+                jnp.asarray(st.adapter_slot, jnp.int32),
+            )
+        with phase("dispatch:prefill", step=step, slot=slot, chunk=chunk,
+                   bucket=bucket) as closed:
+            cache, last = self._run_prefill(*args)
+        with phase("commit", step=step):
+            self.cache = cache
+            self.sched.note_prefill(slot, chunk)
+            m["prefill_steps"] += 1
+            m["prefill_scheduled_tokens"] += bucket
+            m["prefill_useful_tokens"] += chunk
+            m["prompt_tokens"] += chunk
+            event.update(slot=slot, chunk=chunk, bucket=bucket)
+        if self.prefix is not None and st.prefill_done:
+            # the completed prompt's NEW full pages register in the content
+            # index (one small block-row fetch; the engine syncs a token
+            # this tick anyway)
+            with phase("host_sync", step=step) as closed:
+                self._insert_prefix(slot, st)
+        if st.prefill_done:
+            # the prompt's last-token logits seed the decode loop — the
+            # first generated token, exactly like generate()
+            with phase("stage:sample", step=step):
+                rng = self._step_rng()
+            with phase("dispatch:sample", step=step):
+                tok_dev = self._sample(last, rng)
+            with phase("host_sync", step=step) as closed:
+                tok = int(tok_dev)
+            with phase("commit", step=step):
+                m["generated_tokens"] += 1
+                self._record_token(slot, tok)
+        return (opened[0], closed[1]) if opened is not None else None
+
+    def _decode_tick(self, slots, phase, event):
+        """One decode step for every decoding slot.  Returns the tracing
+        window (``stage`` start to ``host_sync`` end) or None."""
+        step = self.steps
+        with phase("plan", step=step):
+            active_slots, evicted = self.sched.plan_evictions(slots)
             self._release_evicted(evicted)
             if active_slots:
                 needing = self.sched.decode_page_need(active_slots)
@@ -924,54 +1005,35 @@ class ServingEngine:
                     tokens[s] = self.sched.slots[s].tokens[-1]
                     active[s] = True
                     adapter_slots[s] = self.sched.slots[s].adapter_slot
-                t_disp = tr.stamp() if tr is not None else 0.0
-                cache, next_tok = self._run_decode(
-                    jnp.asarray(tokens), jnp.asarray(active),
-                    jnp.asarray(adapter_slots), self._step_rng(),
-                )
-                if tr is not None:
-                    tr.phase("dispatch:decode", t_disp,
-                             slots=list(active_slots), step=self.steps)
-                self.cache = cache
-                self.sched.note_decode(needing, active_slots)
-                t_sync = tr.stamp() if tr is not None else 0.0
-                next_np = np.asarray(next_tok)
-                if tr is not None:
-                    tr.phase("host_sync", t_sync, step=self.steps)
-                    window = (t_disp, tr.recorder.clock())
-                done_slots = []
-                for s in active_slots:
-                    if self._record_token(s, int(next_np[s]), release=False):
-                        done_slots.append(s)
-                if done_slots and not self.hold_finished:
-                    self._release_slots(done_slots)
-                    self._finish_decode_slots(done_slots)
-                m = self.metrics
-                m["decode_steps"] += 1
-                m["scheduled_decode_slots"] += n
-                m["useful_decode_tokens"] += len(active_slots)
-                m["generated_tokens"] += len(active_slots)
-                m["decode_lane_passes"] += len(active_slots)
-                m["decode_emitted_tokens"] += len(active_slots)
-                event.update(slots=tuple(active_slots))
-            else:
-                event["cancelled"] = True
-        else:
-            self.metrics["idle_steps"] += 1
-        used = self.sched.used_pages
-        self.metrics["page_step_sum"] += used
-        self.metrics["peak_used_pages"] = max(self.metrics["peak_used_pages"], used)
-        if tr is not None:
-            # lifecycle spans off the scheduler's deterministic event log
-            # (submit/admit/swap/bypass/prefill/evict/finish this tick)
-            tr.consume_scheduler_events(self.sched.events, self.steps,
-                                        window=window)
-        # the tick boundary owes the device every refcount-death push the
-        # host counted this tick (mirror exact at every boundary — the
-        # refcounted invariant checker runs between ticks)
-        self._drain_prefix_frees()
-        self.steps += 1
-        return event
+        if not active_slots:
+            event["cancelled"] = True
+            return None
+        with phase("stage:decode", step=step) as opened:
+            args = (jnp.asarray(tokens), jnp.asarray(active),
+                    jnp.asarray(adapter_slots), self._step_rng())
+        with phase("dispatch:decode", step=step, slots=list(active_slots)):
+            cache, next_tok = self._run_decode(*args)
+        with phase("host_sync", step=step) as closed:
+            next_np = np.asarray(next_tok)
+        with phase("commit", step=step):
+            self.cache = cache
+            self.sched.note_decode(needing, active_slots)
+            done_slots = []
+            for s in active_slots:
+                if self._record_token(s, int(next_np[s]), release=False):
+                    done_slots.append(s)
+            if done_slots and not self.hold_finished:
+                self._release_slots(done_slots)
+                self._finish_decode_slots(done_slots)
+            m = self.metrics
+            m["decode_steps"] += 1
+            m["scheduled_decode_slots"] += n
+            m["useful_decode_tokens"] += len(active_slots)
+            m["generated_tokens"] += len(active_slots)
+            m["decode_lane_passes"] += len(active_slots)
+            m["decode_emitted_tokens"] += len(active_slots)
+            event.update(slots=tuple(active_slots))
+        return (opened[0], closed[1]) if opened is not None else None
 
     def run(self, trace: list[Request], max_steps: int = 200_000) -> dict[int, list[int]]:
         """Replay ``trace`` (arrivals keyed on virtual step time) to
@@ -1038,6 +1100,7 @@ class ServingEngine:
         self._arrival_wall.pop(uid, None)
         self._last_token_wall.pop(uid, None)
         self._ttft_seen.discard(uid)
+        self._dispatch_seen.discard(uid)
 
     def _inject_cancel_oldest(self) -> None:
         """The cancellation-storm fault payload: cancel the oldest live
@@ -1051,89 +1114,101 @@ class ServingEngine:
         elif sched.waiting:
             sched.cancel_queued(sched.waiting[0].uid, reason="cancel")
 
-    def _verify_tick(self, candidate_slots, tr, event):
+    def _verify_tick(self, candidate_slots, phase, event):
         """One speculative draft-and-verify pass (the decode action with
         speculation armed).  Draft first (the proposals size the page
         reservation), evict for the WORST-CASE page demand, dispatch the
         bucket-padded verify program, then settle the host mirror off the
         device-accepted lengths.  Returns the tracing window (or None).
+        The phases are the decode tick's: drafting and eviction planning
+        are ``plan``.
 
         The ``verify_step`` fault site fires FIRST — a ``preempt`` armed
         there drains the engine mid-verify with nothing dispatched and no
         state touched, so the drain/resume contract (and every invariant)
         holds at the finest-grained boundary speculation has."""
-        for ev in _faults.fault_point("verify_step"):
-            if ev.kind == "preempt":
-                self.interrupted = True
-                event["preempted"] = True
+        step = self.steps
+        with phase("plan", step=step):
+            for ev in _faults.fault_point("verify_step"):
+                if ev.kind == "preempt":
+                    self.interrupted = True
+                    event["preempted"] = True
+                    return None
+            sp = self._spec
+            sched = self.sched
+            cand = list(candidate_slots)
+            n = self.plugin.num_slots
+            # the draft batch is padded to the FULL slot width like every
+            # other engine program: a draft-model provider jits per batch
+            # shape, and a shape that tracked the live candidate count would
+            # recompile mid-traffic the first time occupancy changed
+            # (strict_compiles).  Contexts carry only the provider's trailing
+            # window — rebuilding the full prompt+generated history per pass
+            # would be quadratic in stream length — and the assembly counts
+            # as draft time (it exists only to feed the drafting layer).
+            t_ctx = time.perf_counter()
+            win = max(2, getattr(sp.provider, "window", 512))
+            contexts = [[1]] * n
+            remaining = [1] * n  # dummy rows clamp to depth 0
+            tenant_ids = [0] * n
+            for s in cand:
+                st = sched.slots[s]
+                toks = st.tokens
+                if len(toks) >= win:
+                    contexts[s] = toks[-win:]
+                else:
+                    contexts[s] = list(st.request.prompt[len(toks) - win:]) + toks
+                remaining[s] = st.request.max_new_tokens - len(toks)
+                tenant_ids[s] = st.request.adapter_id
+            sp.draft_time_s += time.perf_counter() - t_ctx
+            drafts, spec_lens = sp.draft(contexts, remaining, tenant_ids)
+            spec_by_slot = {s: int(spec_lens[s]) for s in cand}
+            active_slots, evicted = sched.plan_speculative_evictions(
+                cand, spec_by_slot
+            )
+            self._release_evicted(evicted)
+            if not active_slots:
+                event["cancelled"] = True
                 return None
-        sp = self._spec
+            worst_need = sched.verify_page_need(active_slots, spec_by_slot)
+            bucket = sp.bucket_for(max(spec_by_slot[s] for s in active_slots))
+            w = bucket + 1
+            tokens = np.zeros((n, w), np.int32)
+            spec_arr = np.zeros((n,), np.int32)
+            active = np.zeros((n,), bool)
+            adapter_slots = np.zeros((n,), np.int32)
+            for s in active_slots:
+                st = sched.slots[s]
+                d = spec_by_slot[s]
+                tokens[s, 0] = st.tokens[-1]
+                if d:
+                    tokens[s, 1:1 + d] = drafts[s, :d]
+                spec_arr[s] = d
+                active[s] = True
+                adapter_slots[s] = st.adapter_slot
+        with phase("stage:verify", step=step) as opened:
+            args = (jnp.asarray(tokens), jnp.asarray(spec_arr),
+                    jnp.asarray(active), jnp.asarray(adapter_slots),
+                    self._step_rng())
+        with phase("dispatch:verify", step=step, slots=list(active_slots),
+                   bucket=bucket):
+            cache, greedy, m_dev = self._run_verify(*args)
+        with phase("host_sync", step=step) as closed:
+            greedy_np = np.asarray(greedy)
+            m_np = np.asarray(m_dev)
+        with phase("commit", step=step):
+            self.cache = cache
+            self._settle_verify(active_slots, spec_by_slot, worst_need,
+                                greedy_np, m_np, bucket, event)
+        return (opened[0], closed[1]) if opened is not None else None
+
+    def _settle_verify(self, active_slots, spec_by_slot, worst_need,
+                       greedy_np, m_np, bucket, event) -> None:
+        """The host side of a verify pass once its tokens are here: the
+        page mirror, the accepted tokens, the finished slots, the counters."""
         sched = self.sched
-        cand = list(candidate_slots)
         n = self.plugin.num_slots
-        # the draft batch is padded to the FULL slot width like every other
-        # engine program: a draft-model provider jits per batch shape, and a
-        # shape that tracked the live candidate count would recompile
-        # mid-traffic the first time occupancy changed (strict_compiles).
-        # Contexts carry only the provider's trailing window — rebuilding
-        # the full prompt+generated history per pass would be quadratic in
-        # stream length — and the assembly counts as draft time (it exists
-        # only to feed the drafting layer).
-        t_ctx = time.perf_counter()
-        win = max(2, getattr(sp.provider, "window", 512))
-        contexts = [[1]] * n
-        remaining = [1] * n  # dummy rows clamp to depth 0
-        tenant_ids = [0] * n
-        for s in cand:
-            st = sched.slots[s]
-            toks = st.tokens
-            if len(toks) >= win:
-                contexts[s] = toks[-win:]
-            else:
-                contexts[s] = list(st.request.prompt[len(toks) - win:]) + toks
-            remaining[s] = st.request.max_new_tokens - len(toks)
-            tenant_ids[s] = st.request.adapter_id
-        sp.draft_time_s += time.perf_counter() - t_ctx
-        drafts, spec_lens = sp.draft(contexts, remaining, tenant_ids)
-        spec_by_slot = {s: int(spec_lens[s]) for s in cand}
-        active_slots, evicted = sched.plan_speculative_evictions(
-            cand, spec_by_slot
-        )
-        self._release_evicted(evicted)
-        if not active_slots:
-            event["cancelled"] = True
-            return None
-        worst_need = sched.verify_page_need(active_slots, spec_by_slot)
-        bucket = sp.bucket_for(max(spec_by_slot[s] for s in active_slots))
         w = bucket + 1
-        tokens = np.zeros((n, w), np.int32)
-        spec_arr = np.zeros((n,), np.int32)
-        active = np.zeros((n,), bool)
-        adapter_slots = np.zeros((n,), np.int32)
-        for s in active_slots:
-            st = sched.slots[s]
-            d = spec_by_slot[s]
-            tokens[s, 0] = st.tokens[-1]
-            if d:
-                tokens[s, 1:1 + d] = drafts[s, :d]
-            spec_arr[s] = d
-            active[s] = True
-            adapter_slots[s] = st.adapter_slot
-        t_disp = tr.stamp() if tr is not None else 0.0
-        cache, greedy, m_dev = self._run_verify(
-            jnp.asarray(tokens), jnp.asarray(spec_arr), jnp.asarray(active),
-            jnp.asarray(adapter_slots), self._step_rng(),
-        )
-        if tr is not None:
-            tr.phase("dispatch:verify", t_disp, slots=list(active_slots),
-                     bucket=bucket, step=self.steps)
-        self.cache = cache
-        t_sync = tr.stamp() if tr is not None else 0.0
-        greedy_np = np.asarray(greedy)
-        m_np = np.asarray(m_dev)
-        if tr is not None:
-            tr.phase("host_sync", t_sync, step=self.steps)
-        window = (t_disp, tr.recorder.clock()) if tr is not None else None
         accepted = {s: int(m_np[s]) for s in active_slots}
         m = self.metrics
         # rollback accounting against the PRE-pass kv lengths (note_verify
@@ -1177,7 +1252,6 @@ class ServingEngine:
         m["accepted_draft_tokens"] += delivered_drafts
         event.update(slots=tuple(active_slots), bucket=bucket,
                      accepted=tuple(accepted[s] for s in active_slots))
-        return window
 
     def _step_rng(self):
         return jax.random.fold_in(self._base_rng, self.steps)
@@ -1187,17 +1261,20 @@ class ServingEngine:
         Returns True when the sequence finished (caller releases if it opted
         out of the immediate release)."""
         st = self.sched.slots[slot]
-        now = time.perf_counter()
+        now = self._clock()
         uid = st.request.uid
         if not st.tokens:
             # once per request: an evicted-and-readmitted sequence must not
             # re-sample its TTFT (the first life already delivered a token)
             if uid not in self._ttft_seen:
                 self._ttft_seen.add(uid)
-                self.ttft_s.append(now - self._arrival_wall[uid])
+                ttft = now - self._arrival_wall[uid]
+                self.ttft_s.append(ttft)
+                self.metrics["ttft_s_sum"] += ttft
+                self.metrics["ttft_n"] += 1
                 self.ttft_ticks.append(self.steps - st.request.arrival_step)
                 if self.slo is not None:
-                    self.slo.observe("ttft_s", self.ttft_s[-1])
+                    self.slo.observe("ttft_s", ttft)
         elif uid in self._last_token_wall:
             self.token_gaps_s.append(now - self._last_token_wall[uid])
             if self.slo is not None:
@@ -1217,6 +1294,7 @@ class ServingEngine:
             self._arrival_wall.pop(uid, None)
             self._last_token_wall.pop(uid, None)
             self._ttft_seen.discard(uid)
+            self._dispatch_seen.discard(uid)
             if self.hold_finished:
                 # prefill-role engine: the KV pages stay resident until the
                 # transport streams them to the decode engine

@@ -68,6 +68,7 @@ Every decision appends to ``events`` — the determinism log now including
 from __future__ import annotations
 
 import dataclasses
+import time
 from collections import deque
 from typing import Optional
 
@@ -189,6 +190,14 @@ class ContinuousBatchingScheduler:
         self._head_block_age = 0             # ticks the line head has been
         self._head_block_uid = None          # adapter-blocked (fairness bound)
         self.events: list[tuple] = []        # the determinism log
+        # when the per-request events happened: (index into ``events``,
+        # time on ``clock``) for submit / admit / evict / shed / cancel /
+        # finish — one clock read per such event, none per tick.  The log
+        # itself stays time-free (it is what the determinism tests pin);
+        # the engine's tracer drains this queue every tick, and with no
+        # tracer the bound drops the oldest
+        self.clock = time.perf_counter
+        self.stamps: deque[tuple[int, float]] = deque(maxlen=4096)
         # overload / cancellation bookkeeping (docs/serving.md): the ladder
         # mutates the two knobs below; the counters feed the serving report
         self.admission_reserve_pages = 0     # tightened-admission free floor
@@ -210,7 +219,14 @@ class ContinuousBatchingScheduler:
 
     # -- queueing -----------------------------------------------------------
 
-    def submit(self, request: Request) -> None:
+    def _log_stamped(self, event: tuple, now: Optional[float] = None) -> None:
+        self.stamps.append(
+            (len(self.events), self.clock() if now is None else now))
+        self.events.append(event)
+
+    def submit(self, request: Request, now: Optional[float] = None) -> None:
+        """Join the waiting line.  ``now`` is the caller's reading of
+        :attr:`clock` at arrival (the engine has one already)."""
         total = request.prompt_len + request.max_new_tokens
         cap = min(self.pages_per_slot, self.num_pages) * self.page_size
         if request.adapter_id:
@@ -248,7 +264,7 @@ class ContinuousBatchingScheduler:
                 request, deadline_ticks=self.default_deadline_ticks
             )
         self.waiting.append(request)
-        self.events.append(("submit", request.uid))
+        self._log_stamped(("submit", request.uid), now)
         # backpressure at the door: the bound holds between ticks too, so a
         # burst of submits can never grow the line past max_queue
         if self.max_queue:
@@ -312,7 +328,7 @@ class ContinuousBatchingScheduler:
             self.deadline_misses += 1
         self.retired_uids.add(req.uid)
         self._force_expired.discard(req.uid)
-        self.events.append(("shed", req.uid, reason))
+        self._log_stamped(("shed", req.uid, reason))
         return req
 
     def predicted_kv_pressure(self) -> float:
@@ -381,7 +397,7 @@ class ContinuousBatchingScheduler:
             self.cancelled += 1
         self.retired_uids.add(req.uid)
         self._force_expired.discard(req.uid)
-        self.events.append(("cancel", req.uid, stage, reason))
+        self._log_stamped(("cancel", req.uid, stage, reason))
 
     def _release_slot_pages(self, st: SlotState) -> int:
         """The ONE host-side page-release arithmetic (finish, evict and
@@ -526,7 +542,7 @@ class ContinuousBatchingScheduler:
                                          prefilled=hit_tokens)
             self._admit_counter += 1
             admitted.append(slot)
-            self.events.append(("admit", req.uid, slot))
+            self._log_stamped(("admit", req.uid, slot))
         return admitted
 
     def _reclaim(self, demand: int, protect: frozenset = frozenset()) -> int:
@@ -754,7 +770,7 @@ class ContinuousBatchingScheduler:
             # evicting a request never evicts a shared hot adapter)
             self.adapters.unpin(st.request.adapter_id)
         self.requeue_front(st.request)
-        self.events.append(("evict", st.request.uid, slot))
+        self._log_stamped(("evict", st.request.uid, slot))
         return st.request
 
     # -- execution feedback (keeps the host page mirror exact) ---------------
@@ -810,7 +826,7 @@ class ContinuousBatchingScheduler:
         if self.adapters is not None:
             self.adapters.unpin(st.request.adapter_id)
         self._force_expired.discard(st.request.uid)
-        self.events.append(("finish", st.request.uid, slot))
+        self._log_stamped(("finish", st.request.uid, slot))
         return st
 
     @property

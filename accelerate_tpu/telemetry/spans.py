@@ -19,8 +19,10 @@ dryrun ``_telemetry_leg``).
 :class:`RequestTracer` layers the serving taxonomy on top: per-request
 lifecycle spans (submit -> admit/pin -> prefill chunk(s) -> decode steps ->
 evict/readmit -> adapter-swap -> retire) driven off the scheduler's
-deterministic event log, and per-serve-step phase spans (scheduler
-decision, device dispatch, host sync) recorded by the engine tick.
+deterministic event log and its time stamps, and per-serve-step phase spans
+(control, schedule, plan, stage, dispatch, host sync, commit, trace) that
+bracket the engine tick's work and, inside a profiler session, are also
+``jax.profiler.TraceAnnotation``s in the profiler's own trace.
 """
 
 from __future__ import annotations
@@ -30,6 +32,12 @@ import time
 from collections import deque
 from contextlib import contextmanager
 from typing import Callable, Optional
+
+import jax
+
+# span arguments repeated on the profiler's annotation (the rest stay in the
+# ring: an annotation's arguments are encoded into its name on every call)
+_ANNOTATED_ARGS = ("step", "uid")
 
 # Chrome trace-event phases this recorder emits: complete, instant, metadata
 _VALID_PHASES = frozenset({"X", "i", "I", "B", "E", "M", "C"})
@@ -91,24 +99,39 @@ class SpanRecorder:
         self._push(("X", name, cat, track, start, max(0.0, end - start), args or None))
         self.overhead_s += time.perf_counter() - t0
 
-    def instant(self, name: str, track: str, cat: str = "", **args) -> None:
+    def instant(self, name: str, track: str, cat: str = "",
+                at: Optional[float] = None, **args) -> None:
+        """One instant event, at ``at`` on the recorder's clock (now when
+        not given: an instant whose moment was stamped at its source passes
+        that stamp)."""
         if not self.enabled:
             return
         t0 = time.perf_counter()
-        self._push(("i", name, cat, track, self.clock(), 0.0, args or None))
+        if at is None:
+            at = self.clock()
+        self._push(("i", name, cat, track, at, 0.0, args or None))
         self.overhead_s += time.perf_counter() - t0
 
     @contextmanager
     def span(self, name: str, track: str, cat: str = "", **args):
-        """Context-manager form of :meth:`complete`."""
+        """Bracketing form of :meth:`complete`: the span covers the ``with``
+        body, and a ``jax.profiler.TraceAnnotation`` of the same name (with
+        the span's ``step`` / ``uid``) is open for as long — inside a
+        profiler session the span therefore also lies in the profiler's own
+        trace, on the timeline of the device's lines; outside one the
+        annotation is a flag test.  Yields ``[start, end]`` on the
+        recorder's clock (``end`` filled at exit; ``None`` when disabled)."""
         if not self.enabled:
-            yield
+            yield None
             return
-        start = self.clock()
-        try:
-            yield
-        finally:
-            self.complete(name, track, start, cat=cat, **args)
+        note = {k: args[k] for k in _ANNOTATED_ARGS if k in args}
+        with jax.profiler.TraceAnnotation(name, **note):
+            times = [self.clock(), None]
+            try:
+                yield times
+            finally:
+                times[1] = self.clock()
+                self.complete(name, track, times[0], times[1], cat=cat, **args)
 
     def stamp(self) -> float:
         """A timestamp on the recorder's clock (0.0 when disabled — callers
@@ -227,22 +250,34 @@ class RequestTracer:
     """The serving-span taxonomy over a :class:`SpanRecorder`.
 
     **Per-request track** (``req <uid>``): ``queued`` span (submit ->
-    admit; re-emitted as the readmit wait after an eviction), ``admit``/
-    ``evict``/``retire`` instants, one ``prefill_chunk`` span per chunk
-    (bracketing the chunk's real dispatch+sync window), one ``decode`` span
-    from prefill completion to retirement, ``adapter_swap`` instants when
-    admission hot-swapped the tenant's adapter in, and the overload-control
-    retirements: a ``shed`` instant (admission-control drop, with its
-    reason — queue / kv_pressure / deadline / overload) or a ``cancel``
-    instant (any-stage retirement, with the stage it struck at and the
-    reason — an explicit cancel or a deadline miss).
+    admit, both stamped by the scheduler when they happen — a request
+    submitted between two ticks has waited since then, not since the tick
+    that admits it; re-emitted as the readmit wait after an eviction),
+    ``admit``/``evict``/``retire`` instants, one ``prefill_chunk`` span per
+    chunk (the tick's ``stage`` start to its ``host_sync`` end), one
+    ``decode`` span from prefill completion to retirement,
+    ``adapter_swap`` instants when admission hot-swapped the tenant's
+    adapter in, and the overload-control retirements: a ``shed`` instant
+    (admission-control drop, with its reason — queue / kv_pressure /
+    deadline / overload) or a ``cancel`` instant (any-stage retirement,
+    with the stage it struck at and the reason — an explicit cancel or a
+    deadline miss).  Every one carries ``uid`` and the ``step`` (tick) whose
+    log held it.
 
-    **Per-step track** (``engine``): ``schedule`` (admission + the
-    scheduler decision), ``dispatch:<kind>`` (the device program call —
-    async, so this is host dispatch time), ``host_sync`` (the token
-    fetch), and ``ladder`` instants marking degradation-ladder stage
-    transitions.  All host-side: the engine's device programs are
-    untouched.
+    **Per-step track** (``engine``): sibling spans that together cover
+    ``ServingEngine.step()`` from entry to return, each carrying the tick's
+    ``step`` — ``control`` (fault point, cancels, deadlines), ``schedule``
+    (admission + the scheduler decision), ``plan`` (eviction planning,
+    releasing the evicted, building the host-side inputs),
+    ``stage:<kind>`` (host-to-device copies of the inputs, the step's RNG
+    key), ``dispatch:<kind>`` (the jitted call alone — async, so this is
+    host dispatch time), ``host_sync`` (the token fetch), ``commit`` (token
+    bookkeeping, releasing finished slots), ``trace`` (this tracer's own
+    :meth:`consume_scheduler_events`) — and ``ladder`` instants marking
+    degradation-ladder stage transitions.  The spans bracket their work
+    (:meth:`SpanRecorder.span`), so inside a profiler session they are
+    also host annotations in the profiler's trace.  All host-side: the
+    engine's device programs are untouched.
     """
 
     def __init__(self, capacity: int = 4096,
@@ -254,38 +289,48 @@ class RequestTracer:
 
     # engine tick hooks --------------------------------------------------
 
-    def stamp(self) -> float:
-        return self.recorder.stamp()
-
-    def phase(self, name: str, start: float, end: Optional[float] = None,
-              **args) -> None:
-        self.recorder.complete(name, "engine", start, end, cat="step", **args)
+    def phase(self, name: str, **args):
+        """``with tracer.phase("plan", step=n):`` — one bracketing span on
+        the ``engine`` track."""
+        return self.recorder.span(name, "engine", cat="step", **args)
 
     def consume_scheduler_events(self, events: list, step: int,
-                                 window: Optional[tuple] = None) -> None:
+                                 window: Optional[tuple] = None,
+                                 stamps: Optional[deque] = None) -> None:
         """Translate the scheduler's deterministic event log (everything
         appended since the last call) into lifecycle spans.  ``window`` is
         the ``(start, end)`` of this tick's device work — prefill-chunk
-        spans reuse it so chunk durations are the real dispatch+sync time."""
+        spans reuse it so chunk durations are the real stage-to-sync time.
+        ``stamps`` is the scheduler's ``(event index, time)`` queue
+        (``ContinuousBatchingScheduler.stamps``): the per-request events
+        (submit, admit, evict, shed, cancel, finish) carry the time at which
+        they happened; an event without a stamp gets the time of this call."""
         rec = self.recorder
+        at: dict[int, float] = {}
+        while stamps and stamps[0][0] < len(events):
+            i, t = stamps.popleft()
+            at[i] = t
         if not rec.enabled:
             self._events_seen = len(events)
             return
         now = rec.clock()
         w0, w1 = window if window is not None else (now, now)
-        for ev in list(events)[self._events_seen:]:
+        for i in range(self._events_seen, len(events)):
+            ev = events[i]
             kind = ev[0]
+            t = at.get(i, now)
             if kind == "submit":
                 uid = ev[1]
-                self._submit_ts[uid] = now
-                rec.instant("submit", f"req {uid}", cat="request", step=step)
+                self._submit_ts[uid] = t
+                rec.instant("submit", f"req {uid}", cat="request", at=t,
+                            uid=uid, step=step)
             elif kind == "admit":
                 uid, slot = ev[1], ev[2]
-                start = self._submit_ts.pop(uid, now)
-                rec.complete("queued", f"req {uid}", start, now,
-                             cat="request", step=step, slot=slot)
-                rec.instant("admit", f"req {uid}", cat="request",
-                            step=step, slot=slot)
+                start = self._submit_ts.pop(uid, t)
+                rec.complete("queued", f"req {uid}", start, t,
+                             cat="request", uid=uid, step=step, slot=slot)
+                rec.instant("admit", f"req {uid}", cat="request", at=t,
+                            uid=uid, step=step, slot=slot)
             elif kind == "swap":
                 tid, slot = ev[1], ev[2]
                 rec.instant("adapter_swap", "engine", cat="adapter",
@@ -297,7 +342,7 @@ class RequestTracer:
             elif kind == "prefill":
                 uid, slot, prefilled = ev[1], ev[2], ev[3]
                 rec.complete("prefill_chunk", f"req {uid}", w0, w1,
-                             cat="request", step=step, slot=slot,
+                             cat="request", uid=uid, step=step, slot=slot,
                              prefilled=prefilled)
                 self._decode_start.setdefault(uid, w1)
             elif kind == "verify":
@@ -308,24 +353,25 @@ class RequestTracer:
                             accepted=[list(p) for p in ev[1]], step=step)
             elif kind == "evict":
                 uid = ev[1]
-                rec.instant("evict", f"req {uid}", cat="request", step=step)
+                rec.instant("evict", f"req {uid}", cat="request", at=t,
+                            uid=uid, step=step)
                 # the readmit wait is the next queued span
-                self._submit_ts[uid] = now
+                self._submit_ts[uid] = t
                 self._decode_start.pop(uid, None)
             elif kind == "shed":
                 uid, reason = ev[1], ev[2]
-                rec.instant("shed", f"req {uid}", cat="request", step=step,
-                            reason=reason)
+                rec.instant("shed", f"req {uid}", cat="request", at=t,
+                            uid=uid, step=step, reason=reason)
                 self._submit_ts.pop(uid, None)
             elif kind == "cancel":
                 uid, stage, reason = ev[1], ev[2], ev[3]
                 start = self._decode_start.pop(uid, None)
                 if start is not None:
                     # close the open decode span at the cancellation point
-                    rec.complete("decode", f"req {uid}", start, now,
-                                 cat="request", step=step)
-                rec.instant("cancel", f"req {uid}", cat="request", step=step,
-                            stage=stage, reason=reason)
+                    rec.complete("decode", f"req {uid}", start, t,
+                                 cat="request", uid=uid, step=step)
+                rec.instant("cancel", f"req {uid}", cat="request", at=t,
+                            uid=uid, step=step, stage=stage, reason=reason)
                 self._submit_ts.pop(uid, None)
             elif kind == "ladder":
                 rec.instant("ladder", "engine", cat="overload", stage=ev[1],
@@ -334,10 +380,10 @@ class RequestTracer:
                 # admission mapped a cached prefix: hit_tokens of prefill
                 # skipped (the COW share boundary for this request)
                 rec.instant("prefix_hit", f"req {ev[1]}", cat="prefix",
-                            hit_tokens=ev[2], step=step)
+                            hit_tokens=ev[2], uid=ev[1], step=step)
             elif kind == "cow_fork":
                 rec.instant("cow_fork", f"req {ev[1]}", cat="prefix",
-                            step=step)
+                            uid=ev[1], step=step)
             elif kind == "prefix_evict":
                 rec.instant("prefix_evict", "engine", cat="prefix",
                             page=ev[1], step=step)
@@ -348,13 +394,14 @@ class RequestTracer:
                 # the disaggregation handoff: one request's KV pages
                 # streamed prefill -> decode
                 rec.instant("page_transfer", f"req {ev[1]}", cat="transfer",
-                            pages=ev[2], bytes=ev[3], step=step)
+                            pages=ev[2], bytes=ev[3], uid=ev[1], step=step)
             elif kind == "finish":
                 uid = ev[1]
-                start = self._decode_start.pop(uid, now)
-                rec.complete("decode", f"req {uid}", start, now,
-                             cat="request", step=step)
-                rec.instant("retire", f"req {uid}", cat="request", step=step)
+                start = self._decode_start.pop(uid, t)
+                rec.complete("decode", f"req {uid}", start, t,
+                             cat="request", uid=uid, step=step)
+                rec.instant("retire", f"req {uid}", cat="request", at=t,
+                            uid=uid, step=step)
                 self._submit_ts.pop(uid, None)
         self._events_seen = len(events)
 

@@ -64,14 +64,13 @@ class TrainTimeline:
             return
         frame = [0.0]
         self._stack.append(frame)
-        start = rec.clock()
         try:
-            yield
+            # a bracketing span: also an annotation in the profiler's trace
+            with rec.span(name, "train", cat="train", **args) as times:
+                yield
         finally:
-            end = rec.clock()
             self._stack.pop()
-            dur = end - start
-            rec.complete(name, "train", start, end, cat="train", **args)
+            dur = times[1] - times[0]
             if self._stack:
                 self._stack[-1][0] += dur
             self._totals[name] = self._totals.get(name, 0.0) \

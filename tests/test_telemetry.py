@@ -536,6 +536,135 @@ def test_engine_trace_ring_bound_under_load():
     assert validate_chrome_trace(tracer.to_chrome_trace()) == []
 
 
+def _spans(tracer, track=None, name=None):
+    """(name, start, end, args) of the recorder's complete spans, in order."""
+    return [(e[1], e[4], e[4] + e[5], e[6] or {}) for e in tracer.recorder.events()
+            if e[0] == "X" and (track is None or e[3] == track)
+            and (name is None or e[1] == name)]
+
+
+def test_request_submitted_between_ticks_has_waited_since_then():
+    """The scheduler stamps submit and admit when they happen: a request that
+    arrives between two ticks has a `queued` span longer than zero that starts
+    BEFORE the tick that admits it (it used to get both stamps from the end of
+    that tick), and the always-on counters see the same wait."""
+    from accelerate_tpu.serving import Request, ServingEngine
+
+    model, params, plugin, gen = _serve_setup()
+    eng = ServingEngine(model, params, plugin, gen)
+    eng.warmup()
+    clk = VirtualClock(1.0)
+    tracer = eng.enable_tracing(clock=clk)
+    eng.add_request(Request(uid=1, prompt=tuple(range(1, 9)), max_new_tokens=4))
+    eng.step()                          # tick 0: admits and prefills request 1
+    eng.step()                          # tick 1: decodes it
+    between = clk.now
+    eng.add_request(Request(uid=2, prompt=tuple(range(3, 9)), max_new_tokens=3))
+    submitted = clk.now
+    assert submitted == between + 1     # one clock read per arrival
+    clk.now += 50.0                     # the caller's own time before it ticks again
+    while not eng.idle():
+        eng.step()
+    (queued,) = _spans(tracer, "req 2", "queued")
+    admitting = min(s for n, s, e, a in _spans(tracer, "engine", "schedule")
+                    if a["step"] == queued[3]["step"])
+    assert queued[1] == submitted and queued[1] < admitting
+    assert queued[2] - queued[1] > 50.0 and queued[3]["uid"] == 2
+    instants = {e[1]: e[4] for e in tracer.recorder.events()
+                if e[0] == "i" and e[3] == "req 2"}
+    assert instants["submit"] == submitted and instants["admit"] == queued[2]
+    m = eng.metrics
+    assert m["queue_wait_n"] == 2 and m["ttft_n"] == 2
+    assert m["queue_wait_s_sum"] > 50.0             # virtual seconds: request 2's wait is in it
+    assert m["ttft_s_sum"] > m["queue_wait_s_sum"]
+    # decode ends when the last token reached the host, not at the tick's end
+    (decode,) = _spans(tracer, "req 2", "decode")
+    assert decode[2] == instants["retire"] and decode[1] < decode[2]
+
+
+def test_engine_track_spans_partition_every_tick():
+    """The phases of a tick are siblings: none overlaps another, and from the
+    first to the last no reading of the clock falls outside them (under a
+    VirtualClock every reading advances time by one, so a gap of exactly one
+    step between neighbours means nothing happened there)."""
+    from accelerate_tpu.serving import ServingEngine, replay, synthesize_trace
+
+    model, params, plugin, gen = _serve_setup(num_pages=16)     # evictions too
+    trace = synthesize_trace(7, 6, vocab_size=model.config.vocab_size,
+                             mean_interarrival_steps=0.3,
+                             prompt_len_range=(12, 24), new_tokens_range=(12, 24))
+    eng = ServingEngine(model, params, plugin, gen)
+    clk = VirtualClock(1.0)
+    tracer = eng.enable_tracing(clock=clk, capacity=1 << 16)
+    rep = replay(eng, trace)
+    assert rep["evictions"] > 0
+    by_step: dict = {}
+    for name, start, end, args in _spans(tracer, "engine"):
+        by_step.setdefault(args["step"], []).append((start, end, name))
+    assert len(by_step) == rep["engine_steps"]
+    seen = set()
+    for step, spans in by_step.items():
+        spans.sort()
+        names = [n for _, _, n in spans]
+        seen.update(n.split(":")[0] for n in names)
+        assert names[0] == "control" and names[-1] == "trace", names
+        for (_, end, _), (start, _, _) in zip(spans, spans[1:]):
+            assert start - end == clk.step, (step, names)     # siblings, nothing between
+    assert seen == {"control", "schedule", "plan", "stage", "dispatch",
+                    "host_sync", "commit", "trace"}
+    # consecutive ticks: only the caller (the replay loop) reads no clock between them
+    ends = {s: max(e for _, e, _ in v) for s, v in by_step.items()}
+    starts = {s: min(b for b, _, _ in v) for s, v in by_step.items()}
+    assert all(starts[s + 1] > ends[s] for s in ends if s + 1 in starts)
+
+
+def test_spans_are_annotations_in_the_profilers_trace(tmp_path):
+    """Inside a profiler session every bracketing span is also a host
+    annotation of the same name with the span's step, on the profiler's own
+    timeline (what perfbench/program_trace.py reads)."""
+    import glob
+    import gzip
+
+    rec = SpanRecorder()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with rec.span("stage:decode", "engine", cat="step", step=41, slots=[0, 1]):
+            jnp.ones((4,)).block_until_ready()
+        rec.complete("queued", "req 1", rec.stamp())     # retroactive: the ring only
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.trace.json.gz"), recursive=True)
+    with gzip.open(path) as f:
+        events = json.load(f)["traceEvents"]
+    named = [e for e in events if e.get("ph") == "X"
+             and e.get("args", {}).get("long_name", e["name"]) == "stage:decode"]
+    assert len(named) == 1 and named[0]["args"]["step"] == "41"
+    assert "slots" not in named[0]["args"]              # step / uid only
+    assert not [e for e in events if e.get("name") == "queued"]
+    assert [e[1] for e in rec.events()] == ["stage:decode", "queued"]
+
+
+def test_latency_samples_are_bounded_and_the_counters_keep_the_whole_run():
+    from accelerate_tpu.serving import ServingEngine, replay, synthesize_trace
+    from accelerate_tpu.serving import engine as engine_mod
+
+    model, params, plugin, gen = _serve_setup()
+    trace = synthesize_trace(4, 10, vocab_size=model.config.vocab_size,
+                             mean_interarrival_steps=0.5,
+                             prompt_len_range=(4, 16), new_tokens_range=(8, 16))
+    eng = ServingEngine(model, params, plugin, gen)
+    assert eng.ttft_s.maxlen == eng.token_gaps_s.maxlen == engine_mod._SAMPLE_WINDOW
+    eng.token_gaps_s = type(eng.token_gaps_s)(maxlen=16)
+    rep = replay(eng, trace)
+    assert len(eng.token_gaps_s) == 16 < rep["generated_tokens"]
+    assert rep["p50_token_latency_ms"] > 0.0
+    m = eng.metrics
+    assert m["ttft_n"] == m["queue_wait_n"] == len(trace) == len(eng.ttft_s)
+    assert m["ttft_s_sum"] == pytest.approx(sum(eng.ttft_s))
+    assert 0.0 < m["queue_wait_s_sum"] < m["ttft_s_sum"]
+    assert not eng._dispatch_seen and not eng._ttft_seen      # live-request state only
+
+
 def test_engine_telemetry_plugin_arms_tracing(monkeypatch):
     from accelerate_tpu.serving import ServingEngine
 
