@@ -1244,14 +1244,9 @@ def per_shard(kernel, in_specs, out_specs):
     local blocks and need no spec.  No mesh, or no free axis left:
     ``kernel`` itself — the bare call.
     """
-    from ..state import ambient_mesh
+    from ..state import free_mesh_axes
 
-    ctx = jax.sharding.get_abstract_mesh()
-    mesh = ambient_mesh() if ctx.empty else ctx
-    if mesh is None:
-        return kernel
-    rest = set(mesh.axis_names) - set(mesh.manual_axes)
-    free = {a: mesh.shape[a] for a in mesh.axis_names if a in rest and mesh.shape[a] > 1}
+    mesh, rest, free = free_mesh_axes()
     if not free:
         return kernel
     return jax.shard_map(kernel, mesh=mesh, in_specs=in_specs(free),

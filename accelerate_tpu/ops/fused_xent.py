@@ -13,6 +13,20 @@ from its engines (e.g. DeepSpeed/Megatron fused CE, reference
 megatron_lm.py loss paths); here it is a custom_vjp over XLA dots, which is
 exactly what the hardware wants (no Pallas needed — the win is scheduling,
 not kernel fusion).
+
+Under a mesh the op is vocabulary-parallel.  When the context leaves a ``tp``
+axis wider than one to GSPMD and the vocabulary divides by it, forward and
+backward each run inside a ``shard_map`` that is manual over ``tp`` alone
+(scope ``fused_xent/.../vocab_shard``): a shard chunks ITS slice of the head
+(``num_chunks`` chunks of what a device holds), so no slice runs across
+shards.  What crosses ``tp``: the max, the rescaled sum-exp and the label
+logit, three [N] fp32 vectors, forward; the partial ``dh`` [N, H], summed once
+in fp32 before the cast, backward.  ``dw`` of a slice stays on its shard.  Rows
+stay split over the data-parallel axes and an FSDP-sharded hidden dim of the
+head is gathered a chunk at a time, by GSPMD inside the region as outside.
+One chip, FSDP only, a region already manual over ``tp`` or a vocabulary
+``tp`` does not divide: the bare chunk loop, with no ``shard_map`` traced.
+Nothing selects the path but the mesh and the vocabulary.
 """
 
 from __future__ import annotations
@@ -26,19 +40,18 @@ import numpy as np
 _MASK = -0.7 * float(np.finfo(np.float32).max)
 
 
-def _chunk_logits(hidden, weight, c, chunk, vocab_major: bool):
+def _chunk_logits(hidden, weight, c, chunk, vocab_major: bool, gather: bool = False):
     """Logits for vocab chunk ``c``: [N, chunk] fp32 (bf16 operands, fp32
-    accumulation), with out-of-vocab columns masked."""
-    if vocab_major:  # weight [V, H]
-        w_c = jax.lax.dynamic_slice_in_dim(weight, c * chunk, chunk, axis=0)
-        logits = jax.lax.dot_general(
-            hidden, w_c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-    else:  # weight [H, V]
-        w_c = jax.lax.dynamic_slice_in_dim(weight, c * chunk, chunk, axis=1)
-        logits = jax.lax.dot_general(
-            hidden, w_c, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+    accumulation), with out-of-vocab columns masked.  ``gather`` (inside the
+    per-``tp``-shard region): the chunk is asked whole along its hidden dim
+    on every device GSPMD still places, so an FSDP-sharded head is gathered
+    a chunk at a time and the rows stay where they are."""
+    axis = 0 if vocab_major else 1
+    w_c = jax.lax.dynamic_slice_in_dim(weight, c * chunk, chunk, axis=axis)
+    if gather:
+        w_c = jax.lax.with_sharding_constraint(w_c, jax.sharding.PartitionSpec())
+    contract = (((1,), (1 - axis,)), ((), ()))  # [V, H] or [H, V]
+    logits = jax.lax.dot_general(hidden, w_c, contract, preferred_element_type=jnp.float32)
     return logits, w_c
 
 
@@ -66,14 +79,17 @@ def _pad_vocab(weight, num_chunks, vocab_major):
     return weight, v, chunk
 
 
-@jax.named_scope("fused_xent")
-def _fwd(hidden, weight, labels, mask, num_chunks, vocab_major):
+def _softmax_stats(hidden, weight, labels, num_chunks, vocab_major, gather=False):
+    """Online max ``m``, sum-exp ``l`` (relative to ``m``) and the label logit
+    over the columns of ``weight``, a chunk at a time: three [N] fp32 vectors.
+    ``labels`` index ``weight``'s own columns; one outside them (-1: the
+    label lives in another shard's slice) leaves ``label_logit`` at 0."""
     n = hidden.shape[0]
     weight_p, v, chunk = _pad_vocab(weight, num_chunks, vocab_major)
 
     def body(c, carry):
         m, l, label_logit = carry
-        logits, _ = _chunk_logits(hidden, weight_p, c, chunk, vocab_major)
+        logits, _ = _chunk_logits(hidden, weight_p, c, chunk, vocab_major, gather)
         cols = c * chunk + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
         logits = jnp.where(cols < v, logits, _MASK)
         m_new = jnp.maximum(m, jnp.max(logits, axis=1))
@@ -89,22 +105,18 @@ def _fwd(hidden, weight, labels, mask, num_chunks, vocab_major):
         jnp.zeros((n,), jnp.float32),
         jnp.zeros((n,), jnp.float32),
     )
-    m, l, label_logit = jax.lax.fori_loop(0, num_chunks, body, init)
-    lse = m + jnp.log(jnp.where(l == 0, 1.0, l))
-    n_valid = jnp.maximum(jnp.sum(mask.astype(jnp.float32)), 1.0)
-    loss = jnp.sum((lse - label_logit) * mask) / n_valid
-    return loss, (hidden, weight, labels, mask, lse, n_valid)
+    return jax.lax.fori_loop(0, num_chunks, body, init)
 
 
-@jax.named_scope("fused_xent")
-def _bwd(num_chunks, vocab_major, res, gbar):
-    hidden, weight, labels, mask, lse, n_valid = res
+def _chunk_grads(hidden, weight, labels, lse, coef, num_chunks, vocab_major, gather=False):
+    """fp32 ``dh`` [N, H] (the sum over ``weight``'s columns only) and ``dw``
+    (``weight``'s shape) from ``p - onehot`` a chunk at a time, ``p`` rebuilt
+    from the global ``lse``.  ``labels`` as in :func:`_softmax_stats`."""
     weight_p, v, chunk = _pad_vocab(weight, num_chunks, vocab_major)
-    coef = (mask.astype(jnp.float32) * (gbar / n_valid))[:, None]  # [N, 1]
 
     def body(c, carry):
         dh, dw = carry
-        logits, w_c = _chunk_logits(hidden, weight_p, c, chunk, vocab_major)
+        logits, w_c = _chunk_logits(hidden, weight_p, c, chunk, vocab_major, gather)
         cols = c * chunk + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
         p = jnp.where(cols < v, jnp.exp(logits - lse[:, None]), 0.0)
         onehot = (cols == labels[:, None]).astype(jnp.float32)
@@ -134,6 +146,80 @@ def _bwd(num_chunks, vocab_major, res, gbar):
     dh, dw = jax.lax.fori_loop(0, num_chunks, body, init)
     if weight_p.shape != weight.shape:  # drop the padded vocab tail
         dw = dw[:v] if vocab_major else dw[:, :v]
+    return dh, dw
+
+
+def _per_vocab_shard(shard_fn, weight, vocab_major, weight_out=False):
+    """``shard_fn(hidden, weight_slice, local_labels, *row_vectors)`` wrapped
+    to run once per ``tp`` shard on that shard's slice of the vocabulary, or
+    None where the bare call is the one to make: no mesh, no ``tp`` axis wider
+    than one that the context still leaves to GSPMD (one chip, FSDP only, a
+    region already manual over ``tp``), or a vocabulary ``tp`` does not divide.
+
+    Only ``tp`` goes manual.  Rows stay split over the data-parallel axes and
+    the head's other dim over FSDP's by GSPMD inside as outside: the gather of
+    an FSDP-sharded head chunk and the reduction of ``dw`` over ``dp_shard``
+    are the ones every parameter has.  ``local_labels`` are the labels as
+    columns of the slice, -1 where the label is another shard's.  ``shard_fn``
+    returns arrays it has already reduced over ``tp`` and, with
+    ``weight_out``, last one shaped like the weight slice."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..state import free_mesh_axes
+
+    mesh, _, free = free_mesh_axes()
+    tp = free.get("tp", 1)
+    v = _num_vocab(weight, vocab_major)
+    if tp == 1 or v % tp:
+        return None
+    w_spec = P("tp", None) if vocab_major else P(None, "tp")
+    width = v // tp
+
+    @jax.named_scope("vocab_shard")
+    def shard(hidden, w, labels, *rows):
+        local = labels - jax.lax.axis_index("tp") * width
+        local = jnp.where((local >= 0) & (local < width), local, -1)
+        return shard_fn(hidden, w, local, *rows)
+
+    def run(hidden, weight, labels, *rows):
+        # jit: a shard_map that leaves axes to GSPMD cannot run eagerly
+        return jax.jit(jax.shard_map(
+            shard, mesh=mesh, in_specs=(P(), w_spec) + (P(),) * (1 + len(rows)),
+            out_specs=(P(), w_spec) if weight_out else P(),
+            axis_names={"tp"}, check_vma=False,
+        ))(hidden, weight, labels, *rows)
+
+    return run
+
+
+@jax.named_scope("fused_xent")
+def _fwd(hidden, weight, labels, mask, num_chunks, vocab_major):
+    def shard_stats(hidden, w, local):
+        m, l, label_logit = _softmax_stats(hidden, w, local, num_chunks, vocab_major, True)
+        m_all = jax.lax.pmax(m, "tp")
+        return m_all, jax.lax.psum(l * jnp.exp(m - m_all), "tp"), jax.lax.psum(label_logit, "tp")
+
+    stats = _per_vocab_shard(shard_stats, weight, vocab_major) or functools.partial(
+        _softmax_stats, num_chunks=num_chunks, vocab_major=vocab_major)
+    m, l, label_logit = stats(hidden, weight, labels)
+    lse = m + jnp.log(jnp.where(l == 0, 1.0, l))
+    n_valid = jnp.maximum(jnp.sum(mask.astype(jnp.float32)), 1.0)
+    loss = jnp.sum((lse - label_logit) * mask) / n_valid
+    return loss, (hidden, weight, labels, mask, lse, n_valid)
+
+
+@jax.named_scope("fused_xent")
+def _bwd(num_chunks, vocab_major, res, gbar):
+    hidden, weight, labels, mask, lse, n_valid = res
+    coef = (mask.astype(jnp.float32) * (gbar / n_valid))[:, None]  # [N, 1]
+
+    def shard_grads(hidden, w, local, lse, coef):
+        dh, dw = _chunk_grads(hidden, w, local, lse, coef, num_chunks, vocab_major, True)
+        return jax.lax.psum(dh, "tp"), dw  # the one [N, H] that crosses tp, in fp32
+
+    grads = _per_vocab_shard(shard_grads, weight, vocab_major, weight_out=True) or functools.partial(
+        _chunk_grads, num_chunks=num_chunks, vocab_major=vocab_major)
+    dh, dw = grads(hidden, weight, labels, lse, coef)
     return (
         dh.astype(hidden.dtype),
         dw.astype(weight.dtype),

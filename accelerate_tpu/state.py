@@ -530,3 +530,18 @@ def ambient_mesh() -> Optional[jax.sharding.Mesh]:
         return AcceleratorState().mesh
     except Exception:  # pragma: no cover - half-built state
         return None
+
+
+def free_mesh_axes():
+    """``(mesh, rest, free)`` of the mesh that trace-time code partitions
+    over: the context's when traced inside a ``shard_map`` (jax rejects any
+    other there), else the Accelerator's.  ``rest`` is the set of axes not
+    yet manual, ``free`` maps those of them wider than one device to their
+    size.  No mesh: ``(None, set(), {})``."""
+    ctx = jax.sharding.get_abstract_mesh()
+    mesh = ambient_mesh() if ctx.empty else ctx
+    if mesh is None:
+        return None, set(), {}
+    rest = set(mesh.axis_names) - set(mesh.manual_axes)
+    free = {a: mesh.shape[a] for a in mesh.axis_names if a in rest and mesh.shape[a] > 1}
+    return mesh, rest, free
