@@ -210,6 +210,28 @@ def get_attention_impl(name: str) -> Callable:
     raise ValueError(f"unknown attention implementation {name!r}")
 
 
+def _rows(x, tp_dim=None):
+    """The layout the cache-free (training) path states for an activation:
+    ``parallel/sharding.constrain_activation`` — rows over the batch axes,
+    sequence over ``cp``/``sp``, ``tp_dim`` over ``tp`` — so that FSDP gathers
+    weights and no activation crosses ``dp_shard``.  Not with the
+    collective-matmul rings on: they hand their tensors over sequence- (or, at
+    the Ulysses boundary, head-) sharded over the ring axis."""
+    from ..ops.collective_matmul import collective_matmul_mode
+
+    if collective_matmul_mode() != "off":
+        return x
+    from ..parallel.sharding import constrain_activation
+
+    return constrain_activation(x, tp_dim)
+
+
+def _as_is(x, tp_dim=None):
+    """What the serving programs (``cache is not None``) get where the
+    training path gets :func:`_rows`: no layout stated, their programs stay."""
+    return x
+
+
 # Sentinel position for unwritten / padding cache slots: larger than any real
 # token position, so the causal comparison `kv_pos <= q_pos` excludes them.
 CACHE_PAD_POSITION = np.int32(2**30)
@@ -480,6 +502,9 @@ class LlamaAttention(nn.Module):
         q = q.reshape(b, t, cfg.num_attention_heads, cfg.head_dim)
         k = k.reshape(b, t, cfg.num_key_value_heads, cfg.head_dim)
         v = v.reshape(b, t, cfg.num_key_value_heads, cfg.head_dim)
+        if cache is None:
+            # heads over tp: what mesh_flash_attention's qkv_spec asks for
+            q, k, v = (_rows(a, tp_dim=2) for a in (q, k, v))
 
         cos, sin = rope_frequencies(cfg.head_dim, cfg.max_position_embeddings, cfg.rope_theta)
         cos, sin = jnp.asarray(cos), jnp.asarray(sin)
@@ -587,7 +612,7 @@ class LlamaAttention(nn.Module):
             # o_proj row ring below scatters the sequence back
             attn_kwargs["heads_sharded"] = True
         out = attn(q, k, v, causal=True, segment_ids=segment_ids, **attn_kwargs)
-        out = out.reshape(b, t, cfg.num_attention_heads * cfg.head_dim)
+        out = _rows(out.reshape(b, t, cfg.num_attention_heads * cfg.head_dim), tp_dim=-1)
         return row(cfg.hidden_size, name="o_proj")(out, adapter_ids)
 
 
@@ -595,16 +620,21 @@ class LlamaMLP(nn.Module):
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, x, adapter_ids=None):
+    def __call__(self, x, adapter_ids=None, rows: bool = False):
+        """``rows``: the cache-free path's call — the MLP's width is stated
+        over ``tp`` and its output in rows (:func:`_rows`)."""
         cfg = self.config
+        pin = _rows if rows else _as_is
         dense = partial(QuantizableDense, use_bias=False, dtype=cfg.dtype, param_dtype=jnp.float32)
         # Megatron roles for the collective-matmul ring over tp: gate/up
         # column-parallel (gather the sequence into the matmul), down
         # row-parallel (reduce-scatter the output back to sequence shards)
-        gate = dense(cfg.intermediate_size, name="gate_proj", tp_mode="column")(x, adapter_ids)
-        up = dense(cfg.intermediate_size, name="up_proj", tp_mode="column")(x, adapter_ids)
-        return dense(cfg.hidden_size, name="down_proj", tp_mode="row")(
-            nn.silu(gate) * up, adapter_ids)
+        gate = pin(dense(cfg.intermediate_size, name="gate_proj", tp_mode="column")(x, adapter_ids),
+                   tp_dim=-1)
+        up = pin(dense(cfg.intermediate_size, name="up_proj", tp_mode="column")(x, adapter_ids),
+                 tp_dim=-1)
+        return pin(dense(cfg.hidden_size, name="down_proj", tp_mode="row")(
+            pin(nn.silu(gate) * up, tp_dim=-1), adapter_ids))
 
 
 class LlamaBlock(nn.Module):
@@ -614,17 +644,22 @@ class LlamaBlock(nn.Module):
     def __call__(self, x, positions, segment_ids=None, cache=None, cache_write_mask=None,
                  adapter_ids=None):
         cfg = self.config
-        attn_in = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="input_layernorm")(x)
+        # the cache-free (training) path states its layout at the block's
+        # boundaries; the serving programs keep theirs
+        rows = cache is None
+        pin = _rows if rows else _as_is
+        x = pin(x)
+        attn_in = pin(RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="input_layernorm")(x))
         attn = LlamaAttention(cfg, name="self_attn")(attn_in, positions, segment_ids, cache,
                                                      cache_write_mask, adapter_ids)
         new_cache = None
         if cache is not None:
             attn, new_cache = attn
-        h = x + attn
-        out = h + LlamaMLP(cfg, name="mlp")(
-            RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="post_attention_layernorm")(h),
-            adapter_ids,
-        )
+        h = pin(x + attn)
+        out = pin(h + LlamaMLP(cfg, name="mlp")(
+            pin(RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="post_attention_layernorm")(h)),
+            adapter_ids, rows,
+        ))
         if cache is not None:
             return out, new_cache
         return out
@@ -646,6 +681,7 @@ class _ScanBody(nn.Module):
         from jax.ad_checkpoint import checkpoint_name
 
         cfg = self.config
+        x = _rows(x)  # the carry, as an unrolled block's input
         frac = getattr(cfg, "boundary_offload_fraction", 1.0)
         if frac < 1.0 and cfg.remat and cfg.remat_policy == "offload":
             # hybrid boundary residency: the head slice of the sequence goes
@@ -797,6 +833,8 @@ class LlamaForCausalLM(nn.Module):
             cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype, param_dtype=jnp.float32, name="embed_tokens"
         )
         x = embed(input_ids)
+        if cache is None:
+            x = _rows(x)
         block = type(self).block_cls
         offload_remat = False
         if cfg.remat and cache is None and cfg.remat_policy == "offload":
@@ -894,6 +932,8 @@ class LlamaForCausalLM(nn.Module):
                 else:
                     x = layer(x, positions, segment_ids)
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="norm")(x)
+        if cache is None:
+            x = _rows(x)  # what the fused CE (or the head) is handed
         if output_hidden:
             # pre-head states for the fused linear+CE loss path (the vocab
             # projection happens inside the loss, chunked over the vocab)

@@ -1264,10 +1264,12 @@ def mesh_flash_attention(q, k, v, *, causal: bool = True, segment_ids=None, **kw
     """
     from jax.sharding import PartitionSpec as P
 
+    from ..parallelism_config import BATCH_AXES
+
     attn = functools.partial(flash_attention, causal=causal, **kwargs)
 
     def qkv_spec(free):
-        batch = tuple(a for a in ("dcn", "dp_replicate", "dp_shard") if a in free)
+        batch = tuple(a for a in BATCH_AXES if a in free)
         if q.shape[0] % int(np.prod([free[a] for a in batch] or [1])):
             batch = ()
         tp = free.get("tp", 1)

@@ -26,7 +26,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from ..parallelism_config import ParallelismConfig
+from ..parallelism_config import BATCH_AXES, SEQ_AXES, ParallelismConfig
 from ..utils.dataclasses import FullyShardedDataParallelPlugin, ShardingStrategy
 
 logger = logging.getLogger(__name__)
@@ -268,6 +268,42 @@ def get_tp_rules(plan: str = "auto"):
     if plan in ("none", None):
         return []
     raise ValueError(f"unknown tp plan {plan!r}")
+
+
+def constrain_activation(x, tp_dim: Optional[int] = None):
+    """Pin a training activation ``[B, T, ...]`` to its rows: the batch dim
+    over the batch axes, the sequence dim over ``cp``/``sp``, dim ``tp_dim``
+    (an MLP's width, the heads) over ``tp`` where ``tp`` divides it, every
+    other dim whole.
+
+    The parameter plan shards every matrix over ``dp_shard`` AND ``tp``.  With
+    no layout stated for the activations GSPMD may read the FSDP axis as a
+    second tensor-parallel axis: gather the batch, multiply against the weight
+    shard it holds and all-reduce the partial products over ``dp_shard`` —
+    activation-sized traffic on the critical path of every matmul.  FSDP means
+    the weights move and the activations stay: with the rows pinned the
+    partitioner gathers the weight shards (and reduces their gradients over
+    the same axis), which depends on no activation and prefetches under the
+    matmuls.
+
+    The axes are those the mesh still leaves to GSPMD and that are wider than
+    one device (``state.free_mesh_axes``: inside a GPipe stage ``pp`` is not
+    among them, inside a fully manual region none is), read from the lists the
+    batch spec is built from, so the batch as it arrives and the activations
+    agree.  No mesh, no free batch axis (one device, ``tp`` alone), or rows or
+    a sequence the axes do not divide: ``x`` itself.
+    """
+    from ..state import free_mesh_axes
+
+    mesh, _, free = free_mesh_axes()
+    rows = tuple(a for a in BATCH_AXES if a in free)
+    seq = tuple(a for a in SEQ_AXES if a in free)
+    if not rows or x.shape[0] % _axis_size(mesh, rows) or x.shape[1] % _axis_size(mesh, seq):
+        return x
+    spec = [rows, seq or None] + [None] * (x.ndim - 2)
+    if tp_dim is not None and "tp" in free and x.shape[tp_dim] % free["tp"] == 0:
+        spec[tp_dim] = "tp"
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, PartitionSpec(*spec)))
 
 
 def shard_params(params, plan):

@@ -47,6 +47,14 @@ from jax.sharding import Mesh, PartitionSpec
 # accounting twins all agree on which hops are expensive.
 MESH_AXIS_ORDER = ("dcn", "dp_replicate", "pp", "dp_shard", "cp", "sp", "tp", "ep")
 
+# The axes the batch dim (pure data parallelism, ``dcn`` outermost) and the
+# sequence dim (CP ring / SP Ulysses) shard over: one list for the batch as it
+# arrives (``batch_spec``, ``Accelerator._default_batch_spec``) and for the
+# layout the training path states for its activations
+# (``parallel/sharding.constrain_activation``, ``mesh_flash_attention``).
+BATCH_AXES = ("dcn", "dp_replicate", "dp_shard")
+SEQ_AXES = ("cp", "sp")
+
 # The per-axis size fields / env vars are derived from the axis list so a new
 # axis cannot silently miss one of the transport surfaces (launcher flags,
 # PARALLELISM_CONFIG_* env, from_env/to_env).
@@ -134,7 +142,7 @@ class ParallelismConfig:
 
     @property
     def dp_dim_names(self) -> tuple[str, ...]:
-        return self._enabled(("dcn", "dp_replicate", "dp_shard"))
+        return self._enabled(BATCH_AXES)
 
     @property
     def dp_shard_cp_dim_names(self) -> tuple[str, ...]:
@@ -157,12 +165,12 @@ class ParallelismConfig:
         """Axes the batch dimension of input data shards over.  ``dcn`` is
         outermost so each slice's hosts feed a contiguous block of the
         global batch (the per-host dataloader sharding contract)."""
-        return self._enabled(("dcn", "dp_replicate", "dp_shard"))
+        return self._enabled(BATCH_AXES)
 
     @property
     def seq_dim_names(self) -> tuple[str, ...]:
         """Axes the sequence dimension shards over (CP ring / SP Ulysses)."""
-        return self._enabled(("cp", "sp"))
+        return self._enabled(SEQ_AXES)
 
     def _enabled(self, names: Sequence[str]) -> tuple[str, ...]:
         sizes = self._sizes()

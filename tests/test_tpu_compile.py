@@ -220,6 +220,54 @@ def test_fused_cross_entropy_compiles(compile_for_chip):
     assert "fusion" in text
 
 
+def test_fsdp_x_tp_block_moves_weights_not_activations(topo, compile_for_chip, monkeypatch):
+    """Forward + backward of ONE ``LlamaBlock`` at Yi-1.5-34B widths (the
+    four-chip benchmark cell: dp_shard 2 x tp 2, batch 4 x 4096, parameters on
+    ``_params_plan``'s shardings, the flash kernel) over ``topo.devices``.
+    With the rows pinned (``parallel/sharding.constrain_activation``) the
+    chip's partitioner runs FSDP: it gathers ``gate``/``up`` whole over
+    ``dp_shard`` and no collective's result carries the global batch — no
+    ``[4, 4096, ...]`` partial-product all-reduce or gather, no all-to-all of
+    the residual stream between a rows layout and a hidden-halved one."""
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from accelerate_tpu import Accelerator, ParallelismConfig
+    from accelerate_tpu.models.llama import LlamaBlock, LlamaConfig
+
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)   # the kernel, not its interpreter
+    batch, seq, hidden, mlp = 4, 4096, 7168, 20480
+    acc = Accelerator(mixed_precision="bf16", parallelism_config=ParallelismConfig(
+        dp_shard_size=2, tp_size=2, devices=list(topo.devices)))
+    block = LlamaBlock(LlamaConfig(
+        hidden_size=hidden, intermediate_size=mlp, num_attention_heads=56, num_key_value_heads=8,
+        max_position_embeddings=seq, attn_implementation="flash", dtype=BF16))
+    abstract = jax.eval_shape(lambda: block.init(
+        jax.random.key(0), jnp.zeros((batch, 8, hidden), BF16), jnp.zeros((batch, 8), jnp.int32)))
+    plan = acc._params_plan(abstract)
+    params = jax.tree_util.tree_map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, BF16, sharding=s), abstract, plan)
+    rows = lambda *rest: NamedSharding(acc.mesh, P("dp_shard", *rest))
+
+    def fwd_bwd(params, x, positions):
+        loss = lambda p, x: jnp.sum(block.apply(p, x, positions).astype(jnp.float32))
+        return jax.value_and_grad(loss, argnums=(0, 1))(params, x)
+
+    out_shardings = (NamedSharding(acc.mesh, P()), (plan, rows(None, None)))
+    text = jax.jit(fwd_bwd, out_shardings=out_shardings).lower(
+        params, jax.ShapeDtypeStruct((batch, seq, hidden), BF16, sharding=rows(None, None)),
+        jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=rows(None))).compile().as_text()
+    collectives = [(kind, tuple(int(d) for d in dims.split(",") if d)) for dims, kind in re.findall(
+        r"= \(?\w+\[([0-9,]*)\]\S* (all-reduce|all-gather|all-to-all|reduce-scatter)(?:-start)?\(",
+        text)]
+    assert ("all-gather", (hidden, mlp // 2)) in collectives      # gate / up, whole, over dp_shard
+    assert [c for c in collectives if c[1][:2] == (batch, seq)] == []   # nothing at the global batch
+    assert [c for c in collectives
+            if c[0] == "all-to-all" and seq in c[1] and hidden // 2 in c[1]] == []
+    assert text.count("tpu_custom_call") >= 3
+
+
 # -- Keye-VL-2.0's serving programs at the cell's shapes (keye-vl2.serve_long) -----------
 
 KEYE_SLOTS, KEYE_PAGES, KEYE_PAGES_PER_SLOT = 8, 4160, 520
