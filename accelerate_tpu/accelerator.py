@@ -363,8 +363,8 @@ class Accelerator:
         self._in_accumulate = False
         # recompile guard: backend-compile events since construction (the
         # process-wide jax.monitoring stream, reported as a delta) — after
-        # the first step compiles, a steady-state loop must stay flat;
-        # bench.py emits the compiles_predicted/compiles_measured twins
+        # the first step compiles, a steady-state loop must stay flat
+        # (perfbench refuses a window with a compile in it)
         from .analysis.compiled_audit import install_global_compile_counter
 
         self._compile_counter = install_global_compile_counter()
@@ -375,7 +375,7 @@ class Accelerator:
 
         # resilience layer (docs/resilience.md): knobs default from the
         # ACCELERATE_RESILIENCE env family; the goodput tracker always exists
-        # (bench.py reads it unconditionally — zeros when the run is clean)
+        # (its report reads zeros when the run is clean)
         self.resilience_plugin = resilience_plugin or ResiliencePlugin()
         self.goodput = GoodputTracker()
         # unified telemetry (docs/observability.md): the training timeline
@@ -2388,8 +2388,7 @@ class Accelerator:
         Returns ``(train_state_or_None, report)`` where ``report`` carries
         ``restore_path`` (``"peer"`` / ``"disk"`` / ``"fresh"``),
         ``restored_step``, ``steps_recomputed``, ``peer_snapshot_bytes`` and
-        ``restore_time_s`` — the shape bench.py's always-emitted ``recovery``
-        block mirrors.  Records the measured ``recovery.restore_time_s``
+        ``restore_time_s``.  Records the measured ``recovery.restore_time_s``
         twin."""
         from .telemetry import twin_registry
 

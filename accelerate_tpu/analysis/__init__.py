@@ -29,7 +29,7 @@ device):
 Surfaces: ``python -m accelerate_tpu lint`` / ``preflight``
 (``commands/lint.py``, ``commands/preflight.py``),
 ``Accelerator.audit_step()`` / ``ACCELERATE_LINT=1``, ``make lint`` /
-``make preflight``, and ``bench.py --plan N --audit``.  Rule catalog and
+``make preflight``.  Rule catalog and
 suppression syntax: ``docs/static_analysis.md``.
 """
 

@@ -661,7 +661,7 @@ def _rule_timing_without_block(index: _ModuleIndex, path: str) -> list[Finding]:
     ``jax.jit``-bound name (or a jit-decorated function, or a direct
     ``jax.jit(f)(x)``) ... ``<expr> - t0`` with no materializing call
     (``jax.block_until_ready``/``float``/``np.asarray``/``.item()``/...)
-    between the LAST jitted call and the delta.  The bench.py timed-loop
+    between the LAST jitted call and the delta.  The timed-loop
     idiom (jitted steps, then ``float(loss)`` + ``block_until_ready``,
     then the closing clock read) passes clean.  Known miss: timing through
     a method call (``engine.run(...)``) or a helper bound outside the

@@ -29,10 +29,11 @@ Plus the **cost report**: per-program flops / bytes-accessed from
 
 The compile-event counter (:class:`CompileCounter`) hooks the
 ``jax.monitoring`` event stream (``/jax/core/compile/
-backend_compile_duration`` — one event per real XLA backend compile, cache
-hits excluded) and backs the runtime recompile guard:
-``ServingEngine.compile_events`` / ``Accelerator.compile_events`` and the
-``compiles_predicted`` / ``compiles_measured`` twins bench.py always emits.
+backend_compile_duration`` — one event per trip to the XLA backend for an
+executable; jit-call cache hits excluded) and backs the runtime recompile
+guard: ``ServingEngine.compile_events`` / ``Accelerator.compile_events`` and
+the ``compiles_predicted`` / ``compiles_measured`` twins of the replay
+harness.
 
 Everything here is CPU-safe: AOT compilation needs a backend but never
 executes the program, so a deploy preflight runs on the CI box.
@@ -57,8 +58,9 @@ except Exception:  # pragma: no cover - private-API drift
     _monitoring = None
 
 
-# one event per actual XLA backend compilation (persistent-cache hits and
-# jit-call cache hits do NOT fire it) — the signal the recompile guard wants
+# one event per trip to the XLA backend for an executable: a compilation
+# or, on jax 0.9, a load from the persistent cache (a jit-call cache hit
+# fires nothing) — the signal the recompile guard wants
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
@@ -194,8 +196,15 @@ def aot_compile_program(
     arrays or ``jax.ShapeDtypeStruct`` stand-ins — nothing executes), timing
     the wall and counting the real backend-compile events (a persistent-
     cache hit costs 0)."""
+    # A plain function is jitted through a function object of its own: jax
+    # keys its in-memory trace, lowering and executable caches on the
+    # function's identity, so ``jax.jit(fn)`` would be handed back whatever
+    # an earlier jit call of ``fn`` left there — after a persistent-cache
+    # hit, the DESERIALIZED executable ``fresh_compile_context`` exists to
+    # keep out of the audit.  An already-jitted ``fn`` is the caller's.
     jitted = fn if hasattr(fn, "trace") else jax.jit(
-        fn, donate_argnums=donate_argnums, static_argnums=static_argnums
+        lambda *args: fn(*args),
+        donate_argnums=donate_argnums, static_argnums=static_argnums,
     )
     counter = CompileCounter()
     t0 = time.perf_counter()

@@ -46,8 +46,7 @@ allocation).
 
 Surfaces: ``preflight --serve --disaggregate`` (:func:`pair_preflight`
 audits both roles as a pair), ``lint`` (the same pair contract on every
-sweep), ``bench --plan --audit`` (summary embedding), and the multichip
-dryrun's ``_distributed_audit_leg``.  Suppression is source-anchored like
+sweep), and the multichip dryrun's ``_distributed_audit_leg``.  Suppression is source-anchored like
 every other engine; findings carry ``engine="distributed"``.
 """
 
@@ -578,7 +577,7 @@ def pair_preflight(model_config, prefill_plugin, decode_plugin, *,
     on ``eval_shape`` stand-ins: zero backend compiles), GL402 resharding
     on those traces, and GL404 warmup coverage per role.  Returns
     ``(findings, summary)`` — the summary is the JSON-able digest
-    ``bench --plan --audit`` and the dryrun leg embed."""
+    the dryrun leg embeds."""
     import jax
 
     path_hint = _transfer_path_hint()

@@ -240,7 +240,7 @@ class Report:
         return c
 
     def summary(self) -> dict:
-        """Compact JSON-able digest (what bench.py / trackers embed)."""
+        """Compact JSON-able digest (what trackers embed)."""
         return {
             **self.counts(),
             "rules": sorted({f.rule for f in self.findings if not f.suppressed}),

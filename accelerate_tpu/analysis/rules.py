@@ -135,8 +135,7 @@ RULES: dict[str, Rule] = {
             "never fails a run)",
             "materialize before reading the clock: "
             "jax.block_until_ready(out) (or float(loss)/np.asarray) between "
-            "the jitted call and the closing perf_counter(), the bench.py "
-            "timed-loop idiom",
+            "the jitted call and the closing perf_counter()",
         ),
         Rule(
             "GL110", "unscaled-fp8-dot", Severity.ERROR, "jaxpr",

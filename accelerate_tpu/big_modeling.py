@@ -585,8 +585,8 @@ def load_checkpoint_and_dispatch(
 def serve_model(model, params, serving_plugin=None, generation_config=None, rng=None):
     """Stand up a continuous-batching :class:`~accelerate_tpu.serving.ServingEngine`
     over an already-dispatched param tree — the serving-side completion of
-    the reference's load→dispatch→generate contract (big_modeling.py:513 +
-    benchmarks/big_model_inference), rebuilt at production scale: paged KV
+    the reference's load→dispatch→generate contract (reference
+    big_modeling.py:513 + its benchmarks/big_model_inference), rebuilt at production scale: paged KV
     cache, per-step admission/eviction, chunked prefill (docs/serving.md).
 
     ``params`` is whatever :func:`load_checkpoint_and_dispatch` or

@@ -182,8 +182,7 @@ def preflight_train(config: PreflightConfig, hbm_budget_bytes=None):
 def _serve_setup():
     """The serving model/plugin the preflight audits: geometry from the
     ``ACCELERATE_SERVE_*`` env family (the ServingPlugin contract), the
-    tiny model on CPU and the 600m-class decode shape on TPU (bench.py's
-    ``--serve`` convention, so preflight audits what the bench measures)."""
+    tiny model on CPU and the 600m-class decode shape on TPU."""
     import jax
     import jax.numpy as jnp
 

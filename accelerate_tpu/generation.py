@@ -1,8 +1,8 @@
 """Autoregressive generation: jitted prefill + KV-cache decode loop.
 
 Reference capability parity: big-model *inference* (reference
-big_modeling.py:513 ``load_checkpoint_and_dispatch`` + the
-benchmarks/big_model_inference harness, which loads GPT-J/NeoX/OPT-class
+big_modeling.py:513 ``load_checkpoint_and_dispatch`` + the reference's
+``benchmarks/big_model_inference`` harness, which loads GPT-J/NeoX/OPT-class
 models and generates).  The reference delegates the actual decode loop to
 transformers ``model.generate``; here the loop is in-tree and TPU-native:
 

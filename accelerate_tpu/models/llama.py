@@ -1,6 +1,6 @@
 """Llama-family decoder — the flagship model (BASELINE.json north star:
-Llama-2-7B fine-tune; reference exercises it via transformers + FSDP2,
-benchmarks/fsdp2 + examples/torch_native_parallelism).
+Llama-2-7B fine-tune; the reference exercises it via transformers + FSDP2,
+its benchmarks/fsdp2 + examples/torch_native_parallelism).
 
 TPU-first design notes:
 - bf16 compute / fp32 master weights via the Accelerator policy; all matmuls
@@ -247,7 +247,8 @@ def init_cache(config, batch_size: int, max_len: int, dtype=None):
 
     TPU-native analog of the engines' paged/contiguous KV caches the
     reference delegates generation to (big-model inference,
-    reference big_modeling.py:513 + benchmarks/big_model_inference).
+    reference big_modeling.py:513 + the reference's
+    benchmarks/big_model_inference).
     """
     dtype = dtype or config.dtype
     hkv, d = config.num_key_value_heads, config.head_dim
@@ -1092,7 +1093,9 @@ def unstack_layer_params(params):
 
 def flops_per_token(cfg: LlamaConfig, seq_len: int) -> float:
     """Training FLOPs/token ≈ 6*N + 12*L*H*D*T attention term (PaLM appendix
-    formula) — used for MFU accounting in bench.py."""
+    formula).  The benchmark counts FLOPs with its own copy
+    (``perfbench/rooflines/train_step.py``: the yardstick does not import
+    the program)."""
     n_params = (
         cfg.vocab_size * cfg.hidden_size * (1 if cfg.tie_word_embeddings else 2)
         + cfg.num_hidden_layers * (

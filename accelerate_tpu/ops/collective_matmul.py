@@ -32,8 +32,7 @@ the ring link carry traffic concurrently).
 Fallbacks: the XLA monolithic path is used whenever the ring axis is trivial
 (size 1) or shapes do not divide the ring.  The knob rides
 ``FullyShardedDataParallelPlugin.collective_matmul``
-/ env ``ACCELERATE_COLLECTIVE_MATMUL`` / ``bench.py --collective-matmul`` and
-is resolved at **trace time** (like ``ops/precision.fp8_autocast``): set it
+/ env ``ACCELERATE_COLLECTIVE_MATMUL`` and is resolved at **trace time** (like ``ops/precision.fp8_autocast``): set it
 before the step compiles.
 """
 
@@ -61,8 +60,8 @@ from ..parallel.collectives import (
 MODES = ("off", "ring", "bidir")
 
 # trace-time mode override (None = fall through to the env default); set by
-# the Accelerator from the plugin knob, by bench.py --collective-matmul, or
-# by the `collective_matmul` context manager in tests
+# the Accelerator from the plugin knob or by the `collective_matmul` context
+# manager in tests
 _MODE_OVERRIDE: list[Optional[str]] = [None]
 
 _NORMALIZE = {

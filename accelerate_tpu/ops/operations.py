@@ -320,8 +320,8 @@ def broadcast(tensor, from_process: int = 0):
 
     Any source rank wires through ``broadcast_one_to_all(is_source=...)`` —
     only the source contributes data, so the traffic is one tensor's worth
-    regardless of pod size (VERDICT r3 weak #6: the old non-zero-source path
-    allgathered every rank's copy and selected one).
+    regardless of pod size (an earlier non-zero-source path allgathered
+    every rank's copy and selected one).
     """
     state = _state()
     if state.num_processes == 1:

@@ -63,8 +63,8 @@ def fp8_hardware_supported() -> bool:
     """Whether the local accelerator has native fp8 matmul paths.
 
     TPU generations before v6 (Trillium) have no fp8 MXU: ``fp8_dot``'s
-    quantize/descale work is pure overhead there (measured −7% vs bf16 on
-    v5e — benchmarks/README.md).  The reference's fp8 backend auto-pick
+    quantize/descale work is pure overhead there (−7% vs bf16 on v5e,
+    measured before PR 1 on another toolchain — ROADMAP.md C9).  The reference's fp8 backend auto-pick
     degrades gracefully on unsupported hardware (reference
     accelerator.py:480-503); this is the capability probe behind the
     equivalent gate here."""

@@ -1,8 +1,9 @@
 """Weight-only int8 matmul — Pallas TPU kernel.
 
-The missing piece that makes int8 decode speed-positive (benchmarks/README:
-in-scan ``dequantize_tree`` re-materializes full-width weights every decode
-step, ~4.9 s/token at 1.1B): here the int8 codes stream HBM→VMEM at one
+The missing piece for int8 decode (in-scan ``dequantize_tree``
+re-materializes full-width weights every decode step: ~4.9 s/token at 1.1B,
+measured before PR 1 on another toolchain; the kernels run in no benchmark
+cell, so on the chip they are not measured — ROADMAP.md A11 / B11): here the int8 codes stream HBM→VMEM at one
 byte per weight and dequantize **inside** the matmul tile, so the HBM read
 — which bounds decode — is halved vs bf16 weights and the bf16 tensor never
 exists in HBM.

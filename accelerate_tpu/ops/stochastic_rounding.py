@@ -109,7 +109,8 @@ def sr_noise_bits(x: jax.Array, salt: jax.Array, consts: Optional[dict] = None,
 def _sr_hash_consts(seed: int) -> dict:
     """The shared deterministic-SR key material, as traced uint32 scalars
     (inside a host region a LITERAL scalar materializes as a full-leaf-size
-    broadcast — hoisted = resident, unhoisted = OOM; bench.py 7B notes).
+    broadcast — hoisted = resident, unhoisted = OOM; docs/offload.md,
+    the 7B study made before PR 1).
     Both SR optimizers carry exactly these keys so the hash scheme can only
     change in one place."""
     return {
@@ -150,7 +151,7 @@ class LionSRState(NamedTuple):
     # hyperparams ride the state as TRACED scalars: under the XLA host-
     # compute lowering a *literal* scalar materializes as a full-leaf-size
     # fp32 broadcast (measured OOM at 7B — same issue inject_hyperparams
-    # solves for the stock optimizers, bench.py 7B notes).  A dict, not a
+    # solves for the stock optimizers; docs/offload.md).  A dict, not a
     # tuple: the chunked host update slices params-congruent subtrees by
     # tree structure, and a 4-tuple could false-match a 4-leaf group.
     hyperparams: dict

@@ -39,8 +39,8 @@ in-jit training path reports exact bytes + predicted overlap through
 :func:`offload_transfer_accounting` (Python-side counters cannot run under
 trace) with the measured counterpart read off the profiler
 (``utils/xplane.streaming_overlap_report``).  Either way a negative result
-is a documented measurement, not a silent regression (``bench.py`` always
-emits ``overlap_frac`` / ``h2d_bytes`` / ``d2h_bytes``).
+is a documented measurement, not a silent regression.  Not measured on
+the chip: no benchmark cell offloads (ROADMAP.md A11).
 """
 
 from __future__ import annotations
@@ -169,8 +169,8 @@ def offload_transfer_accounting(
     ``GradSyncKwargs(grad_dtype='bf16')``); ``h2d_bytes`` = the compute-width
     param fetch (zero when masters stay resident).  Host-update time comes
     from the recipe's host-byte ladder row at the **measured** serialized
-    host-region rate (``benchmarks/host_compute_probe.py``: 1.61 GiB/s on
-    the quiet reference box); transfer time from a nominal PCIe rate.  The
+    host-region rate (1.61 GiB/s on a quiet worker host, measured before
+    PR 1 on another toolchain); transfer time from a nominal PCIe rate.  The
     predicted ``overlap_frac`` is the share of transfer hideable under the
     host update — ≈1.0 whenever the step is host-DRAM-bound, which is
     exactly the 7B regime (94.7 % host compute, docs/performance.md).

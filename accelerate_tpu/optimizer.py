@@ -27,8 +27,8 @@ from .state import AcceleratorState, GradientState
 
 
 # ---------------------------------------------------------------------------
-# Named optimizer recipes (the measured operating points of bench.py /
-# docs/performance.md, constructible by name).  Families:
+# Named optimizer recipes, constructible by name (both train cells of the
+# benchmark run ``lion-sr``: perfbench/families/llama.py).  Families:
 #   <base>      — fp32 masters, bf16 first moment (the stock recipe)
 #   <base>-sr   — bf16 params with stochastic rounding, bf16 moments
 #                 (ops/stochastic_rounding.py; no fp32 master tree)
@@ -49,7 +49,9 @@ OPTIMIZER_RECIPES: dict[str, str] = {
 
 def reference_recipe(name: str) -> str:
     """The fp32-master reference recipe an -sr/-sr8 recipe is validated
-    against (benchmarks/sr_quality.py): ``lion-sr8`` -> ``lion``."""
+    against (tests/test_stochastic_rounding.py, tests/test_int8_state.py;
+    the train cells' ``correct`` holds ``lion-sr`` to a float32 plain Lion):
+    ``lion-sr8`` -> ``lion``."""
     return name.split("-", 1)[0]
 
 

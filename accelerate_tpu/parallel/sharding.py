@@ -328,8 +328,8 @@ def replicated_plan(params, mesh: Mesh):
 def plan_bytes_per_device(abstract_tree, plan) -> int:
     """Per-device bytes of a pytree under a sharding plan (abstract: pure
     arithmetic over specs — works with :class:`jax.sharding.AbstractMesh`,
-    no real devices needed).  Used by ``bench.py --plan`` and the memory
-    estimator to report multi-chip footprints from one host."""
+    no real devices needed).  Used by the dryrun's plan leg
+    (``__graft_entry__.py``) to report multi-chip footprints from one host."""
     total = 0
     leaves = jax.tree_util.tree_leaves(
         abstract_tree, is_leaf=lambda x: hasattr(x, "shape") and hasattr(x, "dtype")

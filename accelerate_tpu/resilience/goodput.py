@@ -11,8 +11,8 @@ overlap accounting (``ops/streaming.py`` ``StreamStats`` /
 - :class:`GoodputTracker` — the measured twin, owned by every
   ``Accelerator`` (``accelerator.goodput``): step/skip/restart/retry
   counters fed by the step wrapper, the guard, ``maybe_resume`` and the
-  retry sites.  ``bench.py`` ALWAYS emits ``nan_skips`` / ``restarts`` /
-  ``goodput_frac`` from it (zeros / 1.0 when the run was clean).
+  retry sites.  :meth:`GoodputTracker.report` ALWAYS carries ``nan_skips``
+  / ``restarts`` / ``goodput_frac`` (zeros / 1.0 when the run was clean).
 - :func:`goodput_accounting` — the predicted model: first-order CheckFreq
   arithmetic over step time, checkpoint cadence/cost, and a Poisson
   preemption rate, for sizing checkpoint intervals before burning chips.
@@ -103,7 +103,7 @@ class GoodputTracker:
         return max(0.0, min(1.0, step_frac * time_frac))
 
     def report(self) -> dict:
-        """The JSON-able digest bench.py embeds (``kind: "measured"`` — the
+        """The JSON-able digest (``kind: "measured"`` — the
         predicted counterpart is :func:`goodput_accounting`).  Also records
         the MEASURED side of the ``goodput.goodput_frac`` twin
         (telemetry/twins.py)."""

@@ -1,7 +1,7 @@
 """Traffic-replay harness: seeded traces, serving metrics, and the
 static-batching baseline.
 
-The bench contract (``bench.py --serve``): replay a **seeded request trace**
+The replay contract: replay a **seeded request trace**
 (Poisson arrivals in virtual engine-step time, mixed prompt/output lengths)
 through a :class:`~.engine.ServingEngine` and ALWAYS emit the serving
 fields — tokens/s/chip, p50/p99 per-token latency, KV-pool utilization
@@ -59,7 +59,7 @@ def synthesize_trace(
     ``shared_prefix_len`` tokens (default: the middle of
     ``prompt_len_range``, so preambles span full pages at the test
     geometries) — the shared-system-prompt traffic mix the prefix cache's
-    hit rate is measured on (``bench.py --serve --prefix-share P``).  The
+    hit rate is measured on (``prefix_share``).  The
     per-request tail stays unique, so shared traffic still exercises the
     copy-on-write fork.
     """

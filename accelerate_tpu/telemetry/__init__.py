@@ -6,8 +6,7 @@ tokens and loss:
 
 - :mod:`.twins` — every predicted/measured cost-model pair registered
   under a stable name with units + drift tolerance;
-  ``twin_registry().drift_report()`` is bench.py's unified ``twins`` block
-  and the ROADMAP-5 autotuner's knob-ranking substrate.
+  ``twin_registry().drift_report()`` is the unified table of all of them.
 - :mod:`.spans` — request-level lifecycle spans and per-serve-step phase
   spans in a bounded ring (``ServingEngine.trace``), exportable as Chrome
   trace-event JSON (Perfetto) or JSONL; :mod:`.timeline` is the training
@@ -17,8 +16,9 @@ tokens and loss:
   always available through ``tracking.py``.
 
 Knobs: :class:`~accelerate_tpu.utils.dataclasses.TelemetryPlugin` /
-``ACCELERATE_TELEMETRY*`` envs.  Measured recording overhead is reported
-as ``telemetry_overhead_frac`` in every bench report.
+``ACCELERATE_TELEMETRY*`` envs.  The recorder measures its own cost
+(``SpanRecorder.overhead_frac``); what tracing costs on the chip is in
+``PERF.md`` section 6 (PR 25).
 """
 
 from .slo import SLOMonitor, SLOStatus, StreamingQuantile, prometheus_text

@@ -257,7 +257,7 @@ def prometheus_text(registry=None, monitors: dict | None = None,
     :func:`~accelerate_tpu.telemetry.twins.twin_registry`; ``monitors`` is
     ``{job_label: SLOMonitor}``; ``extra_gauges`` is flat ``{name: value}``.
     Serve the returned text at ``/metrics`` (any WSGI one-liner) and any
-    Prometheus scraper ingests the same numbers bench.py reports.
+    Prometheus scraper ingests the same numbers the reports carry.
     """
     from .twins import twin_registry
 

@@ -10,8 +10,8 @@ or JSONL, and :func:`validate_chrome_trace` checks the schema the dryrun
 leg gates on.
 
 The recorder measures its own cost: ``overhead_s`` accumulates the wall
-time spent inside record calls, and ``overhead_frac(wall_s)`` is what
-bench.py reports as ``telemetry_overhead_frac``.  When ``enabled`` is
+time spent inside record calls, and ``overhead_frac(wall_s)`` is its
+share of a run's wall time.  When ``enabled`` is
 False every record call is a single attribute check — telemetry off is
 bitwise-invisible to tokens and loss (pinned by tests and the multichip
 dryrun ``_telemetry_leg``).
@@ -147,8 +147,7 @@ class SpanRecorder:
         return list(self._events)
 
     def overhead_frac(self, wall_s: float) -> float:
-        """Share of ``wall_s`` spent inside record calls — the measured
-        ``telemetry_overhead_frac`` bench.py reports."""
+        """Share of ``wall_s`` spent inside record calls."""
         if wall_s <= 0:
             return 0.0
         return round(min(1.0, self.overhead_s / wall_s), 6)

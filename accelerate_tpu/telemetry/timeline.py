@@ -19,7 +19,7 @@ all host-side, zero added device syncs, no new compiled programs:
 The timeline shares the span machinery (:class:`~.spans.SpanRecorder`):
 bounded ring, injectable clock for deterministic tests, Chrome-trace/JSONL
 export, self-measured ``overhead_s``.  ``summary()`` is the per-phase
-digest (count/total/mean) bench.py embeds; per-step ``step_time_s``
+digest (count/total/mean); per-step ``step_time_s``
 observations can feed an :class:`~.slo.SLOMonitor` (the accelerator wires
 this when both are enabled).
 """

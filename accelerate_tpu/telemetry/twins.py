@@ -18,8 +18,8 @@ Conventions:
   nothing but the recorded values.
 - **rel_err** is the symmetric relative error ``|m - p| / max(|p|, |m|)``
   — bounded to ``[0, 1]``, and exactly ``0.0`` when both sides agree or
-  neither side was recorded (the zeros-clean idle contract bench.py's
-  always-emitted ``twins`` block relies on).
+  neither side was recorded (the zeros-clean idle contract
+  :meth:`TwinRegistry.drift_report` relies on).
 - **status**: ``idle`` (a side missing / both zero), ``ok`` (within
   tolerance), ``warn`` (beyond ``tolerance``), ``error`` (beyond
   ``error_tolerance``, default ``2 * tolerance``; a tolerance of ``0.0``
@@ -37,8 +37,8 @@ import dataclasses
 import threading
 from typing import Optional
 
-# the canonical twin set every bench report declares up front (zeros-clean:
-# the `twins` block always carries all of these, idle rows included) —
+# the canonical twin set a drift report declares up front (zeros-clean: it
+# always carries all of these, idle rows included) —
 # name -> (units, tolerance, error_tolerance or None for the 2x default)
 STANDARD_TWINS: dict[str, tuple] = {
     # ops/streaming.offload_transfer_accounting vs xplane.streaming_overlap_report
@@ -170,7 +170,7 @@ class Twin:
         return "ok"
 
     def row(self) -> dict:
-        """The JSON row bench.py's ``twins`` block carries (zeros-clean:
+        """The JSON row a drift report carries (zeros-clean:
         unrecorded sides read as 0.0, status says ``idle``)."""
         return {
             "predicted": round(float(self.predicted or 0.0), 6),
@@ -208,7 +208,7 @@ class TwinRegistry:
 
     def declare_standard_twins(self) -> None:
         """Pre-register the canonical set (:data:`STANDARD_TWINS`) so the
-        bench ``twins`` block is zeros-clean: every name present, idle rows
+        drift report is zeros-clean: every name present, idle rows
         carrying zeros, whether or not the run exercised the subsystem."""
         for name, (units, tol, err_tol) in STANDARD_TWINS.items():
             self.register(name, units=units, tolerance=tol,
@@ -260,8 +260,8 @@ class TwinRegistry:
 
     def drift_report(self) -> dict:
         """``name -> {predicted, measured, rel_err, status, units,
-        tolerance}``, sorted by name — the unified ``twins`` block bench.py
-        emits, and the table the autotuner ranks knobs with."""
+        tolerance}``, sorted by name — the unified twins table (the serving
+        harness's reports embed it)."""
         return {name: self._twins[name].row() for name in self.names()}
 
     def drifting(self, min_status: str = "warn") -> list[Twin]:

@@ -1,7 +1,7 @@
 """JAX persistent compilation-cache placement — one rule, one setter.
 
-Every entry point (``chip_smoke.py``, ``bench.py``, the fleet router, the
-fabric scripts, ``tests/conftest.py``) places its cache through
+Every entry point (the fleet router, the fabric scripts,
+``tests/conftest.py``) places its cache through
 :func:`enable_scoped_compilation_cache`, and the rule lives here only:
 
 - ``JAX_COMPILATION_CACHE_DIR`` **set**: that directory is the cache.  jax
@@ -12,7 +12,7 @@ fabric scripts, ``tests/conftest.py``) places its cache through
   directory is part of the cache key, so a path that moves never hits.
   Below it the cache is keyed by toolchain (``jax``/Python version — an
   upgraded toolchain never reads a stale cache), by a **tag** per harness
-  (``tests``, ``bench``, ``smoke`` ...) and, for concurrent runs, by a
+  (``tests``, ``fleet`` ...) and, for concurrent runs, by a
   scope: ``ACCELERATE_JAX_CACHE_SCOPE``, the pytest-xdist worker id, and
   the launched process id — concurrent jax processes never share a leaf.
 

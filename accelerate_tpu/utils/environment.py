@@ -196,150 +196,3 @@ def set_cpu_affinity(local_process_index: int, total_local_processes: int | None
             "Pinned process %d to %d/%d cpu cores: %s",
             local_process_index, len(mine), len(cores), mine,
         )
-
-
-# ---------------------------------------------------------------------------
-# Quiet-box discipline for host-compute probes (VERDICT r5 weak #7)
-# ---------------------------------------------------------------------------
-# The offloaded 7B step is host-DRAM-bound, so any host-bandwidth number
-# taken on a loaded box measures the load, not the machine (the r5 probe
-# swung 0.35-1.61 GiB/s with operator-box load).  These helpers turn the
-# documented prose discipline into an enforced precondition: a loadavg gate
-# plus a short host-compute calibration chain compared against the quiet
-# reference baseline.
-
-# Serialized single-stream host-region rate measured on the quiet reference
-# worker host at 1 GiB granularity (benchmarks/host_compute_probe.py,
-# docs/performance.md "7B-offload ceiling").
-HOST_COMPUTE_BASELINE_GIBS = 1.71
-
-
-def host_load_status(max_load_per_cpu: float = 0.25) -> dict:
-    """1-minute loadavg normalized by core count; ``loaded`` flips when the
-    box is busy enough to distort a host-bandwidth measurement."""
-    try:
-        load1 = os.getloadavg()[0]
-    except (OSError, AttributeError):  # pragma: no cover - exotic platforms
-        load1 = 0.0
-    ncpu = os.cpu_count() or 1
-    per_cpu = load1 / ncpu
-    return {
-        "load1": round(load1, 2),
-        "cpus": ncpu,
-        "load_per_cpu": round(per_cpu, 3),
-        "loaded": per_cpu > max_load_per_cpu,
-        "max_load_per_cpu": max_load_per_cpu,
-    }
-
-
-def calibrate_host_compute(gib: float = 0.125, iters: int = 4,
-                           streams: int = 1) -> dict:
-    """The ONE lion-shaped host-compute measurement kernel (read fp32
-    master + bf16 momentum + bf16 grad, write master + momentum, inside
-    ``compute_on("device_host")``) — the same op shape as the 7B offload
-    step.  At the defaults it is the ~1-second quiet-box calibration chain;
-    ``benchmarks/host_compute_probe.py`` drives the same function at 1-GiB
-    granularity and ``streams`` independent regions, so calibration and
-    baseline can never drift onto different kernels.  Each call varies a
-    traced salt so identical-dispatch caching cannot serve a replay."""
-    import time
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from jax.experimental.compute_on import compute_on
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    from ..parallel.sharding import host_offload_supported
-
-    mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("d",))
-    kind = "pinned_host" if host_offload_supported() else None
-    sh = (NamedSharding(mesh, PartitionSpec(), memory_kind=kind) if kind
-          else NamedSharding(mesh, PartitionSpec()))
-    S = max(1, streams)
-    n = int(gib * 256 * 1024 * 1024)
-    masters = [jax.device_put(jnp.zeros((n,), jnp.float32), sh) for _ in range(S)]
-    moms = [jax.device_put(jnp.zeros((n,), jnp.bfloat16), sh) for _ in range(S)]
-    grads = [jax.device_put(jnp.ones((n,), jnp.bfloat16), sh) for _ in range(S)]
-
-    @jax.jit
-    def step(masters, moms, grads, salt):
-        # grads and salt ride as jit ARGUMENTS, never closure constants: a
-        # captured GiB-scale array would be baked into the executable as a
-        # trace-time constant (compile blowup, memory kind not guaranteed),
-        # and every operand entering the host region — the salt included —
-        # must already sit in host memory space (jax rejects mixed-space
-        # elementwise ops)
-        new_masters, new_moms, parts = [], [], []
-        for master, mom, grad in zip(masters, moms, grads):
-            with compute_on("device_host"):
-                g = grad.astype(jnp.float32) + salt
-                m = mom.astype(jnp.float32)
-                new_master = master - 1e-4 * jnp.sign(0.9 * m + 0.1 * g)
-                new_mom = (0.99 * m + 0.01 * g).astype(jnp.bfloat16)
-                part = new_master[0] + new_master[-1]
-            new_masters.append(jax.device_put(new_master, sh))
-            new_moms.append(jax.device_put(new_mom, sh))
-            parts.append(part)
-        # summed OUTSIDE the regions: a cross-region checksum chain would
-        # serialize the streams the probe exists to measure independently
-        return new_masters, new_moms, sum(parts)
-
-    def _salt(v):
-        return jax.device_put(jnp.float32(v), sh)
-
-    masters, moms, cs = step(masters, moms, grads, _salt(0.0))  # compile + warm
-    float(cs)
-    t0 = time.perf_counter()
-    for i in range(iters):
-        masters, moms, cs = step(masters, moms, grads, _salt(i + 1.0))
-        float(cs)
-    dt = time.perf_counter() - t0
-    bytes_per = n * (4 + 2 + 2 + 4 + 2) * S
-    return {
-        "gib": gib,
-        "iters": iters,
-        "streams": S,
-        "seconds": round(dt, 3),
-        "secs_per_iter": round(dt / iters, 3),
-        "gibs": round(bytes_per * iters / dt / 2**30, 3),
-    }
-
-
-def quiet_box_gate(
-    baseline_gibs: float = HOST_COMPUTE_BASELINE_GIBS,
-    *,
-    calibrate: bool = True,
-    min_frac: float = 0.5,
-    max_load_per_cpu: float = 0.25,
-) -> dict:
-    """The enforced quiet-box precondition: loadavg gate + calibration chain
-    vs the documented baseline.  ``ok`` is False when the box is loaded or
-    the calibration lands under ``min_frac`` of ``baseline_gibs`` — callers
-    warn (bench) or refuse without ``--force`` (the probe).  The baseline
-    comparison only binds on TPU worker hosts (CPU backends run the same
-    chain at whatever the operator box does, reported but not judged)."""
-    import jax
-
-    rep: dict = {"load": host_load_status(max_load_per_cpu)}
-    warnings = []
-    if rep["load"]["loaded"]:
-        warnings.append(
-            f"box is loaded (load1/cpu {rep['load']['load_per_cpu']} > "
-            f"{max_load_per_cpu}): host-bandwidth numbers would measure the "
-            "load, not the machine"
-        )
-    if calibrate:
-        rep["calibration"] = calibrate_host_compute()
-        rep["baseline_gibs"] = baseline_gibs
-        on_tpu = jax.default_backend() == "tpu"
-        rep["baseline_binding"] = on_tpu
-        if on_tpu and rep["calibration"]["gibs"] < min_frac * baseline_gibs:
-            warnings.append(
-                f"calibration chain measured {rep['calibration']['gibs']} GiB/s "
-                f"< {min_frac} x the quiet baseline {baseline_gibs} GiB/s: "
-                "the worker host is degraded or contended"
-            )
-    rep["warnings"] = warnings
-    rep["ok"] = not warnings
-    return rep

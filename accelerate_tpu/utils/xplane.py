@@ -27,9 +27,9 @@ from typing import Optional
 
 @lru_cache(maxsize=16)
 def _cached_planes(path: str, size: int, mtime_ns: int) -> tuple:
-    """Parsed planes per file, keyed by (path, size, mtime) so one
-    ``op_class_breakdown`` + ``top_ops`` pass decodes each artifact once
-    (the pure-Python wire parse of a real trace costs seconds)."""
+    """Parsed planes per file, keyed by (path, size, mtime), so that
+    ``op_class_breakdown`` and the two overlap reports decode each artifact
+    once (the pure-Python wire parse of a real trace costs seconds)."""
     return tuple(parse_xspace(path))
 
 
@@ -209,9 +209,10 @@ def _lhs_base(name: str) -> str:
 def classify_op(name: str) -> str:
     """Map one HLO event name to an op class.
 
-    Heuristics tuned against real v5e train-step traces of this package
-    (`bench.py --trace`): Pallas kernels surface as ``custom-call``s whose
-    instruction keeps the model scope name (``self_attn`` = flash
+    Heuristics tuned against v5e train-step traces of this package (taken
+    before PR 1 of CHANGES.md; the benchmark classifies with its own copy,
+    ``perfbench/trace_reduce.py``): Pallas kernels surface as
+    ``custom-call``s whose instruction keeps the model scope name (``self_attn`` = flash
     attention); projection/embedding matmuls are the ``convolution*``/
     ``dot*`` fusions plus XLA:TPU's *unnamed* ``fusion.N`` output fusions
     (named elementwise fusions spell their root ops instead, e.g.
@@ -380,8 +381,3 @@ def ici_overlap_report(trace_dir: str, device_substr: str = "TPU",
         "kind": "measured",
     }
 
-
-def top_ops(trace_dir: str, n: int = 20, device_substr: str = "TPU") -> list[tuple[str, float]]:
-    per_op = device_op_times(trace_dir, device_substr)
-    ranked = sorted(per_op.items(), key=lambda kv: -kv[1])[:n]
-    return [(name[:160], ms) for name, ms in ranked]

@@ -1,5 +1,5 @@
 """Autoregressive generation with a KV cache (reference capability:
-big-model inference — benchmarks/big_model_inference loads GPT-class models
+big-model inference — the reference's benchmarks/big_model_inference loads GPT-class models
 and generates via transformers ``model.generate``; here the decode loop is
 in-tree and jit-compiled).
 

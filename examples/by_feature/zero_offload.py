@@ -5,7 +5,9 @@ examples/deepspeed config zoo).
 ``FullyShardedDataParallelPlugin(cpu_offload=True)`` pins the Adam moments
 and fp32 master params to host memory; the optimizer update runs as XLA
 host compute.  On a 16GB v5e this is what lets 32k+ token contexts and
-Llama-2-7B train on one chip (see docs/offload.md and bench.py --model 7b).
+Llama-2-7B train on one chip (see docs/offload.md; measured before PR 1 on
+another toolchain — on today's code offload is not measured on the chip:
+no benchmark cell offloads, ROADMAP.md A11).
 """
 
 import argparse

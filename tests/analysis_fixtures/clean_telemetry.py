@@ -20,7 +20,7 @@ jitted_step = jax.jit(lambda x: x * 2.0)
 
 
 def times_with_block_until_ready(x):
-    # the bench.py timed-loop idiom: materialize, then read the clock
+    # the timed-loop idiom: materialize, then read the clock
     t0 = time.perf_counter()
     y = decorated_step(x)
     jax.block_until_ready(y)

@@ -46,12 +46,11 @@ def const_capture_step(x):
 
 def transfer_in_trace_step(x):
     """GL103 (audited with ``default_memory_kind='device'``): an explicit
-    device_put inside traced code — on TPU this is a host<->device copy
-    serialized into the step."""
+    device_put to host memory inside traced code — a host<->device copy
+    serialized into the step.  The destination is named, not read off the
+    backend: the CPU backend's default memory kind is "device" too."""
     y = x * 2.0
-    dst = jax.sharding.SingleDeviceSharding(
-        jax.devices()[0], memory_kind=jax.devices()[0].default_memory().kind
-    )
+    dst = jax.sharding.SingleDeviceSharding(jax.devices()[0], memory_kind="pinned_host")
     return jax.device_put(y, dst)
 
 

@@ -9,8 +9,8 @@ self-launch tests (tests/test_launch.py) still exercise the real launcher.
 import os
 
 # Must run before JAX's backend initializes.  Force CPU even when a TPU is
-# attached — unit tests always use the virtual 8-device mesh; chip_smoke.py
-# exercises the real chip.
+# attached — unit tests always use the virtual 8-device mesh; the benchmark
+# (perfbench/run.py --workload <cell>) exercises the real chip.
 os.environ["JAX_PLATFORMS"] = os.environ.get("ACCELERATE_TEST_PLATFORM", "cpu")
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:

@@ -710,7 +710,7 @@ def test_fixture_telemetry_planted_gl109_fires():
 def test_fixture_telemetry_clean_twin_quiet():
     """The corrected twins (block_until_ready / float fetch / np.asarray
     before the closing clock read, plain host timing, jit outside the
-    window) stay quiet — the bench.py timed-loop idiom passes clean."""
+    window) stay quiet — the timed-loop idiom passes clean."""
     rep = lint_paths([FIXTURES / "clean_telemetry.py"], excludes=())
     assert not rep.unsuppressed(), rep.render()
 
@@ -923,11 +923,18 @@ def test_canonical_train_step_audits_clean():
         GradientState._reset_state()
 
 
-def test_offloaded_pipelined_step_audits_clean_tpu_shaped():
+def test_offloaded_pipelined_step_audits_clean_tpu_shaped(monkeypatch):
     """Hot spot 2 (ops/streaming.py pipeline inside the offloaded step),
-    audited as if on TPU (default_memory_kind='device'): every in-trace
+    traced as the TPU traces it: the CPU backend keeps offloaded state in
+    "device" memory (``host_offload_supported``), so its own trace holds no
+    transfer at all.  The state is built on the CPU, then handed to the
+    step as abstract leaves placed where the TPU places them (``host_plan``:
+    params and optimizer state in ``pinned_host``) — tracing compiles
+    nothing, so no backend has to accept the placement.  Every in-trace
     transfer must be an inline-suppressed intentional pipeline stage."""
+    import accelerate_tpu.accelerator as accelerator_mod
     from accelerate_tpu import Accelerator
+    from accelerate_tpu.parallel.sharding import host_plan
     from accelerate_tpu.utils.dataclasses import FullyShardedDataParallelPlugin
 
     plugin = FullyShardedDataParallelPlugin(
@@ -940,12 +947,20 @@ def test_offloaded_pipelined_step_audits_clean_tpu_shaped():
         return jnp.mean((batch @ p["w"] + p["b"]) ** 2)
 
     state = acc.create_train_state(params, "lion-sr")
+    monkeypatch.setattr(accelerator_mod, "host_offload_supported", lambda: True)
+    plan = acc._state_sharding
+    plan = plan.replace(params=host_plan(plan.params), opt_state=host_plan(plan.opt_state))
+    acc._state_sharding = plan
+    abstract_state = jax.tree_util.tree_map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s), state, plan
+    )
     step = acc.prepare_train_step(loss_fn)
-    rep = audit_jitted(step, state, jax.ShapeDtypeStruct((8, 16), jnp.float32),
+    rep = audit_jitted(step, abstract_state, jax.ShapeDtypeStruct((8, 16), jnp.float32),
                        default_memory_kind="device")
     assert not rep.unsuppressed(), rep.render()
     suppressed = [f for f in rep.findings if f.suppressed]
     assert suppressed, "expected the intentional pipeline transfers to be visible-but-suppressed"
+    assert {f.rule for f in suppressed} == {"GL103"}
     assert all(f.suppress_reason for f in suppressed)
 
 

@@ -242,7 +242,8 @@ def test_tpu_pod_env_one_host_is_not_a_pod(monkeypatch):
     """A one-host TPU VM exports the pod variables too: no coordinator is
     handed out (a worker given one must call jax.distributed.initialize
     before its first jax call — a script that probes jax.devices() first,
-    as chip_smoke.py did on the chip, would die in the launcher's env)."""
+    as PR 21's bring-up script did on the chip, would die in the launcher's
+    env)."""
     from accelerate_tpu.utils.launch import prepare_tpu_pod_env
 
     monkeypatch.setenv("TPU_WORKER_ID", "0")

@@ -5,8 +5,8 @@ H100s; here the path is QuantizableDense -> fp8_current_scaled_dot under
 the fp8_autocast trace-time region).
 
 On the CPU mesh fp8 dtypes are emulated, so these tests pin semantics
-(routing, gradients, loss parity with bf16), not speed; the measured v5e
-delta is recorded in benchmarks/README.
+(routing, gradients, loss parity with bf16), not speed; the one v5e delta
+ever taken (−7%, before PR 1 on another toolchain) is in ROADMAP.md C9.
 """
 
 import jax

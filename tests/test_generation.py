@@ -1,7 +1,7 @@
 """Generation tests: KV-cache decode parity with the full forward, sampling
 filters, variable-length prompts, EOS handling, MoE decode (reference
-capability role: big-model inference / generate — big_modeling.py:513 +
-benchmarks/big_model_inference)."""
+capability role: big-model inference / generate — the reference's
+big_modeling.py:513 + benchmarks/big_model_inference)."""
 
 import jax
 import jax.numpy as jnp

@@ -5,8 +5,8 @@ and FSDP CPUOffload).
 On the CPU test mesh, memory-kind placement is unsupported so storage stays
 in device memory, but the host-compute update region (``compute_on``) — the
 code path that runs on TPU — is fully exercised, and numerics are pinned
-offload-vs-resident.  The real pinned-host placement is asserted on-chip by
-``bench.py --offload``.
+offload-vs-resident.  The real pinned-host placement is not measured on the
+chip: no benchmark cell offloads (ROADMAP.md A11).
 """
 
 import jax
@@ -457,8 +457,8 @@ def test_pipelined_offload_update_matches_serial_bitwise():
     same values either way, but stage C's per-chunk placements DO run here
     (deliberately not gated on kinds_ok) — pipelined and serial trace
     genuinely different programs and must still agree bit-for-bit.  The
-    pinned-host transfer legs are the on-chip concern
-    (bench.py --pipeline on|off A/B)."""
+    pinned-host transfer legs are the on-chip concern (not measured on the
+    chip: ROADMAP.md A11)."""
     losses_ser, params_ser = _run(offload=True, chunk_gib=1e-6, pipeline=False)
     losses_pipe, params_pipe = _run(offload=True, chunk_gib=1e-6, pipeline=True)
     assert losses_pipe == losses_ser

@@ -431,7 +431,7 @@ def test_nan_guard_aborts_after_consecutive_skips(tmp_path):
 
 def test_nan_guard_counts_skips_with_abort_disabled(tmp_path):
     """max_consecutive_nan_skips=0 disables only the abort: skips still land
-    in the goodput counters bench.py always emits."""
+    in the goodput counters ``GoodputTracker.report`` always carries."""
     acc, dl, state, step = _guard_setup(tmp_path, max_consecutive=0)
     batch = next(iter(dl))
     with fault_plan(FaultPlan([FaultEvent("nan_grad", at=1, count=2)])):

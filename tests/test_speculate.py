@@ -132,14 +132,39 @@ def test_generate_paged_speculate_matches_generate(tiny_model):
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
 
 
+def _prompts_the_drafter_can_follow(model, params, lengths, new, k):
+    """Prompts that hold the n-grams the model goes on to emit.  Each is a
+    random 3-token seed followed by the model's own greedy continuation of
+    it, cut at the wanted length: a tiny random model soon repeats itself,
+    so its next tokens continue n-grams the prompt already contains — which
+    uniformly random prompts never do, and then the prompt-lookup drafter
+    has nothing to accept.  Seeds are drawn until the draft-and-verify
+    arithmetic replayed over the prompt's solo run (no engine) both accepts
+    a draft and rejects one, so acceptance AND rollback are exercised."""
+    rng = np.random.default_rng(1)
+    prompts = []
+    for n in lengths:
+        for _ in range(64):
+            seed = tuple(int(x) for x in rng.integers(1, 255, 3))
+            prompt = (seed + tuple(_ref_tokens(model, params, seed, n - 3)))[:n]
+            pred = predicted_acceptance(
+                [Request(uid=0, prompt=prompt, max_new_tokens=new)],
+                {0: _ref_tokens(model, params, prompt, new)}, NgramDraft(), k)
+            if 0 < pred["accepted"] < pred["drafted"]:
+                prompts.append(prompt)
+                break
+        else:
+            raise AssertionError(f"no seed gave a followable prompt of length {n}")
+    return prompts
+
+
 def test_speculate_parity_under_eviction_pressure(tiny_model):
     """A pool too small for the offered load forces evictions mid-
     speculation: every request still emits exactly its solo-run tokens,
     rejected drafts rolled real pages back, and the host free-page mirror
     ends exactly in sync with the device allocator."""
     model, params = tiny_model
-    rng = np.random.default_rng(1)
-    prompts = [tuple(int(x) for x in rng.integers(1, 255, n)) for n in (9, 7, 8)]
+    prompts = _prompts_the_drafter_can_follow(model, params, (9, 7, 8), new=8, k=3)
     plugin = ServingPlugin(num_slots=3, page_size=2, pages_per_slot=10,
                            num_pages=12, prefill_chunk=8,
                            decode_kernel="native", speculate="ngram",

@@ -5,7 +5,7 @@ prefetcher are both built from these pieces; their end-to-end parity lives
 in tests/test_offload.py and tests/test_generation.py.  Here the machinery
 itself is pinned: chunk partitioning (a numerics contract — SR hash streams
 key on group-relative leaf indices), congruent slice/merge round-trips,
-prefetcher ordering/accounting, and the overlap arithmetic bench.py emits.
+prefetcher ordering/accounting, and the overlap arithmetic.
 """
 
 import jax
