@@ -386,21 +386,132 @@ def test_hf_names_of_the_language_model_load_and_the_towers_are_skipped(model, w
         np.testing.assert_array_equal(got[key], want[key], err_msg=key)
 
 
-@pytest.mark.parametrize("case", ["plain", "ties", "few_visible"])
+INT_MIN = np.int32(-2 ** 31)
+
+
+def _threshold_oracle(keys, k):
+    """``numpy`` alone: sort each row by (value down, position up), keep the first
+    ``k`` that are visible.  Returns ``(threshold, ties_taken, selected)``."""
+    keys = np.asarray(keys)
+    rows = keys.reshape(-1, keys.shape[-1])
+    threshold, ties, chosen = [], [], np.zeros(rows.shape, bool)
+    for i, row in enumerate(rows):
+        order = np.argsort(-row.astype(np.int64), kind="stable")[:k]
+        order = order[row[order] > INT_MIN]
+        chosen[i, order] = True
+        threshold.append(row[order[-1]] if len(order) == k else INT_MIN + 1)
+        ties.append(k - int(np.sum(row > threshold[-1])))
+    lead = keys.shape[:-1]
+    return (np.asarray(threshold, np.int32).reshape(lead), np.asarray(ties, np.int32).reshape(lead),
+            chosen.reshape(keys.shape))
+
+
+# case -> (shape of the scores, k, share visible, kv_len or None)
+THRESHOLD_CASES = {
+    "plain": ((3, 5, 700), 64, 0.7, None),
+    "ties": ((3, 5, 700), 64, 0.7, None),
+    "few_visible": ((3, 5, 700), 64, 0.05, None),
+    "decode_rows": ((8, 1, 5000), 64, 0.7, None),          # one tile of 8, three counting steps
+    "chunk_rows": ((1, 96, 1300), 64, 0.7, None),          # tiles of 32 rows, columns padded
+    "rows_off_the_tile": ((13, 300), 64, 0.7, None),       # padded to 16 rows
+    "width_of_whole_lanes": ((2, 8, 1024), 64, 0.7, None),
+    "k_over_the_width": ((2, 4, 40), 64, 0.9, None),
+    "a_row_with_nothing_visible": ((2, 8, 300), 64, 0.7, None),
+    "exactly_k_visible": ((2, 8, 300), 64, 0.7, None),
+    "all_scores_equal": ((2, 8, 300), 64, 0.7, None),
+    "signed_zeros": ((2, 8, 300), 64, 0.7, None),
+    "kv_len_cuts_the_walk": ((8, 1, 8192), 64, 0.7, 500),
+}
+
+
+@pytest.mark.parametrize("case", list(THRESHOLD_CASES))
 def test_the_threshold_selects_what_top_k_selects(case):
-    """``ops/sparse_attention``: the bisection's threshold, with ties taken in
-    order of position, is ``jax.lax.top_k``'s set — also where many scores
-    are equal and where fewer than ``k`` keys are visible."""
+    """``ops/sparse_attention``: the kernel's threshold (interpret mode here),
+    with ties taken in order of position, is ``jax.lax.top_k``'s set and a
+    ``numpy`` sort's — for a decode step's rows and a chunk's, widths on and
+    off the lanes, many equal scores, rows with fewer than ``k`` visible keys,
+    with none and with exactly ``k``, ``k`` over the width, and a ``kv_len``
+    past which the kernel does not look."""
     from accelerate_tpu.ops import sparse_attention as sa
 
+    shape, k, share, kv_len = THRESHOLD_CASES[case]
     rng = np.random.default_rng(4)
-    x = rng.normal(size=(3, 5, 700)).astype(np.float32)
+    x = rng.normal(size=shape).astype(np.float32)
+    visible = rng.random(x.shape) < share
     if case == "ties":
         x = np.round(x * 3) / 3
-    visible = rng.random(x.shape) < (0.05 if case == "few_visible" else 0.7)
-    keys = jnp.where(jnp.asarray(visible), sa.order_key(jnp.asarray(x)), np.int32(-2 ** 31))
-    got, _ = sa.selected(keys, *sa.kth_largest_key(keys, 64))
-    top, where = jax.lax.top_k(jnp.where(jnp.asarray(visible), jnp.asarray(x), -jnp.inf), 64)
-    want = np.zeros(x.shape, bool)
-    np.put_along_axis(want, np.asarray(where), np.asarray(top > -jnp.inf), -1)
-    np.testing.assert_array_equal(got, want)
+    elif case == "a_row_with_nothing_visible":
+        visible[0, 3] = False
+    elif case == "exactly_k_visible":
+        visible[1, 2] = np.arange(shape[-1]) % 4 == 1
+        visible[1, 2, 4 * k:] = False
+        assert visible[1, 2].sum() == k
+    elif case == "all_scores_equal":
+        x[:] = 0.25
+    elif case == "signed_zeros":
+        x = np.where(rng.random(x.shape) < 0.5, np.float32(-0.0), np.float32(0.0))
+        x[..., ::7] = -1.0
+    keys = np.array(jnp.where(jnp.asarray(visible), sa.order_key(jnp.asarray(x)), INT_MIN))
+    handed = keys
+    if kv_len is not None:
+        # by contract nothing is visible at or past kv_len; the keys planted in the last
+        # lanes would change every threshold if the kernel's counts walked that far
+        keys[..., kv_len:] = INT_MIN
+        handed = keys.copy()
+        handed[..., -128:] = np.int32(2 ** 31 - 1)
+    want = _threshold_oracle(keys, k)
+    threshold, ties_taken = sa.kth_largest_key(jnp.asarray(handed), k, kv_len)
+    np.testing.assert_array_equal(threshold, want[0])
+    np.testing.assert_array_equal(ties_taken, want[1])
+    got, _ = sa.selected(jnp.asarray(keys), threshold, ties_taken)
+    np.testing.assert_array_equal(got, want[2])
+    if k <= shape[-1] and case != "signed_zeros":     # and jax.lax.top_k's own set (it tells -0.0 from +0.0)
+        seen = jnp.asarray(keys > INT_MIN)
+        top, where = jax.lax.top_k(jnp.where(seen, jnp.asarray(x), -jnp.inf), k)
+        theirs = np.zeros(x.shape, bool)
+        np.put_along_axis(theirs, np.asarray(where), np.asarray(top > -jnp.inf), -1)
+        np.testing.assert_array_equal(got, theirs)
+
+
+def _equations(jaxpr, outer=""):
+    """Every equation of a jaxpr and of the jaxprs its equations hold, a kernel's
+    own body left out: (primitive, scope from the program's root, params)."""
+    for eqn in jaxpr.eqns:
+        scope = f"{outer}/{eqn.source_info.name_stack}"
+        yield eqn.primitive.name, scope, eqn.params
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner, scope)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_the_threshold_is_one_kernel_in_both_programs(model, weights, program):
+    """The engine's decode and prefill programs find each layer's threshold
+    with ONE ``sparse_threshold`` kernel inside ``sparse_index``: no sort or
+    ``top_k`` in the attention's scopes, and no loop of a fixed trip count
+    (the 16-step counting loops of an XLA bisection) beside it — the scoring
+    loop's trip count follows ``kv_len``."""
+    from accelerate_tpu.serving.engine import fresh_engine_jits
+
+    params = family.to_program(weights)
+    cache = model.init_paged_cache(20, 8, 2, 10)
+    decode, prefill, *_ = fresh_engine_jits(model, GEN, 8)
+    if program == "decode":
+        traced = decode.trace(params, cache, jnp.zeros((2,), jnp.int32), jnp.ones((2,), bool),
+                              jnp.zeros((2,), jnp.uint32))
+    else:
+        traced = prefill.trace(params, cache, jnp.int32(0), jnp.zeros((16,), jnp.int32),
+                               jnp.int32(0), jnp.int32(16))
+    eqns = list(_equations(traced.jaxpr.jaxpr))
+    assert [name for name, scope, _ in eqns if name in ("sort", "top_k", "approx_top_k")
+            and "sparse_" in scope] == []               # the router's own top-8 is elsewhere
+    index = [(name, params) for name, scope, params in eqns if "sparse_index" in scope]
+    kernels = [params for name, params in index if name == "pallas_call"]
+    assert len(kernels) == LAYERS
+    assert all("sparse_threshold" in str(params["name"]) for params in kernels)
+    assert [name for name, _ in index if name == "scan"] == []
+    assert [name for name, _ in index].count("while") == LAYERS          # the scoring loop alone

@@ -275,18 +275,21 @@ KEYE_SLOTS, KEYE_PAGES, KEYE_PAGES_PER_SLOT = 8, 4160, 520
 
 @pytest.mark.parametrize("program,width", [("decode", 1), ("prefill", 512), ("prefill", 2048)])
 def test_keye_vl2_serving_programs_compile_at_the_cells_shapes(one_chip, compile_for_chip,
-                                                               program, width):
+                                                               monkeypatch, program, width):
     """The engine's decode and prefill programs of ``models/keye_vl2.py`` at
     the published widths and the cell's geometry (8 slots, 4,160 pages of 64,
     520 a slot), one layer deep (every layer is alike): the grouped matmul
-    must be the Mosaic kernel, and no op may copy or relay a whole page pool
-    (the pools are written in the layout the reads use)."""
+    must be the Mosaic kernel, the selection threshold the ``sparse_threshold``
+    kernel with no sort beside it, and no op may copy or relay a whole page
+    pool (the pools are written in the layout the reads use)."""
     import re
 
     from accelerate_tpu.generation import GenerationConfig
     from accelerate_tpu.models import KeyeVL2Config, KeyeVL2ForCausalLM
+    from accelerate_tpu.ops import sparse_attention as sa
     from accelerate_tpu.serving.engine import fresh_engine_jits
 
+    monkeypatch.setattr(sa, "_on_tpu", lambda: True)   # the kernel, not its interpreter
     model = KeyeVL2ForCausalLM(KeyeVL2Config(num_hidden_layers=1))
     on_chip = lambda tree, dtype=None: jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, dtype or x.dtype, sharding=one_chip), tree)
@@ -305,8 +308,33 @@ def test_keye_vl2_serving_programs_compile_at_the_cells_shapes(one_chip, compile
     compiled = lowered.compile()
     text = compiled.as_text()
     assert text.count("ragged-dot") >= 3 and "tpu_custom_call" in text      # gate, up, down
+    assert len(re.findall(r"%sparse_threshold\S* = .* custom-call\(", text)) == 1
+    attention = [line for line in text.splitlines() if "/self_attn/" in line]
+    assert attention and not [line for line in attention if re.search(r" (sort|topk)\(", line)]
     pools = re.findall(r"= bf16\[4160,64,(?:512|128)\]\S* (copy|transpose)\(", text)
     assert pools == []
     stats = compiled.memory_analysis()
     assert stats.alias_size_in_bytes >= 3 * KEYE_PAGES * PAGE * 128 * 2      # the pools alias in place
     assert stats.temp_size_in_bytes < 2 * 2**30
+
+
+@pytest.mark.parametrize("rows,width", [(8, 36864), (2048, 34816), (512, 34816)],
+                         ids=["decode", "prefill_2048", "prefill_512"])
+def test_sparse_threshold_kernel_compiles_at_the_cells_shapes(one_chip, compile_for_chip,
+                                                              monkeypatch, rows, width):
+    """``ops/sparse_attention.kth_largest_key`` at the rows and padded widths
+    the cell's decode step and prefill buckets hand it (``topk`` 2048): Mosaic
+    takes the kernel with its whole row tile, double-buffered, inside the
+    default scoped VMEM (the call raises no limit, so a tile over it is
+    refused here), nothing pads or copies the keys on the way in, and the
+    program holds no sort."""
+    import re
+
+    from accelerate_tpu.ops import sparse_attention as sa
+
+    monkeypatch.setattr(sa, "_on_tpu", lambda: True)   # the kernel, not its interpreter
+    text = compile_for_chip(lambda keys, kv_len: sa.kth_largest_key(keys, 2048, kv_len),
+                            ((rows, width), jnp.int32), ((), jnp.int32))
+    assert len(re.findall(r"%sparse_threshold\S* = .* custom-call\(", text)) == 1
+    assert not re.search(rf"= s32\[{rows},{width}\]\S* (copy|pad|fusion)\(", text)
+    assert not re.search(r" (sort|topk)\(", text)
