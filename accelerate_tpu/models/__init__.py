@@ -5,23 +5,28 @@ Decoders and what carries ``head_dim``: ``LlamaConfig`` (Llama, Mistral, Yi;
 num_attention_heads``; ``KeyeVL2Config`` takes an explicit ``head_dim``
 (32 heads x 128 over a hidden size of 2048) and is the family for a published
 config whose ``num_attention_heads * head_dim != hidden_size``.
+``KExaoneConfig`` (window and full-attention layers, sigmoid-routed experts
+beside a shared expert) does too, and adds what of a layer a chip HOLDS.
 ``docs/supported_models.md`` has the table of what each family trains,
 serves and refuses."""
 
 from .bert import BertConfig, BertForSequenceClassification, make_bert_loss_fn
 from .hf_interop import (
     hf_bert_key_map,
+    hf_k_exaone_key_map,
     hf_keye_vl2_key_map,
     hf_llama_key_map,
     hf_llama_tensor_map,
     hf_mixtral_key_map,
     hf_t5_key_map,
     load_hf_bert,
+    load_hf_k_exaone,
     load_hf_keye_vl2,
     load_hf_llama,
     load_hf_mixtral,
     load_hf_t5,
 )
+from .k_exaone import KExaoneConfig, KExaoneForCausalLM
 from .keye_vl2 import KeyeVL2Config, KeyeVL2ForCausalLM
 from .llama import (
     LlamaConfig,

@@ -257,6 +257,65 @@ def load_hf_keye_vl2(model, checkpoint, *, mesh=None, dtype=None, rng=None,
         key_map=hf_keye_vl2_key_map, tensor_map=hf_llama_tensor_map, **kwargs)
 
 
+# -- K-EXAONE (window + full attention, sigmoid-routed experts + a shared expert) ---
+# Names follow the DeepSeek-V3-style block whose keys the published config
+# carries (``mlp.gate.e_score_correction_bias``, ``mlp.shared_experts.*``);
+# per-expert tensors are stacked [E, in, out] like Mixtral's.  The
+# next-token-prediction module's tensors (``model.mtp.*``) map to ``params.mtp``.
+_K_EXAONE_EXPERT_RE = re.compile(
+    r"^model\.layers\.(\d+)\.mlp\.experts\.(\d+)\.(gate|up|down)_proj\.weight$")
+_K_EXAONE_BLOCK: list[tuple[str, str]] = [
+    (r"self_attn\.(q|k|v|o)_proj\.weight$", r"self_attn.\1_proj.kernel"),
+    (r"self_attn\.(q|k)_norm\.weight$", r"self_attn.\1_norm.scale"),
+    (r"(input|post_attention)_layernorm\.weight$", r"\1_layernorm.scale"),
+    (r"mlp\.gate\.weight$", r"mlp.gate.kernel"),
+    (r"mlp\.gate\.e_score_correction_bias$", r"mlp.e_score_correction_bias"),
+    (r"mlp\.experts_stacked\.(gate|up|down)_proj$", r"mlp.experts_\1_proj"),
+    (r"mlp\.shared_experts\.(gate|up|down)_proj\.weight$", r"mlp.shared_experts.\1_proj.kernel"),
+    (r"mlp\.(gate|up|down)_proj\.weight$", r"mlp.\1_proj.kernel"),
+]
+_K_EXAONE_TOP = {"model.embed_tokens.weight": "params.embed_tokens.embedding",
+                 "model.norm.weight": "params.norm.scale", "lm_head.weight": "params.lm_head.kernel",
+                 "model.mtp.hnorm.weight": "params.mtp.hnorm.scale",
+                 "model.mtp.enorm.weight": "params.mtp.enorm.scale",
+                 "model.mtp.eh_proj.weight": "params.mtp.eh_proj.kernel"}
+
+
+def hf_k_exaone_key_map(name: str) -> Optional[str]:
+    """HF ``exaone_moe`` ``state_dict`` name -> ``KExaoneForCausalLM``'s param
+    path (the whole model: a share's slices are the caller's to cut); None
+    for rotary buffers."""
+    if name.endswith("rotary_emb.inv_freq"):
+        return None
+    if name in _K_EXAONE_TOP:
+        return _K_EXAONE_TOP[name]
+    m = re.match(r"^model\.(?:layers\.(\d+)|mtp\.block)\.(.+)$", name)
+    if m:
+        scope = "mtp.block" if m.group(1) is None else f"layers_{m.group(1)}"
+        for pattern, template in _K_EXAONE_BLOCK:
+            if re.match(pattern, m.group(2)):
+                return f"params.{scope}." + re.sub(pattern, template, m.group(2))
+    return name  # unknown names pass through and surface as `unexpected`
+
+
+def load_hf_k_exaone(model, checkpoint, *, mesh=None, dtype=None, rng=None,
+                     sample_args=(), strict: bool = True, **kwargs):
+    """Stream an HF-format K-EXAONE checkpoint into ``KExaoneForCausalLM``'s
+    param tree; experts stacked as in :func:`load_hf_mixtral`."""
+    import jax.numpy as jnp
+
+    from ..big_modeling import load_checkpoint_and_dispatch
+
+    if not sample_args:
+        sample_args = (jnp.ones((1, 8), jnp.int32),)
+    stream = _stack_expert_stream(
+        checkpoint, model.config.num_experts, _K_EXAONE_EXPERT_RE, lambda w: f"{w}_proj",
+        "model.layers.{layer}.mlp.experts_stacked.{proj}")
+    return load_checkpoint_and_dispatch(
+        model, stream, rng=rng, sample_args=sample_args, mesh=mesh, dtype=dtype, strict=strict,
+        key_map=hf_k_exaone_key_map, tensor_map=hf_llama_tensor_map, **kwargs)
+
+
 # -- BERT (encoder classifier) -----------------------------------------------
 _BERT_RULES: list[tuple[str, str]] = [
     (r"^bert\.embeddings\.word_embeddings\.weight$", r"params.word_embeddings.embedding"),

@@ -232,16 +232,7 @@ class KeyeVL2Attention(nn.Module):
         live = jnp.ones((b, t), bool) if cache_write_mask is None else cache_write_mask
         tables = cache["block_tables"]
         with jax.named_scope("paged_write_kv"):
-            if t == 1:
-                logical = jnp.clip(pos[:, 0] // page, 0, tables.shape[1] - 1)
-                ids = jnp.take_along_axis(tables, logical[:, None], axis=1)[:, 0]
-                write = lambda pages, rows: sa.write_token_rows(
-                    pages, rows[:, 0], ids, pos[:, 0] % page, live[:, 0])
-            else:
-                # one chunk of one sequence: contiguous positions from a page boundary
-                length = jnp.sum(live[0].astype(jnp.int32))
-                write = lambda pages, rows: sa.write_chunk_pages(
-                    pages, rows[0], tables[0], pos[0, 0], length)
+            write = sa.page_writer(tables, pos, live, page)
             k_pages = write(cache["k_pages"], k.reshape(b, t, hkv * d))
             v_pages = write(cache["v_pages"], v.reshape(b, t, hkv * d))
             lanes = cache["index_pages"].shape[2]

@@ -209,15 +209,19 @@ def index_keys(q_idx, w_idx, index_pages, block_tables, q_positions, topk: int, 
     return (keys, *kth_largest_key(keys, topk, kv_len))
 
 
-@jax.named_scope("sparse_attend")
-def paged_selected_attention(q, k_pages, v_pages, block_tables, keys, threshold, ties_taken,
-                             kv_len):
-    """Attention of ``q`` [B, T, H, D] over the keys its row selected, walking
-    the pages of ``block_tables`` [B, n] a block at a time with a running
-    softmax.  ``k_pages``/``v_pages``: [P, page, Hkv * D] (a token's heads in
-    one row); ``keys`` / ``threshold`` / ``ties_taken`` as :func:`index_keys` returns them.  A
-    row that selected nothing (dead slot, padding) comes back zero.  Returns
-    [B, T, H, D]."""
+def paged_masked_attention(q, k_pages, v_pages, block_tables, kv_len, block_mask,
+                           mask_carry=lambda: ()):
+    """The page walk every masked attention over paged keys shares: ``q``
+    [B, T, H, D] against the pages of ``block_tables`` [B, n] (n a whole
+    number of loop steps: ``pad_block_tables``), a block of
+    ``block_pages_for`` pages at a time with a running softmax, so that no
+    ``[T, S]`` array is held whole.  ``k_pages``/``v_pages``: [P, page,
+    Hkv * D] (a token's heads in one row).  ``block_mask(i, blk, carry)`` ->
+    ``(mask [B, T, blk] bool, carry)`` says which keys of block ``i`` (the
+    positions ``i * blk + arange(blk)``) each query attends; ``mask_carry()``
+    makes its state before the first block.  ``kv_len``: scalar, the longest live
+    context (no step walks past it).  A row that attends nothing (dead slot,
+    padding) comes back zero.  Returns [B, T, H, D]."""
     b, t, h, d = q.shape
     _, page, width = k_pages.shape
     hkv = width // d
@@ -231,13 +235,12 @@ def paged_selected_attention(q, k_pages, v_pages, block_tables, keys, threshold,
     scale = 1.0 / np.sqrt(d)
 
     def attend_block(i, carry):
-        m, l, acc, ties = carry
+        m, l, acc, state = carry
         pages = lax.dynamic_slice_in_dim(block_tables, i * bp, bp, axis=1)        # [B, bp]
         k_blk = k_pages[pages].reshape(b, blk, hkv, d)
         v_blk = v_pages[pages].reshape(b, blk, hkv, d)
         s = jnp.einsum("bthgd,bshd->bhgts", qg, k_blk, preferred_element_type=jnp.float32) * scale
-        sel, ties = selected(lax.dynamic_slice_in_dim(keys, i * blk, blk, axis=2), threshold,
-                             ties_taken, ties)
+        sel, state = block_mask(i, blk, state)
         sel = sel[:, None, None]                                                  # [B,1,1,T,blk]
         m_new = jnp.maximum(m, jnp.max(jnp.where(sel, s, -jnp.inf), axis=-1))
         safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
@@ -246,15 +249,42 @@ def paged_selected_attention(q, k_pages, v_pages, block_tables, keys, threshold,
         l = l * alpha + jnp.sum(p, axis=-1)
         pv = jnp.einsum("bhgts,bshd->bhgtd", p.astype(v_blk.dtype), v_blk,
                         preferred_element_type=jnp.float32)
-        return m_new, l, acc * alpha[..., None] + pv, ties
+        return m_new, l, acc * alpha[..., None] + pv, state
 
     steps = jnp.minimum((kv_len + blk - 1) // blk, n // bp)
     init = (jnp.full((b, hkv, g, t), -jnp.inf, jnp.float32),
             jnp.zeros((b, hkv, g, t), jnp.float32),
-            jnp.zeros((b, hkv, g, t, d), jnp.float32), jnp.zeros((b, t), jnp.int32))
+            jnp.zeros((b, hkv, g, t, d), jnp.float32), mask_carry())
     _, l, acc, _ = lax.fori_loop(0, steps, attend_block, init)
     out = acc / jnp.where(l > 0, l, 1.0)[..., None]
     return out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, d).astype(q.dtype)
+
+
+@jax.named_scope("sparse_attend")
+def paged_selected_attention(q, k_pages, v_pages, block_tables, keys, threshold, ties_taken,
+                             kv_len):
+    """Attention of ``q`` [B, T, H, D] over the keys its row selected:
+    :func:`paged_masked_attention` with the selection as the mask.  ``keys``
+    / ``threshold`` / ``ties_taken`` as :func:`index_keys` returns them.
+    Returns [B, T, H, D]."""
+    def selection(i, blk, ties):
+        return selected(lax.dynamic_slice_in_dim(keys, i * blk, blk, axis=2), threshold,
+                        ties_taken, ties)
+
+    return paged_masked_attention(q, k_pages, v_pages, block_tables, kv_len, selection,
+                                  lambda: jnp.zeros(q.shape[:2], jnp.int32))
+
+
+@jax.named_scope("global_attend")
+def paged_causal_attention(q, k_pages, v_pages, block_tables, q_positions, kv_len):
+    """Full causal attention over paged keys: the same walk with the mask
+    ``s <= t``.  ``q_positions`` [B, T] int32, -1 for a query that sees
+    nothing (dead slot, padding)."""
+    def causal(i, blk, state):
+        pos = i * blk + jnp.arange(blk, dtype=jnp.int32)
+        return pos[None, None, :] <= q_positions[:, :, None], state
+
+    return paged_masked_attention(q, k_pages, v_pages, block_tables, kv_len, causal)
 
 
 def dense_selected_attention(q, k, v, q_idx, w_idx, k_idx, positions, topk: int):
@@ -290,6 +320,22 @@ def dense_selected_attention(q, k, v, q_idx, w_idx, k_idx, positions, topk: int)
 # in the layout it arrives in and puts no relayout copy around either (the
 # head-major ``[Hkv, P, page, D]`` of ``models/llama.py`` is the Pallas
 # kernels' tile; under these XLA ops it costs two copies of the pool a write).
+
+
+def page_writer(block_tables, positions, live, page_size: int):
+    """``write(pages, rows [B, T, W])`` of one paged call, for every pool of a
+    layer: a decode step ``[B, 1]`` writes one row a slot at its position's
+    page and offset, a prefill chunk ``[1, C]`` its first ``sum(live)`` rows a
+    page at a time from ``positions[0, 0]`` (a page boundary)."""
+    if positions.shape[1] == 1:
+        logical = jnp.clip(positions[:, 0] // page_size, 0, block_tables.shape[1] - 1)
+        ids = jnp.take_along_axis(block_tables, logical[:, None], axis=1)[:, 0]
+        return lambda pages, rows: write_token_rows(
+            pages, rows[:, 0], ids, positions[:, 0] % page_size, live[:, 0])
+    # one chunk of one sequence: contiguous positions from a page boundary
+    length = jnp.sum(live[0].astype(jnp.int32))
+    return lambda pages, rows: write_chunk_pages(
+        pages, rows[0], block_tables[0], positions[0, 0], length)
 
 
 def write_token_rows(pages, rows, page_ids, offsets, live):

@@ -219,9 +219,20 @@ class DroplessRouting(NamedTuple):
 
 @jax.named_scope("moe_route")
 def route_dropless(router_logits, top_k: int, experts_held=None, *,
-                   normalize: bool = True, token_mask=None) -> DroplessRouting:
-    """Softmax over all experts, the ``top_k`` largest, and the sort that the
-    grouped matmul needs.  ``router_logits``: [N, E] (float32).
+                   normalize: bool = True, token_mask=None, scoring: str = "softmax",
+                   select_bias=None, gate_scale: float = 1.0) -> DroplessRouting:
+    """Scores over all experts, the ``top_k`` chosen, their gates, and the
+    sort that the grouped matmul needs.  ``router_logits``: [N, E] (float32).
+
+    ``scoring``: ``"softmax"`` over the experts (the default) or an
+    elementwise ``"sigmoid"``.  ``select_bias`` [E] (optional): the choice is
+    the ``top_k`` of ``score + select_bias`` while the gates are the UNBIASED
+    scores of the chosen (a learned per-expert correction that balances load
+    without touching the output's weights).  ``normalize``: gates divided by
+    their sum over the ``top_k`` chosen, whoever holds them; ``gate_scale``
+    multiplies them afterwards.  The defaults are softmax -> top_k ->
+    renormalise, and trace exactly the operations they always have.
+
     ``experts_held``: the global ids this layer holds (None = all): the
     layer routes over every expert and computes its own experts' part; what
     the others would add is another chip's (``ROADMAP.md`` B1).  Tokens that
@@ -229,10 +240,21 @@ def route_dropless(router_logits, top_k: int, experts_held=None, *,
     expert: they cost no row of the grouped matmul and are not counted."""
     n, e = router_logits.shape
     held = tuple(range(e)) if experts_held is None else tuple(int(i) for i in experts_held)
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    gate, experts = lax.top_k(probs, top_k)                       # [N, k]
+    if scoring == "softmax":
+        probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+    elif scoring == "sigmoid":
+        probs = jax.nn.sigmoid(router_logits.astype(jnp.float32))
+    else:
+        raise ValueError(f"scoring {scoring!r} is neither 'softmax' nor 'sigmoid'")
+    if select_bias is None:
+        gate, experts = lax.top_k(probs, top_k)                   # [N, k]
+    else:
+        _, experts = lax.top_k(probs + select_bias.astype(jnp.float32), top_k)
+        gate = jnp.take_along_axis(probs, experts, axis=-1)
     if normalize:
         gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    if gate_scale != 1.0:
+        gate = gate * gate_scale
     local = np.full((e,), len(held), np.int32)                    # not held -> one past the last
     local[list(held)] = np.arange(len(held), dtype=np.int32)
     flat = experts.reshape(-1).astype(jnp.int32)
@@ -248,6 +270,28 @@ def route_dropless(router_logits, top_k: int, experts_held=None, *,
                            experts.astype(jnp.int32), counts)
 
 
+def held_row_block(routing: DroplessRouting) -> int:
+    """Rows one step of :func:`grouped_ffn` covers.  A layer that holds every
+    expert the router scores takes all ``N * k`` pairs at once.  A share
+    takes a quarter over what an even routing sends to its experts, in whole
+    128-row tiles (so that one step is the usual case and a skewed routing
+    takes more steps, never fewer rows), and never more than the pairs there
+    are."""
+    pairs = routing.order.shape[0]
+    held, experts = routing.group_sizes.shape[0], routing.tokens_per_expert.shape[0]
+    if held == experts:
+        return pairs
+    even = -(-pairs * held // experts)
+    return int(min(-(-pairs // 8) * 8, -(-(even + even // 4) // 128) * 128))
+
+
+def held_rows_fed(routing: DroplessRouting):
+    """Rows :func:`grouped_ffn` feeds its matmuls (int32 scalar): the rows
+    routed to the held experts, up to the last block's padding."""
+    block = held_row_block(routing)
+    return (jnp.sum(routing.group_sizes) + block - 1) // block * block
+
+
 @jax.named_scope("moe_experts")
 def grouped_ffn(x, routing: DroplessRouting, w_gate, w_up, w_down):
     """``sum_{e in T held} g_e W_down,e (silu(W_gate,e x) * W_up,e x)`` for
@@ -256,9 +300,21 @@ def grouped_ffn(x, routing: DroplessRouting, w_gate, w_up, w_down):
     routed row is computed: there is no capacity.
 
     x: [N, H]; w_gate/w_up: [E_held, H, F]; w_down: [E_held, F, H].  Returns
-    [N, H] float32 (the down projection's accumulator, gated and summed)."""
+    [N, H] float32 (the down projection's accumulator, gated and summed).
+
+    The sorted rows are walked in blocks of :func:`held_row_block`, which the
+    routing itself decides: it knows how many experts the router scores
+    (``tokens_per_expert``) and how many are held (``group_sizes``).  All
+    held: one block of ``N * k`` rows, gathered and multiplied at once.  A
+    share would feed mostly other chips' rows that way, so its blocks run
+    under a ``while`` whose trip count follows ``sum(group_sizes)``: the
+    gather and the three matmuls cover the held rows only (up to the last
+    block's padding), whatever the routing: no capacity, no drop
+    (:func:`held_rows_fed` counts them)."""
     n, k = routing.weights.shape
     sizes = routing.group_sizes
+    if sizes.shape[0] < routing.tokens_per_expert.shape[0]:
+        return _held_rows_ffn(x, routing, w_gate, w_up, w_down, held_row_block(routing))
     rows = x[routing.order // k]                                   # [N*k, H], sorted by expert
     gate = lax.ragged_dot(rows, w_gate, sizes)
     up = lax.ragged_dot(rows, w_up, sizes)
@@ -269,3 +325,31 @@ def grouped_ffn(x, routing: DroplessRouting, w_gate, w_up, w_down):
     out = jnp.where(mine[:, None], out * routing.weights.reshape(-1)[routing.order][:, None], 0.0)
     pairs = jnp.zeros_like(out).at[routing.order].set(out)        # back to (token, choice) order
     return jnp.sum(pairs.reshape(n, k, -1), axis=1)
+
+
+def _held_rows_ffn(x, routing: DroplessRouting, w_gate, w_up, w_down, block: int):
+    """The share's path of :func:`grouped_ffn`: ``block`` sorted rows a step,
+    each step's groups cut from the layer's, its outputs gated and added to
+    their tokens' rows.  Returns [N, H] float32."""
+    n, k = routing.weights.shape
+    sizes = routing.group_sizes
+    total = jnp.sum(sizes)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    order = jnp.pad(routing.order, (0, -(n * k) % block))          # a step's slice never clamps
+    gates = routing.weights.reshape(-1)
+
+    def step(i, acc):
+        at = i * block
+        pairs = lax.dynamic_slice_in_dim(order, at, block)
+        groups = jnp.clip(ends - at, 0, block) - jnp.clip(starts - at, 0, block)
+        tokens = pairs // k
+        rows = x[tokens]
+        hidden = jax.nn.silu(lax.ragged_dot(rows, w_gate, groups)) * lax.ragged_dot(rows, w_up, groups)
+        out = lax.ragged_dot(hidden, w_down, groups, preferred_element_type=jnp.float32)
+        mine = at + jnp.arange(block) < total                      # the last block's padding
+        out = jnp.where(mine[:, None], out * gates[pairs][:, None], 0.0)
+        return acc.at[jnp.where(mine, tokens, n)].add(out, mode="drop")
+
+    return lax.fori_loop(0, (total + block - 1) // block, step,
+                         jnp.zeros((n, w_down.shape[-1]), jnp.float32))

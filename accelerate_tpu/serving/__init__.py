@@ -39,16 +39,26 @@ The production-inference rebuild of the reference's
   harnesses (docs/serving.md "Fleet serving").
 
 **The family protocol** — what :class:`ServingEngine` asks of the model object
-it is handed (``models/llama.py`` and ``models/keye_vl2.py`` are the two
-families; the engine imports neither):
+it is handed (``models/llama.py``, ``models/keye_vl2.py`` and
+``models/k_exaone.py`` are the three families; the engine imports none):
 
 - ``init_paged_cache(num_pages, page_size, num_slots, pages_per_slot,
   kv_dtype=None)`` (required) — the cache pytree of
-  :func:`.paged_cache.init_paged_pools` around one dict of page pools per
-  layer: what a layer keeps per token is the layer's kind's to say, and one
-  block table addresses all of it;
+  :func:`.paged_cache.init_paged_pools` around one dict of arrays per
+  layer: what a layer keeps per token is the layer's kind's to say.  Two
+  kinds exist.  A *paged* layer's arrays are ``[num_pages, ...]`` and the
+  ONE block table addresses all of them (K and V pages, scales, an
+  indexer's keys).  A *slot-addressed* layer's arrays are ``[num_slots,
+  ...]``, addressed by slot id and outside the allocator (a window layer's
+  ring): its bytes do not grow with the context, and it must stay correct
+  when a slot is handed on, evicted or re-admitted WITHOUT being cleared
+  (the engine clears nothing) — a ring does so by reading a row only inside
+  the owner's window;
 - a paged ``__call__(ids, positions=, cache=, cache_write_mask=)`` that
-  returns ``(logits, layers)`` or ``(logits, layers, counters)``;
+  returns ``(logits, layers)`` or ``(logits, layers, counters)``.  Each
+  layer's view in ``cache`` is the layer's arrays plus ``block_tables``
+  (the block-table rows of the call's ``B`` sequences) and ``slots`` (their
+  slot ids ``[B]``); the model returns the arrays, updated, per layer;
 - ``tick_counters`` (optional) — ``((name, length), ...)`` of the int32
   vector such a call returns third; the engine sums it into ``metrics``,
   fetched with a decode tick's tokens;
@@ -57,7 +67,10 @@ families; the engine imports neither):
   ``speculate``, ``prefix_cache``, ``hold_finished``): asking for one raises
   at construction;
 - ``prefill_writes_whole_pages`` (optional) — the prefill buckets must be
-  whole pages.
+  whole pages;
+
+What a family's cache takes, by kind of layer state, is read from the shapes
+``init_paged_cache`` builds: :func:`.paged_cache.cache_accounting`.
 """
 
 from .adapters import (
@@ -83,7 +96,8 @@ from .prefix_cache import (
     prefix_cache_accounting,
     unbounded_prefix_hit_rate,
 )
-from .paged_cache import allocate, kv_pool_accounting, pages_for, push_pages, release
+from .paged_cache import (allocate, cache_accounting, kv_pool_accounting, pages_for,
+                          push_pages, release)
 from .router import FleetRouter, fleet_chaos_replay, fleet_replay
 from .scheduler import ContinuousBatchingScheduler, Request, SlotState
 from .speculate import (
@@ -115,6 +129,7 @@ __all__ = [
     "release",
     "push_pages",
     "pages_for",
+    "cache_accounting",
     "kv_pool_accounting",
     "NgramDraft",
     "DraftModelDraft",

@@ -60,18 +60,20 @@ def _no_phase(name, **args):
     return _NO_SPAN
 
 
-def _layer_view(layer, block_tables):
-    """One layer's model-facing cache dict: every pool the layer's kind keeps
+def _layer_view(layer, block_tables, slots):
+    """One layer's model-facing cache dict: every array the layer's kind keeps
     (K and V pages; ``k_scales`` / ``v_scales`` of quantized pools, which the
     model detects to route quantize-on-write / dequant-on-read; an indexer's
-    ``index_pages``) plus the block table that addresses all of them."""
-    return {**layer, "block_tables": block_tables}
+    ``index_pages``; a slot-addressed kind's per-slot state) plus how a call
+    addresses them: the block-table rows of the call's sequences, and their
+    slot ids ``[B]`` for state that is kept per slot and not in pages."""
+    return {**layer, "block_tables": block_tables, "slots": slots}
 
 
 def _layer_keep(layer):
     """The engine-side carry of one layer returned by the model (drop the
-    per-step block-table alias, keep the pools)."""
-    return {k: v for k, v in layer.items() if k != "block_tables"}
+    per-step addressing, keep the state)."""
+    return {k: v for k, v in layer.items() if k not in ("block_tables", "slots")}
 
 
 def _with_counters(cache, new_cache, aux, tokens=None):
@@ -122,11 +124,12 @@ def _engine_step_fns(model, gen_config, page_size: int, lora: bool = False,
         pos = seq_lens
         n_slots = tokens.shape[0]
         need = active & (pos % page_size == 0)
+        slot_ids = jnp.arange(n_slots, dtype=jnp.int32)
         block_tables, free_top = allocate(
             cache["block_tables"], cache["free_stack"], cache["free_top"],
-            jnp.arange(n_slots, dtype=jnp.int32), pos // page_size, need,
+            slot_ids, pos // page_size, need,
         )
-        layer_caches = [_layer_view(l, block_tables) for l in cache["layers"]]
+        layer_caches = [_layer_view(l, block_tables, slot_ids) for l in cache["layers"]]
         variables = {**params, "lora": lora_pool} if lora else params
         kwargs = {"adapter_ids": adapter_slots} if lora else {}
         logits, new_layers, *aux = apply(
@@ -157,7 +160,7 @@ def _engine_step_fns(model, gen_config, page_size: int, lora: bool = False,
             jnp.full((width,), slot, jnp.int32), positions // page_size, need,
         )
         row = jax.lax.dynamic_slice_in_dim(block_tables, slot, 1, axis=0)
-        layer_caches = [_layer_view(l, row) for l in cache["layers"]]
+        layer_caches = [_layer_view(l, row, jnp.reshape(slot, (1,))) for l in cache["layers"]]
         variables = {**params, "lora": lora_pool} if lora else params
         kwargs = {"adapter_ids": jnp.reshape(adapter_slot, (1,))} if lora else {}
         logits, new_layers, *aux = apply(
@@ -195,12 +198,13 @@ def _engine_step_fns(model, gen_config, page_size: int, lora: bool = False,
         live = active[:, None] & (lane[None, :] <= spec_len[:, None])
         logical = positions // page_size
         need = live & (positions % page_size == 0)
+        slot_ids = jnp.arange(n, dtype=jnp.int32)
         block_tables, free_top = allocate(
             cache["block_tables"], cache["free_stack"], cache["free_top"],
-            jnp.repeat(jnp.arange(n, dtype=jnp.int32), w),
+            jnp.repeat(slot_ids, w),
             logical.reshape(-1), need.reshape(-1),
         )
-        layer_caches = [_layer_view(l, block_tables) for l in cache["layers"]]
+        layer_caches = [_layer_view(l, block_tables, slot_ids) for l in cache["layers"]]
         variables = {**params, "lora": lora_pool} if lora else params
         kwargs = {"adapter_ids": adapter_slots} if lora else {}
         logits, new_layers = apply(

@@ -161,6 +161,10 @@ def verify_serving_invariants(engine) -> list[str]:
       exclusion);
     - device ``seq_lens`` match the host ``kv_tokens`` per occupied slot and
       read 0 for free slots;
+    - every layer's state is paged (a ``num_pages`` axis, first or behind
+      the kv heads) or slot-addressed
+      (``[num_slots, ...]``: a window layer's ring, which needs no clearing
+      and no conservation — a row is read only inside its owner's window);
     - slot accounting: ``free_slots`` ∪ occupied == all slots, disjoint;
     - adapter refcounts balance the in-flight census per tenant.
 
@@ -205,6 +209,16 @@ def verify_serving_invariants(engine) -> list[str]:
     else:
         problems.extend(_verify_refcounted(engine, stack, seq_lens,
                                            block_tables))
+    # every array of every layer is one of the two kinds of state: paged
+    # (a page axis first, or second behind the kv heads: addressed through the
+    # block table) or slot-addressed ([num_slots, ...]: a window layer's ring)
+    for i, layer in enumerate(cache["layers"]):
+        for name, arr in layer.items():
+            if sched.num_pages not in arr.shape[:2] and arr.shape[0] != sched.num_slots:
+                problems.append(
+                    f"layer {i}: {name} {tuple(arr.shape)} is neither paged (an axis of "
+                    f"{sched.num_pages} pages) nor slot-addressed ([{sched.num_slots}, ..])"
+                )
     for slot, st in sched.slots.items():
         if int(seq_lens[slot]) != st.kv_tokens:
             problems.append(

@@ -26,7 +26,9 @@ Design notes (vLLM PagedAttention discipline):
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def pages_for(tokens, page_size: int):
@@ -168,6 +170,40 @@ def kv_pool_accounting(config, num_pages: int, page_size: int,
             source="serving/paged_cache.kv_pool_accounting",
         )
     return out
+
+
+def cache_accounting(model, num_pages: int, page_size: int, num_slots: int,
+                     pages_per_slot: int, kv_dtype=None) -> dict:
+    """What a family's cache takes, by kind of layer state, read from the
+    shapes ``model.init_paged_cache`` builds (nothing is allocated): arrays
+    with an axis of ``num_pages`` pages (first, or second behind the kv heads)
+    are paged, arrays ``[num_slots, ...]`` are kept per slot outside the
+    allocator — the two kinds ``verify_serving_invariants`` admits.
+
+    :func:`kv_pool_accounting` predicts from a configuration and assumes that
+    every layer keeps K and V pages of every KV head; this counts what the
+    family really builds, so it also holds for a model that keeps some layers'
+    state per slot (a window layer's ring), holds a share of the KV heads, or
+    keeps more than K and V in its pages."""
+    cache = jax.eval_shape(lambda: model.init_paged_cache(
+        num_pages, page_size, num_slots, pages_per_slot, kv_dtype=kv_dtype))
+    pool = slot_state = paged_layers = 0
+    for i, layer in enumerate(cache["layers"]):
+        paged_here = 0
+        for name, arr in layer.items():
+            nbytes = int(np.prod(arr.shape)) * arr.dtype.itemsize
+            if num_pages in arr.shape[:2]:
+                paged_here += nbytes
+            elif arr.shape[0] == num_slots:
+                slot_state += nbytes
+            else:
+                raise ValueError(f"layer {i}: {name} {tuple(arr.shape)} is neither paged nor "
+                                 f"slot-addressed")
+        pool += paged_here
+        paged_layers += paged_here > 0
+    return {"page_size_tokens": page_size, "num_pages": num_pages, "paged_layers": paged_layers,
+            "bytes_per_page": pool // num_pages, "pool_bytes": pool,
+            "slot_state_bytes": slot_state, "tokens_capacity": num_pages * page_size}
 
 
 def init_paged_pools(layers: list, num_pages: int, num_slots: int, pages_per_slot: int,

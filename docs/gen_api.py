@@ -67,6 +67,7 @@ PAGES: dict[str, tuple[str, list[str] | None]] = {
         "AdapterStore", "LoraTrainer", "adapter_pool_accounting",
         "predicted_adapter_hit_rate",
         "allocate", "release", "push_pages", "pages_for", "kv_pool_accounting",
+        "cache_accounting",
         "synthesize_trace", "replay", "chaos_replay", "static_batching_report",
         "predicted_pool_utilization", "DegradationLadder",
         "verify_serving_invariants",

@@ -1,0 +1,13 @@
+"""global_attend_device_ms.reason: device self-time under the ``global_attend`` scope (the two full-attention layers' causal walk
+over the pages), per run of the DECODE program (64 slots; a prefill tick is in the traced window of some runs only)."""
+
+from perfbench import scopes
+
+layer = "window and global attention"
+unit = "ms"
+moves = "serve_tokens_per_s"
+source = "device_trace"
+
+
+def read(run):
+    return scopes.scoped_ms_per_run(run, ("global_attend",), ("decode",))

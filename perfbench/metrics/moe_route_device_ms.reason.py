@@ -1,0 +1,13 @@
+"""moe_route_device_ms.reason: device self-time under the ``moe_route`` scope (sigmoid over 128, the biased top-8, the gates, the
+sort by held expert), per run of the DECODE program (64 slots; a prefill tick is in the traced window of some runs only)."""
+
+from perfbench import scopes
+
+layer = "experts"
+unit = "ms"
+moves = "serve_tokens_per_s"
+source = "device_trace"
+
+
+def read(run):
+    return scopes.scoped_ms_per_run(run, ("moe_route",), ("decode",))

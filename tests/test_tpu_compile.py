@@ -338,3 +338,57 @@ def test_sparse_threshold_kernel_compiles_at_the_cells_shapes(one_chip, compile_
     assert len(re.findall(r"%sparse_threshold\S* = .* custom-call\(", text)) == 1
     assert not re.search(rf"= s32\[{rows},{width}\]\S* (copy|pad|fusion)\(", text)
     assert not re.search(r" (sort|topk)\(", text)
+
+
+# -- K-EXAONE's serving programs at the cell's shapes (k-exaone.serve_reason) -------------
+
+EXAONE_SLOTS, EXAONE_PAGES, EXAONE_PAGES_PER_SLOT = 64, 18432, 288
+
+
+@pytest.mark.parametrize("program,width", [("decode", 1), ("prefill", 512), ("prefill", 2048)])
+def test_k_exaone_serving_programs_compile_at_the_cells_shapes(one_chip, program, width):
+    """The engine's decode and prefill programs of ``models/k_exaone.py`` at
+    the published widths, the cell's share (8 of 64 heads, 1 of 8 KV heads,
+    16 of 128 experts, 19,200 vocabulary rows) and geometry (64 slots, 18,432
+    pages of 64, 288 a slot), one period deep (``LLLG``: the dense layer,
+    three sparse ones, window and full attention): the grouped matmuls are
+    the Mosaic kernel over BLOCKS of held rows (never ``N x k``), and no op
+    copies or relays a whole page pool or a whole ring (both are written in
+    the layout the reads use)."""
+    import re
+
+    from accelerate_tpu.generation import GenerationConfig
+    from accelerate_tpu.models import KExaoneConfig, KExaoneForCausalLM
+    from accelerate_tpu.serving.engine import fresh_engine_jits
+
+    model = KExaoneForCausalLM(KExaoneConfig(
+        num_hidden_layers=4, experts_held=tuple(range(16)), attention_heads_held=8,
+        key_value_heads_held=1, vocab_held=19200))
+    on_chip = lambda tree, dtype=None: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, dtype or x.dtype, sharding=one_chip), tree)
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))), BF16)
+    cache = on_chip(jax.eval_shape(
+        lambda: model.init_paged_cache(EXAONE_PAGES, PAGE, EXAONE_SLOTS, EXAONE_PAGES_PER_SLOT)))
+    gen = GenerationConfig(max_new_tokens=2048, do_sample=False, eos_token_id=None)
+    decode, prefill, *_ = fresh_engine_jits(model, gen, PAGE)
+    arg = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    if program == "decode":
+        lowered = decode.lower(params, cache, arg((EXAONE_SLOTS,)), arg((EXAONE_SLOTS,), jnp.bool_),
+                               arg((2,), jnp.uint32))
+    else:
+        lowered = prefill.lower(params, cache, arg(()), arg((width,)), arg(()), arg(()))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("ragged-dot") >= 9 and "tpu_custom_call" in text   # gate, up, down x 3 sparse layers
+    rows = {1: 128, 512: 640, 2048: 2560}[width]     # held_row_block: a quarter over an eighth of the pairs
+    assert re.search(rf"%ragged-dot\S* = \S*\[{rows},", text)               # a block of held rows
+    assert not re.search(rf"\[{max(width, EXAONE_SLOTS) * 8},(6144|2048)\]", text)   # never all N x k rows
+    # no pool- or ring-shaped relayout: a copy, or a transpose that permutes anything
+    moved = r"(copy\(|transpose\([^)]*\), dimensions=\{(?!0,1,2\}))"
+    assert re.findall(rf"= bf16\[{EXAONE_PAGES},64,128\]\S* {moved}", text) == []
+    assert re.findall(rf"= bf16\[{EXAONE_SLOTS},128,128\]\S* {moved}", text) == []
+    stats = compiled.memory_analysis()
+    pool, rings = 2 * EXAONE_PAGES * PAGE * 128 * 2, 3 * 2 * EXAONE_SLOTS * 128 * 128 * 2
+    assert stats.alias_size_in_bytes >= pool + rings                         # both kinds alias in place
+    assert stats.temp_size_in_bytes < 2**30
