@@ -44,10 +44,12 @@ class LlamaConfig:
     rope_theta: float = 10000.0
     tie_word_embeddings: bool = False
     attn_implementation: str = "native"  # native | flash | ring | ulysses
-    # explicit flash kernel tiling (None = ops/flash_attention.py heuristic;
-    # the heuristic's d>=128 clamp to block_q 512 exists for REMATTED
-    # contexts hitting the Mosaic scoped-VMEM limit — remat-off configs at
-    # head_dim 128 may prefer the (1024, 1024) tile, measure per shape)
+    # explicit flash kernel tiling, pinned for BOTH passes (None = each pass
+    # takes its own measured default, ops/flash_attention.default_block_sizes:
+    # the forward's d>=128 clamp to block_q 512 dates from the two-kernel
+    # backward that shared its tiles and overran the Mosaic scoped-VMEM limit
+    # under remat; the one-pass backward runs (512, 512) and states its own
+    # VMEM — sweep each pass with autotune_block_sizes before pinning)
     flash_block_q: Optional[int] = None
     flash_block_k: Optional[int] = None
     remat: bool = False

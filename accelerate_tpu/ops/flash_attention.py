@@ -9,11 +9,13 @@ O(T²), and every matmul lands on the MXU at 128-aligned tiles.
 Causal masking skips fully-masked KV blocks (upper-triangular blocks cost
 zero compute — the grid still visits them but predication makes them free).
 
-Backward: fused Pallas kernels (dq + dk/dv), recompute-based — the forward
-saves (q, k, v, out, logsumexp); each backward tile rebuilds its probability
-block from (q, k, lse) and accumulates gradients in VMEM scratch, so the
-[T, T] tensors of the naive backward never touch HBM.  Split into two kernels
-(dq accumulates over kv, dk/dv over q) instead of atomics — the TPU idiom.
+Backward: ONE fused Pallas kernel, recompute-based — the forward saves
+(q, k, v, out, logsumexp); the backward rebuilds each probability tile from
+(q, k, lse) once and feeds dq, dk and dv from it (five matmuls and one exp a
+tile), so the [T, T] tensors of the naive backward never touch HBM.  dq sums
+over kv and dk/dv over q: a kv head's K, V and f32 dk/dv accumulators stay
+in VMEM for the whole sequence while the kernel walks the kv sub-blocks of
+each q block itself, so neither sum needs atomics or a second pass.
 
 On a TPU backend every kernel compiles (or the run fails); on the CPU
 backend the same kernels run in Pallas interpret mode so the tests run on
@@ -51,26 +53,41 @@ def _on_tpu() -> bool:
 _VMEM_BUDGET_BYTES = 10 * 1024 * 1024
 
 
-def default_block_sizes(t: int, s: int, d: int) -> tuple[int, int]:
-    """Heuristic (block_q, block_k) keyed on sequence lengths and head dim.
+def default_block_sizes(t: int, s: int, d: int, backward: bool = False) -> tuple[int, int]:
+    """Heuristic (block_q, block_k) of one pass, keyed on sequence lengths and
+    head dim.  The forward and the backward are different kernels and take
+    their tiles apart.
 
-    Start from the sweet spot measured at seq 2048-8192 / head_dim≤128 on
-    v5e ((1024, 1024) — the autotune sweep at those shapes, worth ~1.5%
-    end-to-end over (512, 1024) on the headline bench); clamp to the actual
-    sequence lengths rounded up to the MXU tile (128); then shrink while the
-    fp32 working set (q/k/v tiles + scores tile + accumulator) exceeds the
-    VMEM budget — at large head_dim the 1024-tiles no longer double-buffer.
+    Forward: start from the sweet spot measured at seq 2048-8192 /
+    head_dim≤128 on v5e ((1024, 1024) — the autotune sweep at those shapes,
+    worth ~1.5% end-to-end over (512, 1024) on the headline bench); clamp to
+    the actual sequence lengths rounded up to the MXU tile (128); then shrink
+    while the fp32 working set (q/k/v tiles + scores tile + accumulator)
+    exceeds the VMEM budget — at large head_dim the 1024-tiles no longer
+    double-buffer.
+
+    Backward (the one-pass kernel, which holds a kv head's whole K/V and
+    walks ``block_k`` sub-blocks of it per ``block_q`` rows): (512, 512),
+    clamped to the lengths alike.  Kernel-only sweep on a v5e at the train
+    cells' per-chip shapes [2, 4096, 32/8, 128] and [2, 4096, 28/4, 128], ms
+    a layer: (512, 512) 4.82 / 4.20; (1024, 1024) 5.00 / 4.37; (1024, 512)
+    5.08 / 4.44; (512, 1024) 5.11 / 4.47; (512, 256) 5.15 / 4.47; (256, 512)
+    5.21 / 4.54; (256, 256) 6.63 / 5.78; (128, 512) 7.17 / 6.24 (PERF.md
+    section 6, PR 35).  Its VMEM is planned and stated to the compiler
+    (:func:`_bwd_vmem_plan`), so no budget shrinks these tiles.
     """
     round_up = lambda x: max(128, -(-x // 128) * 128)
+    if backward:
+        return min(512, round_up(t)), min(512, round_up(s))
     block_q = min(1024, round_up(t))
     block_k = min(1024, round_up(s))
     if round_up(t) >= 32768 or d >= 128:
-        # The (1024, 1024) backward tile exceeds the Mosaic scoped-VMEM
-        # stack limit (by ~160KB) once the remat'd layer context is fused
-        # around it, at long sequence or at head_dim >= 128 (7B-class
-        # models) — and at 32k it is 1.55x slower standalone anyway; halve
-        # block_q.  (At 16k/d<128 the 1024 tile is ~6% faster end-to-end,
-        # so the clamp stays off there.)
+        # Measured with a backward that shared these tiles (its (1024, 1024)
+        # tile overran the Mosaic scoped-VMEM stack limit at long sequence
+        # or head_dim >= 128; at 16k/d<128 the 1024 tile was ~6% faster
+        # end-to-end, so the clamp stays off there).  The backward has its
+        # own tiles now; the forward keeps (512, 1024) at these shapes until
+        # it is swept alone (ROADMAP A7).
         block_q = min(block_q, 512)
 
     def working_set(bq, bk):
@@ -84,18 +101,29 @@ def default_block_sizes(t: int, s: int, d: int) -> tuple[int, int]:
     return block_q, block_k
 
 
+def _tiles(block_q, block_k, t: int, s: int, d: int, backward: bool = False):
+    """A pass's (block_q, block_k): the caller's pins, else the pass's own
+    default, clamped to the sequence lengths."""
+    bq, bk = default_block_sizes(t, s, d, backward)
+    return min(block_q or bq, t), min(block_k or bk, s)
+
+
 def autotune_block_sizes(
     b: int, t: int, h: int, d: int, hkv: Optional[int] = None, *,
-    dtype=jnp.bfloat16, causal: bool = True, candidates=None, iters: int = 3,
+    dtype=jnp.bfloat16, causal: bool = True, backward: bool = False,
+    candidates=None, iters: int = 3,
 ) -> tuple[int, int]:
-    """Measure the best (block_q, block_k) for a shape on the current device.
+    """Measure the best (block_q, block_k) of ONE pass for a shape on the
+    current device.
 
-    Runs a short sweep of forward+backward over candidate tilings and returns
-    the fastest.  Results are cached per (shape, device kind) for the
+    The forward and the backward are different kernels with tiles of their
+    own, so each is swept apart: ``backward=False`` times the forward kernel
+    alone, ``backward=True`` the backward kernel alone on a forward's saved
+    (out, lse).  Results are cached per (shape, pass, device kind) for the
     process.  Meant for offline tuning (bench setup), not the hot path —
     each candidate pays a compile.
     """
-    key = (b, t, h, d, hkv, str(dtype), causal,
+    key = (b, t, h, d, hkv, str(dtype), causal, backward,
            getattr(jax.devices()[0], "device_kind", "cpu"))
     if key in _AUTOTUNE_CACHE:
         return _AUTOTUNE_CACHE[key]
@@ -103,33 +131,38 @@ def autotune_block_sizes(
 
     hkv = hkv or h
     rng = np.random.default_rng(0)
-    mk = lambda heads: jnp.asarray(rng.normal(size=(b, t, heads, d)), dtype)
-    inputs = [(mk(h), mk(hkv), mk(hkv)) for _ in range(iters + 1)]
+    mk = lambda heads: jnp.asarray(rng.normal(size=(b * heads, t, d)), dtype)
+    none = jnp.zeros((b, 1, t), jnp.int32)
+    static = (causal, 1.0 / float(np.sqrt(d)))
+    flags = (False, False, not _on_tpu())  # segmented, positioned, interpret
+    args = (mk(h), mk(hkv), mk(hkv))
+    if backward:  # + the forward's saved (out, lse) and a cotangent
+        args += (*_flash_fwd(*args, none, none, none, none, *static, None, None, *flags), mk(h))
     if candidates is None:
-        base_q, base_k = default_block_sizes(t, t, d)
+        base_q, base_k = default_block_sizes(t, t, d, backward)
         candidates = {
             (base_q, base_k), (max(base_q // 2, 128), base_k), (base_q, max(base_k // 2, 128)),
-            (min(1024, base_q * 2), base_k), (256, 256), (512, 512),
+            (min(1024, base_q * 2), base_k), (base_q, min(1024, base_k * 2)), (256, 256),
         }
-        # keep MXU-aligned tiles; the kernel clamps to t internally, so
+        # keep MXU-aligned tiles; the kernels clamp to t internally, so
         # oversized candidates just duplicate the largest feasible tiling
         candidates = {(bq, bk) for bq, bk in candidates if bq % 128 == 0 and bk % 128 == 0}
     best, best_dt = None, float("inf")
     for bq, bk in sorted(candidates):
-        # sum-of-grad-norms: one scalar whose fetch ends the timed work
-        def score(q, k, v, bq=bq, bk=bk):
-            g = jax.grad(lambda q: jnp.sum(flash_attention(
-                q, k, v, causal=causal, block_q=bq, block_k=bk).astype(jnp.float32)))(q)
-            return jnp.sum(jnp.abs(g).astype(jnp.float32))
+        def one_pass(q, k, v, *saved, bq=bq, bk=bk):
+            if backward:
+                return _flash_bwd(q, k, v, none, none, none, none, *saved, None,
+                                  *static, bq, bk, *flags)
+            return _flash_fwd(q, k, v, none, none, none, none, *static, bq, bk, *flags)
 
         # graft-lint: disable=GL306 -- autotuner: one jit per (bq, bk) candidate is the point; each tiling is a distinct program, compiled and measured exactly once
-        f = jax.jit(score)
+        f = jax.jit(one_pass)
         try:
-            float(f(*inputs[0]))  # compile + warm
+            jax.block_until_ready(f(*args))  # compile + warm
             t0 = time.perf_counter()
-            for i in range(iters):
-                acc = f(*inputs[i + 1])
-            float(acc)
+            for _ in range(iters):
+                res = f(*args)
+            jax.block_until_ready(res)
             dt = time.perf_counter() - t0
         except Exception:  # a sweep may skip a tiling the compiler refuses (VMEM)
             continue
@@ -158,12 +191,11 @@ def _masked_scores(q, k, sm_scale, q_start, k_start, t_len, s_len, causal,
                    block_q, block_k, seg_q=None, seg_k=None, pos_q=None, pos_k=None):
     """Scaled q@kᵀ tile with causal + segment + out-of-bounds masking.
 
-    Shared by the forward and both backward kernels so the masking convention
-    cannot drift between them.  Returns (scores, valid): padded rows/cols of
-    the last (non-divisible) blocks, cross-segment pairs (packed sequences),
-    and causally-forbidden entries get DEFAULT_MASK_VALUE; ``valid`` is the
-    boolean tile for callers that must hard-zero probabilities (the backward,
-    where lse of padded rows is garbage).
+    The forward's tile (the backward builds the same masks kv-major, and
+    only those a sub-block needs: :func:`_bwd_kernel`).  Returns (scores,
+    valid): padded rows/cols of the last (non-divisible) blocks,
+    cross-segment pairs (packed sequences), and causally-forbidden entries
+    get DEFAULT_MASK_VALUE; ``valid`` is the boolean tile.
 
     With ``pos_q/pos_k`` (explicit global token positions — the ring-CP
     zigzag layout), the causal comparison uses positions instead of local
@@ -248,8 +280,7 @@ def _flash_fwd(q, k, v, seg_q, seg_kv, pos_q, pos_kv, causal: bool, sm_scale: fl
     n_batch = seg_q.shape[0]
     n_heads = bh // n_batch
     n_rep = bh // k.shape[0]
-    block_q = min(block_q, t)
-    block_k = min(block_k, s)
+    block_q, block_k = _tiles(block_q, block_k, t, s, d)
     grid = (bh, pl.cdiv(t, block_q), pl.cdiv(s, block_k))
 
     kernel = functools.partial(
@@ -297,128 +328,176 @@ def _flash_fwd(q, k, v, seg_q, seg_kv, pos_q, pos_kv, causal: bool, sm_scale: fl
     return out, lse[:, 0, :]
 
 
-def _bwd_tile(q, k, v, g, lse, delta, sm_scale, q_start, k_start, t_len, s_len,
-              causal, block_q, block_k, seg_q=None, seg_k=None, pos_q=None, pos_k=None):
-    """(p, ds) for one backward tile — the recompute shared by dq and dk/dv.
+def _both(a, b):
+    """``a & b`` where either mask may be absent (None = everything valid)."""
+    return b if a is None else a if b is None else a & b
 
-    p is hard-zeroed on invalid entries (padded rows read garbage lse/delta,
-    so masking via scores alone is not enough); ds = p * (dp - delta) * scale.
+
+def _bwd_kernel(*refs, causal, sm_scale, block_q, block_k, t_len, s_len, chunk_blocks,
+                q_blocks, segmented, positioned):
+    """Grid: (batch*kv_heads, kv_chunks, group*q_blocks); innermost/serial dim
+    walks every (GQA group member, q block) pair of the kv head.
+
+    K and V of the kv head (``chunk_blocks`` sub-blocks of ``block_k`` rows;
+    the whole sequence unless :func:`_bwd_vmem_plan` had to cut it) stay in
+    VMEM with their f32 dk/dv accumulators; one grid step walks the kv
+    sub-blocks its q block can see and, per sub-block, rebuilds the
+    probability tile ONCE — kv-major, ``[block_k, block_q]``, so lse/delta
+    broadcast along sublanes as they lie and four of the five matmuls need
+    no transpose: s = k qᵀ, dp = v gᵀ, dv += p g, dk += ds q, dq += dsᵀ k.
+    dq sums over the walk in f32 and is cast once; dk/dv sum over the q
+    blocks and the group's q heads and are written when the kv head is done.
+
+    Causal (by index): the walk ends at the diagonal, exact to ``block_k``,
+    and only the sub-blocks the diagonal crosses (the last of the walk) build
+    the causal mask.  ``positioned``: the diagonal is data-dependent, every
+    sub-block is walked and masked by position.  Bounds masks exist only
+    where a length is not a multiple of its block; p is hard-zeroed there
+    (padded rows read garbage lse/delta, so masked scores are not enough).
     """
-    s, valid = _masked_scores(
-        q, k, sm_scale, q_start, k_start, t_len, s_len, causal, block_q, block_k,
-        seg_q, seg_k, pos_q, pos_k,
-    )
-    p = jnp.where(valid, jnp.exp(s - lse), 0.0)
-    dp = jax.lax.dot_general(
-        g, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    ds = jnp.where(valid, p * (dp - delta) * sm_scale, 0.0)
-    return p, ds
-
-
-def _dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, seg_q_ref, seg_kv_ref,
-               pos_q_ref, pos_kv_ref, dq_ref, dq_scratch,
-               *, causal, sm_scale, block_q, block_k, t_len, s_len, segmented, positioned):
-    """Grid: (batch*heads, q_blocks, kv_blocks); kv innermost/serial.
-
-    Blockwise flash backward for dq: recompute the probability tile from
-    (q, k, lse), form ds = p * (dp - delta), accumulate ds @ k.  Memory stays
-    O(block²) in VMEM — the [T, T] tensors of the naive backward never exist.
-    """
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        dq_scratch[:] = jnp.zeros_like(dq_scratch)
-
-    q_start = qi * block_q
-    k_start = ki * block_k
-    should_compute = (not causal) or positioned or (q_start + block_q - 1 >= k_start)
-
-    @pl.when(should_compute)
-    def _compute():
-        q = q_ref[0]
-        k = _zero_oob_rows(k_ref[0], k_start, s_len)
-        v = _zero_oob_rows(v_ref[0], k_start, s_len)
-        g = _zero_oob_rows(g_ref[0], q_start, t_len)
-        lse = lse_ref[0, 0][:, None]      # [block_q, 1]
-        delta = delta_ref[0, 0][:, None]  # [block_q, 1]
-        _, ds = _bwd_tile(
-            q, k, v, g, lse, delta, sm_scale,
-            q_start, k_start, t_len, s_len, causal, block_q, block_k,
-            seg_q_ref[0, 0] if segmented else None,
-            seg_kv_ref[0, 0] if segmented else None,
-            pos_q_ref[0, 0] if positioned else None,
-            pos_kv_ref[0, 0] if positioned else None,
-        )
-        dq_scratch[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(ki == pl.num_programs(2) - 1)
-    def _finalize():
-        dq_ref[0] = dq_scratch[:].astype(dq_ref.dtype)
-
-
-def _dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, seg_q_ref, seg_kv_ref,
-                pos_q_ref, pos_kv_ref, dk_ref, dv_ref,
-                dk_scratch, dv_scratch, *, causal, sm_scale, block_q, block_k,
-                t_len, s_len, q_blocks, segmented, positioned):
-    """Grid: (batch*kv_heads, kv_blocks, group*q_blocks); innermost/serial dim
-    walks every (GQA group member, q block) pair.
-
-    Same tile recompute as :func:`_dq_kernel`, accumulated along q — and,
-    under GQA, across the group's q heads (dk/dv sum over the group here
-    instead of a post-hoc reduction over repeated heads): dv += pᵀ @ g and
-    dk += dsᵀ @ q — separate kernel per accumulation direction instead of
-    atomics (the TPU idiom)."""
-    ki = pl.program_id(1)
+    q_ref, g_ref, lse_ref, delta_ref, k_ref, v_ref, *refs = refs
+    seg_q_ref = seg_kv_ref = pos_q_ref = pos_kv_ref = None
+    if segmented:
+        seg_q_ref, seg_kv_ref, *refs = refs
+    if positioned:
+        pos_q_ref, pos_kv_ref, *refs = refs
+    dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs
+    ci = pl.program_id(1)
     gi = pl.program_id(2)
-    qi = gi % q_blocks  # q-block index within the current group member
 
     @pl.when(gi == 0)
     def _init():
-        dk_scratch[:] = jnp.zeros_like(dk_scratch)
-        dv_scratch[:] = jnp.zeros_like(dv_scratch)
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    q_start = qi * block_q
-    k_start = ki * block_k
-    should_compute = (not causal) or positioned or (q_start + block_q - 1 >= k_start)
+    q_start = (gi % q_blocks) * block_q
+    chunk_start = ci * (chunk_blocks * block_k)
+    ragged_q = t_len % block_q != 0
+    ragged_k = s_len % block_k != 0
+    ragged = ragged_q or ragged_k
+    tile = (block_k, block_q)
 
-    @pl.when(should_compute)
-    def _compute():
-        q = _zero_oob_rows(q_ref[0], q_start, t_len)
-        g = _zero_oob_rows(g_ref[0], q_start, t_len)
-        lse = lse_ref[0, 0][:, None]
-        delta = delta_ref[0, 0][:, None]
-        p, ds = _bwd_tile(
-            q, k_ref[0], v_ref[0], g, lse, delta, sm_scale,
-            q_start, k_start, t_len, s_len, causal, block_q, block_k,
-            seg_q_ref[0, 0] if segmented else None,
-            seg_kv_ref[0, 0] if segmented else None,
-            pos_q_ref[0, 0] if positioned else None,
-            pos_kv_ref[0, 0] if positioned else None,
-        )
-        dv_scratch[:] += jax.lax.dot_general(
-            p.astype(q.dtype), g, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dk_scratch[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    q = q_ref[0]
+    g = g_ref[0]
+    lse = lse_ref[0]      # [1, block_q]
+    delta = delta_ref[0]  # [1, block_q]
+    if ragged_q:
+        q = _zero_oob_rows(q, q_start, t_len)
+        g = _zero_oob_rows(g, q_start, t_len)
+        # 0 * garbage = NaN would leak through ds where p is zeroed
+        cols = q_start + jax.lax.broadcasted_iota(jnp.int32, delta.shape, 1)
+        delta = jnp.where(cols < t_len, delta, 0.0)
+    dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    def sub_block(diagonal):
+        def body(j, carry):
+            off = pl.multiple_of(j * block_k, block_k)
+            k_start = chunk_start + off
+            k = k_ref[0, pl.ds(off, block_k), :]
+            v = v_ref[0, pl.ds(off, block_k), :]
+            if ragged_k:
+                k = _zero_oob_rows(k, k_start, s_len)
+                v = _zero_oob_rows(v, k_start, s_len)
+            s = jax.lax.dot_general(
+                k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            ) * sm_scale  # [block_k, block_q]
+            valid = None
+            by_index = diagonal and not positioned
+            if ragged or by_index:
+                kv_idx = k_start + jax.lax.broadcasted_iota(jnp.int32, tile, 0)
+                q_idx = q_start + jax.lax.broadcasted_iota(jnp.int32, tile, 1)
+                if ragged:
+                    valid = (q_idx < t_len) & (kv_idx < s_len)
+                if by_index:
+                    valid = _both(valid, q_idx >= kv_idx)
+            if diagonal and positioned:
+                pos_k = pos_kv_ref[0, 0, pl.ds(off, block_k)]
+                valid = _both(valid, pos_q_ref[0] >= pos_k[:, None])
+            if segmented:
+                seg_k = seg_kv_ref[0, 0, pl.ds(off, block_k)]
+                valid = _both(valid, seg_q_ref[0] == seg_k[:, None])
+            p = jnp.exp(s - lse)
+            if valid is not None:
+                p = jnp.where(valid, p, 0.0)
+            dp = jax.lax.dot_general(
+                v, g, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
+            rows = pl.ds(off, block_k)
+            dv_acc[rows, :] += jax.lax.dot_general(
+                p.astype(q.dtype), g, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            dk_acc[rows, :] += jax.lax.dot_general(
+                ds, q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            )
+            dq_acc[:] += jax.lax.dot_general(
+                ds, k, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            )
+            return carry
+
+        return body
+
+    # sub-blocks of this chunk that hold real kv rows; of those, the ones
+    # wholly at or below the diagonal (no causal mask) come first
+    n_kv = jnp.minimum(chunk_blocks, pl.cdiv(s_len, block_k) - ci * chunk_blocks)
+    n_free = n_kv
+    if causal:
+        n_free = 0
+        if not positioned:
+            rel = q_start - chunk_start
+            n_kv = jnp.minimum(n_kv, (jnp.maximum(rel + block_q, 0) + block_k - 1) // block_k)
+            n_free = jnp.minimum(jnp.maximum(rel, 0) // block_k, n_kv)
+    jax.lax.fori_loop(0, n_free, sub_block(diagonal=False), None)
+    if causal:
+        jax.lax.fori_loop(n_free, n_kv, sub_block(diagonal=True), None)
+    dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
 
     @pl.when(gi == pl.num_programs(2) - 1)
     def _finalize():
-        dk_ref[0] = dk_scratch[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scratch[:].astype(dv_ref.dtype)
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+# What the backward may ask of a core's VMEM: a v5e / v6e core has 128 MiB
+# (the 16 MiB that `_VMEM_BUDGET_BYTES` designs against is Mosaic's DEFAULT
+# scoped limit, which `vmem_limit_bytes` raises); the rest is left to Mosaic's
+# own scratch and to what XLA keeps around the call.
+_BWD_VMEM_BYTES = 88 * 1024 * 1024
+
+
+def _bwd_vmem_plan(kv_blocks, block_q, block_k, d, itemsize):
+    """(kv sub-blocks resident at once, buffers per resident array, bytes).
+
+    Resident per kv row: K, V in and dk, dv out (``buffers`` each, in the
+    operands' dtype) and two f32 accumulators; per grid step: q and g
+    double-buffered, dq out (f32 at worst) double-buffered, the f32 dq
+    accumulator, and eight live f32 ``[block_k, block_q]`` tiles (scores,
+    probabilities, dp, ds, their casts and masks).  The whole sequence
+    double-buffered where that fits, single-buffered where only that fits
+    (the next kv head's 2 x S x D then loads behind the last step, not under
+    it), else the kv sequence in equal chunks, each with its own dq partial.
+    At D 128 in bf16: two buffers to 16k, one to 32k, chunks beyond.
+    """
+    lanes = max(d, 128)  # VMEM pads the minor dim to a lane tile
+    step = block_q * lanes * (4 * itemsize + 12) + 8 * block_q * block_k * 4
+
+    def need(blocks, buffers):
+        return blocks * block_k * lanes * (4 * buffers * itemsize + 8) + step
+
+    for buffers in (2, 1):
+        if need(kv_blocks, buffers) <= _BWD_VMEM_BYTES:
+            return kv_blocks, buffers, need(kv_blocks, buffers)
+    fit = max(1, (_BWD_VMEM_BYTES - step) // (need(1, 1) - step))
+    blocks = pl.cdiv(kv_blocks, pl.cdiv(kv_blocks, fit))
+    return blocks, 1, need(blocks, 1)
 
 
 def _flash_bwd(q, k, v, seg_q, seg_kv, pos_q, pos_kv, out, lse, g, g_lse, causal,
                sm_scale, block_q, block_k, segmented, positioned, interpret):
-    """Fused blockwise backward: dq [B*H, T, D], dk/dv [B*Hkv, S, D].
+    """One-pass blockwise backward: dq [B*H, T, D], dk/dv [B*Hkv, S, D] from
+    ONE kernel (:func:`_bwd_kernel`; its ``pallas_call`` keeps the name
+    ``flash_bwd_dkv`` that the trace readers look for).
 
     ``g_lse`` is the cotangent of the lse output (nonzero when callers
     combine partial attentions by logsumexp — ring CP): its score-gradient
@@ -429,10 +508,16 @@ def _flash_bwd(q, k, v, seg_q, seg_kv, pos_q, pos_kv, out, lse, g, g_lse, causal
     bhkv, s_len, _ = k.shape
     n_batch = seg_q.shape[0]
     n_heads = bh // n_batch
+    hkv = bhkv // n_batch  # kv heads per batch element
     n_rep = bh // bhkv
-    block_q = min(block_q, t)
-    block_k = min(block_k, s_len)
+    block_q, block_k = _tiles(block_q, block_k, t, s_len, d, backward=True)
     q_blocks = pl.cdiv(t, block_q)
+    kv_blocks = pl.cdiv(s_len, block_k)
+    positioned = positioned and causal
+    chunk_blocks, buffers, vmem_bytes = _bwd_vmem_plan(
+        kv_blocks, block_q, block_k, d, q.dtype.itemsize)
+    chunk = chunk_blocks * block_k
+    kv_chunks = pl.cdiv(kv_blocks, chunk_blocks)
 
     # delta_i = g_i . out_i — one cheap fused XLA pass, carried as [BH, 1, T]
     # (same tiling-friendly layout as lse)
@@ -442,68 +527,57 @@ def _flash_bwd(q, k, v, seg_q, seg_kv, pos_q, pos_kv, out, lse, g, g_lse, causal
     delta = delta[:, None, :]
     lse3 = lse[:, None, :]
 
-    compiler_params = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary")
-    )
+    def q_head(b, i):  # kv head b, serial step i -> the group member's q-head row
+        return (b // hkv) * n_heads + (b % hkv) * n_rep + i // q_blocks
 
-    # dq grid: (q heads, q_blocks, kv_blocks) — kv specs map to the group head
-    qspec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    kspec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // n_rep, j, 0))
-    rowspec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))
-    seg_q_spec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b // n_heads, 0, i))
-    seg_kv_spec = pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b // n_heads, 0, j))
-    dq = pl.pallas_call(
+    resident = pl.Buffered(buffers)
+    qspec = pl.BlockSpec((1, block_q, d), lambda b, c, i: (q_head(b, i), i % q_blocks, 0))
+    rowspec = pl.BlockSpec((1, 1, block_q), lambda b, c, i: (q_head(b, i), 0, i % q_blocks))
+    kspec = pl.BlockSpec((1, chunk, d), lambda b, c, i: (b, c, 0), pipeline_mode=resident)
+    batch_q = pl.BlockSpec((1, 1, block_q), lambda b, c, i: (b // hkv, 0, i % q_blocks))
+    batch_kv = pl.BlockSpec((1, 1, chunk), lambda b, c, i: (b // hkv, 0, c))
+    operands = [q, g, lse3, delta, k, v]
+    in_specs = [qspec, qspec, rowspec, rowspec, kspec, kspec]
+    if segmented:
+        operands += [seg_q, seg_kv]
+        in_specs += [batch_q, batch_kv]
+    if positioned:
+        operands += [pos_q, pos_kv]
+        in_specs += [batch_q, batch_kv]
+    # one dq partial per kv chunk: with the whole sequence resident (every
+    # shape up to 32k x 128 in bf16) that is dq itself, cast in the kernel
+    dq_dtype = q.dtype if kv_chunks == 1 else jnp.float32
+    dq, dk, dv = pl.pallas_call(
         functools.partial(
-            _dq_kernel, causal=causal, sm_scale=sm_scale, block_q=block_q, block_k=block_k,
-            t_len=t, s_len=s_len, segmented=segmented, positioned=positioned,
-        ),
-        name="flash_bwd_dq",
-        grid=(bh, q_blocks, pl.cdiv(s_len, block_k)),
-        in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec, seg_q_spec, seg_kv_spec,
-                  seg_q_spec, seg_kv_spec],
-        out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=compiler_params,
-        interpret=interpret,
-    )(q, k, v, g, lse3, delta, seg_q, seg_kv, pos_q, pos_kv)
-
-    # dk/dv grid: (kv heads, kv_blocks, group*q_blocks) — the serial dim walks
-    # every (group member, q block) pair so GQA head-sums happen in-scratch
-    hkv = bhkv // n_batch  # kv heads per batch element
-
-    def q_map(b, j, i):  # kv head b, serial step i -> q-head row + q block
-        return ((b // hkv) * n_heads + (b % hkv) * n_rep + i // q_blocks, i % q_blocks, 0)
-
-    def row_map(b, j, i):
-        return ((b // hkv) * n_heads + (b % hkv) * n_rep + i // q_blocks, 0, i % q_blocks)
-
-    qspec2 = pl.BlockSpec((1, block_q, d), q_map)
-    kspec2 = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
-    rowspec2 = pl.BlockSpec((1, 1, block_q), row_map)
-    seg_q_spec2 = pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b // hkv, 0, i % q_blocks))
-    seg_kv_spec2 = pl.BlockSpec((1, 1, block_k), lambda b, j, i: (b // hkv, 0, j))
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _dkv_kernel, causal=causal, sm_scale=sm_scale, block_q=block_q, block_k=block_k,
-            t_len=t, s_len=s_len, q_blocks=q_blocks, segmented=segmented, positioned=positioned,
+            _bwd_kernel, causal=causal, sm_scale=sm_scale, block_q=block_q, block_k=block_k,
+            t_len=t, s_len=s_len, chunk_blocks=chunk_blocks, q_blocks=q_blocks,
+            segmented=segmented, positioned=positioned,
         ),
         name="flash_bwd_dkv",
-        grid=(bhkv, pl.cdiv(s_len, block_k), n_rep * q_blocks),
-        in_specs=[qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2, seg_q_spec2, seg_kv_spec2,
-                  seg_q_spec2, seg_kv_spec2],
-        out_specs=[kspec2, kspec2],
+        grid=(bhkv, kv_chunks, n_rep * q_blocks),
+        in_specs=in_specs,
+        out_specs=[
+            pl.BlockSpec((1, 1, block_q, d),
+                         lambda b, c, i: (c, q_head(b, i), i % q_blocks, 0)),
+            kspec, kspec,
+        ],
         out_shape=[
+            jax.ShapeDtypeStruct((kv_chunks, bh, t, d), dq_dtype),
             jax.ShapeDtypeStruct((bhkv, s_len, d), k.dtype),
             jax.ShapeDtypeStruct((bhkv, s_len, d), v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((chunk, d), jnp.float32),
+            pltpu.VMEM((chunk, d), jnp.float32),
         ],
-        compiler_params=compiler_params,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_bytes + vmem_bytes // 4 + (4 << 20),
+        ),
         interpret=interpret,
-    )(q, k, v, g, lse3, delta, seg_q, seg_kv, pos_q, pos_kv)
+    )(*operands)
+    dq = dq[0] if kv_chunks == 1 else jnp.sum(dq, axis=0).astype(q.dtype)
     return dq, dk, dv
 
 
@@ -1145,8 +1219,12 @@ def flash_attention(
     """Drop-in replacement for :func:`models.llama.native_attention`.
 
     q: [B, T, H, D]; k/v: [B, S, Hkv, D].  GQA runs without repeating K/V —
-    the kernel's BlockSpecs map each q head to its group's kv head, and dk/dv
-    accumulate the group sum in VMEM scratch.
+    the forward's BlockSpecs map each q head to its group's kv head, and the
+    backward walks a kv head's whole group against its resident K/V, so
+    dk/dv accumulate the group sum in VMEM scratch.
+
+    ``block_q``/``block_k`` pin BOTH passes' tiles; left None, each pass
+    takes its own measured default (:func:`default_block_sizes`).
 
     ``segment_ids`` [B, T] masks cross-segment attention in-kernel (packed
     sequences at flash speed).  ``kv_segment_ids`` [B, S] gives the KV side
@@ -1169,11 +1247,6 @@ def flash_attention(
         sm_scale = 1.0 / float(np.sqrt(d))
     if interpret is None:
         interpret = not _on_tpu()
-    if block_q is None or block_k is None:
-        bq, bk = default_block_sizes(t, s, d)
-        block_q = block_q or bq
-        block_k = block_k or bk
-
     segmented = segment_ids is not None
     if segmented:
         if kv_segment_ids is None:

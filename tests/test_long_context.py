@@ -45,7 +45,7 @@ def test_flash_matches_native_interpret():
 
 
 def test_flash_grads_match_native():
-    """dq AND dk/dv (both backward kernels) against the native reference."""
+    """dq AND dk/dv (all three from the one backward kernel) against the native reference."""
     q, k, v = _qkv()
     f = lambda q, k, v: jnp.sum(flash_attention(q, k, v, causal=True, block_q=8, block_k=8, interpret=True) ** 2)
     g = lambda q, k, v: jnp.sum(native_attention(q, k, v, causal=True) ** 2)
@@ -58,7 +58,7 @@ def test_flash_grads_match_native():
 @pytest.mark.slow
 def test_flash_non_divisible_seq_len():
     """Sequence lengths not divisible by the block size must still be exact
-    (padded tile rows/cols are masked, not garbage): fwd + both bwd kernels."""
+    (padded tile rows/cols are masked, not garbage): fwd + the bwd kernel."""
     rng = np.random.default_rng(3)
     B, T, H, D = 1, 12, 2, 8  # T=12 with block 8 -> padded second block
     q = jnp.asarray(rng.normal(size=(B, T, H, D)), jnp.float32)
@@ -270,6 +270,151 @@ def test_flash_positions_and_lse():
     np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse), atol=1e-4)
 
 
+def _dense_attention(q, k, v, *, causal, seg_q=None, seg_kv=None, pos_q=None, pos_kv=None):
+    """``flash_attention``'s semantics, dense and in f32: causal by index from
+    the top-left corner (``native_attention`` aligns T != S bottom-right) or
+    by explicit position; returns (out [B, T, H, D], lse [B, T, H])."""
+    b, t, h, d = q.shape
+    s, hkv = k.shape[1], k.shape[2]
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    k, v = (jnp.repeat(x, h // hkv, axis=2) for x in (k, v))
+    scores = jnp.einsum("bthd,bshd->bhts", q, k) / np.sqrt(d)
+    mask = jnp.ones((b, t, s), bool)
+    if causal:
+        rows = jnp.arange(t)[None, :] if pos_q is None else pos_q
+        cols = jnp.arange(s)[None, :] if pos_kv is None else pos_kv
+        mask &= rows[:, :, None] >= cols[:, None, :]
+    if seg_q is not None:
+        mask &= seg_q[:, :, None] == seg_kv[:, None, :]
+    scores = jnp.where(mask[:, None], scores, -1e30)
+    out = jnp.einsum("bhts,bshd->bthd", jax.nn.softmax(scores, -1), v)
+    return out, jax.nn.logsumexp(scores, -1).transpose(0, 2, 1)
+
+
+# (id, T, S, q heads, kv heads, causal, extras, dtype): blocks of 8, so every
+# case walks several kv sub-blocks a q block and several q blocks a kv head
+_ONE_PASS_CASES = [
+    ("group1-causal", 24, 24, 2, 2, True, "", "float32"),
+    ("group1-full", 24, 24, 2, 2, False, "", "float32"),
+    ("group4-causal", 24, 24, 8, 2, True, "", "float32"),
+    ("group4-full", 24, 24, 8, 2, False, "", "float32"),
+    ("group7-causal", 16, 16, 7, 1, True, "", "float32"),
+    ("group7-full", 16, 16, 7, 1, False, "", "float32"),
+    ("segments-causal", 24, 24, 4, 2, True, "segments", "float32"),
+    ("segments-full", 24, 24, 4, 2, False, "segments", "float32"),
+    ("positions-lse", 16, 16, 4, 2, True, "positions", "float32"),
+    ("positions-lse-ragged", 20, 20, 4, 1, True, "positions", "float32"),
+    ("ragged-causal", 20, 20, 4, 2, True, "", "float32"),
+    ("ragged-full", 12, 12, 2, 2, False, "", "float32"),
+    ("ragged-segments", 20, 20, 7, 1, True, "segments", "float32"),
+    ("cross-full", 16, 24, 4, 2, False, "", "float32"),
+    ("cross-causal", 24, 16, 4, 2, True, "", "float32"),
+    ("cross-ragged-segments", 12, 20, 4, 2, False, "segments", "float32"),
+    ("bf16-group4-causal", 24, 24, 8, 2, True, "", "bfloat16"),
+    ("bf16-group7-full", 16, 16, 7, 1, False, "", "bfloat16"),
+    ("bf16-positions-lse", 16, 16, 4, 2, True, "positions", "bfloat16"),
+    ("bf16-ragged-segments", 20, 20, 4, 2, True, "segments", "bfloat16"),
+]
+
+
+@pytest.mark.parametrize("t,s,h,hkv,causal,extra,dtype", [c[1:] for c in _ONE_PASS_CASES],
+                         ids=[c[0] for c in _ONE_PASS_CASES])
+def test_flash_one_pass_backward(t, s, h, hkv, causal, extra, dtype):
+    """dq, dk AND dv of the one backward kernel, through ``jax.grad``: GQA
+    groups 1/4/7, causal on/off, packed segments, explicit positions with a
+    nonzero lse cotangent, T off the block, T != S, f32 and bf16 — against
+    ``native_attention`` wherever it has the same semantics (self-attention
+    shapes, no positions), else against the dense reference above."""
+    rng = np.random.default_rng(35)
+    b, d = 2, 8
+    dtype = jnp.dtype(dtype)
+    q = jnp.asarray(rng.normal(size=(b, t, h, d)), dtype)
+    k = jnp.asarray(rng.normal(size=(b, s, hkv, d)), dtype)
+    v = jnp.asarray(rng.normal(size=(b, s, hkv, d)), dtype)
+    w_out = jnp.asarray(rng.normal(size=(b, t, h, d)), jnp.float32)
+    w_lse = jnp.asarray(rng.normal(size=(b, t, h)), jnp.float32)
+    kwargs, dense = {}, {}
+    if extra == "segments":
+        cut = lambda n: jnp.asarray(np.repeat([[0] * (n // 3) + [1] * (n - n // 3)], b, 0), jnp.int32)
+        kwargs = dict(segment_ids=cut(t), kv_segment_ids=cut(s))
+        dense = dict(seg_q=cut(t), seg_kv=cut(s))
+    if extra == "positions":
+        perm = lambda n: jnp.asarray(np.stack([rng.permutation(n) for _ in range(b)]), jnp.int32)
+        kwargs = dict(positions=perm(t), kv_positions=perm(s))
+        dense = dict(pos_q=kwargs["positions"], pos_kv=kwargs["kv_positions"])
+
+    def flash_loss(q, k, v):
+        out, lse = flash_attention(q, k, v, causal=causal, return_lse=True,
+                                   block_q=8, block_k=8, interpret=True, **kwargs)
+        loss = jnp.sum(out.astype(jnp.float32) * w_out)
+        return loss + jnp.sum(lse * w_lse) if extra == "positions" else loss
+
+    def reference_loss(q, k, v):
+        if t == s and extra != "positions":
+            out = native_attention(q.astype(jnp.float32), k.astype(jnp.float32),
+                                   v.astype(jnp.float32), causal=causal,
+                                   segment_ids=kwargs.get("segment_ids"))
+            return jnp.sum(out * w_out)
+        out, lse = _dense_attention(q, k, v, causal=causal, **dense)
+        loss = jnp.sum(out * w_out)
+        return loss + jnp.sum(lse * w_lse) if extra == "positions" else loss
+
+    got = jax.grad(flash_loss, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(reference_loss, argnums=(0, 1, 2))(q, k, v)
+    atol = 1e-4 if dtype == jnp.float32 else 0.15
+    for name, a, ref in zip("qkv", got, want):
+        assert a.dtype == dtype and a.shape == ref.shape, f"d{name}"
+        a, ref = np.asarray(a, np.float32), np.asarray(ref, np.float32)
+        assert np.all(np.isfinite(a)), f"d{name} has NaN/inf"
+        np.testing.assert_allclose(a, ref, atol=atol, rtol=0 if dtype == jnp.float32 else 0.03,
+                                   err_msg=f"d{name}")
+
+
+def test_flash_one_pass_dq_sums_kv_blocks_in_f32_and_casts_once():
+    """dq of bf16 operands: per kv sub-block ``ds`` is rounded to bf16 (the
+    matmul's operand), the products sum in f32 ACROSS the sub-blocks and the
+    sum is cast once — equal to that blockwise reference to bf16's last bit,
+    and not to one that rounds the running sum after every sub-block."""
+    from accelerate_tpu.ops import flash_attention as fa
+
+    rng = np.random.default_rng(36)
+    bh, t, d, blk = 2, 64, 16, 8
+    bf16 = jnp.bfloat16
+    q, k, v, g = (jnp.asarray(rng.normal(size=(bh, t, d)), bf16) for _ in range(4))
+    none = jnp.zeros((bh, 1, t), jnp.int32)
+    sm = 1.0 / np.sqrt(d)
+    out, lse = fa._flash_fwd(q, k, v, none, none, none, none, True, sm, blk, blk, False, False, True)
+    dq, _, _ = fa._flash_bwd(q, k, v, none, none, none, none, out, lse, g, None, True, sm,
+                             blk, blk, False, False, True)
+    assert dq.dtype == bf16
+
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), -1)
+    idx = jnp.arange(t)
+
+    def blockwise(round_each_block):
+        acc = jnp.zeros((bh, t, d), jnp.float32)
+        for start in range(0, t, blk):
+            kb, vb = k[:, start:start + blk], v[:, start:start + blk]
+            s = jnp.einsum("bsd,btd->bst", kb, q, preferred_element_type=jnp.float32) * sm
+            p = jnp.where(idx[None, None, :] >= idx[None, start:start + blk, None],
+                          jnp.exp(s - lse[:, None, :]), 0.0)
+            dp = jnp.einsum("bsd,btd->bst", vb, g, preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta[:, None, :]) * sm).astype(bf16)
+            acc = acc + jnp.einsum("bst,bsd->btd", ds, kb, preferred_element_type=jnp.float32)
+            if round_each_block:
+                acc = acc.astype(bf16).astype(jnp.float32)
+        return acc.astype(bf16)
+
+    def ulps(a, b):  # distance in bf16 steps between two bf16 arrays
+        bits = lambda x: np.asarray(x).view(np.int16).astype(np.int32)
+        order = lambda x: np.where(bits(x) < 0, -(bits(x) & 0x7FFF), bits(x))
+        return np.abs(order(a) - order(b))
+
+    exact = ulps(dq, blockwise(round_each_block=False))
+    assert exact.max() <= 1 and np.mean(exact == 0) > 0.99
+    assert np.mean(ulps(dq, blockwise(round_each_block=True)) == 0) < 0.9
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("use_flash", [False, True])
 def test_ring_attention_gqa_no_repeat(cp_mesh, use_flash):
@@ -389,6 +534,55 @@ def test_default_block_sizes_heuristic():
     bq, bk = default_block_sizes(8192, 8192, 1024)  # giant head dim must shrink
     assert 4 * (2 * bq * 1024 + 2 * bk * 1024 + bq * bk) <= _VMEM_BUDGET_BYTES
     assert bq % 128 == 0 and bk % 128 == 0
+    # the backward's tiles are its own: the sweep's winner at the train
+    # cells' shapes, clamped to the lengths, never shrunk by the forward's
+    # budget (the kernel states its VMEM from its plan)
+    assert default_block_sizes(4096, 4096, 128) == (512, 1024)
+    assert default_block_sizes(4096, 4096, 128, backward=True) == (512, 512)
+    assert default_block_sizes(12, 300, 8, backward=True) == (128, 384)
+    assert default_block_sizes(32768, 32768, 1024, backward=True) == (512, 512)
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["forward", "backward"])
+def test_autotune_sweeps_one_pass_alone(backward):
+    """The autotuner times the forward's or the backward's tiles apart and
+    returns one of its candidates for that pass."""
+    from accelerate_tpu.ops import flash_attention as fa
+
+    cands = {(8, 8), (16, 8)}
+    best = fa.autotune_block_sizes(1, 32, 2, 8, 1, dtype=jnp.float32, backward=backward,
+                                   candidates=cands, iters=1)
+    assert best in cands
+    key = [k for k in fa._AUTOTUNE_CACHE if k[:4] == (1, 32, 2, 8) and k[7] == backward]
+    assert len(key) == 1 and fa._AUTOTUNE_CACHE[key[0]] == best
+
+
+def test_backward_vmem_plan_follows_the_shapes(monkeypatch):
+    """What stays resident is a function of (S, D, itemsize) against the
+    stated VMEM: two buffers, then one, then equal kv chunks — and the
+    chunked walk (a dq partial a chunk, summed in f32) gives the same
+    gradients as the whole sequence resident."""
+    from accelerate_tpu.ops import flash_attention as fa
+
+    plan = lambda s, d=128, itemsize=2: fa._bwd_vmem_plan(-(-s // 512), 512, 512, d, itemsize)
+    assert plan(4096)[:2] == (8, 2) and plan(16384)[:2] == (32, 2)
+    assert plan(32768)[:2] == (64, 1)
+    blocks, buffers, _ = plan(65536)
+    assert buffers == 1 and blocks < 128 and -(-128 // blocks) == 2
+    assert all(plan(s, d, i)[2] <= fa._BWD_VMEM_BYTES
+               for s in (4096, 32768, 131072) for d in (64, 128, 256) for i in (2, 4))
+
+    q, k, v = _qkv(b=1, t=44, h=4, d=8, seed=9)
+    k, v = k[:, :, :2], v[:, :, :2]
+    loss = lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=True, block_q=8, block_k=8, interpret=True) ** 2)
+    whole = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    step = fa._bwd_vmem_plan(1, 8, 8, 8, 4)[2] - 8 * 128 * 24
+    monkeypatch.setattr(fa, "_BWD_VMEM_BYTES", step + 2 * 8 * 128 * 24)  # room for 2 of 6 sub-blocks
+    assert fa._bwd_vmem_plan(6, 8, 8, 8, 4)[:2] == (2, 1)
+    chunked = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", chunked, whole):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5, err_msg=f"d{name}")
 
 
 @pytest.mark.parametrize("causal", [True, False])

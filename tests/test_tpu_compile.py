@@ -86,16 +86,69 @@ def _scales(rest):
     return dict(k_scales=rest[0], v_scales=rest[1]) if rest else {}
 
 
-@WIDTHS
-def test_flash_forward_and_backward_compile(compile_for_chip, h, hkv, d, hidden):
+# (batch, seq, q heads, kv heads, head_dim): the smoke's widths at 2048, the
+# two train cells' per-chip shapes (Mistral; Yi's tp shard), and the longest
+# sequence whose K/V the backward keeps whole in VMEM (single-buffered)
+FLASH_SHAPES = pytest.mark.parametrize("b,t,h,hkv,d", [
+    (2, 2048) + LLAMA2_7B[:3], (2, 2048) + CONTROL_513M[:3],
+    (2, 4096, 32, 8, 128), (2, 4096, 28, 4, 128), (1, 32768, 8, 2, 128),
+], ids=["llama2_7b", "control_513m", "mistral_cell", "yi_cell_shard", "seq_32k"])
+
+
+def _custom_calls(text, name):
+    """Mosaic custom calls of the compiled HLO whose op_name carries the
+    kernel's ``pallas_call(name=)`` as a whole word."""
+    import re
+
+    return [line for line in text.splitlines()
+            if "tpu_custom_call" in line and re.search(rf"\b{name}\b", line)]
+
+
+@FLASH_SHAPES
+def test_flash_forward_and_backward_compile(compile_for_chip, b, t, h, hkv, d):
+    """The forward and the ONE backward kernel compile for the chip at the
+    shapes the cells run, inside the VMEM the backward states from its plan
+    (Mosaic refuses a kernel over its scoped limit at compile time)."""
     def fwd_bwd(q, k, v):
         loss = lambda q, k, v: jnp.sum(fa.flash_attention(
             q, k, v, causal=True, interpret=False).astype(jnp.float32))
         return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
 
-    text = compile_for_chip(fwd_bwd, ((2, 2048, h, d), BF16),
-                            ((2, 2048, hkv, d), BF16), ((2, 2048, hkv, d), BF16))
-    assert text.count("tpu_custom_call") >= 3  # fwd, dq, dk/dv
+    text = compile_for_chip(fwd_bwd, ((b, t, h, d), BF16),
+                            ((b, t, hkv, d), BF16), ((b, t, hkv, d), BF16))
+    assert len(_custom_calls(text, "flash_fwd")) == 1
+    assert len(_custom_calls(text, "flash_bwd_dkv")) == 1
+    assert not _custom_calls(text, "flash_bwd_dq")
+    assert text.count("tpu_custom_call") == 2
+    blocks, buffers, vmem = fa._bwd_vmem_plan(-(-t // 512), 512, 512, d, 2)
+    assert (blocks, buffers) == (-(-t // 512), 1 if t > 16384 else 2)
+    assert vmem <= fa._BWD_VMEM_BYTES
+
+
+@pytest.mark.parametrize("case", ["segments", "positions_lse", "ragged", "kv_chunks_64k"])
+def test_flash_backward_flags_compile(compile_for_chip, case):
+    """The same kernel under the static flags no cell runs: packed segments,
+    ring CP's positions with an lse cotangent, lengths off the block, and a
+    sequence too long to stay whole in VMEM (equal kv chunks)."""
+    t = {"ragged": 4000, "kv_chunks_64k": 65536}.get(case, 4096)
+    b, h, hkv, d = (1, 4, 2, 128) if case == "kv_chunks_64k" else (2, 8, 2, 128)
+
+    def grads(q, k, v, ids):
+        kwargs = {"segments": dict(segment_ids=ids),
+                  "positions_lse": dict(positions=ids, return_lse=True)}.get(case, {})
+
+        def loss(q, k, v):
+            out = fa.flash_attention(q, k, v, causal=True, interpret=False, **kwargs)
+            if case == "positions_lse":
+                return jnp.sum(out[0].astype(jnp.float32)) + jnp.sum(out[1])
+            return jnp.sum(out.astype(jnp.float32))
+
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    text = compile_for_chip(grads, ((b, t, h, d), BF16), ((b, t, hkv, d), BF16),
+                            ((b, t, hkv, d), BF16), ((b, t), jnp.int32))
+    assert len(_custom_calls(text, "flash_bwd_dkv")) == 1
+    assert not _custom_calls(text, "flash_bwd_dq")
 
 
 @pytest.mark.parametrize("region", ["gspmd", "pipeline_stage", "bare_kernel"])
@@ -133,7 +186,9 @@ def test_flash_under_a_four_chip_mesh(topo, compile_for_chip, region):
         with pytest.raises(Exception, match="Mosaic kernels cannot be automatically partitioned"):
             lowered().compile()
     else:
-        assert lowered().compile().as_text().count("tpu_custom_call") >= 3
+        text = lowered().compile().as_text()
+        assert len(_custom_calls(text, "flash_fwd")) == 1
+        assert len(_custom_calls(text, "flash_bwd_dkv")) == 1  # the one backward kernel
 
 
 @WIDTHS
@@ -265,7 +320,7 @@ def test_fsdp_x_tp_block_moves_weights_not_activations(topo, compile_for_chip, m
     assert [c for c in collectives if c[1][:2] == (batch, seq)] == []   # nothing at the global batch
     assert [c for c in collectives
             if c[0] == "all-to-all" and seq in c[1] and hidden // 2 in c[1]] == []
-    assert text.count("tpu_custom_call") >= 3
+    assert text.count("tpu_custom_call") >= 2      # flash forward, the one backward kernel
 
 
 # -- Keye-VL-2.0's serving programs at the cell's shapes (keye-vl2.serve_long) -----------
