@@ -7,12 +7,17 @@ num_attention_heads``; ``KeyeVL2Config`` takes an explicit ``head_dim``
 config whose ``num_attention_heads * head_dim != hidden_size``.
 ``KExaoneConfig`` (window and full-attention layers, sigmoid-routed experts
 beside a shared expert) does too, and adds what of a layer a chip HOLDS.
+``JoyAIFlashConfig`` (latent attention: a token's key and value are one row
+of ``kv_lora_rank + qk_rope_head_dim`` values shared by every head) has no
+``head_dim`` to carry: a head is ``qk_nope_head_dim + qk_rope_head_dim`` wide
+where it is scored and ``v_head_dim`` where it is summed.
 ``docs/supported_models.md`` has the table of what each family trains,
 serves and refuses."""
 
 from .bert import BertConfig, BertForSequenceClassification, make_bert_loss_fn
 from .hf_interop import (
     hf_bert_key_map,
+    hf_joyai_flash_key_map,
     hf_k_exaone_key_map,
     hf_keye_vl2_key_map,
     hf_llama_key_map,
@@ -20,12 +25,14 @@ from .hf_interop import (
     hf_mixtral_key_map,
     hf_t5_key_map,
     load_hf_bert,
+    load_hf_joyai_flash,
     load_hf_k_exaone,
     load_hf_keye_vl2,
     load_hf_llama,
     load_hf_mixtral,
     load_hf_t5,
 )
+from .joyai_flash import JoyAIFlashConfig, JoyAIFlashForCausalLM
 from .k_exaone import KExaoneConfig, KExaoneForCausalLM
 from .keye_vl2 import KeyeVL2Config, KeyeVL2ForCausalLM
 from .llama import (

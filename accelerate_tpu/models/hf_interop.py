@@ -316,6 +316,76 @@ def load_hf_k_exaone(model, checkpoint, *, mesh=None, dtype=None, rng=None,
         key_map=hf_k_exaone_key_map, tensor_map=hf_llama_tensor_map, **kwargs)
 
 
+# -- JoyAI-LLM-Flash (latent attention, sigmoid-routed experts + a shared expert) ---
+# ASSUMED: DeepSeek-V3's tensor names, which every key of the published config
+# is one of (``q_a_proj`` / ``q_a_layernorm`` / ``q_b_proj``,
+# ``kv_a_proj_with_mqa`` / ``kv_a_layernorm`` / ``kv_b_proj``); the MLP, router
+# and next-token-prediction names are K-EXAONE's rules above.  ``kv_b_proj``
+# stays ONE tensor ``[kv_lora_rank, heads x (nope + v)]``, head-major, each
+# head's columns ``[W_UK,h ; W_UV,h]``: the model cuts it per head.
+_JOYAI_BLOCK: list[tuple[str, str]] = [
+    (r"self_attn\.(q_a|q_b|kv_a_proj_with_mqa|o)(_proj)?\.weight$", r"self_attn.\1\2.kernel"),
+    (r"self_attn\.(q_a|kv_a)_layernorm\.weight$", r"self_attn.\1_layernorm.scale"),
+    (r"self_attn\.kv_b_proj\.weight$", r"self_attn.kv_b_proj"),
+] + _K_EXAONE_BLOCK[2:]
+
+
+def hf_joyai_flash_key_map(name: str) -> Optional[str]:
+    """HF ``joyai_llm_flash`` ``state_dict`` name -> ``JoyAIFlashForCausalLM``'s
+    param path (the whole model: a share's slices are the caller's to cut);
+    None for rotary buffers."""
+    if name.endswith("rotary_emb.inv_freq"):
+        return None
+    if name in _K_EXAONE_TOP:
+        return _K_EXAONE_TOP[name]
+    m = re.match(r"^model\.(?:layers\.(\d+)|mtp\.block)\.(.+)$", name)
+    if m:
+        scope = "mtp.block" if m.group(1) is None else f"layers_{m.group(1)}"
+        for pattern, template in _JOYAI_BLOCK:
+            if re.match(pattern, m.group(2)):
+                return f"params.{scope}." + re.sub(pattern, template, m.group(2))
+    return name  # unknown names pass through and surface as `unexpected`
+
+
+def load_hf_joyai_flash(model, checkpoint, *, mesh=None, dtype=None, rng=None,
+                        sample_args=(), strict: bool = True, **kwargs):
+    """Stream an HF-format JoyAI-LLM-Flash checkpoint into
+    ``JoyAIFlashForCausalLM``'s param tree; experts stacked as in
+    :func:`load_hf_mixtral`.  ``rope_interleave``: the checkpoint's rotary
+    dims pair as ``(2i, 2i + 1)`` and the program rotates halves, so the last
+    ``qk_rope_head_dim`` columns of ``kv_a_proj_with_mqa`` and of every head
+    of ``q_b_proj`` are de-interleaved on the way in
+    (``models/joyai_flash.deinterleave_rope``)."""
+    import jax.numpy as jnp
+
+    from ..big_modeling import load_checkpoint_and_dispatch
+    from .joyai_flash import deinterleave_rope_columns
+
+    cfg = model.config
+    dr = cfg.qk_rope_head_dim
+
+    def tensor_map(our_key: str, arr: np.ndarray) -> np.ndarray:
+        if our_key.endswith("/kv_b_proj"):
+            return arr.T
+        arr = hf_llama_tensor_map(our_key, arr)
+        if not cfg.rope_interleave:
+            return arr
+        if our_key.endswith("q_b_proj/kernel"):
+            return deinterleave_rope_columns(arr, cfg.qk_nope_head_dim + dr, dr)
+        if our_key.endswith("kv_a_proj_with_mqa/kernel"):
+            return deinterleave_rope_columns(arr, arr.shape[-1], dr)
+        return arr
+
+    if not sample_args:
+        sample_args = (jnp.ones((1, 8), jnp.int32),)
+    stream = _stack_expert_stream(
+        checkpoint, cfg.num_experts, _K_EXAONE_EXPERT_RE, lambda w: f"{w}_proj",
+        "model.layers.{layer}.mlp.experts_stacked.{proj}")
+    return load_checkpoint_and_dispatch(
+        model, stream, rng=rng, sample_args=sample_args, mesh=mesh, dtype=dtype, strict=strict,
+        key_map=hf_joyai_flash_key_map, tensor_map=tensor_map, **kwargs)
+
+
 # -- BERT (encoder classifier) -----------------------------------------------
 _BERT_RULES: list[tuple[str, str]] = [
     (r"^bert\.embeddings\.word_embeddings\.weight$", r"params.word_embeddings.embedding"),

@@ -51,7 +51,7 @@ import jax.numpy as jnp
 from ..ops import sparse_attention as sa
 from ..ops import window_attention as wa
 from ..parallel.expert_parallel import grouped_ffn, held_rows_fed, route_dropless
-from .keye_vl2 import _Float32Dense, _Float32Out, _proj, apply_rotary, rotary_angles
+from .layers import Float32Dense, Float32Out, apply_rotary, bias_free_proj, rotary_angles
 from .llama import LMHead, RMSNorm
 
 _PERIOD = ("sliding_attention", "sliding_attention", "sliding_attention", "full_attention")
@@ -169,16 +169,16 @@ class KExaoneAttention(nn.Module):
         h, hkv, d, window = cfg.heads, cfg.kv_heads, cfg.head_dim, cfg.sliding_window
         sliding = self.kind == "sliding_attention"
         x = x32.astype(cfg.dtype)
-        q = _proj(h * d, cfg, "q_proj")(x).reshape(b, t, h, d)
-        k = _proj(hkv * d, cfg, "k_proj")(x).reshape(b, t, hkv, d)
-        v = _proj(hkv * d, cfg, "v_proj")(x).reshape(b, t, hkv, d)
+        q = bias_free_proj(h * d, cfg, "q_proj")(x).reshape(b, t, h, d)
+        k = bias_free_proj(hkv * d, cfg, "k_proj")(x).reshape(b, t, hkv, d)
+        v = bias_free_proj(hkv * d, cfg, "v_proj")(x).reshape(b, t, hkv, d)
         q = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="q_norm")(q)
         k = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="k_norm")(k)
         if sliding:            # rotary on window layers only
             ang = rotary_angles(positions, d, cfg.rope_theta)
             q = apply_rotary(q, ang).astype(cfg.dtype)
             k = apply_rotary(k, ang).astype(cfg.dtype)
-        o_proj = _Float32Out(cfg.hidden_size, cfg.dtype, name="o_proj")
+        o_proj = Float32Out(cfg.hidden_size, cfg.dtype, name="o_proj")
 
         if cache is None:
             with jax.named_scope("window_attend" if sliding else "global_attend"):
@@ -229,15 +229,16 @@ class KExaoneMLP(nn.Module):
     """One SwiGLU: bf16 operands, the down projection's float32 accumulator
     handed on.  The dense layers' MLP and the shared expert."""
 
-    config: KExaoneConfig
+    config: Any
     width: int
 
     @nn.compact
     def __call__(self, x32):
         cfg = self.config
         x = x32.astype(cfg.dtype)
-        hidden = nn.silu(_proj(self.width, cfg, "gate_proj")(x)) * _proj(self.width, cfg, "up_proj")(x)
-        return _Float32Out(cfg.hidden_size, cfg.dtype, name="down_proj")(hidden)
+        hidden = nn.silu(bias_free_proj(self.width, cfg, "gate_proj")(x)) \
+            * bias_free_proj(self.width, cfg, "up_proj")(x)
+        return Float32Out(cfg.hidden_size, cfg.dtype, name="down_proj")(hidden)
 
 
 class KExaoneSparseMoE(nn.Module):
@@ -245,14 +246,14 @@ class KExaoneSparseMoE(nn.Module):
     router over all ``num_experts`` (float32), selection by ``score + bias``,
     gates from the unbiased scores."""
 
-    config: KExaoneConfig
+    config: Any
 
     @nn.compact
     def __call__(self, x32, token_mask=None):
         cfg = self.config
         b, t, hid = x32.shape
         held, f = cfg.held, cfg.moe_intermediate_size
-        logits = _Float32Dense(cfg.num_experts, name="gate")(x32)
+        logits = Float32Dense(cfg.num_experts, name="gate")(x32)
         bias = self.param("e_score_correction_bias", nn.initializers.zeros, (cfg.num_experts,),
                           jnp.float32)
         init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=0)
@@ -275,15 +276,21 @@ class KExaoneSparseMoE(nn.Module):
 
 
 class KExaoneBlock(nn.Module):
-    config: KExaoneConfig
+    """Pre-norm block: ``h = x + attn(norm(x))``, ``y = h + mlp(norm(h))``.
+    ``attention``: the module class ``(config, kind)`` of the attention
+    (another family's, e.g. ``models/joyai_flash.py``'s latent attention,
+    whose configuration then answers to the names the MLPs here read)."""
+
+    config: Any
     kind: str
     mlp: str
+    attention: Any = KExaoneAttention
 
     @nn.compact
     def __call__(self, x, positions, cache=None, cache_write_mask=None):
         cfg = self.config
         n = RMSNorm(cfg.rms_norm_eps, jnp.float32, name="input_layernorm")(x)
-        attn, state, seen = KExaoneAttention(cfg, self.kind, name="self_attn")(
+        attn, state, seen = self.attention(cfg, self.kind, name="self_attn")(
             n, positions, cache, cache_write_mask)
         h = x + attn
         n = RMSNorm(cfg.rms_norm_eps, jnp.float32, name="post_attention_layernorm")(h)
@@ -299,7 +306,9 @@ class KExaoneMTP(nn.Module):
     full-attention sparse block whose output, through the model's own final
     norm and head, predicts token ``t + 2``."""
 
-    config: KExaoneConfig
+    config: Any
+    kind: str = "full_attention"
+    attention: Any = KExaoneAttention
 
     @nn.compact
     def __call__(self, hidden, next_embeds, positions):
@@ -307,8 +316,8 @@ class KExaoneMTP(nn.Module):
         joined = jnp.concatenate(
             [RMSNorm(cfg.rms_norm_eps, jnp.float32, name="hnorm")(hidden),
              RMSNorm(cfg.rms_norm_eps, jnp.float32, name="enorm")(next_embeds)], axis=-1)
-        x = _Float32Out(cfg.hidden_size, cfg.dtype, name="eh_proj")(joined)
-        return KExaoneBlock(cfg, "full_attention", "sparse", name="block")(x, positions)[0]
+        x = Float32Out(cfg.hidden_size, cfg.dtype, name="eh_proj")(joined)
+        return KExaoneBlock(cfg, self.kind, "sparse", self.attention, name="block")(x, positions)[0]
 
 
 class KExaoneForCausalLM(nn.Module):

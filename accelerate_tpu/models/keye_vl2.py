@@ -33,11 +33,10 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax import lax
 
 from ..ops import sparse_attention as sa
 from ..parallel.expert_parallel import grouped_ffn, route_dropless
+from .layers import Float32Dense, Float32Out, apply_rotary, bias_free_proj, rotary_angles
 from .llama import LMHead, RMSNorm
 
 
@@ -112,65 +111,6 @@ class KeyeVL2Config:
         return cls(**kw)
 
 
-def rotary_angles(positions, dim: int, theta: float, sections=None):
-    """Angles ``[B, T, dim / 2]`` (float32) computed from the positions.
-    ``positions`` ``[B, T]``, or ``[3, B, T]`` with ``sections``: frequency
-    pair ``i`` takes its angle from axis 0, 1 or 2 by the section it lies in."""
-    inv = 1.0 / (theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim))
-    ang = positions.astype(jnp.float32)[..., None] * inv
-    if positions.ndim == 2:
-        return ang
-    axis = np.repeat(np.arange(3), sections)                       # [dim / 2]
-    return sum(jnp.where(axis == a, ang[a], 0.0) for a in range(3))
-
-
-def apply_rotary(x, angles):
-    """x ``[B, T, heads, D]`` (rotate-half); computed and returned in float32."""
-    cos, sin = jnp.cos(angles)[:, :, None, :], jnp.sin(angles)[:, :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-
-
-def _proj(features: int, cfg, name: str):
-    return nn.Dense(features, use_bias=False, dtype=cfg.dtype, param_dtype=jnp.float32, name=name)
-
-
-class _Float32Out(nn.Module):
-    """A bias-free projection with operands in ``dtype`` (one MXU pass) whose
-    float32 accumulator is handed on unrounded: the residual stream of this
-    family is float32, so that what reaches the next router and indexer has
-    been rounded once (the operands), not at every addition."""
-
-    features: int
-    dtype: Any
-
-    @nn.compact
-    def __call__(self, x):
-        kernel = self.param("kernel", nn.initializers.lecun_normal(),
-                            (x.shape[-1], self.features), jnp.float32)
-        return lax.dot_general(x.astype(self.dtype), kernel.astype(self.dtype),
-                               (((x.ndim - 1,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-
-
-class _Float32Dense(nn.Module):
-    """A bias-free projection of float32 operands at the highest matmul
-    precision.  For the router's logits and the indexer, whose outputs feed a
-    discrete choice (top-8 of 128, top-2048 of the context): a bf16 product
-    moves the choice, and these matrices are small (2048 x 128, 2048 x 1104)."""
-
-    features: int
-
-    @nn.compact
-    def __call__(self, x):
-        kernel = self.param("kernel", nn.initializers.lecun_normal(),
-                            (x.shape[-1], self.features), jnp.float32)
-        return lax.dot_general(x.astype(jnp.float32), kernel.astype(jnp.float32),
-                               (((x.ndim - 1,), (0,)), ((), ())),
-                               precision=lax.Precision.HIGHEST,
-                               preferred_element_type=jnp.float32)
-
-
 class KeyeVL2Indexer(nn.Module):
     """``qI [B, T, J, Di]``, ``w [B, T, J]`` and ``kI [B, T, Di]`` of the
     layer's normed input, float32: rotary on all ``Di`` dims at the model's
@@ -183,7 +123,7 @@ class KeyeVL2Indexer(nn.Module):
         cfg = self.config
         b, t = x.shape[:2]
         j, di = cfg.indexer_num_heads, cfg.indexer_head_dim
-        proj = lambda n, name: _Float32Dense(n, name=name)(x)
+        proj = lambda n, name: Float32Dense(n, name=name)(x)
         ang = rotary_angles(text_positions, di, cfg.rope_theta)
         q_idx = apply_rotary(proj(j * di, "q_proj").reshape(b, t, j, di), ang)
         k_idx = apply_rotary(proj(di, "k_proj").reshape(b, t, 1, di), ang)[:, :, 0]
@@ -202,9 +142,9 @@ class KeyeVL2Attention(nn.Module):
         h, hkv, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
         text_positions = positions if positions.ndim == 2 else positions[0]
         x = x32.astype(cfg.dtype)
-        q = _proj(h * d, cfg, "q_proj")(x).reshape(b, t, h, d)
-        k = _proj(hkv * d, cfg, "k_proj")(x).reshape(b, t, hkv, d)
-        v = _proj(hkv * d, cfg, "v_proj")(x).reshape(b, t, hkv, d)
+        q = bias_free_proj(h * d, cfg, "q_proj")(x).reshape(b, t, h, d)
+        k = bias_free_proj(hkv * d, cfg, "k_proj")(x).reshape(b, t, hkv, d)
+        v = bias_free_proj(hkv * d, cfg, "v_proj")(x).reshape(b, t, hkv, d)
         # per-head RMSNorm of q and k before the rotary (assumed: the
         # lineage's practice; the published config has no key for it)
         q = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="q_norm")(q)
@@ -214,7 +154,7 @@ class KeyeVL2Attention(nn.Module):
         k = apply_rotary(k, ang).astype(cfg.dtype)
         with jax.named_scope("sparse_index"):
             q_idx, w_idx, k_idx = KeyeVL2Indexer(cfg, name="indexer")(x32, text_positions)
-        o_proj = _Float32Out(cfg.hidden_size, cfg.dtype, name="o_proj")
+        o_proj = Float32Out(cfg.hidden_size, cfg.dtype, name="o_proj")
 
         if cache is None:
             out, selected = sa.dense_selected_attention(
@@ -269,7 +209,7 @@ class KeyeVL2SparseMoE(nn.Module):
         cfg = self.config
         b, t, hid = x32.shape
         held, f = cfg.held, cfg.moe_intermediate_size
-        logits = _Float32Dense(cfg.num_experts, name="gate")(x32)
+        logits = Float32Dense(cfg.num_experts, name="gate")(x32)
         x = x32.astype(cfg.dtype)
         init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=0)
         experts = lambda name, shape: self.param(name, init, shape, jnp.float32).astype(cfg.dtype)
@@ -359,7 +299,7 @@ class KeyeVL2ForCausalLM(nn.Module):
         if (input_ids is None) == (inputs_embeds is None):
             raise ValueError("pass exactly one of input_ids and inputs_embeds")
         x = embed(input_ids) if inputs_embeds is None else inputs_embeds.astype(cfg.dtype)
-        x = x.astype(jnp.float32)              # the residual stream (see _Float32Out)
+        x = x.astype(jnp.float32)              # the residual stream (see layers.Float32Out)
         if positions is None:
             if cache is not None:
                 raise ValueError("a paged call needs explicit positions")

@@ -21,6 +21,15 @@ variable collection and the caller passes per-row ``adapter_ids``, the
 segment-batched adapter contribution ``(x @ A[ids]) @ B[ids]`` joins the
 base matmul as one gathered einsum — fixed shapes for any tenant mix, so
 the serving decode step never recompiles on adapter routing.
+
+What the served expert families share (``models/keye_vl2.py``,
+``models/k_exaone.py``, ``models/joyai_flash.py``) also lives here, under
+public names: :func:`rotary_angles` / :func:`apply_rotary` (angles computed
+from the positions, rotate-half, float32), :func:`bias_free_proj` (a bf16
+projection), :class:`Float32Out` (bf16 operands, the float32 accumulator
+handed on: these families' residual stream is float32) and
+:class:`Float32Dense` (float32 at the highest precision, for outputs that feed
+a discrete choice).
 """
 
 from __future__ import annotations
@@ -29,6 +38,8 @@ from typing import Any, Callable, Optional
 
 import flax.linen as nn
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 
 from ..ops.collective_matmul import dense_collective_matmul
 from ..ops.fp8 import fp8_delayed_dot, fp8_fake_quantize
@@ -126,3 +137,65 @@ class QuantizableDense(nn.Module):
                 adapter_ids,
             )
         return y
+
+
+# -- shared by the served expert families ----------------------------------------
+
+
+def rotary_angles(positions, dim: int, theta: float, sections=None):
+    """Angles ``[B, T, dim / 2]`` (float32) computed from the positions.
+    ``positions`` ``[B, T]``, or ``[3, B, T]`` with ``sections``: frequency
+    pair ``i`` takes its angle from axis 0, 1 or 2 by the section it lies in."""
+    inv = 1.0 / (theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim))
+    ang = positions.astype(jnp.float32)[..., None] * inv
+    if positions.ndim == 2:
+        return ang
+    axis = np.repeat(np.arange(3), sections)                       # [dim / 2]
+    return sum(jnp.where(axis == a, ang[a], 0.0) for a in range(3))
+
+
+def apply_rotary(x, angles):
+    """x ``[B, T, heads, D]`` (rotate-half); computed and returned in float32."""
+    cos, sin = jnp.cos(angles)[:, :, None, :], jnp.sin(angles)[:, :, None, :]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+
+def bias_free_proj(features: int, cfg, name: str):
+    return nn.Dense(features, use_bias=False, dtype=cfg.dtype, param_dtype=jnp.float32, name=name)
+
+
+class Float32Out(nn.Module):
+    """A bias-free projection with operands in ``dtype`` (one MXU pass) whose
+    float32 accumulator is handed on unrounded: the residual stream of this
+    family is float32, so that what reaches the next router and indexer has
+    been rounded once (the operands), not at every addition."""
+
+    features: int
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param("kernel", nn.initializers.lecun_normal(),
+                            (x.shape[-1], self.features), jnp.float32)
+        return lax.dot_general(x.astype(self.dtype), kernel.astype(self.dtype),
+                               (((x.ndim - 1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+class Float32Dense(nn.Module):
+    """A bias-free projection of float32 operands at the highest matmul
+    precision.  For the router's logits and the indexer, whose outputs feed a
+    discrete choice (top-8 of 128, top-2048 of the context): a bf16 product
+    moves the choice, and these matrices are small (2048 x 128, 2048 x 1104)."""
+
+    features: int
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param("kernel", nn.initializers.lecun_normal(),
+                            (x.shape[-1], self.features), jnp.float32)
+        return lax.dot_general(x.astype(jnp.float32), kernel.astype(jnp.float32),
+                               (((x.ndim - 1,), (0,)), ((), ())),
+                               precision=lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)

@@ -17,6 +17,15 @@ The threshold is EXACT, and keys that tie AT the threshold are taken in order
 of position until the row has ``topk``: the set a stable sort by descending
 score keeps.
 
+The page walk under that mask, :func:`paged_masked_attention`, is shared by
+every masked attention over paged keys (``models/k_exaone.py``'s full causal
+layers, ``models/joyai_flash.py``'s latent attention).  What a row of a page
+holds is the caller's to say: a token's key heads in one pool and its value
+heads in another, of one head width; or ONE row that is the key and, in its
+first values, the value (a latent ``[c ; kr]`` shared by every head, scored
+whole at the caller's scale); or a row that is up-projected to per-head keys
+and values a gathered block at a time before it is scored.
+
 The threshold is the one Pallas kernel here (``sparse_threshold``,
 :func:`kth_largest_key`): a tile of rows of the order-preserving integer image
 of the float32 scores is brought into VMEM once and a 32-step bisection on its
@@ -210,35 +219,60 @@ def index_keys(q_idx, w_idx, index_pages, block_tables, q_positions, topk: int, 
 
 
 def paged_masked_attention(q, k_pages, v_pages, block_tables, kv_len, block_mask,
-                           mask_carry=lambda: ()):
+                           mask_carry=lambda: (), *, scale=None, value_width=None, expand=None):
     """The page walk every masked attention over paged keys shares: ``q``
     [B, T, H, D] against the pages of ``block_tables`` [B, n] (n a whole
     number of loop steps: ``pad_block_tables``), a block of
     ``block_pages_for`` pages at a time with a running softmax, so that no
-    ``[T, S]`` array is held whole.  ``k_pages``/``v_pages``: [P, page,
-    Hkv * D] (a token's heads in one row).  ``block_mask(i, blk, carry)`` ->
+    ``[T, S]`` array is held whole.  ``block_mask(i, blk, carry)`` ->
     ``(mask [B, T, blk] bool, carry)`` says which keys of block ``i`` (the
     positions ``i * blk + arange(blk)``) each query attends; ``mask_carry()``
     makes its state before the first block.  ``kv_len``: scalar, the longest live
     context (no step walks past it).  A row that attends nothing (dead slot,
-    padding) comes back zero.  Returns [B, T, H, D]."""
+    padding) comes back zero.  Returns [B, T, H, Dv].
+
+    What a row of a page may be:
+
+    - ``k_pages`` and ``v_pages`` [P, page, Hkv * D], two pools of one head
+      width: a token's key heads in one row, its value heads in the other
+      (``Dv = D``);
+    - ``v_pages=None``: ONE pool [P, page, Hkv * D] whose row is the key and,
+      in its first ``value_width`` values, the value (a latent row ``[c ;
+      kr]``: scored whole, summed as ``c``).  The row is summed whole and the
+      sum cut to ``Dv = value_width``, so no slice of a gathered block is made;
+    - ``expand(rows [B, blk, W]) -> (k [B, blk, H, D], v [B, blk, H,
+      value_width])``: the gathered rows of that one pool are up-projected to
+      per-head keys and values before they are scored (a latent row expanded
+      by ``W_UK`` / ``W_UV`` for a prefill chunk).
+
+    ``scale`` multiplies the scores (default ``1 / sqrt(D)``)."""
     b, t, h, d = q.shape
     _, page, width = k_pages.shape
-    hkv = width // d
+    hkv = h if expand is not None else width // d
+    dv = d if value_width is None else value_width
     g = h // hkv
     n = block_tables.shape[1]
     bp = block_pages_for(b, t, h, page)
     if n % bp:
         raise ValueError(f"block tables of {n} pages are not a whole number of {bp}-page steps")
+    if v_pages is not None and (expand is not None or value_width is not None):
+        raise ValueError("a value is a second pool, or a part of the key pool's row, not both")
     blk = bp * page
     qg = q.reshape(b, t, hkv, g, d)
-    scale = 1.0 / np.sqrt(d)
+    if scale is None:
+        scale = 1.0 / np.sqrt(d)
+    summed = d if v_pages is None and expand is None else dv       # a whole row is summed, then cut
+
+    def gathered(pages):
+        if expand is not None:
+            return expand(k_pages[pages].reshape(b, blk, width))
+        k_blk = k_pages[pages].reshape(b, blk, hkv, d)
+        return k_blk, (k_blk if v_pages is None else v_pages[pages].reshape(b, blk, hkv, d))
 
     def attend_block(i, carry):
         m, l, acc, state = carry
         pages = lax.dynamic_slice_in_dim(block_tables, i * bp, bp, axis=1)        # [B, bp]
-        k_blk = k_pages[pages].reshape(b, blk, hkv, d)
-        v_blk = v_pages[pages].reshape(b, blk, hkv, d)
+        k_blk, v_blk = gathered(pages)
         s = jnp.einsum("bthgd,bshd->bhgts", qg, k_blk, preferred_element_type=jnp.float32) * scale
         sel, state = block_mask(i, blk, state)
         sel = sel[:, None, None]                                                  # [B,1,1,T,blk]
@@ -254,10 +288,11 @@ def paged_masked_attention(q, k_pages, v_pages, block_tables, kv_len, block_mask
     steps = jnp.minimum((kv_len + blk - 1) // blk, n // bp)
     init = (jnp.full((b, hkv, g, t), -jnp.inf, jnp.float32),
             jnp.zeros((b, hkv, g, t), jnp.float32),
-            jnp.zeros((b, hkv, g, t, d), jnp.float32), mask_carry())
+            jnp.zeros((b, hkv, g, t, summed), jnp.float32), mask_carry())
     _, l, acc, _ = lax.fori_loop(0, steps, attend_block, init)
     out = acc / jnp.where(l > 0, l, 1.0)[..., None]
-    return out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, d).astype(q.dtype)
+    out = out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, summed).astype(q.dtype)
+    return out if summed == dv else out[..., :dv]
 
 
 @jax.named_scope("sparse_attend")
@@ -275,16 +310,23 @@ def paged_selected_attention(q, k_pages, v_pages, block_tables, keys, threshold,
                                   lambda: jnp.zeros(q.shape[:2], jnp.int32))
 
 
-@jax.named_scope("global_attend")
-def paged_causal_attention(q, k_pages, v_pages, block_tables, q_positions, kv_len):
-    """Full causal attention over paged keys: the same walk with the mask
-    ``s <= t``.  ``q_positions`` [B, T] int32, -1 for a query that sees
-    nothing (dead slot, padding)."""
+def causal_mask(q_positions):
+    """The ``block_mask`` of full causal attention: key ``s`` is seen by the
+    query at position ``t`` iff ``s <= t``.  ``q_positions`` [B, T] int32, -1
+    for a query that sees nothing (dead slot, padding)."""
     def causal(i, blk, state):
         pos = i * blk + jnp.arange(blk, dtype=jnp.int32)
         return pos[None, None, :] <= q_positions[:, :, None], state
 
-    return paged_masked_attention(q, k_pages, v_pages, block_tables, kv_len, causal)
+    return causal
+
+
+@jax.named_scope("global_attend")
+def paged_causal_attention(q, k_pages, v_pages, block_tables, q_positions, kv_len):
+    """Full causal attention over paged keys: the same walk with the mask
+    ``s <= t`` (:func:`causal_mask`)."""
+    return paged_masked_attention(q, k_pages, v_pages, block_tables, kv_len,
+                                  causal_mask(q_positions))
 
 
 def dense_selected_attention(q, k, v, q_idx, w_idx, k_idx, positions, topk: int):
@@ -315,9 +357,10 @@ def dense_selected_attention(q, k, v, q_idx, w_idx, k_idx, positions, topk: int)
 # ---------------------------------------------------------------------------
 #
 # A pool is ``[P, page, W]``: page-major, a token's whole row (every KV head,
-# or the indexer's one key) contiguous.  Reads gather whole pages along the
-# leading dim and writes update a row or a page of it, so XLA keeps the pool
-# in the layout it arrives in and puts no relayout copy around either (the
+# the indexer's one key, or a latent row with no head axis at all) contiguous.
+# Reads gather whole pages along the leading dim and writes update a row or a
+# page of it, so XLA keeps the pool in the layout it arrives in and puts no
+# relayout copy around either (the
 # head-major ``[Hkv, P, page, D]`` of ``models/llama.py`` is the Pallas
 # kernels' tile; under these XLA ops it costs two copies of the pool a write).
 

@@ -39,8 +39,9 @@ The production-inference rebuild of the reference's
   harnesses (docs/serving.md "Fleet serving").
 
 **The family protocol** — what :class:`ServingEngine` asks of the model object
-it is handed (``models/llama.py``, ``models/keye_vl2.py`` and
-``models/k_exaone.py`` are the three families; the engine imports none):
+it is handed (``models/llama.py``, ``models/keye_vl2.py``,
+``models/k_exaone.py`` and ``models/joyai_flash.py`` are the four families;
+the engine imports none):
 
 - ``init_paged_cache(num_pages, page_size, num_slots, pages_per_slot,
   kv_dtype=None)`` (required) — the cache pytree of
@@ -48,7 +49,9 @@ it is handed (``models/llama.py``, ``models/keye_vl2.py`` and
   layer: what a layer keeps per token is the layer's kind's to say.  Two
   kinds exist.  A *paged* layer's arrays are ``[num_pages, ...]`` and the
   ONE block table addresses all of them (K and V pages, scales, an
-  indexer's keys).  A *slot-addressed* layer's arrays are ``[num_slots,
+  indexer's keys; or ONE pool of latent rows ``[num_pages, page, width]``
+  with no kv-head axis, a row serving as every head's key and value).  A
+  *slot-addressed* layer's arrays are ``[num_slots,
   ...]``, addressed by slot id and outside the allocator (a window layer's
   ring): its bytes do not grow with the context, and it must stay correct
   when a slot is handed on, evicted or re-admitted WITHOUT being cleared

@@ -323,12 +323,11 @@ def _prefix_step_fns(page_size: int):
         block_tables = jax.lax.dynamic_update_slice_in_dim(
             cache["block_tables"], row[None], slot, 0
         )
+        # (``**cache``: a family's extras, e.g. pending ``tick_counters``, ride along)
         return {
-            "layers": cache["layers"],
+            **cache,
             "block_tables": block_tables,
             "seq_lens": cache["seq_lens"].at[slot].set(n_shared * page_size),
-            "free_stack": cache["free_stack"],
-            "free_top": cache["free_top"],
         }
 
     def release_cow_step(cache, mask, keep_counts):
@@ -343,8 +342,7 @@ def _prefix_step_fns(page_size: int):
             cache["block_tables"].reshape(-1), owned.reshape(-1),
         )
         return {
-            "layers": cache["layers"],
-            "block_tables": cache["block_tables"],
+            **cache,
             "seq_lens": jnp.where(mask, 0, cache["seq_lens"]),
             "free_stack": free_stack,
             "free_top": free_top,
@@ -354,13 +352,7 @@ def _prefix_step_fns(page_size: int):
         free_stack, free_top = push_pages(
             cache["free_stack"], cache["free_top"], page_ids, mask
         )
-        return {
-            "layers": cache["layers"],
-            "block_tables": cache["block_tables"],
-            "seq_lens": cache["seq_lens"],
-            "free_stack": free_stack,
-            "free_top": free_top,
-        }
+        return {**cache, "free_stack": free_stack, "free_top": free_top}
 
     return adopt_step, release_cow_step, push_free_step
 

@@ -447,3 +447,61 @@ def test_k_exaone_serving_programs_compile_at_the_cells_shapes(one_chip, program
     pool, rings = 2 * EXAONE_PAGES * PAGE * 128 * 2, 3 * 2 * EXAONE_SLOTS * 128 * 128 * 2
     assert stats.alias_size_in_bytes >= pool + rings                         # both kinds alias in place
     assert stats.temp_size_in_bytes < 2**30
+
+
+# -- JoyAI-LLM-Flash's serving programs at the cell's shapes (joyai-flash.serve_docs) -------------
+
+JOYAI_SLOTS, JOYAI_PAGES, JOYAI_PAGES_PER_SLOT = 48, 12672, 264
+
+
+@pytest.mark.parametrize("program,width", [("decode", 1), ("prefill", 512), ("prefill", 2048)])
+def test_joyai_flash_serving_programs_compile_at_the_cells_shapes(one_chip, program, width):
+    """The engine's decode and prefill programs of ``models/joyai_flash.py`` at
+    the published widths, the cell's share (4 of 32 heads, 32 of 256 experts,
+    16,160 vocabulary rows) and geometry (48 slots, 12,672 pages of 64, 264 a
+    slot), the dense layer and one sparse one: the latent pool ``[P, 64,
+    640]`` is written and read in one layout (no op copies or relays it, and
+    it aliases in place), a decode step gathers blocks of whole latent rows
+    (the absorbed walk: no per-head key or value is built), a prefill chunk
+    up-projects each gathered block (the expanded walk), and the grouped
+    matmuls are the Mosaic kernel over BLOCKS of held rows."""
+    import re
+
+    from accelerate_tpu.generation import GenerationConfig
+    from accelerate_tpu.models import JoyAIFlashConfig, JoyAIFlashForCausalLM
+    from accelerate_tpu.serving.engine import fresh_engine_jits
+
+    model = JoyAIFlashForCausalLM(JoyAIFlashConfig(
+        num_hidden_layers=2, experts_held=tuple(range(32)), attention_heads_held=4,
+        vocab_held=16160))
+    assert model.config.latent_row == 640
+    on_chip = lambda tree, dtype=None: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, dtype or x.dtype, sharding=one_chip), tree)
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))), BF16)
+    cache = on_chip(jax.eval_shape(
+        lambda: model.init_paged_cache(JOYAI_PAGES, PAGE, JOYAI_SLOTS, JOYAI_PAGES_PER_SLOT)))
+    gen = GenerationConfig(max_new_tokens=512, do_sample=False, eos_token_id=None)
+    decode, prefill, *_ = fresh_engine_jits(model, gen, PAGE)
+    arg = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    if program == "decode":
+        lowered = decode.lower(params, cache, arg((JOYAI_SLOTS,)), arg((JOYAI_SLOTS,), jnp.bool_),
+                               arg((2,), jnp.uint32))
+    else:
+        lowered = prefill.lower(params, cache, arg(()), arg((width,)), arg(()), arg(()))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("ragged-dot") >= 3 and "tpu_custom_call" in text   # gate, up, down of the sparse layer
+    rows = {1: 128, 512: 640, 2048: 2560}[width]     # held_row_block: a quarter over an eighth of the pairs
+    assert re.search(rf"%ragged-dot\S* = \S*\[{rows},", text)               # a block of held rows
+    assert not re.search(rf"\[{max(width, JOYAI_SLOTS) * 8},2048\]", text)          # never all N x k rows
+    # no pool-shaped relayout: a copy, or a transpose that permutes anything
+    moved = r"(copy\(|transpose\([^)]*\), dimensions=\{(?!0,1,2\}))"
+    assert re.findall(rf"= bf16\[{JOYAI_PAGES},64,640\]\S* {moved}", text) == []
+    gathered = re.findall(r"= bf16\[((?:\d+,)?64,64,640)\]\S* gather\(", text)   # blocks of 64 pages of rows
+    assert gathered and set(gathered) == {"48,64,64,640" if program == "decode" else "64,64,640"}, gathered
+    expanded = re.findall(r"latent_prefill/while/body/bsr,rhd->bshd", text)
+    assert bool(expanded) == (program == "prefill")          # W_kvb meets the rows in the chunk's walk only
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes >= 2 * JOYAI_PAGES * PAGE * 640 * 2     # the pools alias in place
+    assert stats.temp_size_in_bytes < 2**30
