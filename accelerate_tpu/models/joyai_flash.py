@@ -18,15 +18,19 @@ equal walks read it, chosen by the call's shape and by nothing else:
 - a decode step ``[S, 1]`` runs the ABSORBED form: ``qa_h = W_UK,h^T qn_h``
   [kv_lora_rank], ``score = (qa_h . c_s + qr_h . kr_s) * scale``, ``u_h =
   sum_s p_s c_s``, ``o_h = W_UV,h u_h`` — the row is the key (whole) and the
-  value (its first ``kv_lora_rank`` values); no key or value head is built;
+  value (its first ``kv_lora_rank`` values); no key or value head is built.
+  The walk is ONE Pallas kernel a layer, ``latent_decode``
+  (``ops/latent_attention.latent_decode_attention``): each slot reads its
+  own pages once, up to its own length, and nothing is gathered;
 - a prefill chunk ``[1, C]`` runs the EXPANDED form: each gathered block of
   latent rows is up-projected by ``W_kvb`` of the held heads to per-head keys
-  and values, then scored (the chunk's own rows, written first, included).
+  and values, then scored (the chunk's own rows, written first, included),
+  through ``ops/sparse_attention.paged_masked_attention`` — the XLA walk the
+  kernel is tested against.
 
-Both go through ``ops/sparse_attention.paged_masked_attention``.  The pool is
-ONE array a layer, ``[P, page, 640]`` at the published sizes: a row is ``[c ;
-kr ; zeros]`` up to whole 128-lane tiles (a 576-wide row takes the same bytes
-in HBM and is relaid around every write).
+The pool is ONE array a layer, ``[P, page, 640]`` at the published sizes: a
+row is ``[c ; kr ; zeros]`` up to whole 128-lane tiles (a 576-wide row takes
+the same bytes in HBM and is relaid around every write).
 
 ``rope_interleave``: the published rotary pairs dims ``(2i, 2i + 1)``.  The
 program rotates halves (``(i, i + rope / 2)``) over weights whose rotary
@@ -62,6 +66,7 @@ import numpy as np
 
 from ..ops import sparse_attention as sa
 from ..ops import window_attention as wa
+from ..ops.latent_attention import latent_decode_attention
 from .k_exaone import KExaoneBlock, KExaoneMTP
 from .layers import Float32Out, apply_rotary, bias_free_proj, rotary_angles
 from .llama import LMHead, RMSNorm
@@ -195,8 +200,8 @@ class JoyAIFlashAttention(nn.Module):
     def __call__(self, x32, positions, cache=None, cache_write_mask=None):
         """``x32``: the layer's normed input (float32).  Returns ``(W_o of the
         HELD heads' attention [B, T, H] float32, the layer's new state, int32
-        [3]: keys visible to the live queries and keys the walk gathered (a
-        decode step), cached rows up-projected (a prefill chunk))``."""
+        [3]: keys visible to the live queries and rows of the pages the kernel
+        read (a decode step), cached rows up-projected (a prefill chunk))``."""
         cfg = self.config
         b, t = x32.shape[:2]
         h, r = cfg.heads, cfg.kv_lora_rank
@@ -237,30 +242,28 @@ class JoyAIFlashAttention(nn.Module):
         pos = positions.astype(jnp.int32)
         live = jnp.ones((b, t), bool) if cache_write_mask is None else cache_write_mask
         q_pos = jnp.where(live, pos, -1)
-        kv_len = jnp.max(q_pos) + 1
+        kv_len = jnp.max(q_pos) + 1 if t > 1 else None     # where a chunk's walk ends
         tables, pool = cache["block_tables"], cache["latent_pages"]
         page, row = pool.shape[1:]
         with jax.named_scope("paged_write_kv"):
             pad = jnp.zeros((b, t, row - r - dr), cfg.dtype)
             pool = sa.page_writer(tables, pos, live, page)(pool, jnp.concatenate([c, kr, pad], -1))
-        bp = sa.block_pages_for(b, t, h, page)
-        padded = sa.pad_block_tables(tables, bp)
-        walked = b * jnp.minimum((kv_len + bp * page - 1) // (bp * page), padded.shape[1] // bp) \
-            * (bp * page)
         zero = jnp.zeros((), jnp.int32)
         if t == 1:      # absorbed: the row is the key, whole, and in its first r values the value
             with jax.named_scope("latent_project"):
                 qa = jnp.einsum("bthd,rhd->bthr", qn, w_kvb[..., :dn]).astype(cfg.dtype)
-                q_abs = jnp.concatenate(
-                    [qa, qr, jnp.zeros((b, t, h, row - r - dr), cfg.dtype)], axis=-1)
             with jax.named_scope("latent_attend"):
-                u = sa.paged_masked_attention(q_abs, pool, None, padded, kv_len,
-                                              sa.causal_mask(q_pos), scale=scale, value_width=r)
+                u = latent_decode_attention(qa[:, 0], qr[:, 0], pool, tables, q_pos[:, 0],
+                                            scale=scale)[:, None]
             with jax.named_scope("latent_project"):
                 out = jnp.einsum("bthr,rhd->bthd", u, w_kvb[..., dn:]).astype(cfg.dtype)
-            counts = jnp.stack([jnp.sum(q_pos + 1, dtype=jnp.int32), walked.astype(jnp.int32),
-                                zero])
+            walked = jnp.sum((q_pos + page) // page, dtype=jnp.int32) * page  # each slot's own pages
+            counts = jnp.stack([jnp.sum(q_pos + 1, dtype=jnp.int32), walked, zero])
         else:           # expanded: each gathered block up-projected to the held heads' keys, values
+            bp = sa.block_pages_for(b, t, h, page)
+            padded = sa.pad_block_tables(tables, bp)
+            walked = b * jnp.minimum((kv_len + bp * page - 1) // (bp * page),
+                                     padded.shape[1] // bp) * (bp * page)
             with jax.named_scope("latent_prefill"):
                 out = sa.paged_masked_attention(
                     jnp.concatenate([qn, qr], axis=-1), pool, None, padded, kv_len,
@@ -298,9 +301,10 @@ class JoyAIFlashForCausalLM(nn.Module):
         and up-projected, summed over the layers) counts prefill chunks; the
         rest count decode steps only: held experts with a row summed over the
         sparse layers, sparse layer-steps, rows routed to held experts and rows
-        the grouped matmuls were fed, keys visible to the live queries and keys
-        the walk gathered (every slot up to the longest live context, in whole
-        blocks), both summed over the layers."""
+        the grouped matmuls were fed, keys visible to the live queries and rows
+        of the pages the ``latent_decode`` kernel read for them (each live
+        slot's own whole pages: ``(position // page + 1) * page``), both
+        summed over the slots and the layers."""
         return (("expert_tokens", len(self.config.held)), ("moe_experts_hit_sum", 1),
                 ("moe_ticks", 1), ("moe_rows_held", 1), ("moe_rows_computed", 1),
                 ("latent_visible_sum", 1), ("latent_walked_sum", 1), ("latent_expanded_sum", 1))

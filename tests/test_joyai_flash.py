@@ -151,7 +151,7 @@ def test_the_absorbed_and_the_expanded_walk_agree_on_the_same_rows(model, weight
     assert counts.tolist() == [0, 0, 512]             # one block (64 pages: the table padded to a step) up-projected
     absorbed, after, counts = layer.apply(params, x[:, 16:17], jnp.asarray([[16]]),
                                           view(state["latent_pages"]), jnp.ones((1, 1), bool))
-    assert counts.tolist() == [17, 512, 0]            # 17 keys visible, a 512-key block gathered
+    assert counts.tolist() == [17, 24, 0]             # 17 keys visible, the slot's own 3 pages of 8 read
     live = jnp.arange(8)[None] < 1
     expanded, again, _ = layer.apply(params, x[:, 16:24], 16 + jnp.arange(8)[None],
                                      view(state["latent_pages"]), live)
@@ -363,10 +363,9 @@ def test_the_model_reports_its_counters(model, weights):
     assert m["moe_ticks"] == 2 * m["decode_steps"]                 # two sparse layers a decode tick
     assert 0 < m["moe_rows_held"] <= m["moe_rows_computed"]
     assert len(m["expert_tokens"]) == 8 and m["expert_tokens"].sum() >= m["moe_rows_held"]
-    # 2 slots at contexts 21..29: visible keys are the contexts, the walk gathers a 512-key block
-    # (64 pages: the table padded to one step) for BOTH slots in each of the 3 layers every step
-    steps = m["decode_steps"]
-    assert m["latent_walked_sum"] == steps * 3 * 2 * 512
+    # 2 slots at contexts 21..29 in each of the 3 layers: visible keys are the contexts, and the
+    # kernel reads each slot's own whole pages of 8 rows (3 pages up to context 24, then 4)
+    assert m["latent_walked_sum"] == 3 * 2 * (4 * 24 + 5 * 32)
     assert m["latent_visible_sum"] == 3 * 2 * sum(range(21, 30))   # each slot: 9 steps at contexts 21..29
     assert m["latent_expanded_sum"] == 2 * 3 * 512                 # one chunk a prompt, one block a layer
 

@@ -455,22 +455,26 @@ JOYAI_SLOTS, JOYAI_PAGES, JOYAI_PAGES_PER_SLOT = 48, 12672, 264
 
 
 @pytest.mark.parametrize("program,width", [("decode", 1), ("prefill", 512), ("prefill", 2048)])
-def test_joyai_flash_serving_programs_compile_at_the_cells_shapes(one_chip, program, width):
+def test_joyai_flash_serving_programs_compile_at_the_cells_shapes(one_chip, monkeypatch, program,
+                                                                  width):
     """The engine's decode and prefill programs of ``models/joyai_flash.py`` at
     the published widths, the cell's share (4 of 32 heads, 32 of 256 experts,
     16,160 vocabulary rows) and geometry (48 slots, 12,672 pages of 64, 264 a
     slot), the dense layer and one sparse one: the latent pool ``[P, 64,
     640]`` is written and read in one layout (no op copies or relays it, and
-    it aliases in place), a decode step gathers blocks of whole latent rows
-    (the absorbed walk: no per-head key or value is built), a prefill chunk
-    up-projects each gathered block (the expanded walk), and the grouped
-    matmuls are the Mosaic kernel over BLOCKS of held rows."""
+    it aliases in place), a decode step walks it inside the ``latent_decode``
+    Mosaic kernel, one call a layer (the absorbed walk: no block of rows is
+    gathered and no per-head key or value is built), a prefill chunk gathers
+    blocks of whole latent rows and up-projects each (the expanded walk), and
+    the grouped matmuls are the Mosaic kernel over BLOCKS of held rows."""
     import re
 
     from accelerate_tpu.generation import GenerationConfig
     from accelerate_tpu.models import JoyAIFlashConfig, JoyAIFlashForCausalLM
+    from accelerate_tpu.ops import latent_attention as la
     from accelerate_tpu.serving.engine import fresh_engine_jits
 
+    monkeypatch.setattr(la, "_on_tpu", lambda: True)   # the kernel, not its interpreter
     model = JoyAIFlashForCausalLM(JoyAIFlashConfig(
         num_hidden_layers=2, experts_held=tuple(range(32)), attention_heads_held=4,
         vocab_held=16160))
@@ -499,7 +503,9 @@ def test_joyai_flash_serving_programs_compile_at_the_cells_shapes(one_chip, prog
     moved = r"(copy\(|transpose\([^)]*\), dimensions=\{(?!0,1,2\}))"
     assert re.findall(rf"= bf16\[{JOYAI_PAGES},64,640\]\S* {moved}", text) == []
     gathered = re.findall(r"= bf16\[((?:\d+,)?64,64,640)\]\S* gather\(", text)   # blocks of 64 pages of rows
-    assert gathered and set(gathered) == {"48,64,64,640" if program == "decode" else "64,64,640"}, gathered
+    assert set(gathered) == (set() if program == "decode" else {"64,64,640"}), gathered
+    kernels = re.findall(r"%latent_decode\S* = bf16\[48,4,512\]\S* custom-call\(", text)
+    assert len(kernels) == (2 if program == "decode" else 0)       # one walk a layer, in the decode step alone
     expanded = re.findall(r"latent_prefill/while/body/bsr,rhd->bshd", text)
     assert bool(expanded) == (program == "prefill")          # W_kvb meets the rows in the chunk's walk only
     stats = compiled.memory_analysis()
