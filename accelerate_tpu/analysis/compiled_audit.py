@@ -62,6 +62,17 @@ except Exception:  # pragma: no cover - private-API drift
 # or, on jax 0.9, a load from the persistent cache (a jit-call cache hit
 # fires nothing) — the signal the recompile guard wants
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# the other parts of bringing a program up that the same stream reports
+# (all present in jax 0.9.0): CompileCounter's field for each timed one
+_TIMED_PARTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    COMPILE_EVENT: "backend_s",
+}
+CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+PARTS = ("trace_s", "lower_s", "backend_s", "cache_load_s", "cache_hits", "cache_misses")
 
 
 @contextlib.contextmanager
@@ -95,27 +106,74 @@ def fresh_compile_context():
 
 
 class CompileCounter:
-    """Counts real XLA backend compiles via the jax monitoring stream.
+    """Counts real XLA backend compiles via the jax monitoring stream, and
+    keeps what the stream hands it: the seconds of tracing, of lowering and in
+    the backend, the seconds spent reading the persistent cache, and its hits
+    and misses (:data:`PARTS`).
+
+    jax brackets each of the three timed parts (``log_elapsed_time``: a scalar
+    at entry, the duration at exit), and a bracket can lie inside another (a
+    ``jit`` traced inside a ``jit``: both report).  Only the outermost one is
+    booked, so the parts never overlap; a cache read happens inside the
+    backend bracket and is taken out of it, so ``backend_s`` is compiling
+    (and writing the cache) alone.
 
     Usable as a context manager (``with CompileCounter() as c: ...``) for
     scoped measurement, or long-lived through
     :func:`install_global_compile_counter` for the per-object
-    ``compile_events`` deltas the engine and accelerator expose.
+    ``compile_events`` deltas the engine and accelerator expose; consumers
+    read deltas of :meth:`parts`.
     """
 
     def __init__(self):
         self.count = 0
+        self.trace_s = 0.0
+        self.lower_s = 0.0
+        self.backend_s = 0.0
+        self.cache_load_s = 0.0
+        self.cache_hits = 0
+        self.cache_misses = 0
+        self._depth = 0
         self._active = False
         self._registered = False
 
+    def parts(self) -> tuple:
+        """``(trace_s, lower_s, backend_s, cache_load_s, cache_hits,
+        cache_misses)`` so far (:data:`PARTS` names them)."""
+        return (self.trace_s, self.lower_s, self.backend_s, self.cache_load_s,
+                self.cache_hits, self.cache_misses)
+
+    def _on_enter(self, event, value=None, **kwargs):
+        if self._active and event in _TIMED_PARTS:
+            self._depth += 1
+
     def _on_event(self, event, duration=None, **kwargs):
-        if self._active and event == COMPILE_EVENT:
+        if not self._active:
+            return
+        if event == COMPILE_EVENT:
             self.count += 1
+        field = _TIMED_PARTS.get(event)
+        if field is not None:
+            self._depth = max(0, self._depth - 1)
+            if self._depth == 0:
+                setattr(self, field, getattr(self, field) + duration)
+        elif event == CACHE_LOAD_EVENT:
+            self.cache_load_s += duration
+            self.backend_s -= duration     # read inside the backend bracket, which ends later
+
+    def _on_count(self, event, **kwargs):
+        if self._active:
+            if event == CACHE_HIT_EVENT:
+                self.cache_hits += 1
+            elif event == CACHE_MISS_EVENT:
+                self.cache_misses += 1
 
     def start(self) -> "CompileCounter":
         self._active = True
         if not self._registered and _monitoring is not None:
             _monitoring.register_event_duration_secs_listener(self._on_event)
+            _monitoring.register_scalar_listener(self._on_enter)
+            _monitoring.register_event_listener(self._on_count)
             self._registered = True
         return self
 
@@ -123,12 +181,12 @@ class CompileCounter:
         self._active = False
         if self._registered and _monitoring is not None:
             try:
-                _monitoring._unregister_event_duration_listener_by_callback(
-                    self._on_event
-                )
+                _monitoring.unregister_event_duration_listener(self._on_event)
+                _monitoring.unregister_scalar_listener(self._on_enter)
+                _monitoring.unregister_event_listener(self._on_count)
                 self._registered = False
             except Exception:  # pragma: no cover - private-API drift
-                pass  # listener stays registered but inert (_active False)
+                pass  # listeners stay registered but inert (_active False)
         return self
 
     def __enter__(self) -> "CompileCounter":

@@ -29,17 +29,18 @@ import contextlib
 import dataclasses
 import time
 from collections import deque
-from functools import lru_cache
+from functools import lru_cache, partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..analysis.compiled_audit import PARTS as _WARMUP_PARTS
 from ..analysis.compiled_audit import install_global_compile_counter
 from ..generation import GenerationConfig, sample_logits
 from ..resilience import faults as _faults
-from ..telemetry import RequestTracer
+from ..telemetry import HostLedger, RequestTracer
 from ..utils.dataclasses import ServingPlugin, TelemetryPlugin
 from .overload import DegradationLadder
 from .paged_cache import allocate, pages_for, push_pages, release
@@ -51,14 +52,6 @@ from .speculate import Speculator, make_draft_provider, speculative_page_need
 # latency samples kept for the harness's percentiles (the newest; the
 # whole-run sums are in ``ServingEngine.metrics``)
 _SAMPLE_WINDOW = 4096
-
-_NO_SPAN = contextlib.nullcontext()
-
-
-def _no_phase(name, **args):
-    """``ServingEngine.step``'s ``phase`` with tracing off."""
-    return _NO_SPAN
-
 
 def _layer_view(layer, block_tables, slots):
     """One layer's model-facing cache dict: every array the layer's kind keeps
@@ -504,8 +497,6 @@ class ServingEngine:
         self.telemetry = telemetry or TelemetryPlugin()
         self._clock = time.perf_counter   # the scheduler's stamps share it
         self.trace: Optional[RequestTracer] = None
-        if self.telemetry.trace_requests:
-            self.enable_tracing()
         # recompile guard: compile events are counted process-wide (the
         # jax.monitoring backend-compile stream) and reported as a delta
         # from engine construction — after warmup() this must stay flat
@@ -555,6 +546,17 @@ class ServingEngine:
             **{name: (0 if size == 1 else np.zeros((size,), np.int64))
                for name, size in self._tick_counters},
         }
+        # the host ledger (telemetry/host_ledger.py), always on: every phase
+        # of step() clocked per tick kind, the caller's time between ticks,
+        # the collector's pauses, and the stall log — flat keys in
+        # ``metrics``; the slow ticks themselves, newest 64, in ``stalls``.
+        # warmup() leaves a row a program in ``warmup_report`` and the sums
+        # ``warmup_*`` in ``metrics``
+        self._ledger = HostLedger(self.metrics, self._clock)
+        self.stalls = self._ledger.stalls
+        self.warmup_report: list[dict] = []
+        if self.telemetry.trace_requests:
+            self.enable_tracing()
         self.ttft_s: deque[float] = deque(maxlen=_SAMPLE_WINDOW)
         # TTFT in VIRTUAL engine ticks (arrival -> first token), the
         # deterministic twin of the wall-clock ttft_s samples: the prefix
@@ -576,13 +578,16 @@ class ServingEngine:
             self.trace = RequestTracer(
                 capacity=capacity or self.telemetry.ring_capacity, clock=clock,
             )
-            # one clock for the spans and for the stamps they are built from
+            # one clock for the spans, the stamps they are built from and
+            # the host ledger
             self._clock = self.sched.clock = self.trace.recorder.clock
+            self._ledger.set_clock(self._clock, self.trace.recorder)
         return self.trace
 
     def disable_tracing(self) -> None:
         self.trace = None
         self._clock = self.sched.clock = time.perf_counter
+        self._ledger.set_clock(self._clock)
 
     # -- request lifecycle ---------------------------------------------------
 
@@ -590,6 +595,7 @@ class ServingEngine:
         now = self._clock()
         self.sched.submit(request, now)
         self._arrival_wall[request.uid] = now
+        self._ledger.note_add_request(now, self._clock())
 
     def cancel(self, uid: int) -> None:
         """Request cancellation of ``uid`` at whatever lifecycle stage it is
@@ -732,6 +738,15 @@ class ServingEngine:
         advance.  Returns the number of backend compile events the warmup
         cost (0 when the persistent compilation cache was already warm).
 
+        Where the time went is left behind: :attr:`warmup_report` has a row a
+        program (the labels of :meth:`warmup_programs`: ``wall_s`` and of it
+        ``trace_s``, ``lower_s``, ``backend_s`` (compiling), ``cache_load_s``
+        (reading the persistent cache), ``execute_s`` (the rest), and
+        ``cache``: ``hit``, ``miss`` or ``none``), ``metrics`` the sums
+        ``warmup_trace_s`` ... ``warmup_cache_misses`` and the call's
+        ``warmup_wall_s``; with a tracer armed each program is also a
+        ``warmup:<label>`` span on the ``warmup`` track.
+
         Call before traffic (the replay harness does); after it,
         :attr:`compile_events` staying flat IS the no-mid-traffic-recompile
         contract.
@@ -739,61 +754,77 @@ class ServingEngine:
         if self.sched.slots:
             raise RuntimeError("warmup() must run before any traffic is admitted")
         before = self._compile_counter.count
+        parts0, t_call = self._compile_counter.parts(), self._clock()
+        rows: dict[str, dict] = {}
+        warming = partial(self._warming, rows)
         n = self.plugin.num_slots
-        rng = jax.random.fold_in(self._base_rng, 0)  # warms the fold_in program
-        cache, _ = self._run_decode(
-            jnp.asarray(np.zeros((n,), np.int32)),
-            jnp.asarray(np.zeros((n,), bool)),
-            jnp.asarray(np.zeros((n,), np.int32)), rng,
-        )
-        self.cache = cache
-        last = None
-        for bucket in self.plugin.prefill_buckets:
-            cache, last = self._run_prefill(
-                jnp.asarray(0, jnp.int32),
-                jnp.asarray(np.zeros((bucket,), np.int32)),
-                jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32),
-                jnp.asarray(0, jnp.int32),
+
+        def decode_pass():
+            cache, _ = self._run_decode(
+                jnp.asarray(np.zeros((n,), np.int32)),
+                jnp.asarray(np.zeros((n,), bool)),
+                jnp.asarray(np.zeros((n,), np.int32)), rng,
             )
             self.cache = cache
-        if last is not None:
-            self._sample(last, rng)
+
+        with warming("decode"):
+            rng = jax.random.fold_in(self._base_rng, 0)  # warms the fold_in program
+            decode_pass()
+        last = None
+        for bucket in self.plugin.prefill_buckets:
+            with warming(f"prefill[{bucket}]"):
+                cache, last = self._run_prefill(
+                    jnp.asarray(0, jnp.int32),
+                    jnp.asarray(np.zeros((bucket,), np.int32)),
+                    jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32),
+                    jnp.asarray(0, jnp.int32),
+                )
+                self.cache = cache
+        with warming("sample_first"):
+            if last is not None:
+                self._sample(last, rng)
         if self._spec is not None:
             # every verify bucket is a production program: one no-op pass
             # per width (zero active slots, zero spec depth), plus the draft
             # provider's own program (the draft-model windowed forward; the
             # n-gram provider compiles nothing)
             for bucket in self.plugin.speculate_buckets:
-                cache, _, _ = self._run_verify(
-                    jnp.asarray(np.zeros((n, bucket + 1), np.int32)),
-                    jnp.asarray(np.zeros((n,), np.int32)),
-                    jnp.asarray(np.zeros((n,), bool)),
-                    jnp.asarray(np.zeros((n,), np.int32)), rng,
-                )
-                self.cache = cache
-            self._spec.provider.warmup(n, self.plugin.speculate_k)
+                with warming(f"verify[{bucket}]"):
+                    cache, _, _ = self._run_verify(
+                        jnp.asarray(np.zeros((n, bucket + 1), np.int32)),
+                        jnp.asarray(np.zeros((n,), np.int32)),
+                        jnp.asarray(np.zeros((n,), bool)),
+                        jnp.asarray(np.zeros((n,), np.int32)), rng,
+                    )
+                    self.cache = cache
+            with warming("draft_provider"):
+                self._spec.provider.warmup(n, self.plugin.speculate_k)
         if self.prefix is not None:
             # the three prefix programs are production programs: a first
             # hit / COW release / refcount-death push mid-traffic must hit
             # a warm cache (no-op passes: zero shared pages, empty masks)
             pps = self.plugin.pages_per_slot
-            self.cache = self._adopt(
-                self.cache, jnp.asarray(0, jnp.int32),
-                jnp.asarray(np.zeros((pps,), np.int32)),
-                jnp.asarray(0, jnp.int32),
-            )
-            self.cache = self._release_cow(
-                self.cache, jnp.asarray(np.zeros((n,), bool)),
-                jnp.asarray(np.zeros((n,), np.int32)),
-            )
-            self.cache = self._push_free(
-                self.cache, jnp.asarray(np.zeros((pps,), np.int32)),
-                jnp.asarray(np.zeros((pps,), bool)),
-            )
+            with warming("prefix_adopt"):
+                self.cache = self._adopt(
+                    self.cache, jnp.asarray(0, jnp.int32),
+                    jnp.asarray(np.zeros((pps,), np.int32)),
+                    jnp.asarray(0, jnp.int32),
+                )
+            with warming("prefix_release_cow"):
+                self.cache = self._release_cow(
+                    self.cache, jnp.asarray(np.zeros((n,), bool)),
+                    jnp.asarray(np.zeros((n,), np.int32)),
+                )
+            with warming("prefix_push_free"):
+                self.cache = self._push_free(
+                    self.cache, jnp.asarray(np.zeros((pps,), np.int32)),
+                    jnp.asarray(np.zeros((pps,), bool)),
+                )
         else:
-            self.cache = self._release(
-                self.cache, jnp.asarray(np.zeros((n,), bool))
-            )
+            with warming("release"):
+                self.cache = self._release(
+                    self.cache, jnp.asarray(np.zeros((n,), bool))
+                )
         # Decode compiled FIRST, against the fresh host-built cache — but
         # every program OUTPUT carries the steady-state placement GSPMD
         # chose (under a mesh-sharded param tree the KV pools come back
@@ -802,18 +833,50 @@ class ServingEngine:
         # plain serving under sharded params, or the ladder's despeculate
         # stage re-entering decode after verify — can never recompile
         # mid-traffic.
-        cache, _ = self._run_decode(
-            jnp.asarray(np.zeros((n,), np.int32)),
-            jnp.asarray(np.zeros((n,), bool)),
-            jnp.asarray(np.zeros((n,), np.int32)), rng,
-        )
-        self.cache = cache
+        with warming("decode"):
+            decode_pass()
         if self.adapters is not None:
             # the pool-insert scatter is a fixed-shape production program
             # too: a first hot-swap mid-traffic must hit a warm cache
-            self.adapters.warmup_insert()
+            with warming("adapter_insert"):
+                self.adapters.warmup_insert()
+        # the no-op passes are still running on the device: the engine is
+        # warm when the last of them has ended
+        jax.block_until_ready(self.cache)
+        self.warmup_report = list(rows.values())
+        m = self.metrics
+        m["warmup_wall_s"] = self._clock() - t_call
+        for key, a, b in zip(_WARMUP_PARTS, parts0, self._compile_counter.parts()):
+            m[f"warmup_{key}"] = b - a
         self.warmed_up = True
         return self._compile_counter.count - before
+
+    @contextlib.contextmanager
+    def _warming(self, rows: dict, label: str):
+        """Bracket one of :meth:`warmup`'s programs: its wall time on the
+        engine's clock and the compile counter's deltas, added to the row of
+        ``label`` (decode runs twice: one row).  ``execute_s`` is the rest of
+        the wall: staging, dispatch, and whatever of an earlier pass the call
+        waited for."""
+        counter = self._compile_counter
+        parts0, t0 = counter.parts(), self._clock()
+        try:
+            yield
+        finally:
+            t1 = self._clock()
+            row = rows.setdefault(label, {"label": label, "wall_s": 0.0,
+                                          **dict.fromkeys(_WARMUP_PARTS, 0)})
+            row["wall_s"] += t1 - t0
+            for key, a, b in zip(_WARMUP_PARTS, parts0, counter.parts()):
+                row[key] += b - a
+            row["execute_s"] = row["wall_s"] - sum(
+                row[k] for k in _WARMUP_PARTS if k.endswith("_s"))
+            # a program jax still held in memory reads neither
+            row["cache"] = ("miss" if row["cache_misses"] else
+                            "hit" if row["cache_hits"] else "none")
+            if self.trace is not None:
+                self.trace.recorder.complete(f"warmup:{label}", "warmup", t0, t1,
+                                             cat="warmup", cache=row["cache"])
 
     def warmup_programs(self) -> frozenset:
         """The static set of program labels :meth:`warmup` compiles for
@@ -835,16 +898,30 @@ class ServingEngine:
     def step(self) -> dict:
         """One scheduler decision + at most one device program.
 
-        With tracing on (:attr:`trace`) the tick is partitioned into
-        sibling phase spans that cover it from entry to return —
-        ``control``, ``schedule``, ``plan``, ``stage:*``, ``dispatch:*``,
-        ``host_sync``, ``commit``, ``trace`` (``RequestTracer``'s docstring
-        says what each holds) — plus the per-request lifecycle spans derived
-        from the scheduler's event log.  All host-side: the device programs
-        are identical."""
-        tr = self.trace
-        phase = tr.phase if tr is not None else _no_phase
+        The tick is partitioned into sibling phases that cover it from entry
+        to return — ``control``, ``schedule``, ``plan``, ``stage:*``,
+        ``dispatch:*``, ``host_sync``, ``commit``, ``trace``
+        (``RequestTracer``'s docstring says what each holds).  The host ledger
+        clocks every one of them, tracer or no tracer (``metrics``'
+        ``host_s.<kind>.<phase>``; a tick far over its class's median is a row
+        of :attr:`stalls`); with tracing on (:attr:`trace`) the same brackets
+        are also spans on the ``engine`` track, beside the per-request
+        lifecycle spans derived from the scheduler's event log.  All
+        host-side: the device programs are identical."""
+        led = self._ledger
         step = self.steps
+        led.begin_tick()
+        event = self._tick(led.phase, step)
+        row = led.end_tick(event["type"], event.get("bucket", 0), step,
+                           busy=not self.sched.idle())
+        if row is not None:
+            row["waiting"] = len(self.sched.waiting)
+            row["live"] = len(self.sched.slots)
+        return event
+
+    def _tick(self, phase, step: int) -> dict:
+        """``step()``'s body, between the ledger's two tick boundaries."""
+        tr = self.trace
         with phase("control", step=step):
             for ev in _faults.fault_point("serve_step"):
                 if ev.kind == "preempt":
@@ -991,7 +1068,7 @@ class ServingEngine:
             with phase("commit", step=step):
                 m["generated_tokens"] += 1
                 self._record_token(slot, tok)
-        return (opened[0], closed[1]) if opened is not None else None
+        return opened.t0, closed.t1
 
     def _decode_tick(self, slots, phase, event):
         """One decode step for every decoding slot.  Returns the tracing
@@ -1039,7 +1116,7 @@ class ServingEngine:
             m["decode_lane_passes"] += len(active_slots)
             m["decode_emitted_tokens"] += len(active_slots)
             event.update(slots=tuple(active_slots))
-        return (opened[0], closed[1]) if opened is not None else None
+        return opened.t0, closed.t1
 
     def run(self, trace: list[Request], max_steps: int = 200_000) -> dict[int, list[int]]:
         """Replay ``trace`` (arrivals keyed on virtual step time) to
@@ -1206,7 +1283,7 @@ class ServingEngine:
             self.cache = cache
             self._settle_verify(active_slots, spec_by_slot, worst_need,
                                 greedy_np, m_np, bucket, event)
-        return (opened[0], closed[1]) if opened is not None else None
+        return opened.t0, closed.t1
 
     def _settle_verify(self, active_slots, spec_by_slot, worst_need,
                        greedy_np, m_np, bucket, event) -> None:

@@ -1,7 +1,7 @@
 """Unified telemetry: twin registry, request trace spans, training
 timeline, SLO monitors (docs/observability.md).
 
-Three pillars, one discipline — host-side, bounded, bitwise-invisible to
+Four pillars, one discipline — host-side, bounded, bitwise-invisible to
 tokens and loss:
 
 - :mod:`.twins` — every predicted/measured cost-model pair registered
@@ -11,6 +11,13 @@ tokens and loss:
   spans in a bounded ring (``ServingEngine.trace``), exportable as Chrome
   trace-event JSON (Perfetto) or JSONL; :mod:`.timeline` is the training
   counterpart.
+- :mod:`.host_ledger` — the serving engine's own account of the host's
+  time, always on: seconds per phase of ``step()`` and tick kind, the
+  caller's time between ticks, the collector's pauses (one ``gc.callbacks``
+  hook a process), a stall log that says which phase of which tick stalled
+  (``engine.stalls``, a WARNING on ``accelerate_tpu.serving``), and
+  ``engine.warmup()`` by program and by part (``engine.warmup_report``) —
+  flat keys of ``engine.metrics``.
 - :mod:`.slo` — streaming p50/p99 estimators (P²) against configurable
   warn/trip thresholds, with Prometheus text exposition; the JSONL sink is
   always available through ``tracking.py``.
@@ -21,6 +28,7 @@ Knobs: :class:`~accelerate_tpu.utils.dataclasses.TelemetryPlugin` /
 ``PERF.md`` section 6 (PR 25).
 """
 
+from .host_ledger import HostLedger, install_global_gc_hook
 from .slo import SLOMonitor, SLOStatus, StreamingQuantile, prometheus_text
 from .spans import (
     RequestTracer,
@@ -38,6 +46,8 @@ __all__ = [
     "twin_registry",
     "SpanRecorder",
     "RequestTracer",
+    "HostLedger",
+    "install_global_gc_hook",
     "VirtualClock",
     "validate_chrome_trace",
     "TrainTimeline",

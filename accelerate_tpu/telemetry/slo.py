@@ -71,12 +71,18 @@ class StreamingQuantile:
                 k += 1
         for i in range(k + 1, 5):
             pos[i] += 1.0
-        for i in range(5):
-            self._desired[i] += self._inc[i]
+        # (the first desired position never moves and the outer two are never
+        # read; this runs on the serving tick's path, for the host ledger)
+        desired, inc = self._desired, self._inc
+        desired[1] += inc[1]
+        desired[2] += inc[2]
+        desired[3] += inc[3]
         # adjust the three interior markers by +-1 toward their desired
         # positions, parabolic (P²) height interpolation, linear fallback
         for i in (1, 2, 3):
-            d = self._desired[i] - pos[i]
+            d = desired[i] - pos[i]
+            if d < 1.0 and d > -1.0:
+                continue
             if (d >= 1.0 and pos[i + 1] - pos[i] > 1.0) or \
                (d <= -1.0 and pos[i - 1] - pos[i] < -1.0):
                 step = 1.0 if d >= 1.0 else -1.0

@@ -273,9 +273,16 @@ class RequestTracer:
     host dispatch time), ``host_sync`` (the token fetch), ``commit`` (token
     bookkeeping, releasing finished slots), ``trace`` (this tracer's own
     :meth:`consume_scheduler_events`) — and ``ladder`` instants marking
-    degradation-ladder stage transitions.  The spans bracket their work
-    (:meth:`SpanRecorder.span`), so inside a profiler session they are
-    also host annotations in the profiler's trace.  All host-side: the
+    degradation-ladder stage transitions.  The spans bracket their work:
+    they are the host ledger's brackets
+    (:meth:`~accelerate_tpu.telemetry.host_ledger.HostLedger.phase`, which
+    clocks the same phases into ``engine.metrics`` with no tracer armed),
+    each also a ``jax.profiler.TraceAnnotation``, so inside a profiler
+    session they are host annotations in the profiler's trace too.  The
+    ledger adds to this track, while a tracer is armed, a ``gc`` span for
+    each collector pause of a millisecond or more and a ``stall`` instant for
+    each slow tick; ``ServingEngine.warmup`` writes one ``warmup:<label>``
+    span a program on a track of its own (``warmup``).  All host-side: the
     engine's device programs are untouched.
     """
 
@@ -287,11 +294,6 @@ class RequestTracer:
         self._decode_start: dict[int, float] = {}
 
     # engine tick hooks --------------------------------------------------
-
-    def phase(self, name: str, **args):
-        """``with tracer.phase("plan", step=n):`` — one bracketing span on
-        the ``engine`` track."""
-        return self.recorder.span(name, "engine", cat="step", **args)
 
     def consume_scheduler_events(self, events: list, step: int,
                                  window: Optional[tuple] = None,

@@ -98,7 +98,7 @@ PAGES: dict[str, tuple[str, list[str] | None]] = {
     "telemetry": ("accelerate_tpu.telemetry", [
         "Twin", "TwinRegistry", "twin_registry", "SpanRecorder",
         "RequestTracer", "VirtualClock", "validate_chrome_trace",
-        "TrainTimeline", "StreamingQuantile", "SLOMonitor", "SLOStatus",
+        "HostLedger", "install_global_gc_hook", "TrainTimeline", "StreamingQuantile", "SLOMonitor", "SLOStatus",
         "prometheus_text",
     ]),
     "operations": ("accelerate_tpu.ops.operations", [

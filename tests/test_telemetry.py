@@ -560,8 +560,8 @@ def test_request_submitted_between_ticks_has_waited_since_then():
     eng.step()                          # tick 1: decodes it
     between = clk.now
     eng.add_request(Request(uid=2, prompt=tuple(range(3, 9)), max_new_tokens=3))
-    submitted = clk.now
-    assert submitted == between + 1     # one clock read per arrival
+    submitted = between + 1             # the request's stamp is the first reading ...
+    assert clk.now == between + 2       # ... of two per arrival: the host ledger's add_request seconds
     clk.now += 50.0                     # the caller's own time before it ticks again
     while not eng.idle():
         eng.step()
