@@ -29,7 +29,10 @@ no collective and nothing in its place on one chip.
 
 **Two kinds of per-token state in one cache** (the family protocol of
 ``serving/__init__.py``).  A ``full_attention`` layer keeps K and V in pages
-``[P, page, Hkv * D]`` under the engine's block table.  A
+``[P, page, Hkv * D]`` under the engine's block table; a decode step reads
+each slot's own pages once, to the slot's own length, inside one Pallas call a
+layer (``paged_walk_decode``, ``ops/latent_attention.py``), a prefill chunk
+walks blocks of gathered pages (``ops/sparse_attention.py``, plain XLA).  A
 ``sliding_attention`` layer keeps, per SLOT, a ring of ``sliding_window`` rows
 (``ops/window_attention.py``) outside the allocator: its bytes do not grow
 with the context, and a slot is handed on without being cleared.  The engine
@@ -50,6 +53,7 @@ import jax.numpy as jnp
 
 from ..ops import sparse_attention as sa
 from ..ops import window_attention as wa
+from ..ops.latent_attention import paged_walk_decode_attention
 from ..parallel.expert_parallel import grouped_ffn, held_rows_fed, route_dropless
 from .layers import Float32Dense, Float32Out, apply_rotary, bias_free_proj, rotary_angles
 from .llama import LMHead, RMSNorm
@@ -162,8 +166,16 @@ class KExaoneAttention(nn.Module):
     @nn.compact
     def __call__(self, x32, positions, cache=None, cache_write_mask=None):
         """``x32``: the layer's normed input (float32).  Returns ``(W_o of the
-        HELD heads' attention [B, T, H] float32, the layer's new state, keys
-        visible to the live queries of a decode step or None)``."""
+        HELD heads' attention [B, T, H] float32, the layer's new state, of a
+        decode step the keys visible to its live queries — and beside them,
+        from a full-attention layer, the rows of the pages read for them, int32
+        [2] — or None)``.
+
+        A full-attention layer's decode step ``[S, 1]`` is the
+        ``paged_walk_decode`` Pallas kernel (``ops/latent_attention.py``: each
+        slot's own K and V pages once, to its own length); its prefill chunk
+        ``[1, C]`` walks blocks of gathered pages
+        (``ops/sparse_attention.paged_causal_attention``, plain XLA)."""
         cfg = self.config
         b, t = x32.shape[:2]
         h, hkv, d, window = cfg.heads, cfg.kv_heads, cfg.head_dim, cfg.sliding_window
@@ -217,11 +229,17 @@ class KExaoneAttention(nn.Module):
             with jax.named_scope("paged_write_kv"):
                 write = sa.page_writer(tables, pos, live, page)
                 k_pages, v_pages = write(cache["k_pages"], flat(k)), write(cache["v_pages"], flat(v))
-            padded = sa.pad_block_tables(tables, sa.block_pages_for(b, t, h, page))
-            out = sa.paged_causal_attention(q, k_pages, v_pages, padded, q_pos,
-                                            jnp.max(q_pos) + 1)
+            if t == 1:      # each slot's own pages once, to its own length: one kernel
+                with jax.named_scope("global_attend"):
+                    out = paged_walk_decode_attention(q[:, 0], k_pages, v_pages, tables,
+                                                      q_pos[:, 0])[:, None]
+            else:           # a chunk's rows against blocks of gathered pages
+                padded = sa.pad_block_tables(tables, sa.block_pages_for(b, t, h, page))
+                out = sa.paged_causal_attention(q, k_pages, v_pages, padded, q_pos,
+                                                jnp.max(q_pos) + 1)
             state = {"k_pages": k_pages, "v_pages": v_pages}
-            seen = jnp.sum(q_pos + 1, dtype=jnp.int32)
+            walked = jnp.sum((q_pos + page) // page, dtype=jnp.int32) * page  # each slot's own pages
+            seen = jnp.stack([jnp.sum(q_pos + 1, dtype=jnp.int32), walked])
         return o_proj(out.reshape(b, t, h * d)), state, (seen if t == 1 else None)
 
 
@@ -349,18 +367,21 @@ class KExaoneForCausalLM(nn.Module):
         program; the rest count decode steps only: held experts with a row
         summed over the sparse layers, sparse layer-steps, rows routed to held
         experts and rows the grouped matmuls were fed, keys visible to the
-        live queries in the full-attention and in the window layers."""
+        live queries in the full-attention layers and rows of the pages the
+        ``paged_walk_decode`` kernel read for them (each live slot's own whole
+        pages: ``(position // page + 1) * page``), keys visible in the window
+        layers; the last three summed over the slots and the layers."""
         return (("expert_tokens", len(self.config.held)), ("moe_experts_hit_sum", 1),
                 ("moe_ticks", 1), ("moe_rows_held", 1), ("moe_rows_computed", 1),
-                ("global_visible_sum", 1), ("window_visible_sum", 1))
+                ("global_visible_sum", 1), ("global_walked_sum", 1), ("window_visible_sum", 1))
 
     def _counters(self, per_expert, fed, seen, decode: bool):
         """The vector ``tick_counters`` lays out, from the layers' parts."""
         held = jnp.zeros((0, len(self.config.held)), jnp.int32)
         rows = jnp.stack(per_expert) if per_expert else held           # [sparse layers, E held]
-        total = lambda parts: sum(parts, jnp.zeros((), jnp.int32))
+        total = lambda parts, shape=(): sum(parts, jnp.zeros(shape, jnp.int32))
         steps = jnp.stack([jnp.sum(rows > 0), rows.shape[0], jnp.sum(rows), total(fed),
-                           total(seen["full_attention"]), total(seen["sliding_attention"])])
+                           *total(seen["full_attention"], (2,)), total(seen["sliding_attention"])])
         return jnp.concatenate([jnp.sum(rows, axis=0),
                                 steps.astype(jnp.int32) if decode else jnp.zeros_like(steps, jnp.int32)])
 

@@ -1,42 +1,75 @@
-"""The absorbed decode walk over latent pages as one Pallas kernel
-(``latent_decode``; ``models/joyai_flash.py``'s ``[S, 1]`` step is its caller).
+"""A decode step's attention over ITS OWN pages as one Pallas kernel: each slot
+reads its own pages once, up to its own length, and nothing is gathered.  Two
+callers, one walk; what a row of a page holds is read off the inputs:
 
-A token's latent row ``[c ; kr ; zeros]`` is the key of every head, whole, and
-in its first ``r`` values the value.  With ``q_h = [qa_h ; qr_h]`` the absorbed
-query of head ``h`` of the slot's one token at position ``t``::
+- ``latent_decode`` (:func:`latent_decode_attention`; ``models/joyai_flash.py``'s
+  ``[S, 1]`` step): ONE pool.  A token's latent row ``[c ; kr ; zeros]`` is the
+  key of every head, whole, and in its first ``r`` values the value.  With
+  ``q_h = [qa_h ; qr_h]`` the absorbed query of head ``h`` of the slot's one
+  token at position ``t``::
 
-    score_h(s) = q_h . row_s * scale          for s <= t
-    u_h        = sum_s softmax_s(score_h) row_s[:r]
+      score_h(s) = q_h . row_s * scale          for s <= t
+      u_h        = sum_s softmax_s(score_h) row_s[:r]
+
+- ``paged_walk_decode`` (:func:`paged_walk_decode_attention`;
+  ``models/k_exaone.py``'s full-attention layers, ``[S, 1]`` step): TWO pools
+  ``[P, page, Hkv * D]``, a token's key heads in a row of one and its value
+  heads in a row of the other; ``G = H / Hkv`` query heads meet each KV head's
+  ``D`` lanes::
+
+      score_h(s) = q_h . k_s[h // G] / sqrt(D)  for s <= t
+      o_h        = sum_s softmax_s(score_h) v_s[h // G]
 
 The mathematics and the precision are those of
-``ops/sparse_attention.paged_masked_attention(..., value_width=r)`` under the
-causal mask (float32 scores from the pool's dtype, a running maximum and sum
-in float32, ``p`` cast to the pool's dtype before it meets the rows, a float32
-sum divided once at the end), which stays the oracle this kernel is tested
-against.  What differs is what is read: that walk gathers EVERY slot's pages up
-to the LONGEST live context into a ``[S, block, row]`` array, writes it and
-reads it twice; here each slot reads ITS OWN pages ONCE, up to ITS OWN length.
+``ops/sparse_attention.paged_masked_attention`` under the causal mask (float32
+scores from the pool's dtype, a running maximum and sum in float32, ``p`` cast
+to the pool's dtype before it meets the values, a float32 sum divided once at
+the end), which stays the oracle this kernel is tested against.  What differs
+is what is read: that walk gathers EVERY slot's pages up to the LONGEST live
+context into a ``[S, block, row]`` array, writes it and reads it twice; here
+each slot reads ITS OWN pages ONCE, up to ITS OWN length.
 
 Grid ``(slots,)``, walked in order.  The block table and the positions ride as
-scalar-prefetch operands, the pool stays in HBM (``pl.ANY``) and the kernel
-walks a slot's pages itself, ``_CHUNK_PAGES`` at a time: one copy a page (a
-page is contiguous in the pool) into one of two VMEM buffers, the next chunk's
-copies in flight while the current chunk is scored — and behind a slot's last
-chunk the NEXT slot's first, so that only the first slot of a call waits for
-copies it has just started (the buffer's parity is carried from slot to slot
-in SMEM).  A page past the slot's last is neither copied nor stepped over; its
-place in the last chunk's buffer is zeroed (whatever an earlier chunk left
-there would meet ``p = 0``, and ``0 x NaN`` is not 0).  A slot that sees
-nothing (``q_pos < 0``) copies nothing of its own and writes zeros.
+scalar-prefetch operands, the pools stay in HBM (``pl.ANY``) and the kernel
+walks a slot's pages itself, a chunk of pages (:func:`chunk_pages`) at a time:
+one copy a page and pool (a page is contiguous in its pool) into one of two
+VMEM buffers a pool, the next chunk's copies in flight while the current chunk
+is scored — and behind a slot's last chunk the NEXT slot's first, so that only
+the first slot of a call waits for copies it has just started (the buffer's
+parity is carried from slot to slot in SMEM).  The copies are started and
+awaited from LOOPS over the chunk's held pages (a buffer's copies share one
+DMA semaphore a pool), so a page past the slot's last is neither copied nor
+stepped over, and the kernel's body does not grow with the chunk; the places
+of those pages in the last chunk's VALUE buffer are zeroed (whatever an
+earlier chunk left there would meet ``p = 0``, and ``0 x NaN`` is not 0; a
+stale key is masked before it is used).  A slot that sees nothing (``q_pos <
+0``) copies nothing of its own and writes zeros.
 
-Timed on a v5e at ``joyai-flash.serve_docs``'s shapes (48 slots, 4 heads, 5,915
-pages of 64 x 640 bf16 a layer; PR 37): 0.704 ms a layer at 16 pages a chunk
-(0.764 at 8, 0.721 at 32; 0.762 without the copies across slots) against the
-XLA walk's 6.80.  The other scheme — ``BlockSpec``s that hand the pool in 16
-times under 16 scalar-prefetched page ids, index maps held at each operand's
-last page so that steps past a slot's length fetch nothing — took 1.40 ms (a
-grid step for every chunk of the table, a copy of each page inside VMEM) and
-is not in the package.
+Timed on a v5e, one layer's call alone, contexts drawn as the cell's traffic
+holds them in steady state (``PERF.md`` section 6, PRs 37 and 39):
+
+- ``k-exaone.serve_reason`` (64 slots of 608 .. 15,586 keys, 8 heads on 1 KV
+  head, 3,666 pages of 64 x 128 bf16 in each pool, 16 KB a page; PR 39): the
+  XLA walk 1.889 ms; the kernel 0.374 ms at 8 pages a chunk, 0.341 at 16,
+  **0.318 at 32**, 0.321 at 64, 0.364 at 128: 377 GB/s of whole pages, 45.6%
+  of the HBM time of the visible K and V.  7,332 copies a call: the scalar
+  core's start and wait a page set the pace, not HBM.  At the published
+  group (8 slots, 64 heads on 8 KV heads, 128 KB a page, 16 pages a chunk)
+  0.175 ms against the XLA walk's 1.126: 77.9%.
+- ``joyai-flash.serve_docs`` (48 slots, 4 heads, 7,077 pages of 64 x 640 bf16,
+  80 KB a page): 0.818 ms at 16 pages a chunk with the copies looped (PR 39)
+  against 0.815-0.820 with 48 predicated copies unrolled (PR 37's body, the
+  same contexts), 0.828-0.833 at 32; the looped body traces and lowers in a
+  third of the time (0.26 s against 0.79 s on that machine's host).  PR 37,
+  5,915 pages, unrolled: 0.704 ms at 16 (0.764 at 8, 0.721 at 32; 0.762
+  without the copies across slots) against the XLA walk's 6.80.
+
+Hence :func:`chunk_pages`: a buffer of 1.25 MiB a pool, no fewer than 16 pages
+and no more than 32.  The other scheme — ``BlockSpec``s that hand the pool in
+16 times under 16 scalar-prefetched page ids, index maps held at each
+operand's last page so that steps past a slot's length fetch nothing — took
+1.40 ms at PR 37's shapes (a grid step for every chunk of the table, a copy of
+each page inside VMEM) and is not in the package.
 """
 
 from __future__ import annotations
@@ -51,51 +84,72 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import _on_tpu
 
-# pages one compute step scores: 16 pages of 64 rows of 640 bf16 values are 1.25 MiB a buffer,
-# two buffers (the timings are in the module's docstring)
+# pages one compute step scores: as many as fill _CHUNK_BYTES of one pool's buffer (16 pages of
+# 64 rows of 640 bf16 values), no fewer than _CHUNK_PAGES (a step's fixed cost) and no more than
+# twice that (the timings are in the module's docstring)
 _CHUNK_PAGES = 16
+_CHUNK_BYTES = 16 * 64 * 640 * 2
 
 
-def _latent_decode_kernel(bt_ref, pos_ref, qa_ref, qr_ref, pool_ref, o_ref, q_scr, buf, sem,
-                          par_ref, *, scale: float, pages_per_slot: int, chunk: int):
+def chunk_pages(page_bytes: int, pages_per_slot: int) -> int:
+    """Pages one compute step scores, from a page's bytes in one pool."""
+    fill = max(_CHUNK_PAGES, min(_CHUNK_BYTES // page_bytes, 2 * _CHUNK_PAGES))
+    return min(fill, pages_per_slot)
+
+
+def _walk_kernel(bt_ref, pos_ref, *refs, scale: float, pages_per_slot: int, chunk: int,
+                 q_parts: int, pools: int, kv_heads: int):
     """One slot: its pages ``0 .. q_pos // page`` in chunks of ``chunk`` under
-    a running softmax kept in registers.  ``par_ref[0]``: which of the two
-    buffers holds this slot's first chunk (started by the slot before it)."""
+    a running softmax kept in registers.  ``refs``: the query's parts (side by
+    side they meet a key head's lanes), the key pool, the value pool if it is
+    another, the output; then the scratch: the query whole, two buffers a
+    pool, a DMA semaphore a buffer and pool, and ``par_ref[0]``: which of the
+    two buffers holds this slot's first chunk (started by the slot before it)."""
+    refs = iter(refs)
+    take = lambda n: [next(refs) for _ in range(n)]
+    q_refs, pool_refs, (o_ref, q_scr), bufs, (sem, par_ref) = \
+        take(q_parts), take(pools), take(2), take(pools), take(2)
     slot = pl.program_id(0)
     slots = pl.num_programs(0)
-    page, row = pool_ref.shape[1:]
-    heads, r = qa_ref.shape[1:]
-    dr = qr_ref.shape[2]
-    rv = o_ref.shape[2]
+    page = pool_refs[0].shape[1]
+    heads, dk = q_scr.shape
+    dv = o_ref.shape[2]
+    group = heads // kv_heads
     pos = pos_ref[slot]
     pages_of = lambda s: jnp.maximum(pos_ref[s] + page, 0) // page
     pages = pages_of(slot)
     chunks = (pages + chunk - 1) // chunk
     nxt = jnp.minimum(slot + 1, slots - 1)
     nxt_pages = jnp.where(slot + 1 < slots, pages_of(nxt), 0)
-
-    def page_copy(s, c, b, j):
-        page_id = bt_ref[s * pages_per_slot + c * chunk + j]
-        return pltpu.make_async_copy(pool_ref.at[page_id], buf.at[b, pl.ds(j * page, page)],
-                                     sem.at[b, j])
+    rows_of = lambda j: pl.ds(pl.multiple_of(j * page, page), page)
 
     def start(s, s_pages, c, b):
-        for j in range(chunk):
-            @pl.when(c * chunk + j < s_pages)
-            def _():
-                page_copy(s, c, b, j).start()
+        """Copies of slot ``s``'s chunk ``c`` into buffer ``b``: its held pages alone."""
+        def page_copies(j, _):
+            page_id = bt_ref[s * pages_per_slot + c * chunk + j]
+            for i in range(pools):
+                pltpu.make_async_copy(pool_refs[i].at[page_id], bufs[i].at[b, rows_of(j)],
+                                      sem.at[b, i]).start()
+            return _
+
+        lax.fori_loop(0, jnp.clip(s_pages - c * chunk, 0, chunk), page_copies, 0)
 
     def wait(c, b):
-        for j in range(chunk):
-            held = c * chunk + j < pages
+        """This slot's chunk ``c`` has landed in buffer ``b``; what it does not hold is zero."""
+        held = jnp.minimum(pages - c * chunk, chunk)
 
-            @pl.when(held)
-            def _():
-                page_copy(slot, c, b, j).wait()
+        def landed(j, _):       # one page's bytes off the pool's semaphore, whichever page it was
+            for i in range(pools):
+                pltpu.make_async_copy(pool_refs[i].at[0], bufs[i].at[b, rows_of(j)],
+                                      sem.at[b, i]).wait()
+            return _
 
-            @pl.when(jnp.logical_not(held))
-            def _():
-                buf[b, pl.ds(j * page, page), :] = jnp.zeros((page, row), buf.dtype)
+        def zeroed(j, _):
+            bufs[-1][b, rows_of(j), :] = jnp.zeros((page, bufs[-1].shape[2]), bufs[-1].dtype)
+            return _
+
+        lax.fori_loop(0, held, landed, 0)
+        lax.fori_loop(held, chunk, zeroed, 0)
 
     @pl.when(slot == 0)
     def _():
@@ -108,10 +162,15 @@ def _latent_decode_kernel(bt_ref, pos_ref, qa_ref, qr_ref, pool_ref, o_ref, q_sc
     def _():
         start(nxt, nxt_pages, 0, par)
 
-    q_scr[...] = jnp.zeros_like(q_scr)
-    q_scr[:, :r] = qa_ref[0]
-    q_scr[:, r:r + dr] = qr_ref[0]
+    at = 0
+    for part in q_refs:
+        q_scr[:, at:at + part.shape[2]] = part[0]
+        at += part.shape[2]
+    if at < dk:
+        q_scr[:, at:] = jnp.zeros((heads, dk - at), q_scr.dtype)
     q = q_scr[...]
+    heads_of = lambda h: slice(h * group, (h + 1) * group)
+    over_heads = lambda parts: parts[0] if kv_heads == 1 else jnp.concatenate(parts, axis=0)
 
     def attend_chunk(c, carry):
         m, l, acc = carry
@@ -120,22 +179,68 @@ def _latent_decode_kernel(bt_ref, pos_ref, qa_ref, qr_ref, pool_ref, o_ref, q_sc
         start(jnp.where(last, nxt, slot), jnp.where(last, nxt_pages, pages),
               jnp.where(last, 0, c + 1), 1 - b)
         wait(c, b)
-        s = lax.dot_general(q, buf[b], (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale     # [H, chunk * page]
+        s = over_heads([lax.dot_general(q[heads_of(h)], bufs[0][b, :, h * dk:(h + 1) * dk],
+                                        (((1,), (1,)), ((), ())),
+                                        preferred_element_type=jnp.float32)
+                        for h in range(kv_heads)]) * scale               # [H, chunk * page]
         seen = c * (chunk * page) + lax.broadcasted_iota(jnp.int32, s.shape, 1) <= pos
         m_new = jnp.maximum(m, jnp.max(jnp.where(seen, s, -jnp.inf), axis=-1, keepdims=True))
         p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m - m_new)
         l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        pv = lax.dot_general(p.astype(buf.dtype), buf[b, :, :rv], (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+        p = p.astype(bufs[-1].dtype)
+        pv = over_heads([lax.dot_general(p[heads_of(h)], bufs[-1][b, :, h * dv:(h + 1) * dv],
+                                         (((1,), (0,)), ((), ())),
+                                         preferred_element_type=jnp.float32)
+                         for h in range(kv_heads)])
         return m_new, l, acc * alpha + pv
 
     init = (jnp.full((heads, 1), -jnp.inf, jnp.float32), jnp.zeros((heads, 1), jnp.float32),
-            jnp.zeros((heads, rv), jnp.float32))
+            jnp.zeros((heads, dv), jnp.float32))
     _, l, acc = lax.fori_loop(0, chunks, attend_chunk, init)
     par_ref[0] = (par + chunks) % 2
     o_ref[0] = (acc / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "value_width", "name", "interpret"))
+def _page_walk(q_parts, k_pages, v_pages, block_tables, q_positions, *, scale, value_width, name,
+               interpret):
+    """The walk under a ``jit`` of its own, so that a program of many layers
+    traces and lowers the kernel once and not once a layer.  ``q_parts``:
+    ``[S, H, w]`` arrays that side by side meet a key head's lanes;
+    ``v_pages=None``: the value is the first ``value_width`` values of the key
+    pool's row (one KV head).  Returns ``[S, H, value_width]`` a KV head's
+    group after another."""
+    s_slots, heads = q_parts[0].shape[:2]
+    _, page, row = k_pages.shape
+    n = block_tables.shape[1]
+    pools = (k_pages,) if v_pages is None else (k_pages, v_pages)
+    kv_heads = 1 if v_pages is None else row // value_width
+    dk = row // kv_heads
+    if sum(part.shape[2] for part in q_parts) > dk or heads % kv_heads:
+        raise ValueError(f"{heads} queries of {[part.shape[2] for part in q_parts]} values do "
+                         f"not meet {kv_heads} key heads of {dk}")
+    # one pool: the sum runs over whole lane tiles of the row, cut below
+    dv = value_width if v_pages is not None else min(row, -(-value_width // 128) * 128)
+    chunk = chunk_pages(page * row * k_pages.dtype.itemsize, n)
+    mine = lambda s, bt, pos: (s, 0, 0)
+    out = pl.pallas_call(
+        functools.partial(_walk_kernel, scale=scale, pages_per_slot=n, chunk=chunk,
+                          q_parts=len(q_parts), pools=len(pools), kv_heads=kv_heads),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(s_slots,),
+            in_specs=[pl.BlockSpec((1, heads, part.shape[2]), mine) for part in q_parts]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+            out_specs=pl.BlockSpec((1, heads, dv), mine),
+            scratch_shapes=[pltpu.VMEM((heads, dk), k_pages.dtype)]
+            + [pltpu.VMEM((2, chunk * page, pool.shape[2]), pool.dtype) for pool in pools]
+            + [pltpu.SemaphoreType.DMA((2, len(pools))), pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((s_slots, heads, dv), q_parts[0].dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name=name,
+    )(block_tables.reshape(-1).astype(jnp.int32), q_positions.astype(jnp.int32), *q_parts, *pools)
+    return out if dv == value_width else out[..., :value_width]
 
 
 def latent_decode_attention(qa, qr, latent_pages, block_tables, q_positions, *, scale: float):
@@ -151,38 +256,22 @@ def latent_decode_attention(qa, qr, latent_pages, block_tables, q_positions, *, 
 
     Entries of a block table past ``position // page`` are never read, and
     neither are the pages they name."""
-    return _latent_decode(qa, qr, latent_pages, block_tables, q_positions, scale=scale,
-                          interpret=not _on_tpu())
+    return _page_walk((qa, qr), latent_pages, None, block_tables, q_positions, scale=scale,
+                      value_width=qa.shape[2], name="latent_decode", interpret=not _on_tpu())
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def _latent_decode(qa, qr, latent_pages, block_tables, q_positions, *, scale, interpret):
-    """Under a ``jit`` of its own, so that a program of many layers traces and
-    lowers the kernel once and not once a layer (a quarter of a second each)."""
-    s_slots, heads, r = qa.shape
-    dr = qr.shape[2]
-    _, page, row = latent_pages.shape
-    n = block_tables.shape[1]
-    if r + dr > row:
-        raise ValueError(f"a latent row of {row} values does not hold {r} + {dr}")
-    rv = min(row, -(-r // 128) * 128)       # the sum runs over whole lane tiles, cut below
-    chunk = min(_CHUNK_PAGES, n)
-    mine = lambda s, bt, pos: (s, 0, 0)
-    u = pl.pallas_call(
-        functools.partial(_latent_decode_kernel, scale=scale, pages_per_slot=n, chunk=chunk),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(s_slots,),
-            in_specs=[pl.BlockSpec((1, heads, r), mine), pl.BlockSpec((1, heads, dr), mine),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((1, heads, rv), mine),
-            scratch_shapes=[pltpu.VMEM((heads, row), latent_pages.dtype),
-                            pltpu.VMEM((2, chunk * page, row), latent_pages.dtype),
-                            pltpu.SemaphoreType.DMA((2, chunk)),
-                            pltpu.SMEM((1,), jnp.int32)]),
-        out_shape=jax.ShapeDtypeStruct((s_slots, heads, rv), qa.dtype),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-        name="latent_decode",
-    )(block_tables.reshape(-1).astype(jnp.int32), q_positions.astype(jnp.int32), qa, qr,
-      latent_pages)
-    return u if rv == r else u[..., :r]
+def paged_walk_decode_attention(q, k_pages, v_pages, block_tables, q_positions):
+    """One decode step's full causal attention over paged keys and values.
+
+    q: ``[S, H, D]``; k_pages, v_pages: ``[P, page, Hkv * D]`` in the queries'
+    dtype (``H / Hkv`` query heads a KV head, a KV head's group after
+    another); block_tables: ``[S, n]`` int32; q_positions: ``[S]`` int32, the
+    token's position (keys ``0 .. position`` are seen), -1 for a slot that
+    sees nothing.  Scores are scaled by ``1 / sqrt(D)``.  Returns ``[S, H,
+    D]`` in ``q``'s dtype; a slot that sees nothing comes back zero.
+
+    Entries of a block table past ``position // page`` are never read, and
+    neither are the pages they name."""
+    d = q.shape[2]
+    return _page_walk((q,), k_pages, v_pages, block_tables, q_positions, scale=1.0 / d ** 0.5,
+                      value_width=d, name="paged_walk_decode", interpret=not _on_tpu())

@@ -18,9 +18,12 @@ of position until the row has ``topk``: the set a stable sort by descending
 score keeps.
 
 The page walk under that mask, :func:`paged_masked_attention`, is shared by
-every masked attention over paged keys (``models/k_exaone.py``'s full causal
-layers, ``models/joyai_flash.py``'s latent attention).  What a row of a page
-holds is the caller's to say: a token's key heads in one pool and its value
+every masked attention over paged keys that is not a kernel of its own: this
+family's prefill chunks and decode steps, and the PREFILL CHUNKS of
+``models/k_exaone.py``'s full causal layers and of ``models/joyai_flash.py``'s
+latent attention (their decode steps ``[S, 1]`` walk each slot's own pages
+inside the Pallas kernels of ``ops/latent_attention.py``, which are tested
+against this walk).  What a row of a page holds is the caller's to say: a token's key heads in one pool and its value
 heads in another, of one head width; or ONE row that is the key and, in its
 first values, the value (a latent ``[c ; kr]`` shared by every head, scored
 whole at the caller's scale); or a row that is up-projected to per-head keys
@@ -220,7 +223,11 @@ def index_keys(q_idx, w_idx, index_pages, block_tables, q_positions, topk: int, 
 
 def paged_masked_attention(q, k_pages, v_pages, block_tables, kv_len, block_mask,
                            mask_carry=lambda: (), *, scale=None, value_width=None, expand=None):
-    """The page walk every masked attention over paged keys shares: ``q``
+    """The page walk every masked attention over paged keys shares (callers:
+    :func:`paged_selected_attention`, Keye's chunks and decode steps;
+    :func:`paged_causal_attention`, K-EXAONE's prefill chunks; JoyAI's
+    prefill chunks through ``expand``; and, as their oracle, the tests of the
+    decode kernels of ``ops/latent_attention.py``): ``q``
     [B, T, H, D] against the pages of ``block_tables`` [B, n] (n a whole
     number of loop steps: ``pad_block_tables``), a block of
     ``block_pages_for`` pages at a time with a running softmax, so that no
@@ -324,7 +331,11 @@ def causal_mask(q_positions):
 @jax.named_scope("global_attend")
 def paged_causal_attention(q, k_pages, v_pages, block_tables, q_positions, kv_len):
     """Full causal attention over paged keys: the same walk with the mask
-    ``s <= t`` (:func:`causal_mask`)."""
+    ``s <= t`` (:func:`causal_mask`).  It gathers EVERY row of ``q``'s batch
+    up to ``kv_len`` in whole blocks, which suits one sequence's chunk ``[1,
+    C]`` (``models/k_exaone.py``'s prefill); a decode step ``[S, 1]`` over
+    ragged contexts is ``ops/latent_attention.paged_walk_decode_attention``,
+    whose oracle this is."""
     return paged_masked_attention(q, k_pages, v_pages, block_tables, kv_len,
                                   causal_mask(q_positions))
 

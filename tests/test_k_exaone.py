@@ -391,6 +391,21 @@ def test_the_model_reports_rows_held_and_rows_computed(model, weights):
     assert m["window_visible_sum"] < m["global_visible_sum"]      # 6 layers x <= 8 keys against 2 x context
 
 
+def test_a_decode_step_reads_each_live_querys_own_whole_pages(model, weights):
+    """``global_walked_sum``: rows of the pages the ``paged_walk_decode``
+    kernel read, beside ``global_visible_sum``, the keys its live queries may
+    see: never fewer, and less than one page of rows more for each live query
+    of each of the two full-attention layers (contexts of 7 .. 52 keys over
+    pages of 8: the walk that gathers every slot to the longest live context
+    in whole blocks would read several pages more for the short ones)."""
+    eng = serve(model, weights, {0: ids_of(0, 40), 1: ids_of(1, 6), 2: ids_of(2, 23)}, new=12)
+    m = eng.metrics
+    queries = 2 * m["decode_lane_passes"]           # a live slot of a decode step, in two layers
+    assert m["decode_steps"] > 0 and queries >= 2 * 3 * 11
+    assert m["global_visible_sum"] <= m["global_walked_sum"] < m["global_visible_sum"] + 8 * queries
+    assert m["global_walked_sum"] % 8 == 0 and m["global_walked_sum"] > m["global_visible_sum"]
+
+
 # -- 6. the published checkpoint's names -----------------------------------------------------
 
 

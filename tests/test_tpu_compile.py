@@ -401,21 +401,26 @@ EXAONE_SLOTS, EXAONE_PAGES, EXAONE_PAGES_PER_SLOT = 64, 18432, 288
 
 
 @pytest.mark.parametrize("program,width", [("decode", 1), ("prefill", 512), ("prefill", 2048)])
-def test_k_exaone_serving_programs_compile_at_the_cells_shapes(one_chip, program, width):
+def test_k_exaone_serving_programs_compile_at_the_cells_shapes(one_chip, monkeypatch, program,
+                                                               width):
     """The engine's decode and prefill programs of ``models/k_exaone.py`` at
     the published widths, the cell's share (8 of 64 heads, 1 of 8 KV heads,
     16 of 128 experts, 19,200 vocabulary rows) and geometry (64 slots, 18,432
     pages of 64, 288 a slot), one period deep (``LLLG``: the dense layer,
     three sparse ones, window and full attention): the grouped matmuls are
-    the Mosaic kernel over BLOCKS of held rows (never ``N x k``), and no op
+    the Mosaic kernel over BLOCKS of held rows (never ``N x k``), no op
     copies or relays a whole page pool or a whole ring (both are written in
-    the layout the reads use)."""
+    the layout the reads use), a decode step walks the full-attention layer's
+    K and V pools inside the ``paged_walk_decode`` Mosaic kernel, one call a
+    layer, and gathers no block of pages; a prefill chunk gathers them."""
     import re
 
     from accelerate_tpu.generation import GenerationConfig
     from accelerate_tpu.models import KExaoneConfig, KExaoneForCausalLM
+    from accelerate_tpu.ops import latent_attention as la
     from accelerate_tpu.serving.engine import fresh_engine_jits
 
+    monkeypatch.setattr(la, "_on_tpu", lambda: True)   # the kernel, not its interpreter
     model = KExaoneForCausalLM(KExaoneConfig(
         num_hidden_layers=4, experts_held=tuple(range(16)), attention_heads_held=8,
         key_value_heads_held=1, vocab_held=19200))
@@ -443,6 +448,11 @@ def test_k_exaone_serving_programs_compile_at_the_cells_shapes(one_chip, program
     moved = r"(copy\(|transpose\([^)]*\), dimensions=\{(?!0,1,2\}))"
     assert re.findall(rf"= bf16\[{EXAONE_PAGES},64,128\]\S* {moved}", text) == []
     assert re.findall(rf"= bf16\[{EXAONE_SLOTS},128,128\]\S* {moved}", text) == []
+    gathered = re.findall(r"= bf16\[((?:\d+,)*\d+,64,128)\]\S* gather\(", text)   # blocks of pages of rows
+    assert bool(gathered) == (program == "prefill"), gathered
+    kernels = re.findall(r"%paged_walk_decode\S* = bf16\[64,8,128\]\S* custom-call\(", text)
+    assert len(kernels) == (1 if program == "decode" else 0)       # one walk a full-attention layer
+    assert ("global_attend/while" in text) == (program == "prefill")   # the XLA walk: the chunk's alone
     stats = compiled.memory_analysis()
     pool, rings = 2 * EXAONE_PAGES * PAGE * 128 * 2, 3 * 2 * EXAONE_SLOTS * 128 * 128 * 2
     assert stats.alias_size_in_bytes >= pool + rings                         # both kinds alias in place
