@@ -11,6 +11,10 @@ beside a shared expert) does too, and adds what of a layer a chip HOLDS.
 of ``kv_lora_rank + qk_rope_head_dim`` values shared by every head) has no
 ``head_dim`` to carry: a head is ``qk_nope_head_dim + qk_rope_head_dim`` wide
 where it is scored and ``v_head_dim`` where it is summed.
+``Qwen3NextConfig`` (three Gated DeltaNet layers, whose state is one matrix a
+value head and sequence, to every gated-attention layer of ``head_dim`` 256)
+carries ``head_dim`` for the attention layers and ``linear_key_head_dim`` /
+``linear_value_head_dim`` for the others.
 ``docs/supported_models.md`` has the table of what each family trains,
 serves and refuses."""
 
@@ -23,6 +27,7 @@ from .hf_interop import (
     hf_llama_key_map,
     hf_llama_tensor_map,
     hf_mixtral_key_map,
+    hf_qwen3_next_key_map,
     hf_t5_key_map,
     load_hf_bert,
     load_hf_joyai_flash,
@@ -30,6 +35,7 @@ from .hf_interop import (
     load_hf_keye_vl2,
     load_hf_llama,
     load_hf_mixtral,
+    load_hf_qwen3_next,
     load_hf_t5,
 )
 from .joyai_flash import JoyAIFlashConfig, JoyAIFlashForCausalLM
@@ -49,5 +55,6 @@ from .mixtral import (
     count_active_params,
     make_mixtral_loss_fn,
 )
+from .qwen3_next import Qwen3NextConfig, Qwen3NextForCausalLM
 from .resnet import ResNet, ResNetConfig, make_resnet_loss_fn
 from .t5 import T5Config, T5ForConditionalGeneration, make_t5_loss_fn

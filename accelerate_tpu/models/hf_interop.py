@@ -386,6 +386,72 @@ def load_hf_joyai_flash(model, checkpoint, *, mesh=None, dtype=None, rng=None,
         key_map=hf_joyai_flash_key_map, tensor_map=tensor_map, **kwargs)
 
 
+# -- Qwen3-Next (Gated DeltaNet + gated attention, softmax-routed experts + a gated shared expert) ---
+# The published modelling code's names.  ``linear_attn.in_proj_qkvz`` and
+# ``in_proj_ba`` stay FUSED, their outputs a key head's group after another
+# (``[q; k; v x r; z x r]``, ``[b x r; a x r]``): the model cuts them per
+# group, and a tensor-parallel share is a run of whole groups
+# (``tests/test_qwen3_next.py`` cuts four and adds them up).  ``conv1d.weight`` ``[C, 1, K]``
+# becomes ``[K, C]``; ``A_log``, ``dt_bias`` and the gated norm's weight are
+# bare leaves.  The multi-token-prediction module (``mtp.*``) is not built.
+_QWEN3_NEXT_BLOCK: list[tuple[str, str]] = [
+    (r"linear_attn\.(in_proj_qkvz|in_proj_ba|out_proj)\.weight$", r"linear_attn.\1.kernel"),
+    (r"linear_attn\.conv1d\.weight$", r"linear_attn.conv1d"),
+    (r"linear_attn\.(A_log|dt_bias)$", r"linear_attn.\1"),
+    (r"linear_attn\.norm\.weight$", r"linear_attn.norm"),
+    (r"self_attn\.(q|k|v|o)_proj\.weight$", r"self_attn.\1_proj.kernel"),
+    (r"self_attn\.(q|k)_norm\.weight$", r"self_attn.\1_norm.weight"),
+    (r"(input|post_attention)_layernorm\.weight$", r"\1_layernorm.weight"),
+    (r"mlp\.gate\.weight$", r"mlp.gate.kernel"),
+    (r"mlp\.experts_stacked\.(gate|up|down)_proj$", r"mlp.experts_\1_proj"),
+    (r"mlp\.shared_expert\.(gate|up|down)_proj\.weight$", r"mlp.shared_expert.\1_proj.kernel"),
+    (r"mlp\.shared_expert_gate\.weight$", r"mlp.shared_expert_gate.kernel"),
+]
+_QWEN3_NEXT_TOP = {"model.embed_tokens.weight": "params.embed_tokens.embedding",
+                   "model.norm.weight": "params.norm.weight",
+                   "lm_head.weight": "params.lm_head.kernel"}
+
+
+def hf_qwen3_next_key_map(name: str) -> Optional[str]:
+    """HF ``qwen3_next`` ``state_dict`` name -> ``Qwen3NextForCausalLM``'s param
+    path (the whole model: a share's slices are the caller's to cut); None
+    for rotary buffers and the multi-token-prediction module."""
+    if name.endswith("rotary_emb.inv_freq") or name.startswith("mtp."):
+        return None
+    if name in _QWEN3_NEXT_TOP:
+        return _QWEN3_NEXT_TOP[name]
+    m = re.match(r"^model\.layers\.(\d+)\.(.+)$", name)
+    if m:
+        for pattern, template in _QWEN3_NEXT_BLOCK:
+            if re.match(pattern, m.group(2)):
+                return f"params.layers_{m.group(1)}." + re.sub(pattern, template, m.group(2))
+    return name  # unknown names pass through and surface as `unexpected`
+
+
+def load_hf_qwen3_next(model, checkpoint, *, mesh=None, dtype=None, rng=None,
+                       sample_args=(), strict: bool = True, **kwargs):
+    """Stream an HF-format Qwen3-Next checkpoint into ``Qwen3NextForCausalLM``'s
+    param tree; experts stacked as in :func:`load_hf_mixtral`, the depthwise
+    conv's ``[C, 1, K]`` weight laid out ``[K, C]``."""
+    import jax.numpy as jnp
+
+    from ..big_modeling import load_checkpoint_and_dispatch
+
+    def tensor_map(our_key: str, arr: np.ndarray) -> np.ndarray:
+        if our_key.endswith("linear_attn/conv1d"):
+            return arr[:, 0, :].T
+        return hf_llama_tensor_map(our_key, arr)
+
+    if not sample_args:
+        sample_args = (jnp.ones((1, 8), jnp.int32),)
+    stream = _stack_expert_stream(
+        checkpoint, model.config.num_experts, _K_EXAONE_EXPERT_RE, lambda w: f"{w}_proj",
+        "model.layers.{layer}.mlp.experts_stacked.{proj}")
+    return load_checkpoint_and_dispatch(
+        model, stream, rng=rng, sample_args=sample_args, mesh=mesh, dtype=dtype, strict=strict,
+        key_map=hf_qwen3_next_key_map, tensor_map=tensor_map, **kwargs)
+
+
 # -- BERT (encoder classifier) -----------------------------------------------
 _BERT_RULES: list[tuple[str, str]] = [
     (r"^bert\.embeddings\.word_embeddings\.weight$", r"params.word_embeddings.embedding"),

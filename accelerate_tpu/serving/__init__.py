@@ -40,7 +40,8 @@ The production-inference rebuild of the reference's
 
 **The family protocol** — what :class:`ServingEngine` asks of the model object
 it is handed (``models/llama.py``, ``models/keye_vl2.py``,
-``models/k_exaone.py`` and ``models/joyai_flash.py`` are the four families;
+``models/k_exaone.py``, ``models/joyai_flash.py`` and ``models/qwen3_next.py``
+  are the five families;
 the engine imports none):
 
 - ``init_paged_cache(num_pages, page_size, num_slots, pages_per_slot,
@@ -52,11 +53,20 @@ the engine imports none):
   indexer's keys; or ONE pool of latent rows ``[num_pages, page, width]``
   with no kv-head axis, a row serving as every head's key and value).  A
   *slot-addressed* layer's arrays are ``[num_slots,
-  ...]``, addressed by slot id and outside the allocator (a window layer's
-  ring): its bytes do not grow with the context, and it must stay correct
-  when a slot is handed on, evicted or re-admitted WITHOUT being cleared
-  (the engine clears nothing) — a ring does so by reading a row only inside
-  the owner's window;
+  ...]``, addressed by slot id and outside the allocator: its bytes do not
+  grow with the context, and it must stay correct when a slot is handed on,
+  evicted or re-admitted WITHOUT being cleared (the engine clears nothing).
+  Two disciplines do so, and both are the model's.  A RING (a window
+  layer's last ``window`` rows) is read only inside the owner's window, so a
+  stale row is never seen.  A CUMULATIVE state (a linear-attention layer's
+  recurrent state and conv window, ``models/qwen3_next.py``) is a sum over
+  the whole past that nothing read later can mask: the model starts it from
+  zero wherever a call's first live position is 0 and from the slot's stored
+  state otherwise, and a lane whose ``cache_write_mask`` is off, or a padded
+  position of a prefill bucket, leaves it as it was.  Eviction re-admits from
+  position 0, so recompute rebuilds either; what would need a snapshot of a
+  cumulative state (a prefix-cache hit, a speculative rollback, a page
+  transfer) the family refuses by name;
 - a paged ``__call__(ids, positions=, cache=, cache_write_mask=)`` that
   returns ``(logits, layers)`` or ``(logits, layers, counters)``.  Each
   layer's view in ``cache`` is the layer's arrays plus ``block_tables``

@@ -164,7 +164,10 @@ def verify_serving_invariants(engine) -> list[str]:
     - every layer's state is paged (a ``num_pages`` axis, first or behind
       the kv heads) or slot-addressed
       (``[num_slots, ...]``: a window layer's ring, which needs no clearing
-      and no conservation — a row is read only inside its owner's window);
+      and no conservation — a row is read only inside its owner's window;
+      or a linear-attention layer's recurrent state and conv window, which
+      the model itself starts from zero at position 0 and masked lanes
+      leave alone);
     - slot accounting: ``free_slots`` ∪ occupied == all slots, disjoint;
     - adapter refcounts balance the in-flight census per tenant.
 
@@ -211,7 +214,7 @@ def verify_serving_invariants(engine) -> list[str]:
                                            block_tables))
     # every array of every layer is one of the two kinds of state: paged
     # (a page axis first, or second behind the kv heads: addressed through the
-    # block table) or slot-addressed ([num_slots, ...]: a window layer's ring)
+    # block table) or slot-addressed ([num_slots, ...]: a window layer's ring, a recurrent state)
     for i, layer in enumerate(cache["layers"]):
         for name, arr in layer.items():
             if sched.num_pages not in arr.shape[:2] and arr.shape[0] != sched.num_slots:

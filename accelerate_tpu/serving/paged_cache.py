@@ -183,8 +183,9 @@ def cache_accounting(model, num_pages: int, page_size: int, num_slots: int,
     :func:`kv_pool_accounting` predicts from a configuration and assumes that
     every layer keeps K and V pages of every KV head; this counts what the
     family really builds, so it also holds for a model that keeps some layers'
-    state per slot (a window layer's ring), holds a share of the KV heads, or
-    keeps more than K and V in its pages."""
+    state per slot (a window layer's ring; a linear-attention layer's
+    recurrent state and conv window, whose bytes are the same at any context),
+    holds a share of the KV heads, or keeps more than K and V in its pages."""
     cache = jax.eval_shape(lambda: model.init_paged_cache(
         num_pages, page_size, num_slots, pages_per_slot, kv_dtype=kv_dtype))
     pool = slot_state = paged_layers = 0

@@ -521,3 +521,75 @@ def test_joyai_flash_serving_programs_compile_at_the_cells_shapes(one_chip, monk
     stats = compiled.memory_analysis()
     assert stats.alias_size_in_bytes >= 2 * JOYAI_PAGES * PAGE * 640 * 2     # the pools alias in place
     assert stats.temp_size_in_bytes < 2**30
+
+
+# -- Qwen3-Next's serving programs at the cell's shapes (qwen3-next.serve_assist) -------------
+
+QWEN_SLOTS, QWEN_PAGES, QWEN_PAGES_PER_SLOT = 128, 36864, 288
+
+
+@pytest.mark.parametrize("program,width", [("decode", 1), ("prefill", 512), ("prefill", 2048)])
+def test_qwen3_next_serving_programs_compile_at_the_cells_shapes(one_chip, monkeypatch, program,
+                                                                 width):
+    """The engine's decode and prefill programs of ``models/qwen3_next.py`` at
+    the published widths, the cell's share (4 of 16 heads, 1 of 2 KV heads, 4
+    of 16 Gated DeltaNet key heads and 8 of 32 value heads, 128 of 512
+    experts, 37,984 vocabulary rows) and geometry (128 slots, 36,864 pages of
+    64, 288 a slot), one period deep (linear x 3, full): the recurrent state
+    ``[128, 8, 128, 128]`` float32, the conv windows and the page pools all
+    alias in place; a decode step updates each Gated DeltaNet layer's state
+    inside ONE ``gated_delta_step`` Mosaic kernel (no ``while`` under
+    ``linear_attend``, no copy or relayout of a state) and walks the
+    full-attention layer's pools (head_dim 256) inside one
+    ``paged_walk_decode`` kernel; a prefill chunk runs the chunked form (one
+    scan over the blocks a layer) and gathers blocks of pages; the grouped
+    matmuls are the Mosaic kernel over BLOCKS of held rows."""
+    import re
+
+    from accelerate_tpu.generation import GenerationConfig
+    from accelerate_tpu.models import Qwen3NextConfig, Qwen3NextForCausalLM
+    from accelerate_tpu.ops import gated_delta as gd
+    from accelerate_tpu.ops import latent_attention as la
+    from accelerate_tpu.serving.engine import fresh_engine_jits
+
+    monkeypatch.setattr(la, "_on_tpu", lambda: True)   # the kernels, not their interpreter
+    monkeypatch.setattr(gd, "_on_tpu", lambda: True)
+    model = Qwen3NextForCausalLM(Qwen3NextConfig(
+        num_hidden_layers=4, experts_held=tuple(range(128)), attention_heads_held=4,
+        key_value_heads_held=1, linear_key_heads_held=4, linear_value_heads_held=8,
+        vocab_held=37984))
+    on_chip = lambda tree, dtype=None: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, dtype or x.dtype, sharding=one_chip), tree)
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))), BF16)
+    cache = on_chip(jax.eval_shape(
+        lambda: model.init_paged_cache(QWEN_PAGES, PAGE, QWEN_SLOTS, QWEN_PAGES_PER_SLOT)))
+    gen = GenerationConfig(max_new_tokens=2048, do_sample=False, eos_token_id=None)
+    decode, prefill, *_ = fresh_engine_jits(model, gen, PAGE)
+    arg = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    if program == "decode":
+        lowered = decode.lower(params, cache, arg((QWEN_SLOTS,)), arg((QWEN_SLOTS,), jnp.bool_),
+                               arg((2,), jnp.uint32))
+    else:
+        lowered = prefill.lower(params, cache, arg(()), arg((width,)), arg(()), arg(()))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("ragged-dot") >= 12 and "tpu_custom_call" in text   # gate, up, down x 4 layers
+    rows = {1: 512, 512: 1664, 2048: 6400}[width]    # held_row_block: a quarter over a quarter of the pairs
+    assert re.search(rf"%ragged-dot\S* = \S*\[{rows},", text)               # a block of held rows
+    assert not re.search(rf"\[{max(width, QWEN_SLOTS) * 10},2048\]", text)  # never all N x k rows
+    steps = re.findall(r"%gated_delta_step\S* = ", text)
+    walks = re.findall(r"%paged_walk_decode\S* = bf16\[128,4,256\]\S* custom-call\(", text)
+    assert (len(steps), len(walks)) == ((3, 1) if program == "decode" else (0, 0))
+    assert "linear_attend/while" not in text                     # the step: one kernel, no loop around it
+    assert ("linear_chunk/while" in text) == (program == "prefill")    # the chunk: a scan over its blocks
+    assert ("global_attend/while" in text) == (program == "prefill")   # the XLA walk: the chunk's alone
+    # no state- or pool-shaped relayout: a copy, or a transpose that permutes anything
+    moved = r"(copy\(|transpose\([^)]*\), dimensions=\{(?!0,1,2(,3)?\}))"
+    assert re.findall(rf"= f32\[{QWEN_SLOTS},8,128,128\]\S* {moved}", text) == []
+    assert re.findall(rf"= bf16\[{QWEN_PAGES},64,256\]\S* {moved}", text) == []
+    stats = compiled.memory_analysis()
+    pools = 2 * QWEN_PAGES * PAGE * 256 * 2
+    state, conv = 3 * QWEN_SLOTS * 8 * 128 * 128 * 4, 3 * QWEN_SLOTS * 3 * 2048 * 2
+    assert stats.alias_size_in_bytes >= pools + state + conv     # all three kinds alias in place
+    assert stats.temp_size_in_bytes < 2**30
