@@ -984,12 +984,18 @@ def make_llama_loss_fn(model: LlamaForCausalLM, fused_vocab_chunks: Optional[int
     """Loss factory.  With ``fused_vocab_chunks`` set, the vocab projection
     moves inside a chunked fused linear+CE (ops/fused_xent.py) so the
     [B, T, V] logits tensor is never materialized — the activation-memory
-    headroom this frees typically pays for a cheaper remat policy.  Under a
-    mesh whose ``tp`` axis is wider than one and divides the vocabulary (the
-    TP plan shards ``lm_head/kernel`` and the tied embedding along it), each
-    ``tp`` shard reduces its own slice of the vocabulary: three [N] fp32
-    vectors and one fp32 [N, H] ``dh`` cross ``tp``, never the logits; any
-    other mesh runs the single-device chunk loop under GSPMD as before."""
+    headroom this frees typically pays for a cheaper remat policy.  The
+    chunks are that many pieces of the SEQUENCE, each against the whole
+    vocabulary a device holds: one loop builds a chunk's logits once and,
+    under ``jax.grad``, its ``dh`` rows and its term of ``dw`` from them in
+    the same pass (three matmuls a chunk; the backward pass only scales).
+    Under a mesh whose ``tp`` axis is wider than one and divides the
+    vocabulary (the TP plan shards ``lm_head/kernel`` and the tied embedding
+    along it), each ``tp`` shard reduces its own slice of the vocabulary:
+    three [N / chunks] fp32 vectors a chunk and one fp32 [N, H] ``dh`` cross
+    ``tp``, never the logits; any other mesh runs the bare loop under GSPMD.
+    Either way the head is gathered over the FSDP axes once and ``dw``
+    reduced over them once, after the loop."""
     if fused_vocab_chunks is None:
         def loss_fn(params, batch):
             logits = model.apply(params, batch["input_ids"], segment_ids=batch.get("segment_ids"))

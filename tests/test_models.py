@@ -290,8 +290,9 @@ def test_fused_linear_xent_matches_logits_path(tied_cases):
 
 @pytest.mark.slow
 def test_fused_linear_xent_non_divisible_vocab():
-    """Vocab not divisible by num_chunks (clamped-slice regression): loss and
-    grads must still match the reference exactly."""
+    """Neither the vocabulary (10 columns) nor the rows (6 positions in 4 or 7
+    chunks: a padded, masked tail) a multiple of anything: loss and grads
+    must still match the reference exactly."""
     from accelerate_tpu.ops.fused_xent import fused_linear_xent
 
     rng = np.random.default_rng(1)
@@ -309,8 +310,8 @@ def test_fused_linear_xent_non_divisible_vocab():
 
     l_r, g_r = jax.value_and_grad(ref, argnums=(0, 1))(h, w)
     for nc in (3, 4, 7):
-        l_f, g_f = jax.value_and_grad(
-            lambda h, w: fused_linear_xent(h, w, labels, mask, nc, True), argnums=(0, 1)
+        l_f, g_f = jax.value_and_grad(   # one sequence of N positions
+            lambda h, w: fused_linear_xent(h[None], w, labels[None], mask[None], nc, True), argnums=(0, 1)
         )(h, w)
         assert abs(float(l_f) - float(l_r)) < 1e-5, (nc, float(l_f), float(l_r))
         np.testing.assert_allclose(np.asarray(g_f[0]), np.asarray(g_r[0]), atol=1e-5)

@@ -600,7 +600,8 @@ def test_engine_track_spans_partition_every_tick():
     assert rep["evictions"] > 0
     by_step: dict = {}
     for name, start, end, args in _spans(tracer, "engine"):
-        by_step.setdefault(args["step"], []).append((start, end, name))
+        if name != "gc":    # a collector pause over 1 ms is laid behind its tick and carries no step
+            by_step.setdefault(args["step"], []).append((start, end, name))
     assert len(by_step) == rep["engine_steps"]
     seen = set()
     for step, spans in by_step.items():
