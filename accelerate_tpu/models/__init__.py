@@ -15,6 +15,11 @@ where it is scored and ``v_head_dim`` where it is summed.
 value head and sequence, to every gated-attention layer of ``head_dim`` 256)
 carries ``head_dim`` for the attention layers and ``linear_key_head_dim`` /
 ``linear_value_head_dim`` for the others.
+``OlmoHybridConfig`` (the same 3 : 1 interleave in a dense, post-norm decoder:
+Gated DeltaNet heads of 96 x 192 with a write strength up to 2, full attention
+with no rotary and QK-norm over all channels) DERIVES ``head_dim`` as Llama's
+does, and is the one family whose linear-attention layers TRAIN
+(``make_olmo_hybrid_loss_fn``; it is not served).
 ``docs/supported_models.md`` has the table of what each family trains,
 serves and refuses."""
 
@@ -27,6 +32,7 @@ from .hf_interop import (
     hf_llama_key_map,
     hf_llama_tensor_map,
     hf_mixtral_key_map,
+    hf_olmo_hybrid_key_map,
     hf_qwen3_next_key_map,
     hf_t5_key_map,
     load_hf_bert,
@@ -35,6 +41,7 @@ from .hf_interop import (
     load_hf_keye_vl2,
     load_hf_llama,
     load_hf_mixtral,
+    load_hf_olmo_hybrid,
     load_hf_qwen3_next,
     load_hf_t5,
 )
@@ -55,6 +62,7 @@ from .mixtral import (
     count_active_params,
     make_mixtral_loss_fn,
 )
+from .olmo_hybrid import OlmoHybridConfig, OlmoHybridForCausalLM, make_olmo_hybrid_loss_fn
 from .qwen3_next import Qwen3NextConfig, Qwen3NextForCausalLM
 from .resnet import ResNet, ResNetConfig, make_resnet_loss_fn
 from .t5 import T5Config, T5ForConditionalGeneration, make_t5_loss_fn

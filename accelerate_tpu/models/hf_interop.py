@@ -452,6 +452,65 @@ def load_hf_qwen3_next(model, checkpoint, *, mesh=None, dtype=None, rng=None,
         key_map=hf_qwen3_next_key_map, tensor_map=tensor_map, **kwargs)
 
 
+# -- Olmo-Hybrid (Gated DeltaNet + full attention, post-norm, dense MLP) ----------------
+# The names are ASSUMED (the checkpoint was not read): the OLMo 2 / OLMo 3
+# modelling code's for what that family has (``self_attn.{q,k,v,o}_proj``,
+# full-width ``q_norm`` / ``k_norm``, ``post_attention_layernorm`` and
+# ``post_feedforward_layernorm`` on the sublayers' outputs, ``mlp``) and the
+# published Gated DeltaNet layer's for the rest (``linear_attn.{q,k,v,a,b,g,o}_proj``,
+# one depthwise conv each for q, k and v, ``A_log``, ``dt_bias``, ``o_norm``).
+# A conv's ``[C, 1, K]`` weight becomes ``[K, C]``; rotary buffers have no
+# counterpart (``rope_theta: null``).
+_OLMO_HYBRID_BLOCK: list[tuple[str, str]] = [
+    (r"linear_attn\.(q|k|v|a|b|g|o)_proj\.weight$", r"linear_attn.\1_proj.kernel"),
+    (r"linear_attn\.(q|k|v)_conv1d\.weight$", r"linear_attn.\1_conv1d"),
+    (r"linear_attn\.(A_log|dt_bias)$", r"linear_attn.\1"),
+    (r"linear_attn\.o_norm\.weight$", r"linear_attn.o_norm.scale"),
+    (r"self_attn\.(q|k|v|o)_proj\.weight$", r"self_attn.\1_proj.kernel"),
+    (r"self_attn\.(q|k)_norm\.weight$", r"self_attn.\1_norm.scale"),
+    (r"(post_attention|post_feedforward)_layernorm\.weight$", r"\1_layernorm.scale"),
+    (r"mlp\.(gate|up|down)_proj\.weight$", r"mlp.\1_proj.kernel"),
+]
+_OLMO_HYBRID_TOP = {"model.embed_tokens.weight": "params.embed_tokens.embedding",
+                    "model.norm.weight": "params.norm.scale",
+                    "lm_head.weight": "params.lm_head.kernel"}
+
+
+def hf_olmo_hybrid_key_map(name: str) -> Optional[str]:
+    """HF ``olmo_hybrid`` ``state_dict`` name -> ``OlmoHybridForCausalLM``'s
+    param path; None for rotary buffers."""
+    if name.endswith("rotary_emb.inv_freq"):
+        return None
+    if name in _OLMO_HYBRID_TOP:
+        return _OLMO_HYBRID_TOP[name]
+    m = re.match(r"^model\.layers\.(\d+)\.(.+)$", name)
+    if m:
+        for pattern, template in _OLMO_HYBRID_BLOCK:
+            if re.match(pattern, m.group(2)):
+                return f"params.layers_{m.group(1)}." + re.sub(pattern, template, m.group(2))
+    return name  # unknown names pass through and surface as `unexpected`
+
+
+def load_hf_olmo_hybrid(model, checkpoint, *, mesh=None, dtype=None, rng=None,
+                        sample_args=(), strict: bool = True, **kwargs):
+    """Stream an HF-format Olmo-Hybrid checkpoint into ``OlmoHybridForCausalLM``'s
+    param tree; a depthwise conv's ``[C, 1, K]`` weight laid out ``[K, C]``."""
+    import jax.numpy as jnp
+
+    from ..big_modeling import load_checkpoint_and_dispatch
+
+    def tensor_map(our_key: str, arr: np.ndarray) -> np.ndarray:
+        if our_key.endswith("_conv1d"):
+            return arr[:, 0, :].T
+        return hf_llama_tensor_map(our_key, arr)
+
+    if not sample_args:
+        sample_args = (jnp.ones((1, 8), jnp.int32),)
+    return load_checkpoint_and_dispatch(
+        model, checkpoint, rng=rng, sample_args=sample_args, mesh=mesh, dtype=dtype, strict=strict,
+        key_map=hf_olmo_hybrid_key_map, tensor_map=tensor_map, **kwargs)
+
+
 # -- BERT (encoder classifier) -----------------------------------------------
 _BERT_RULES: list[tuple[str, str]] = [
     (r"^bert\.embeddings\.word_embeddings\.weight$", r"params.word_embeddings.embedding"),

@@ -32,13 +32,17 @@ Two forms of the one recurrence:
       V' = U - W S;   O = (Q * exp(gamma)) S + (Q K^T * D, lower with diagonal) V'
       S <- exp(gamma_C) S + (K * exp(gamma_C - gamma))^T V'
 
-  ``A`` is nilpotent (``A^C = 0``), so ``T = (I + A)(I + A^2)(I + A^4) ..
-  (I + A^(C/2))``: ten small matmuls for every block at once in place of 63
-  dependent rows of a forward substitution.  Everything a block needs but
-  ``S`` is computed for all blocks at once; the state is then carried block to
-  block by one ``scan`` (and handed to the next chunk by the caller).  Plain
-  XLA, float32 at the highest matmul precision: these products are ~4% of a
-  chunk's operations and their sum runs over thousands of tokens.
+  ``T`` is built by halves (the inverse of a block-triangular matrix from its
+  two diagonal blocks' inverses): twelve small matmuls for every block at once
+  in place of 63 dependent rows of a forward substitution, and as stable as
+  one.  Everything a block needs but ``S`` is computed for all blocks at once;
+  the state is then carried block to block by one ``scan`` (and handed to the
+  next chunk by the caller).  Plain XLA, float32 at the highest matmul
+  precision: these products are ~4% of a chunk's operations and their sum
+  runs over thousands of tokens.  Differentiable (the training path,
+  ``models/olmo_hybrid.py``): the backward pass keeps the inputs and ONE state
+  a block, makes each block's ``A``, ``T``, ``U``, ``W`` again and walks the
+  blocks backwards.
 
 The short causal convolution in front of the rule (depthwise, ``K`` taps, the
 last ``K - 1`` input rows kept per sequence) is here too:
@@ -59,6 +63,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .flash_attention import _on_tpu
 
 BLOCK = 64               # tokens of one block of the chunked form
+SEGMENT = 32             # blocks whose parts are made at once: a longer sequence goes a segment at a time
 
 
 def l2norm(x, eps: float = 1e-6):
@@ -100,32 +105,34 @@ def causal_conv_chunk(x, window, weight, length):
 
 def _inverse_of_one_minus(a):
     """``(I - a)^-1`` of strictly lower-triangular ``a`` [..., C, C], ``C`` a
-    power of two: the product of ``I + a^(2^j)``."""
+    power of two, by halves: the inverse of ``[[P, 0], [-R, Q]]`` is ``[[P^-1,
+    0], [Q^-1 R P^-1, Q^-1]]``, from diagonal blocks of one element up.  With
+    ``inv`` the block-diagonal matrix of the blocks' inverses and ``r`` the
+    lower-left quarters of ``a`` between the blocks of a pair, one level is
+    ``inv + inv r inv`` on whole ``[C, C]`` matrices (the zeros cost less than
+    matmuls of 2 x 2).  Every intermediate is a diagonal block of the result
+    itself, so nothing larger than the result is ever summed (the product
+    ``(I + a)(I + a^2)(I + a^4)..`` is the same matrix, but its terms reach
+    ``|a|^k C(C, k)`` and cancel: with keys a quarter aligned and ``beta``
+    near 2 it lost every digit)."""
     c = a.shape[-1]
     mm = functools.partial(jnp.matmul, precision=lax.Precision.HIGHEST)
-    inv, power = jnp.eye(c, dtype=a.dtype) + a, a
-    for _ in range(int(np.log2(c)) - 1):
-        power = mm(power, power)
-        inv = inv + mm(inv, power)
+    at = np.arange(c)
+    inv, m = jnp.broadcast_to(jnp.eye(c, dtype=a.dtype), a.shape), 1
+    while m < c:
+        same_pair = at[:, None] // (2 * m) == at[None, :] // (2 * m)
+        lower_left = same_pair & (at[:, None] % (2 * m) >= m) & (at[None, :] % (2 * m) < m)
+        inv = inv + mm(mm(inv, jnp.where(lower_left, a, 0.0)), inv)
+        m *= 2
     return inv
 
 
-@jax.named_scope("linear_chunk")
-def gated_delta_chunk(q, k, v, g, beta, state):
-    """``q``, ``k`` [T, Hv, Dk], ``v`` [T, Hv, Dv], ``g``, ``beta`` [T, Hv]
-    (all float32; ``beta = 0`` and ``g = 0`` at a position that is not live)
-    from ``state`` [Hv, Dk, Dv].  Returns ``(o [T, Hv, Dv], the state behind
-    the last position)``, float32."""
-    t, hv, dk = q.shape
-    dv = v.shape[-1]
-    block = BLOCK
-    pad = -t % block
-    if pad:     # positions that change nothing
-        widen = lambda a: jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
-        q, k, v, g, beta = (widen(a) for a in (q, k, v, g, beta))
-    n = (t + pad) // block
-    heads_first = lambda a: jnp.moveaxis(a.reshape((n, block) + a.shape[1:]), 2, 0)  # [Hv, n, C, ..]
-    q, k, v, g, beta = (heads_first(a.astype(jnp.float32)) for a in (q, k, v, g, beta))
+def _block_parts(q, k, v, g, beta):
+    """What a block needs but ``S``, for every block at once.  ``q``, ``k``
+    [Hv, n, C, Dk], ``v`` [Hv, n, C, Dv], ``g``, ``beta`` [Hv, n, C] ->
+    ``(U, W, Q K^T * D, Q * exp(gamma), K * exp(gamma_C - gamma),
+    exp(gamma_C))``, blocks first ([n, Hv, ...]: what the scan walks)."""
+    block = q.shape[2]
     mm = functools.partial(jnp.einsum, precision=lax.Precision.HIGHEST)
     gamma = jnp.cumsum(g, axis=-1)                                  # [Hv, n, C]
     at = np.arange(block)
@@ -140,16 +147,103 @@ def gated_delta_chunk(q, k, v, g, beta, state):
     q_in = q * jnp.exp(gamma)[..., None]
     k_out = k * jnp.exp(gamma[..., -1:] - gamma)[..., None]
     last = jnp.exp(gamma[..., -1])                                  # [Hv, n]
+    return tuple(jnp.moveaxis(x, 1, 0) for x in (u, w, within, q_in, k_out, last))
 
-    def one_block(s, xs):
-        u_i, w_i, within_i, q_i, k_i, last_i = xs
-        v_new = u_i - mm("hid,hde->hie", w_i, s)
-        o = mm("hid,hde->hie", q_i, s) + mm("hij,hje->hie", within_i, v_new)
-        return s * last_i[:, None, None] + mm("hid,hie->hde", k_i, v_new), o
 
-    blocks_first = lambda x: jnp.moveaxis(x, 1, 0)
-    state, o = lax.scan(one_block, state.astype(jnp.float32),
-                        tuple(blocks_first(x) for x in (u, w, within, q_in, k_out, last)))
+def _one_block(s, xs):
+    """The state ``s`` [Hv, Dk, Dv] through one block: ``(the state behind it, O [Hv, C, Dv])``."""
+    mm = functools.partial(jnp.einsum, precision=lax.Precision.HIGHEST)
+    u_i, w_i, within_i, q_i, k_i, last_i = xs
+    v_new = u_i - mm("hid,hde->hie", w_i, s)
+    o = mm("hid,hde->hie", q_i, s) + mm("hij,hje->hie", within_i, v_new)
+    return s * last_i[:, None, None] + mm("hid,hie->hde", k_i, v_new), o
+
+
+@jax.custom_vjp
+def _blocks(q, k, v, g, beta, state):
+    """The blocked rule, ``_block_parts``' shapes: ``(the last state, O [n, Hv, C, Dv])``."""
+    return lax.scan(_one_block, state, _block_parts(q, k, v, g, beta))
+
+
+def _blocks_fwd(q, k, v, g, beta, state):
+    """What the backward pass keeps: the inputs and the state each block STARTED from."""
+    def keeping(s, xs):
+        behind, o = _one_block(s, xs)
+        return behind, (o, s)
+
+    last, (o, starts) = lax.scan(keeping, state, _block_parts(q, k, v, g, beta))
+    return (last, o), (q, k, v, g, beta, starts)
+
+
+@jax.named_scope("linear_chunk")
+def _blocks_bwd(kept, cotangents):
+    """Every block's parts made again from the inputs (the triangular inverse
+    included), the blocks walked backwards from the stored starts, then the
+    parts' own gradient: nothing of ``[Hv, n, C, C]`` outlives this call."""
+    *inputs, starts = kept
+    d_last, d_o = cotangents
+    parts, pull_parts = jax.vjp(_block_parts, *inputs)
+
+    def one_back(d_s, xs):
+        s, xs_i, d_o_i = xs
+        _, pull = jax.vjp(_one_block, s, xs_i)
+        return pull((d_s, d_o_i))
+
+    d_state, d_parts = lax.scan(one_back, d_last, (starts, parts, d_o), reverse=True)
+    return pull_parts(d_parts) + (d_state,)
+
+
+_blocks.defvjp(_blocks_fwd, _blocks_bwd)
+
+
+@jax.named_scope("linear_chunk")
+def gated_delta_chunk(q, k, v, g, beta, state):
+    """``q``, ``k`` [T, Hv, Dk], ``v`` [T, Hv, Dv], ``g``, ``beta`` [T, Hv]
+    (all float32; ``beta = 0`` and ``g = 0`` at a position that is not live)
+    from ``state`` [Hv, Dk, Dv].  Returns ``(o [T, Hv, Dv], the state behind
+    the last position)``, float32.  With a leading batch axis on all six
+    ([B, T, Hv, ..], [B, Hv, Dk, Dv]) every row is a sequence of its own.  More
+    than ``SEGMENT`` blocks (a training row; a prefill chunk is one segment)
+    go a segment at a time, the state handed on (the blocks behind the last
+    whole segment as one shorter call: no row is padded past its last block):
+    what is live of ``[Hv, n, C, C]`` is a segment's, forwards and backwards.
+
+    Differentiable in all six (the training path, ``models/olmo_hybrid.py``),
+    with a backward pass that keeps the inputs and one state a block
+    (``_blocks_bwd``).  ``Dk`` and ``Dv`` may differ, and ``beta`` may reach 2
+    (a transition ``I - beta k k^T`` with a negative eigenvalue)."""
+    if q.ndim == 4:             # rows become heads: the rule knows no other axis
+        rows, hv = q.shape[0], q.shape[2]
+        fold = lambda a: jnp.moveaxis(a, 0, 1).reshape((a.shape[1], rows * hv) + a.shape[3:])
+        o, last = _chunk(*(fold(a) for a in (q, k, v, g, beta)),
+                         state.reshape((rows * hv,) + state.shape[2:]))
+        o = jnp.moveaxis(o.reshape((o.shape[0], rows, hv, o.shape[-1])), 1, 0)
+        return o, last.reshape(state.shape)
+    return _chunk(q, k, v, g, beta, state)
+
+
+def _chunk(q, k, v, g, beta, state):
+    """:func:`gated_delta_chunk` of one sequence (or of rows folded into its heads)."""
+    t, hv, dk = q.shape
+    dv = v.shape[-1]
+    block = BLOCK
+    pad = -t % block
+    if pad:     # positions that change nothing
+        widen = lambda a: jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+        q, k, v, g, beta = (widen(a) for a in (q, k, v, g, beta))
+    n = (t + pad) // block
+    heads_first = lambda a: jnp.moveaxis(a.reshape((n, block) + a.shape[1:]), 2, 0)  # [Hv, n, C, ..]
+    parts = tuple(heads_first(a.astype(jnp.float32)) for a in (q, k, v, g, beta))
+    state, outs = state.astype(jnp.float32), []
+    whole = n - n % SEGMENT if n > SEGMENT else 0
+    if whole:   # a segment's parts (and, backwards, their gradient) at a time
+        cut = lambda a: jnp.moveaxis(a[:, :whole].reshape((hv, whole // SEGMENT, SEGMENT) + a.shape[2:]), 1, 0)
+        state, o = lax.scan(lambda s, xs: _blocks(*xs, s), state, tuple(cut(a) for a in parts))
+        outs.append(o.reshape((whole,) + o.shape[2:]))
+    if n > whole:   # the blocks behind the last whole segment (all of them, up to one segment)
+        state, o = _blocks(*(parts if not whole else (a[:, whole:] for a in parts)), state)
+        outs.append(o)
+    o = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
     o = jnp.moveaxis(o, 1, 0).reshape(hv, n * block, dv)            # [n, Hv, C, Dv] -> [Hv, T, Dv]
     return jnp.moveaxis(o, 0, 1)[:t], state
 

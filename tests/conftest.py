@@ -50,6 +50,12 @@ STALE_POSITIONAL = {
         "PR 40 appended qwen3-next.serve_assist behind them",
     "perfbench_suite/test_host_ledger_metrics.py::test_the_benchmark_lists_the_eight_as_the_issue_gives_them":
         "asserts per_layer[-8:] are PR 38's eight metrics; PR 40 appended its twenty behind them",
+    # PR 42: the same kind of clause in PR 40's own test.  The driver refuses an entry put in
+    # the middle of a list, and refused this PR for rewording the clause (BENCHMARK_REFUSED.md);
+    # tests/perfbench_suite/test_olmo_hybrid_cell.py::test_the_metrics_before_this_cells_keep_their_entries
+    # asserts all the rest of it (the eight together, in order, their keys, the twenty right behind).
+    "perfbench_suite/test_qwen3_next_cell.py::test_the_host_ledgers_eight_metrics_keep_their_entries":
+        "asserts that only .assist names follow PR 38's eight in per_layer; PR 42 appended its six behind them",
 }
 
 

@@ -1,0 +1,336 @@
+"""Olmo-Hybrid on the training path (``models/olmo_hybrid.py``, the
+differentiable chunked rule of ``ops/gated_delta.py``) against the plain
+reference (``perfbench/reference/olmo_hybrid.py``: float32, the rule as the
+TOKEN recurrence), on seeded weights at tiny widths that keep ``Dk != Dv``,
+one period ``LLLG``.  Each tolerance is written with its reason."""
+
+import dataclasses
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+
+from accelerate_tpu.models import (OlmoHybridConfig, OlmoHybridForCausalLM,  # noqa: E402
+                                   hf_olmo_hybrid_key_map, load_hf_olmo_hybrid, make_olmo_hybrid_loss_fn)
+from accelerate_tpu.ops import gated_delta as gd  # noqa: E402
+from perfbench.families import olmo_hybrid as family  # noqa: E402
+from perfbench.reference import olmo_hybrid as reference  # noqa: E402
+from perfbench.weights import make_weights  # noqa: E402
+
+LAYERS = 4
+BASE = dict(
+    vocab_size=256, hidden_size=64, intermediate_size=96, num_hidden_layers=LAYERS,
+    num_attention_heads=2, num_key_value_heads=2,
+    layer_types=["linear_attention"] * 3 + ["full_attention"], linear_num_key_heads=4,
+    linear_num_value_heads=4, linear_key_head_dim=8, linear_value_head_dim=16,
+    linear_conv_kernel_dim=4, linear_allow_neg_eigval=True, max_position_embeddings=512,
+    rms_norm_eps=1e-6, tie_word_embeddings=False, rope_parameters={"rope_theta": None},
+    assumed={"weight_scales": {"conv": 1.0, "A_log": 1.0, "A_log_mean": -4.0, "dt_bias": 0.5}})
+f32 = lambda tree: jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), tree)
+
+
+def _ids(seed, batch, seq, vocab=BASE["vocab_size"]):
+    return np.asarray(jax.random.randint(jax.random.key(seed), (batch, seq), 0, vocab), np.int32)
+
+
+def _float32_pair(cfg=BASE, seed=5, remat=False):
+    """The float32 model with its params, and the float32 weights the reference reads."""
+    weights = f32(make_weights(family.weight_shapes(cfg, LAYERS), seed))
+    model = family.build_model(cfg, LAYERS, remat=remat)
+    model = OlmoHybridForCausalLM(dataclasses.replace(model.config, dtype=jnp.float32))
+    return model, family.to_program(weights, cfg), weights
+
+
+def _reference_loss(weights, cfg, ids):
+    """The reference's loss as a function of the flat weight dict (``A_log`` with its mean)."""
+    c = dict(reference.cfg_key(cfg))
+    x = weights["embed"][ids]
+    for i, kind in enumerate(reference.kinds(cfg, LAYERS)):
+        x = reference.block(x, {k: weights[f"layers.{i}.{k}"] for k in reference.KEYS[kind]},
+                            reference.NO_FAULT, kind, c)
+    return reference.head_loss(x, weights["final_norm"], weights["head"], jnp.asarray(ids), c)
+
+
+# -- 1. the forward --------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("seq", [100, 64])
+def test_the_float32_models_logits_are_the_references(seq):
+    """Both sides float32; the program runs the chunked form (blocks of 64, a
+    padded block at 100 positions) and the flash kernel, the reference the
+    token recurrence and a softmax: 2e-4 of logits of size ~4 is their
+    summation orders (read: 4e-5)."""
+    model, params, weights = _float32_pair()
+    ids = _ids(1, 2, seq)
+    want = reference.forward_logits(weights, BASE, LAYERS, ids)
+    np.testing.assert_allclose(model.apply(params, ids), want, atol=2e-4, rtol=0)
+
+
+def test_the_bf16_models_logits_follow_the_references():
+    """bf16 weights and matmul operands at hidden 64: a logit moves by up to
+    ~0.3 of ~4 (read: 0.23-0.31 over seeds 5-7); 0.6 is no rounding - a
+    planted fault of ``reference.FAULTS`` moves one by 2.5-6."""
+    weights = make_weights(family.weight_shapes(BASE, LAYERS), 5)
+    ids = _ids(1, 2, 100)
+    got = family.build_model(BASE, LAYERS).apply(family.to_program(weights, BASE), ids)
+    want = reference.forward_logits(weights, BASE, LAYERS, ids)
+    assert float(jnp.max(jnp.abs(got - want))) < 0.6
+    for fault in reference.FAULTS:
+        faulty = reference.forward_logits(weights, BASE, LAYERS, ids, quant=fault)
+        assert float(jnp.max(jnp.abs(faulty - want))) > 1.0, fault
+
+
+def test_a_packed_row_and_a_rotary_are_refused():
+    model, params, _ = _float32_pair()
+    with pytest.raises(NotImplementedError, match="one document a row"):
+        model.apply(params, _ids(1, 1, 8), segment_ids=jnp.zeros((1, 8), jnp.int32))
+    with pytest.raises(NotImplementedError, match="rope_theta"):
+        OlmoHybridConfig.tiny(rope_theta=10000.0)
+    with pytest.raises(ValueError, match="layer_types"):
+        OlmoHybridConfig.tiny(layer_types=("linear_attention",))
+    assert OlmoHybridConfig.tiny().kinds == ("linear_attention",) * 3 + ("full_attention",)
+    assert OlmoHybridConfig.olmo_hybrid_7b().kinds.count("full_attention") == 8
+
+
+# -- 2. the loss and every leaf's gradient ------------------------------------------------
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_the_loss_and_every_leafs_gradient_are_the_references(remat):
+    """Float32 on both sides, the fused CE in two chunks against the
+    reference's plain log-softmax, ``jax.grad`` through the chunked rule's
+    own backward pass against ``jax.grad`` of the token recurrence.  A leaf's
+    gradient agrees to 2e-4 of its largest element (read: up to 3e-5; the
+    leaves include ``A_log``, ``dt_bias``, the convs' taps and the ``b`` / ``a``
+    projections, which only the rule's backward reaches)."""
+    model, params, weights = _float32_pair(remat=remat)
+    ids = _ids(2, 2, 100)
+    batch = {"input_ids": jnp.asarray(ids), "labels": jnp.asarray(ids)}
+    loss, grads = jax.value_and_grad(make_olmo_hybrid_loss_fn(model, fused_vocab_chunks=2))(params, batch)
+    seeded = reference.seeded(weights, BASE)
+    want_loss, want = jax.value_and_grad(_reference_loss)(seeded, BASE, ids)
+    assert abs(float(loss) - float(want_loss)) < 1e-5
+    got = family.from_program(grads)
+    assert sorted(got) == sorted(want) and len(got) == 3 * 15 + 11 + 3
+    for name in want:
+        scale = float(jnp.max(jnp.abs(want[name])))
+        assert scale > 0, name
+        np.testing.assert_allclose(got[name], want[name], atol=2e-4 * scale, rtol=0, err_msg=name)
+
+
+# -- 3. the rule's own gradients ---------------------------------------------------------------
+
+
+def _recurrence(q, k, v, g, beta, state):
+    def token(s, xs):
+        q_t, k_t, v_t, g_t, beta_t = xs
+        s = s * jnp.exp(g_t)[:, None, None]
+        written = beta_t[:, None] * (v_t - jnp.einsum("hkv,hk->hv", s, k_t, precision="highest"))
+        s = s + k_t[:, :, None] * written[:, None, :]
+        return s, jnp.einsum("hkv,hk->hv", s, q_t, precision="highest")
+
+    state, o = jax.lax.scan(token, state, (q, k, v, g, beta))
+    return o, state
+
+
+def _rule_inputs(seed, t, heads, dk, dv, neg_eigval, aligned=0.0):
+    """q, k normed as the model norms them; ``aligned`` mixes a common
+    direction into every key (silu's positive mean does that in the model),
+    which is what made the product form of the inverse lose its digits."""
+    keys = jax.random.split(jax.random.key(seed), 7)
+    common = jax.random.normal(keys[6], (heads, dk))
+    q = gd.l2norm(jax.random.normal(keys[0], (t, heads, dk))) / np.sqrt(dk)
+    k = gd.l2norm(jax.random.normal(keys[1], (t, heads, dk)) + aligned * common)
+    v = jax.random.normal(keys[2], (t, heads, dv))
+    g = -jnp.exp(-4 + jax.random.normal(keys[3], (heads,))) * jax.nn.softplus(
+        jax.random.normal(keys[4], (t, heads)))
+    write = jax.nn.sigmoid(jax.random.normal(keys[5], (t, heads)))
+    beta = 1.0 + write if neg_eigval else write           # (1, 2): every transition has a negative eigenvalue
+    return q, k, v, g, beta, 0.3 * jax.random.normal(keys[6], (heads, dk, dv))
+
+
+@pytest.mark.parametrize("t,aligned,neg_eigval", [
+    (64, 0.0, True), (100, 0.0, True), (100, 0.0, False), (192, 1.0, True), (192, 1.0, False),
+    (64 * (gd.SEGMENT + 2) + 5, 0.5, True)])
+def test_the_chunked_rules_gradients_are_the_token_recurrences(t, aligned, neg_eigval):
+    """o, the last state and the gradients of q, k, v, g, beta and the initial
+    state, at lengths that are and are not multiples of 64 and past one
+    segment, with ``beta`` in (1, 2) and in (0, 1), ``Dk != Dv``, keys up to
+    half aligned: 2e-5 of the largest element, float32 summation orders
+    (read: up to 3e-6)."""
+    args = _rule_inputs(t, t, 2, 8, 12, neg_eigval, aligned)
+    w_o = jax.random.normal(jax.random.key(1), (t, 2, 12))
+    w_s = jax.random.normal(jax.random.key(2), (2, 8, 12))
+    scalar = lambda f: lambda *a: (lambda o, s: jnp.sum(o * w_o) + jnp.sum(s * w_s))(*f(*a))
+    for got, want in zip(gd.gated_delta_chunk(*args), _recurrence(*args)):
+        np.testing.assert_allclose(got, want, atol=2e-5 * float(jnp.max(jnp.abs(want))), rtol=0)
+    got = jax.grad(scalar(gd.gated_delta_chunk), argnums=range(6))(*args)
+    want = jax.grad(scalar(_recurrence), argnums=range(6))(*args)
+    for name, a, b in zip(("q", "k", "v", "g", "beta", "state"), got, want):
+        np.testing.assert_allclose(a, b, atol=2e-5 * float(jnp.max(jnp.abs(b))), rtol=0, err_msg=name)
+
+
+def test_a_batch_of_rows_is_each_row_alone():
+    rows = [_rule_inputs(seed, 100, 3, 8, 12, True) for seed in (1, 2)]
+    o, last = gd.gated_delta_chunk(*(jnp.stack(pair) for pair in zip(*rows)))
+    for i, row in enumerate(rows):
+        o_i, last_i = gd.gated_delta_chunk(*row)
+        np.testing.assert_array_equal(o[i], o_i)
+        np.testing.assert_array_equal(last[i], last_i)
+
+
+def test_the_triangular_inverse_keeps_its_digits_where_the_product_form_lost_them():
+    """``(I - A)^-1`` for a block of 64 keys a quarter aligned written at
+    ``beta`` 2 (what layers 1 and 2 of the cell see): by halves it equals the
+    float64 inverse to float32's rounding; the product ``(I + A)(I + A^2)..``
+    that the serving-only code used does not."""
+    k = np.asarray(_rule_inputs(3, 64, 1, 96, 8, True, aligned=0.6)[1][:, 0], np.float64)
+    a = -np.tril(2.0 * (k @ k.T), -1)
+    want = np.linalg.inv(np.eye(64) - a)
+    got = gd._inverse_of_one_minus(jnp.asarray(a, jnp.float32)[None])[0]
+    assert float(np.max(np.abs(got - want))) < 1e-5 * float(np.max(np.abs(want)))
+    product, power = np.eye(64, dtype=np.float32) + a.astype(np.float32), a.astype(np.float32)
+    for _ in range(5):
+        power = power @ power
+        product = product + product @ power
+    assert float(np.max(np.abs(product - want))) > 1e-3 * float(np.max(np.abs(want)))
+
+
+# -- 4. the normal path: Accelerator -> create_train_state -> prepare_train_step ------------------
+
+RECIPE = {"optimizer": "lion-sr", "ce_chunks": 2, "parallelism": {},
+          "optimizer_hyper": {"lr": 0.004, "b1": 0.9, "b2": 0.99, "weight_decay": 0.0}}
+WIDER = dict(BASE, vocab_size=512, hidden_size=128, intermediate_size=256, num_attention_heads=4,
+             num_key_value_heads=4, linear_key_head_dim=16, linear_value_head_dim=32)
+
+
+def test_three_prepared_steps_with_lion_sr_follow_the_train_reference():
+    """The rehearsal's widths through the entry points the cell uses (bf16,
+    the fused CE, lion-sr, every block recomputed) against three float32 Lion
+    steps of ``TrainReference``: each loss within 0.05 (bf16 at hidden 128 and
+    stochastic rounding at lr 4e-3; read: up to 0.021), the first gradient's
+    norm leaf by leaf within 0.3 of the larger of that leaf's and the median
+    leaf's, and no leaf's change a frozen step's (1.0).  0.3 is wide because
+    bf16 operands at these widths move a q / k leaf's gradient through the
+    rule by 0.005-0.02 on rows whose tokens seldom repeat (the rehearsal's) and
+    by 0.15 here, 0.19-0.28 where every token repeats (a repeated token is a
+    repeated KEY at layer 0, written again at ``beta`` up to 2); the float32
+    program agrees to 7e-5 on the same rows (the test above), so this one
+    holds the path, not the precision."""
+    seed, hy = 1, RECIPE["optimizer_hyper"]
+    acc, step, new_state = family.build_trainer(WIDER, LAYERS, RECIPE)
+    state = new_state(seed)
+    ids = [_ids(10 + i, 1, 128, WIDER["vocab_size"]) for i in range(3)]
+    ref = reference.TrainReference(make_weights(family.weight_shapes(WIDER, LAYERS), seed), WIDER,
+                                   LAYERS, hy["lr"], hy["b1"], hy["b2"], 3)
+    floor = None
+    for i, batch in enumerate(ids):
+        state, metrics = step(state, {"input_ids": jnp.asarray(batch), "labels": jnp.asarray(batch)})
+        want_loss, want_norms = ref.step(batch)
+        assert abs(float(metrics["loss"]) - want_loss) < 0.05, i
+        if i == 0:
+            got = {k: float(jnp.linalg.norm(v.astype(jnp.float32))) / (1.0 - hy["b2"])
+                   for k, v in family.momentum_of(state).items()}
+            floor = float(np.median(list(want_norms.values())))
+            gaps = {k: abs(got[k] - want_norms[k]) / max(want_norms[k], floor) for k in want_norms}
+            assert max(gaps.values()) < 0.3, sorted(gaps.items(), key=lambda kv: -kv[1])[:3]
+    assert acc.compile_events >= 1
+    moved = family.params_of(state)
+    seeded = make_weights(family.weight_shapes(WIDER, LAYERS), seed)
+    changes = {k: float(jnp.linalg.norm(moved[k].astype(jnp.float32) - seeded[k].astype(jnp.float32)))
+               for k in seeded}
+    want = ref.change_norms()
+    floor = float(np.median(list(want.values())))
+    assert max(abs(changes[k] - want[k]) / max(want[k], floor) for k in want) < 0.6
+
+
+def test_the_plan_shards_the_new_leaves_and_two_shards_compute_the_one_devices_loss():
+    """``dp_shard_size=2`` on the suite's CPU mesh: the parameter plan puts
+    every matrix of the new family (the Gated DeltaNet mixer's seven
+    projections among them) over ``dp_shard``, and the loss of a batch of two
+    rows is the one device's to bf16's summation order (1e-3 of ~5.5)."""
+    from accelerate_tpu import Accelerator
+    from accelerate_tpu.parallelism_config import ParallelismConfig
+    from accelerate_tpu.state import AcceleratorState, GradientState
+
+    weights = make_weights(family.weight_shapes(WIDER, LAYERS), 3)
+    ids = jnp.asarray(_ids(4, 2, 128, WIDER["vocab_size"]))
+    batch = {"input_ids": ids, "labels": ids}
+    model = family.build_model(WIDER, LAYERS, remat=True)
+    loss_fn = make_olmo_hybrid_loss_fn(model, fused_vocab_chunks=2)
+    alone = float(jax.jit(loss_fn)(family.to_program(weights, WIDER), batch))
+
+    acc = Accelerator(mixed_precision="bf16",
+                      parallelism_config=ParallelismConfig(dp_shard_size=2, devices=jax.devices()[:2]))
+    plan = family.param_shardings(acc, WIDER, LAYERS)
+    sharded = {name for name, s in plan.items() if "dp_shard" in jax.tree_util.tree_leaves(tuple(s.spec))}
+    matrices = {name for name, (shape, _) in family.weight_shapes(WIDER, LAYERS).items()
+                if len(shape) == 2 and min(shape) >= 4 and shape[0] * shape[1] >= 2 ** 12}
+    assert matrices - {f"layers.{i}.{leaf}" for i in range(3) for leaf in ("ba", "conv")} <= sharded
+    assert {"layers.0.lq", "layers.0.lz", "layers.1.lo", "layers.3.q"} <= sharded
+    whole = acc._params_plan(jax.eval_shape(lambda: family.to_program(weights, WIDER)))
+    for name in ("q_proj", "k_proj", "v_proj", "g_proj", "o_proj"):     # by the program's own names too
+        spec = whole["params"]["layers_0"]["linear_attn"][name]["kernel"].spec
+        assert "dp_shard" in jax.tree_util.tree_leaves(tuple(spec)), name
+    state = acc.create_train_state(family.to_program(make_weights(
+        family.weight_shapes(WIDER, LAYERS), 3, plan), WIDER), "lion-sr", apply_fn=model.apply)
+    step = acc.prepare_train_step(loss_fn)
+    _, metrics = step(state, {k: jax.device_put(v, family.batch_sharding(acc, v)) for k, v in batch.items()})
+    assert abs(float(metrics["loss"]) - alone) < 1e-3 * alone
+    AcceleratorState._reset_state(reset_partial_state=True)
+    GradientState._reset_state()
+
+
+# -- 5. the published checkpoint's names -----------------------------------------------------
+
+
+def test_hf_names_load_into_the_tree_the_benchmark_builds():
+    """``load_hf_olmo_hybrid``: torch ``[out, in]`` tensors under ``model.``,
+    the three depthwise convs' ``[C, 1, 4]``, the bare ``A_log`` / ``dt_bias``,
+    the OLMo 2 norm names; rotary buffers are skipped, and every name maps
+    back (the round trip)."""
+    whole = f32(make_weights(family.weight_shapes(BASE, LAYERS), seed=5))
+    want_tree = family.to_program(whole, BASE)
+    block = {**{f"l{n}": f"linear_attn.{p}_proj.weight" for n, p in zip("qkvzo", "qkvgo")},
+             "A_log": "linear_attn.A_log", "dt_bias": "linear_attn.dt_bias",
+             "o_norm": "linear_attn.o_norm.weight",
+             **{n: f"self_attn.{n}_proj.weight" for n in "qkvo"},
+             "q_norm": "self_attn.q_norm.weight", "k_norm": "self_attn.k_norm.weight",
+             "mixer_norm": "post_attention_layernorm.weight", "mlp_norm": "post_feedforward_layernorm.weight",
+             "gate": "mlp.gate_proj.weight", "up": "mlp.up_proj.weight", "down": "mlp.down_proj.weight"}
+    pairs = [("lm_head.weight", np.asarray(whole["head"]).T),
+             ("model.embed_tokens.weight", np.asarray(whole["embed"])),
+             ("model.norm.weight", np.asarray(whole["final_norm"])),
+             ("model.layers.3.self_attn.rotary_emb.inv_freq", np.zeros((4,), np.float32))]
+    for name, arr in whole.items():
+        if name.startswith("layers."):
+            _, i, leaf = name.split(".")
+            arr, at = np.asarray(arr), f"model.layers.{i}"
+            if leaf in ("conv", "ba"):          # one benchmark leaf, several tensors of the checkpoint
+                cuts = np.split(arr, np.cumsum(family.cut_widths(BASE, leaf))[:-1], axis=-1)
+                names = [f"{n}_conv1d" for n in "qkv"] if leaf == "conv" else ["b_proj", "a_proj"]
+                pairs += [(f"{at}.linear_attn.{n}.weight", c.T[:, None, :] if leaf == "conv" else c.T)
+                          for n, c in zip(names, cuts)]
+                continue
+            if leaf == "A_log":
+                arr = arr + BASE["assumed"]["weight_scales"]["A_log_mean"]
+            pairs.append((f"{at}.{block[leaf]}", arr.T if arr.ndim == 2 else arr))
+    model = OlmoHybridForCausalLM(dataclasses.replace(family.build_model(BASE, LAYERS).config,
+                                                      dtype=jnp.float32))
+    params, _ = load_hf_olmo_hybrid(model, pairs, dtype=jnp.float32)
+    flat = lambda tree: {jax.tree_util.keystr(k): v for k, v in
+                         jax.tree_util.tree_flatten_with_path(tree)[0]}
+    got, want = flat(params), flat(want_tree)
+    assert sorted(got) == sorted(want)
+    for key in want:
+        np.testing.assert_array_equal(got[key], want[key], err_msg=key)
+    mapped = {hf_olmo_hybrid_key_map(name) for name, _ in pairs} - {None}
+    assert len(mapped) == len(pairs) - 1                     # one name a leaf, the rotary buffer none
+    assert hf_olmo_hybrid_key_map("model.layers.2.linear_attn.g_proj.weight") == \
+        "params.layers_2.linear_attn.g_proj.kernel"
+    assert hf_olmo_hybrid_key_map("model.layers.9.unknown.weight") == "model.layers.9.unknown.weight"
