@@ -211,7 +211,9 @@ class OlmoHybridForCausalLM(nn.Module):
                            param_dtype=jnp.float32, name="embed_tokens")(input_ids))
         block = OlmoHybridBlock
         if cfg.remat:
-            block = nn.remat(block, policy=jax.checkpoint_policies.nothing_saveable)
+            # all of a block is made again in the backward pass but the rule's triangular inverse (``T`` and
+            # ``A``, 126 MB a layer at 8,192 tokens: what the rule's backward keeps anyway, a third of its forward)
+            block = nn.remat(block, policy=jax.checkpoint_policies.save_only_these_names(gd.KEPT_ACROSS_REMAT))
         for i, kind in enumerate(cfg.kinds):
             x = block(cfg, kind, name=f"layers_{i}")(x)
         x = _rows(RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="norm")(x))

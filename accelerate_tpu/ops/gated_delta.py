@@ -39,10 +39,28 @@ Two forms of the one recurrence:
   the state is then carried block to block by one ``scan`` (and handed to the
   next chunk by the caller).  Plain XLA, float32 at the highest matmul
   precision: these products are ~4% of a chunk's operations and their sum
-  runs over thousands of tokens.  Differentiable (the training path,
-  ``models/olmo_hybrid.py``): the backward pass keeps the inputs and ONE state
-  a block, makes each block's ``A``, ``T``, ``U``, ``W`` again and walks the
-  blocks backwards.
+  runs over thousands of tokens.  That is what an UNDIFFERENTIATED call
+  traces, all of it (a serving engine's prefill: the scan is not where a
+  chunk's time goes, and a start pays no Mosaic lowering for it).
+
+  Differentiable (the training path, ``models/olmo_hybrid.py``), and what
+  ``jax.grad`` traces is another path, reachable only through ``_blocks``'
+  ``defvjp``: the forward rule makes the same parts heads first and walks the
+  blocks inside ONE Pallas kernel a segment (``linear_chunk_fwd``: grid (head
+  group, block), the state ``[Dk, Dv]`` in VMEM across a head group's blocks,
+  a block's parts streamed through, ``O`` and the state each block STARTED
+  from written out); the backward rule walks them last to first in one kernel
+  (``linear_chunk_bwd``: the state's cotangent in VMEM, ``V'`` made again
+  from the stored start) and takes the parts' gradient by hand - through ``T``
+  as ``dA = T^T dT T^T = (T^T dU) U^T + (T^T dW) W^T`` in place of autodiff
+  through the inverse's six levels.  It keeps the inputs, one state a block
+  and ``T`` and ``A`` (the inverse is not made twice; ``U``, ``W`` and the
+  rest of what a walk streams are, from ``T``: kept as well they cost the
+  train cell 2.3 GB of its step).  ``T`` and ``A`` carry a name
+  (``KEPT_ACROSS_REMAT``), so a caller that recomputes its blocks may save
+  the two across the recompute too (``save_only_these_names``:
+  ``models/olmo_hybrid.py`` does, and ``remat``'s forward makes no inverse).
+  The same products at the same precision as the primal, kernels included.
 
 The short causal convolution in front of the rule (depthwise, ``K`` taps, the
 last ``K - 1`` input rows kept per sequence) is here too:
@@ -57,10 +75,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
-from .flash_attention import _on_tpu
+from ..parallelism_config import BATCH_AXES
+from .flash_attention import _on_tpu, per_shard
 
 BLOCK = 64               # tokens of one block of the chunked form
 SEGMENT = 32             # blocks whose parts are made at once: a longer sequence goes a segment at a time
@@ -165,32 +186,198 @@ def _blocks(q, k, v, g, beta, state):
     return lax.scan(_one_block, state, _block_parts(q, k, v, g, beta))
 
 
-def _blocks_fwd(q, k, v, g, beta, state):
-    """What the backward pass keeps: the inputs and the state each block STARTED from."""
-    def keeping(s, xs):
-        behind, o = _one_block(s, xs)
-        return behind, (o, s)
+# -- the differentiated path: an undifferentiated call traces nothing from here to ``defvjp`` --
 
-    last, (o, starts) = lax.scan(keeping, state, _block_parts(q, k, v, g, beta))
-    return (last, o), (q, k, v, g, beta, starts)
+KEPT_ACROSS_REMAT = "linear_chunk_inverse"    # the name ``T`` and ``A`` carry: a caller's ``remat`` may save them by it
+# heads whose blocks one grid step of a walk holds (the largest divisor of Hv up to this): independent chains for the
+# scheduler - with one, the train cell read 0.6% fewer tokens/s; the micro-benchmark flattens past three
+HEADS_A_STEP = 3
+
+
+def _dot(a, b, contract):
+    """``a . b`` over one axis of each, float32 at the highest precision (inside a kernel)."""
+    return lax.dot_general(a, b, (((contract[0],), (contract[1],)), ((), ())),
+                           precision=lax.Precision.HIGHEST, preferred_element_type=jnp.float32)
+
+
+def _walk_fwd_kernel(wq_ref, u_ref, within_ref, k_out_ref, last_ref, state_ref,
+                     starts_ref, o_ref, s_ref, *, heads: int):
+    """One block of ``heads`` heads: ``_one_block`` with the state in VMEM
+    (``s_ref``, the last state's own output block, which stays while the grid
+    walks a head group's blocks).  ``wq``: ``W`` over ``Q e^gamma``, [2 C, Dk]."""
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        s_ref[...] = state_ref[...]
+
+    block = u_ref.shape[1]
+    for h in range(heads):
+        s = s_ref[h]
+        starts_ref[h] = s
+        ws_qs = _dot(wq_ref[h], s, (1, 0))
+        v_new = u_ref[h] - ws_qs[:block]
+        o_ref[h] = ws_qs[block:] + _dot(within_ref[h], v_new, (1, 0))
+        s_ref[h] = s * last_ref[h] + _dot(k_out_ref[h], v_new, (0, 0))
+
+
+def _walk_bwd_kernel(wq_ref, u_ref, within_ref, k_out_ref, last_ref, starts_ref, d_o_ref, d_state_ref,
+                     d_wq_ref, d_u_ref, d_within_ref, d_k_out_ref, d_last_ref, d_s_ref, *, heads: int):
+    """One block of ``heads`` heads, the last block first: ``_one_block``'s
+    transpose with the state's cotangent in VMEM (``d_s_ref``, the incoming
+    state's own cotangent block).  ``V'`` is made again from the start the
+    forward walk stored; ``d(last)`` leaves as its sums over ``Dk``."""
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        d_s_ref[...] = d_state_ref[...]
+
+    block = u_ref.shape[1]
+    for h in range(heads):
+        s, d_s, wq, d_o = starts_ref[h], d_s_ref[h], wq_ref[h], d_o_ref[h]
+        v_new = u_ref[h] - _dot(wq[:block], s, (1, 0))
+        d_v = _dot(within_ref[h], d_o, (0, 0)) + _dot(k_out_ref[h], d_s, (1, 0))
+        d_u_ref[h] = d_v
+        d_within_ref[h] = _dot(d_o, v_new, (1, 1))
+        d_k_out_ref[h] = _dot(v_new, d_s, (1, 1))
+        both = jnp.concatenate([-d_v, d_o])                         # against [W; Q e^gamma]
+        d_wq_ref[h] = _dot(both, s, (1, 1))
+        d_last_ref[h] = jnp.sum(s * d_s, axis=0, keepdims=True)
+        d_s_ref[h] = d_s * last_ref[h] + _dot(wq, both, (0, 0))
+
+
+def _walk(kernel, name, heads_first, blocks_first, carried, heads_first_out, blocks_first_out, *, backwards):
+    """``kernel`` over the grid (head group, block), one launch a segment.  A
+    step reads one block of each array of ``heads_first`` ([Hv, n, rows,
+    cols]) and ``blocks_first`` ([n, Hv, ..], ``O``'s order) and writes one
+    block ``(rows, cols)`` for each entry of ``heads_first_out`` and
+    ``blocks_first_out``; ``carried`` [Hv, Dk, Dv] is read at a head group's
+    first step, and the last output is one more of its shape whose block
+    stays in VMEM across the group's blocks.  Heads know nothing of each
+    other: under a mesh each device walks its own (``per_shard``; a batch's
+    rows were folded into the heads rows first, so the batch axes split
+    them, along a row's heads where the rows alone do not divide; where the
+    folded axis does not divide either, every device walks all of it).  Only
+    the batch axes are named: across ``tp`` the heads are seen whole (gathered
+    at the boundary where the projections' ``tp`` rule had split them) and
+    every device of a ``tp`` group walks the same ones - right, at ``tp``
+    times the work: a row's heads over ``tp`` need rows and heads as two axes
+    here, and ``gated_delta_chunk`` folds them for the primal too."""
+    def launch(*arrays):
+        hv, n = arrays[0].shape[:2]
+        heads = max(m for m in range(1, HEADS_A_STEP + 1) if hv % m == 0)
+        at = (lambda i: n - 1 - i) if backwards else (lambda i: i)
+        of_heads = lambda block: pl.BlockSpec((heads, None) + block, lambda h, i: (h, at(i), 0, 0))
+        of_blocks = lambda block: pl.BlockSpec((None, heads) + block, lambda h, i: (at(i), h, 0, 0))
+        whole = pl.BlockSpec((heads,) + arrays[-1].shape[1:], lambda h, i: (h, 0, 0))
+        ins = arrays[:len(heads_first)], arrays[len(heads_first):-1]
+        shapes = ([(hv, n) + block for block in heads_first_out]
+                  + [(n, hv) + block for block in blocks_first_out] + [arrays[-1].shape])
+        return pl.pallas_call(
+            functools.partial(kernel, heads=heads),
+            grid=(hv // heads, n),
+            in_specs=[of_heads(a.shape[2:]) for a in ins[0]] + [of_blocks(a.shape[2:]) for a in ins[1]] + [whole],
+            out_specs=[of_heads(block) for block in heads_first_out]
+            + [of_blocks(block) for block in blocks_first_out] + [whole],
+            out_shape=[jax.ShapeDtypeStruct(shape, jnp.float32) for shape in shapes],
+            compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+            interpret=not _on_tpu(),
+            name=name,
+        )(*arrays)
+
+    def split(heads_first_count, blocks_first_count):
+        def specs(free):
+            axes = tuple(a for a in BATCH_AXES if a in free)
+            axes = axes if axes and carried.shape[0] % int(np.prod([free[a] for a in axes])) == 0 else None
+            return (P(axes),) * heads_first_count + (P(None, axes),) * blocks_first_count + (P(axes),)
+        return specs
+
+    return per_shard(launch, split(len(heads_first), len(blocks_first)),
+                     split(len(heads_first_out), len(blocks_first_out)))(*heads_first, *blocks_first, carried)
+
+
+def _decays(g):
+    """Of ``g`` [Hv, n, C]: ``D`` [.., C, C] (zero above the diagonal), and as
+    columns [.., C, 1] ``e^gamma`` and ``e^(gamma_C - gamma)``."""
+    gamma = jnp.cumsum(g, axis=-1)
+    at = np.arange(g.shape[-1])
+    decay = jnp.exp(jnp.where(at[:, None] >= at[None, :], gamma[..., :, None] - gamma[..., None, :], -jnp.inf))
+    return decay, jnp.exp(gamma)[..., None], jnp.exp(gamma[..., -1:] - gamma)[..., None]
+
+
+def _made(q, k, v, g, beta, inverse=None):
+    """``_block_parts`` for the walks' kernels, heads first and ``W`` stacked
+    over ``Q e^gamma``: ``(wq, U, Q K^T * D, K e^(gamma_C - gamma), e^gamma_C
+    along a row of Dv)``; behind them ``(T, A)``, which the gradient reads
+    too - made here, or taken as ``inverse`` where the forward rule kept
+    them - and ``_decays``' three with ``beta K``, for the gradient."""
+    mm = functools.partial(jnp.einsum, precision=lax.Precision.HIGHEST)
+    decay, grown, shrunk = _decays(g)
+    k_beta = k * beta[..., None]
+    if inverse is None:
+        a = -jnp.tril(mm("hnid,hnjd->hnij", k_beta, k) * decay, -1)
+        inverse = tuple(checkpoint_name(x, KEPT_ACROSS_REMAT) for x in (_inverse_of_one_minus(a), a))
+    u = mm("hnij,hnjd->hnid", inverse[0], v * beta[..., None])
+    w = mm("hnij,hnjd->hnid", inverse[0], k_beta * grown)
+    within = mm("hnid,hnjd->hnij", q, k) * decay
+    last = jnp.broadcast_to(grown[:, :, -1:], g.shape[:2] + (1, v.shape[-1]))
+    streamed = jnp.concatenate([w, q * grown], axis=2), u, within, k * shrunk, last
+    return streamed, inverse, (decay, grown, shrunk, k_beta)
+
+
+def _blocks_fwd(q, k, v, g, beta, state):
+    """The forward walk as one kernel.  Kept for the backward pass: the
+    inputs, the state each block STARTED from, and ``T`` and ``A`` (the
+    inverse's products are not made again; what the walk streams
+    is, from ``T``: kept, it stood 2.3 GB higher in the cell's step)."""
+    streamed, inverse, _ = _made(q, k, v, g, beta)
+    starts, o, last = _walk(_walk_fwd_kernel, "linear_chunk_fwd", streamed, (), state,
+                            [state.shape[1:]], [v.shape[2:]], backwards=False)
+    return (last, o), (q, k, v, g, beta, starts, inverse)
+
+
+def _cotangent_of_a(inv, u, w, d_u, d_w):
+    """``U = T b``, ``W = T c`` with ``T = (I - A)^-1``: ``dA = T^T dT T^T``
+    (strictly lower) where ``dT = dU b^T + dW c^T``, which is ``(T^T dU) U^T +
+    (T^T dW) W^T`` - no product of two ``[C, C]`` matrices.  Returns ``(T^T
+    dU, T^T dW, dA)``: the first two are ``b``'s and ``c``'s cotangents."""
+    mm = functools.partial(jnp.einsum, precision=lax.Precision.HIGHEST)
+    t_du, t_dw = mm("hnji,hnjd->hnid", inv, d_u), mm("hnji,hnjd->hnid", inv, d_w)
+    return t_du, t_dw, jnp.tril(mm("hnid,hnjd->hnij", t_du, u) + mm("hnid,hnjd->hnij", t_dw, w), -1)
 
 
 @jax.named_scope("linear_chunk")
 def _blocks_bwd(kept, cotangents):
-    """Every block's parts made again from the inputs (the triangular inverse
-    included), the blocks walked backwards from the stored starts, then the
-    parts' own gradient: nothing of ``[Hv, n, C, C]`` outlives this call."""
-    *inputs, starts = kept
+    """The backwards walk as one kernel, from the stored starts, then the
+    parts' gradient by hand (``_made`` read backwards; every ``[C, C]``
+    cotangent is zero above the diagonal because ``D`` is)."""
+    q, k, v, g, beta, starts, inverse = kept
+    (wq, u, within, k_out, last), (inv, a), (decay, grown, shrunk, k_beta) = _made(
+        q, k, v, g, beta, inverse)
     d_last, d_o = cotangents
-    parts, pull_parts = jax.vjp(_block_parts, *inputs)
+    block = q.shape[2]
+    mm = functools.partial(jnp.einsum, precision=lax.Precision.HIGHEST)
+    d_wq, d_u, d_within, d_k_out, d_last_sums, d_state = _walk(
+        _walk_bwd_kernel, "linear_chunk_bwd", (wq, u, within, k_out, last, starts), (d_o,), d_last,
+        [a.shape[2:] for a in (wq, u, within, k_out, last)], [], backwards=True)
+    d_w, d_q_in = d_wq[:, :, :block], d_wq[:, :, block:]
+    w, q_in = wq[:, :, :block], wq[:, :, block:]
 
-    def one_back(d_s, xs):
-        s, xs_i, d_o_i = xs
-        _, pull = jax.vjp(_one_block, s, xs_i)
-        return pull((d_s, d_o_i))
-
-    d_state, d_parts = lax.scan(one_back, d_last, (starts, parts, d_o), reverse=True)
-    return pull_parts(d_parts) + (d_state,)
+    t_du, t_dw, d_a = _cotangent_of_a(inv, u, w, d_u, d_w)
+    d_v = t_du * beta[..., None]
+    d_kk = -d_a * decay                                             # of (beta K) K^T
+    d_qk = d_within * decay                                         # of Q K^T
+    d_k_beta = t_dw * grown + mm("hnij,hnjd->hnid", d_kk, k)
+    d_beta = jnp.sum(t_du * v, axis=-1) + jnp.sum(d_k_beta * k, axis=-1)
+    d_q = mm("hnij,hnjd->hnid", d_qk, k) + d_q_in * grown
+    d_k = (mm("hnij,hnid->hnjd", d_kk, k_beta) + mm("hnij,hnid->hnjd", d_qk, q)
+           + d_k_out * shrunk + d_k_beta * beta[..., None])
+    # gamma: through D (d(D) * D is dA * A + d(within) * within), e^gamma, e^(gamma_C - gamma), e^gamma_C
+    through_d = d_a * a + d_within * within
+    out = jnp.sum(d_k_out * k_out, axis=-1)
+    d_gamma = (jnp.sum(through_d, axis=-1) - jnp.sum(through_d, axis=-2)
+               + jnp.sum(t_dw * k_beta * grown + d_q_in * q_in, axis=-1) - out)
+    at_end = jnp.sum(out, axis=-1) + jnp.sum(d_last_sums[:, :, 0], axis=-1) * last[:, :, 0, 0]
+    d_gamma = d_gamma.at[..., -1].add(at_end)
+    d_g = lax.cumsum(d_gamma, axis=2, reverse=True)
+    return d_q, d_k, d_v, d_g, d_beta, d_state
 
 
 _blocks.defvjp(_blocks_fwd, _blocks_bwd)
@@ -208,10 +395,11 @@ def gated_delta_chunk(q, k, v, g, beta, state):
     whole segment as one shorter call: no row is padded past its last block):
     what is live of ``[Hv, n, C, C]`` is a segment's, forwards and backwards.
 
-    Differentiable in all six (the training path, ``models/olmo_hybrid.py``),
-    with a backward pass that keeps the inputs and one state a block
-    (``_blocks_bwd``).  ``Dk`` and ``Dv`` may differ, and ``beta`` may reach 2
-    (a transition ``I - beta k k^T`` with a negative eigenvalue)."""
+    Differentiable in all six (the training path, ``models/olmo_hybrid.py``):
+    under ``jax.grad`` a segment's blocks are walked by two Pallas kernels
+    (``_blocks_fwd``, ``_blocks_bwd``); a call that is not differentiated
+    traces none of that.  ``Dk`` and ``Dv`` may differ, and ``beta`` may reach
+    2 (a transition ``I - beta k k^T`` with a negative eigenvalue)."""
     if q.ndim == 4:             # rows become heads: the rule knows no other axis
         rows, hv = q.shape[0], q.shape[2]
         fold = lambda a: jnp.moveaxis(a, 0, 1).reshape((a.shape[1], rows * hv) + a.shape[3:])

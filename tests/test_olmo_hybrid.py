@@ -175,6 +175,147 @@ def test_the_chunked_rules_gradients_are_the_token_recurrences(t, aligned, neg_e
         np.testing.assert_allclose(a, b, atol=2e-5 * float(jnp.max(jnp.abs(b))), rtol=0, err_msg=name)
 
 
+def _batched_recurrence(*args):
+    return jax.vmap(_recurrence)(*args) if args[0].ndim == 4 else _recurrence(*args)
+
+
+@pytest.mark.parametrize("t,rows,heads,dk,dv,from_zero,state_read,shards", [
+    (130, 0, 1, 96, 192, False, True, 0),           # the cell's Dk x Dv, one head: a head group of one
+    (64 * (gd.SEGMENT + 1), 0, 2, 8, 16, False, True, 0),   # two calls: a whole segment, then one block
+    (64 * 2 * gd.SEGMENT + 70, 0, 3, 8, 12, False, True, 0),    # a scan over two segments and a ragged tail
+    (100, 2, 3, 8, 12, False, True, 0),             # a batch axis: two rows of three heads are six heads
+    (200, 3, 1, 16, 8, False, True, 0),             # three rows of one head, Dk > Dv
+    (150, 0, 4, 8, 12, True, True, 0),              # from a zero state, as the model calls it
+    (150, 0, 4, 8, 12, False, False, 0),            # no cotangent on the last state: d(last) only through O
+    (150, 2, 2, 8, 12, True, False, 0),             # the model's own call: rows, from zero, O alone
+    (100, 2, 3, 8, 12, False, True, 2),             # ``dp_shard`` 2: each device walks its own row's heads
+    (100, 3, 2, 8, 12, False, True, 2),             # three rows on two devices: a row's heads on both
+    (100, 1, 3, 8, 12, False, True, 2),             # three heads on two devices: every device walks them all
+], ids=["cell_widths", "segment_and_block", "two_segments_ragged", "rows", "rows_of_one_head",
+        "from_zero", "o_alone", "as_the_model_calls_it", "two_shards", "two_shards_across_rows",
+        "two_shards_replicated"])
+def test_the_hand_written_rule_is_the_token_recurrences(t, rows, heads, dk, dv, from_zero, state_read, shards):
+    """What ``jax.grad`` runs of the rule - ``_blocks_fwd`` and ``_blocks_bwd``,
+    both walks as kernels (interpreted here), the parts' gradient by hand -
+    against ``jax.grad`` of the token recurrence: o, the last state, and the
+    gradients of all six inputs (the incoming state's among them, with and
+    without a cotangent on the last state), ``beta`` in (1, 2), keys a third
+    aligned: 2e-5 of the largest element, as the cases above (read: up to 4e-6).
+    With ``shards`` the rows lie over ``dp_shard`` of an Accelerator's mesh and
+    the call is jitted: each walk then goes through ``_walk``'s ``shard_map``
+    (the interpreted kernels take the same specs as the compiled ones), the
+    folded rows-of-heads axis split where the devices divide it and seen whole
+    where they do not."""
+    one = lambda seed: _rule_inputs(seed, t, heads, dk, dv, True, aligned=0.3)
+    args = tuple(jnp.stack(both) for both in zip(*(one(t + r) for r in range(rows)))) if rows else one(t)
+    if from_zero:
+        args = args[:5] + (jnp.zeros_like(args[5]),)
+    w_o = jax.random.normal(jax.random.key(1), args[2].shape)
+    w_s = jax.random.normal(jax.random.key(2), args[5].shape) * state_read
+    scalar = lambda f: lambda *a: (lambda o, s: jnp.sum(o * w_o) + jnp.sum(s * w_s))(*f(*a))
+    want_out = _batched_recurrence(*args)
+    want = jax.grad(scalar(_batched_recurrence), argnums=range(6))(*args)
+    chunk, pulled = gd.gated_delta_chunk, lambda *a: jax.vjp(gd.gated_delta_chunk, *a[:6])[1](a[6:])
+    if shards:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from accelerate_tpu import Accelerator
+        from accelerate_tpu.parallelism_config import ParallelismConfig
+
+        acc = Accelerator(parallelism_config=ParallelismConfig(dp_shard_size=shards, devices=jax.devices()[:shards]))
+        over_rows = NamedSharding(acc.mesh, P("dp_shard") if rows % shards == 0 else P())
+        args, w_o, w_s = jax.device_put((args, w_o, w_s), over_rows)
+        chunk, pulled = jax.jit(chunk), jax.jit(pulled)
+    for got, wanted in zip(chunk(*args), want_out):
+        np.testing.assert_allclose(got, wanted, atol=2e-5 * float(jnp.max(jnp.abs(wanted))), rtol=0)
+    (o, last), _ = jax.vjp(chunk, *args)                            # the forward rule's own outputs
+    for got, wanted in zip((o, last), want_out):
+        np.testing.assert_allclose(got, wanted, atol=2e-5 * float(jnp.max(jnp.abs(wanted))), rtol=0)
+    for name, a, b in zip(("q", "k", "v", "g", "beta", "state"), pulled(*args, w_o, w_s), want):
+        np.testing.assert_allclose(a, b, atol=2e-5 * float(jnp.max(jnp.abs(b))), rtol=0, err_msg=name)
+
+
+@pytest.mark.parametrize("aligned,dk,dv", [(0.0, 8, 12), (0.6, 96, 192)])
+def test_the_inverses_cotangent_by_hand_is_autodiffs(aligned, dk, dv):
+    """``T = (I - A)^-1`` alone: ``dA = T^T dT T^T`` below the diagonal is what
+    ``jax.vjp(_inverse_of_one_minus)`` returns there (autodiff through the six
+    levels), and with ``dT = dU b^T + dW c^T`` it is ``_cotangent_of_a``'s
+    ``(T^T dU) U^T + (T^T dW) W^T``, which never forms ``dT``: 1e-5 of the
+    largest element at ``beta`` 2 and keys 0.6 aligned (read: 2e-6)."""
+    _, k, v, _, beta, _ = _rule_inputs(7, 64, 3, dk, dv, True, aligned=aligned)
+    k, v, beta = (jnp.moveaxis(x, 1, 0)[:, None] for x in (k, v, beta))             # [Hv, 1, C, ..]
+    mm = lambda eq, *xs: jnp.einsum(eq, *xs, precision="highest")
+    a = -jnp.tril(mm("hnid,hnjd->hnij", k * beta[..., None], k), -1)
+    b, c = v * beta[..., None], k * beta[..., None]
+    inv, pull = jax.vjp(gd._inverse_of_one_minus, a)
+    d_u, d_w = (jax.random.normal(jax.random.key(i), x.shape) for i, x in enumerate((b, c)))
+    d_inv = mm("hnid,hnjd->hnij", d_u, b) + mm("hnid,hnjd->hnij", d_w, c)
+    want = jnp.tril(pull(d_inv)[0], -1)
+    by_hand = jnp.tril(mm("hnji,hnjk,hnlk->hnil", inv, d_inv, inv), -1)
+    t_du, t_dw, fused = gd._cotangent_of_a(inv, mm("hnij,hnjd->hnid", inv, b), mm("hnij,hnjd->hnid", inv, c), d_u, d_w)
+    scale = float(jnp.max(jnp.abs(want)))
+    assert scale > 0
+    np.testing.assert_allclose(by_hand, want, atol=1e-5 * scale, rtol=0)
+    np.testing.assert_allclose(fused, want, atol=1e-5 * scale, rtol=0)
+    np.testing.assert_allclose(t_du, mm("hnji,hnjd->hnid", inv, d_u), atol=1e-5 * float(jnp.max(jnp.abs(t_du))), rtol=0)
+    np.testing.assert_allclose(t_dw, mm("hnji,hnjd->hnid", inv, d_w), atol=1e-5 * float(jnp.max(jnp.abs(t_dw))), rtol=0)
+
+
+def _eqns(jaxpr):
+    """Every equation in order, through every nested jaxpr."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _primitives(jaxpr):
+    return [eqn.primitive.name for eqn in _eqns(jaxpr)]
+
+
+@pytest.mark.parametrize("t,rows,digest", [(200, 0, "bdcc9bbf7ba86d06"), (64 * (gd.SEGMENT + 2), 2, "aea06d3a8a028ce3")],
+                         ids=["a_prefill_chunk", "rows_past_a_segment"])
+def test_only_a_differentiated_call_traces_the_kernels(t, rows, digest):
+    """The split between the rule's two users falls on the call itself: an
+    undifferentiated ``gated_delta_chunk`` (Qwen3-Next's prefill) traces the
+    plain-XLA primal alone - no ``pallas_call``, and the primitive list of
+    the commit before the kernels, pinned by its digest - so a serving
+    program lowers what it always lowered; under ``jax.grad`` the two walks
+    are there, one ``linear_chunk_fwd`` and one ``linear_chunk_bwd`` a call of
+    ``_blocks``, and no scan over the blocks."""
+    import hashlib
+    one = lambda seed: _rule_inputs(seed, t, 2, 8, 12, True)
+    args = tuple(jnp.stack(both) for both in zip(one(1), one(2))) if rows else one(1)
+    primal = _primitives(jax.make_jaxpr(gd.gated_delta_chunk)(*args).jaxpr)
+    assert "pallas_call" not in primal and primal.count("scan") >= 1
+    assert hashlib.sha256(",".join(primal).encode()).hexdigest()[:16] == digest, ",".join(primal)
+    grad = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(gd.gated_delta_chunk(*a)[0]), argnums=range(6)))(*args)
+    kernels = [eqn.params["name"] for eqn in _eqns(grad.jaxpr) if eqn.primitive.name == "pallas_call"]
+    calls = 2 if rows else 1                        # whole segments under one scan, the blocks behind them
+    assert sorted(kernels) == ["linear_chunk_bwd"] * calls + ["linear_chunk_fwd"] * calls
+    assert _primitives(grad.jaxpr).count("scan") == (2 if rows else 0)    # ``_chunk``'s, forwards and backwards
+
+
+def test_remat_makes_the_rules_inverse_once_a_layer():
+    """The model's ``remat`` saves ``T`` and ``A`` by name and nothing else: of
+    the rule's products with a ``[C, C]`` result a linear layer's first forward
+    makes 14 (``A``, twelve of the inverse, ``Q K^T``), the recomputed forward
+    1 (``Q K^T`` alone) and the backward 3 (``Q K^T`` once more and ``dA``'s
+    two); with everything made again (``nothing_saveable``) the recomputed
+    forward makes all 14, so 31 a layer for 18."""
+    def square_products(apply):
+        loss = lambda p: jnp.sum(jnp.square(apply(p, ids)))
+        return sum(eqn.primitive.name == "dot_general" and eqn.outvars[0].aval.shape[1:] == (2, 64, 64)
+                   for eqn in _eqns(jax.make_jaxpr(jax.grad(loss))(params).jaxpr))
+
+    model, params, _ = _float32_pair(remat=True)
+    ids = jnp.asarray(_ids(3, 1, 100))                              # two blocks a head
+    assert square_products(model.apply) == 3 * 18
+    plain = OlmoHybridForCausalLM(dataclasses.replace(model.config, remat=False))
+    again = jax.checkpoint(plain.apply, policy=jax.checkpoint_policies.nothing_saveable)
+    assert square_products(again) == 3 * 31
+
+
 def test_a_batch_of_rows_is_each_row_alone():
     rows = [_rule_inputs(seed, 100, 3, 8, 12, True) for seed in (1, 2)]
     o, last = gd.gated_delta_chunk(*(jnp.stack(pair) for pair in zip(*rows)))
@@ -209,21 +350,42 @@ WIDER = dict(BASE, vocab_size=512, hidden_size=128, intermediate_size=256, num_a
              num_key_value_heads=4, linear_key_head_dim=16, linear_value_head_dim=32)
 
 
-def test_three_prepared_steps_with_lion_sr_follow_the_train_reference():
-    """The rehearsal's widths through the entry points the cell uses (bf16,
-    the fused CE, lion-sr, every block recomputed) against three float32 Lion
-    steps of ``TrainReference``: each loss within 0.05 (bf16 at hidden 128 and
-    stochastic rounding at lr 4e-3; read: up to 0.021), the first gradient's
-    norm leaf by leaf within 0.3 of the larger of that leaf's and the median
-    leaf's, and no leaf's change a frozen step's (1.0).  0.3 is wide because
-    bf16 operands at these widths move a q / k leaf's gradient through the
-    rule by 0.005-0.02 on rows whose tokens seldom repeat (the rehearsal's) and
-    by 0.15 here, 0.19-0.28 where every token repeats (a repeated token is a
-    repeated KEY at layer 0, written again at ``beta`` up to 2); the float32
-    program agrees to 7e-5 on the same rows (the test above), so this one
-    holds the path, not the precision."""
+@pytest.mark.parametrize("model_dtype,loss_limits,norm_limit,change_limit", [
+    ("bfloat16", (0.05, 0.05, 0.1), 0.3, 0.6), ("float32", (1e-4, 0.01, 0.05), 0.01, 0.1)])
+def test_three_prepared_steps_with_lion_sr_follow_the_train_reference(model_dtype, loss_limits, norm_limit,
+                                                                      change_limit):
+    """The rehearsal's widths through the entry points the cell uses (the
+    fused CE, lion-sr, every block recomputed, so the rule's hand-written
+    backward under ``remat``) against three float32 Lion steps of
+    ``TrainReference``: each loss, the first gradient's norm leaf by leaf
+    (within ``norm_limit`` of the larger of that leaf's and the median leaf's)
+    and every leaf's change after the three (1.0 is a frozen step's).
+
+    ``float32`` (the rehearsal's program: float32 compute over bf16 leaves)
+    holds the PATH tightly: the first loss to 1e-4 (read: 1e-5), the second
+    to 0.01 (0.0019-0.0063 over seeds 1-4), the third to 0.05 (0.001-0.041:
+    parameters rounded stochastically to bf16 and Lion's sign of a bf16
+    gradient), norms to 0.01 (read: 0.0008), changes to 0.1 (0.04).
+
+    ``bfloat16`` (the chip's program) holds it as far as bf16 at hidden 128
+    lets it: the first loss within 0.05 (read: up to 0.003), the second
+    within 0.05 (0.012-0.020 on this seed), norms within 0.3: bf16 operands at
+    these widths move a q / k leaf's gradient through the rule by 0.005-0.02
+    on rows whose tokens seldom repeat (the rehearsal's) and by 0.15 here,
+    0.19-0.28 where every token repeats (a repeated token is a repeated KEY
+    at layer 0, written again at ``beta`` up to 2).  The THIRD loss is held to
+    0.1, not 0.05: what it reads is a draw, not a distance.  A change of two
+    float32 ulps anywhere in the forward is rounded up to bf16's own 0.4%
+    within a few layers (two such programs' first gradients differ by 1% in
+    every leaf, 0.3% of Lion's signs), and two lion-sr steps of lr 4e-3 turn
+    that into 0.02-0.06 of loss.  On this seed the third loss reads 0.023
+    with the scan-based rule of PR 42, 0.062 and 0.054 with THAT rule's output
+    times ``1 + 2**-22`` and ``1 - 2**-22``; 0.062 with the kernels of PR 44
+    and 0.020 with their output times ``1 + 2**-22``; over seeds 1-4 and those
+    five programs 0.005-0.075, mean 0.035, neither rule the lower (my CPU
+    runs, PR 44: ``_chunk``'s ``o`` scaled in a copy of each tree)."""
     seed, hy = 1, RECIPE["optimizer_hyper"]
-    acc, step, new_state = family.build_trainer(WIDER, LAYERS, RECIPE)
+    acc, step, new_state = family.build_trainer(WIDER, LAYERS, dict(RECIPE, model_dtype=model_dtype))
     state = new_state(seed)
     ids = [_ids(10 + i, 1, 128, WIDER["vocab_size"]) for i in range(3)]
     ref = reference.TrainReference(make_weights(family.weight_shapes(WIDER, LAYERS), seed), WIDER,
@@ -232,13 +394,13 @@ def test_three_prepared_steps_with_lion_sr_follow_the_train_reference():
     for i, batch in enumerate(ids):
         state, metrics = step(state, {"input_ids": jnp.asarray(batch), "labels": jnp.asarray(batch)})
         want_loss, want_norms = ref.step(batch)
-        assert abs(float(metrics["loss"]) - want_loss) < 0.05, i
+        assert abs(float(metrics["loss"]) - want_loss) < loss_limits[i], i
         if i == 0:
             got = {k: float(jnp.linalg.norm(v.astype(jnp.float32))) / (1.0 - hy["b2"])
                    for k, v in family.momentum_of(state).items()}
             floor = float(np.median(list(want_norms.values())))
             gaps = {k: abs(got[k] - want_norms[k]) / max(want_norms[k], floor) for k in want_norms}
-            assert max(gaps.values()) < 0.3, sorted(gaps.items(), key=lambda kv: -kv[1])[:3]
+            assert max(gaps.values()) < norm_limit, sorted(gaps.items(), key=lambda kv: -kv[1])[:3]
     assert acc.compile_events >= 1
     moved = family.params_of(state)
     seeded = make_weights(family.weight_shapes(WIDER, LAYERS), seed)
@@ -246,7 +408,7 @@ def test_three_prepared_steps_with_lion_sr_follow_the_train_reference():
                for k in seeded}
     want = ref.change_norms()
     floor = float(np.median(list(want.values())))
-    assert max(abs(changes[k] - want[k]) / max(want[k], floor) for k in want) < 0.6
+    assert max(abs(changes[k] - want[k]) / max(want[k], floor) for k in want) < change_limit
 
 
 def test_the_plan_shards_the_new_leaves_and_two_shards_compute_the_one_devices_loss():

@@ -583,6 +583,8 @@ def test_qwen3_next_serving_programs_compile_at_the_cells_shapes(one_chip, monke
     assert (len(steps), len(walks)) == ((3, 1) if program == "decode" else (0, 0))
     assert "linear_attend/while" not in text                     # the step: one kernel, no loop around it
     assert ("linear_chunk/while" in text) == (program == "prefill")    # the chunk: a scan over its blocks
+    # ... in plain XLA: the rule's kernels are the differentiated path's alone (a start pays no Mosaic lowering for them)
+    assert [line for line in text.splitlines() if "tpu_custom_call" in line and "linear_chunk" in line] == []
     assert ("global_attend/while" in text) == (program == "prefill")   # the XLA walk: the chunk's alone
     # no state- or pool-shaped relayout: a copy, or a transpose that permutes anything
     moved = r"(copy\(|transpose\([^)]*\), dimensions=\{(?!0,1,2(,3)?\}))"
@@ -593,3 +595,70 @@ def test_qwen3_next_serving_programs_compile_at_the_cells_shapes(one_chip, monke
     state, conv = 3 * QWEN_SLOTS * 8 * 128 * 128 * 4, 3 * QWEN_SLOTS * 3 * 2048 * 2
     assert stats.alias_size_in_bytes >= pools + state + conv     # all three kinds alias in place
     assert stats.temp_size_in_bytes < 2**30
+
+
+def test_olmo_hybrids_linear_layer_trains_through_the_two_walks_kernels(one_chip, compile_for_chip, monkeypatch):
+    """A Gated DeltaNet layer of ``models/olmo_hybrid.py`` at the published
+    widths (30 value heads, ``Dk`` 96, ``Dv`` 192, hidden 3,840), forward and
+    backward over the cell's 1 x 8,192 tokens under the model's ``remat``
+    (all made again but ``T`` and ``A``, saved by name): the rule's forward
+    rule runs TWICE (the first pass and the recompute: jax runs a
+    ``custom_vjp``'s forward rule, not its primal, wherever the call is being
+    differentiated) and its backward once, each ONE Mosaic kernel a segment
+    inside ``_chunk``'s loop over the four segments, all under the
+    ``linear_chunk`` scope that the cell's two metrics read; no other loop is
+    left there (the 32-step scans over the blocks are gone), and both kernels
+    fit the scoped VMEM (Mosaic refuses at compile time)."""
+    import re
+
+    from accelerate_tpu.models import OlmoHybridConfig
+    from accelerate_tpu.models.olmo_hybrid import OlmoHybridGatedDeltaNet
+    from accelerate_tpu.ops import gated_delta as gd
+
+    del compile_for_chip                                # the compile cache off, as for every compile here
+    monkeypatch.setattr(gd, "_on_tpu", lambda: True)
+    cfg = OlmoHybridConfig.olmo_hybrid_7b(dtype=BF16)
+    assert (cfg.linear_num_value_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim) == (30, 96, 192)
+    layer = OlmoHybridGatedDeltaNet(cfg)
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    x = jax.ShapeDtypeStruct((1, 8192, cfg.hidden_size), jnp.float32, sharding=one_chip)
+    params = on_chip(jax.eval_shape(layer.init, jax.random.key(0), x))
+    mixed = jax.checkpoint(layer.apply, policy=jax.checkpoint_policies.save_only_these_names(gd.KEPT_ACROSS_REMAT))
+    loss = lambda p, x: jnp.sum(jnp.square(mixed(p, x)))                 # the layers behind it read its output
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile()
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(_custom_calls(text, "linear_chunk_fwd")) == 2 and len(_custom_calls(text, "linear_chunk_bwd")) == 1
+    assert len(kernels) == 3 and all(re.search(r"\blinear_chunk\b", line) for line in kernels)
+    loops = re.findall(r' while\(.*op_name="([^"]*)"', text)
+    assert len(loops) == 3 and all("linear_chunk" in name and name.count("while") == 1 for name in loops), loops
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2**30
+
+
+def test_the_walks_kernels_under_a_four_chip_mesh(topo, compile_for_chip, monkeypatch):
+    """The rule's forward + backward over ``topo.devices`` as ``dp_shard`` 4,
+    four rows of the cell's heads: GSPMD cannot partition a Mosaic call, so
+    each walk goes manual over the batch axes (``per_shard``; the rows were
+    folded into the heads rows first) and every device walks its own row's
+    30 heads.  Invisible on the CPU mesh, where interpreted kernels are plain XLA."""
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from accelerate_tpu import Accelerator, ParallelismConfig
+    from accelerate_tpu.ops import gated_delta as gd
+
+    del compile_for_chip
+    monkeypatch.setattr(gd, "_on_tpu", lambda: True)
+    acc = Accelerator(parallelism_config=ParallelismConfig(dp_shard_size=4, devices=list(topo.devices)))
+    rows = NamedSharding(acc.mesh, P("dp_shard"))
+    arg = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=rows)
+    b, t, hv, dk, dv = 4, 256, 30, 96, 192
+    loss = lambda *a: jnp.sum(jnp.square(gd.gated_delta_chunk(*a)[0]))
+    text = jax.jit(jax.grad(loss, argnums=range(6))).lower(
+        arg(b, t, hv, dk), arg(b, t, hv, dk), arg(b, t, hv, dv), arg(b, t, hv), arg(b, t, hv),
+        arg(b, hv, dk, dv)).compile().as_text()
+    assert len(_custom_calls(text, "linear_chunk_fwd")) == 1 and len(_custom_calls(text, "linear_chunk_bwd")) == 1
+    assert re.search(r"%linear_chunk_fwd\S* = \(f32\[30,4,96,192\]", text)      # one row's heads a device
+    assert "all-gather" not in text and "all-to-all" not in text           # no row leaves its device
