@@ -24,7 +24,14 @@ With ``rms(x; w) = x / sqrt(mean(x^2) + eps) * w``, layer ``i`` of kind
   softplus(a + dt_bias)``; the gated delta rule from a zero state at position 0
   (``ops/gated_delta.gated_delta_chunk``: the chunked form, differentiable
   with a backward pass that keeps one state a block); out: ``W_o [rms(o; w_o)
-  * silu(z)]`` with ``w_o`` of ``Dv`` shared by the heads.
+  * silu(z)]`` with ``w_o`` of ``Dv`` shared by the heads.  What stands around
+  the rule runs as two passes over the rows (``ops/delta_mixer.py``), each one
+  Pallas kernel forward and one backward with its gradient by hand:
+  ``conv_silu_l2norm`` takes the q, k and v projections' float32 outputs
+  through conv, silu, the L2 norms and q's scale in one launch (each array
+  read and written where it lies), ``gated_rmsnorm`` makes ``rms(o; w_o) *
+  silu(z)`` and its one cast; each keeps only its inputs for the backward,
+  which ``remat`` makes again anyway.
 
 Precision: weights and the large matmuls' operands in ``dtype`` (bf16); the
 residual stream, the norms, the conv, the L2 norms, ``beta``, ``g`` and the
@@ -46,6 +53,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops import delta_mixer
 from ..ops import gated_delta as gd
 from ..ops.flash_attention import mesh_flash_attention
 from .k_exaone import KExaoneMLP
@@ -139,6 +147,14 @@ class OlmoHybridAttention(nn.Module):
             _rows(out.reshape(b, t, h * d), tp_dim=-1))
 
 
+class _Scale(nn.Module):
+    """A norm's weight under the name ``RMSNorm`` gives it (``<name>/scale``)."""
+
+    @nn.compact
+    def __call__(self, width):
+        return self.param("scale", nn.initializers.ones, (width,), jnp.float32)
+
+
 class OlmoHybridGatedDeltaNet(nn.Module):
     config: OlmoHybridConfig
 
@@ -153,24 +169,19 @@ class OlmoHybridGatedDeltaNet(nn.Module):
         vector = lambda name: self.param(name, nn.initializers.zeros, (vh,), jnp.float32).astype(jnp.float32)
         with jax.named_scope("linear_project"):
             q, k, v = proj(kh * dk, "q_proj")(x), proj(kh * dk, "k_proj")(x), proj(vh * dv, "v_proj")(x)
-            z = proj(vh * dv, "g_proj")(x).reshape(b, t, vh, dv)
+            z = proj(vh * dv, "g_proj")(x)
             beta = jax.nn.sigmoid(proj(vh, "b_proj")(x)) * (2.0 if cfg.linear_allow_neg_eigval else 1.0)
             g = -jnp.exp(vector("A_log")) * jax.nn.softplus(proj(vh, "a_proj")(x) + vector("dt_bias"))
-
-        def conved(a, name):
-            """silu of the depthwise causal conv, every row from zeros before position 0."""
-            weight = self.param(name, nn.initializers.lecun_normal(), (taps, a.shape[-1]), jnp.float32)
-            window = jnp.zeros((taps - 1, a.shape[-1]), jnp.float32)
-            return jax.nn.silu(jax.vmap(lambda row: gd.causal_conv_chunk(row, window, weight, t)[0])(a))
-
+        conv = lambda a, name: self.param(name, nn.initializers.lecun_normal(), (taps, a.shape[-1]), jnp.float32)
         with jax.named_scope("linear_conv"):
-            q = gd.l2norm(conved(q, "q_conv1d").reshape(b, t, kh, dk)) * dk ** -0.5
-            k = gd.l2norm(conved(k, "k_conv1d").reshape(b, t, kh, dk))
-            v = conved(v, "v_conv1d").reshape(b, t, vh, dv)
-        o, _ = gd.gated_delta_chunk(q, k, v, g, beta, jnp.zeros((b, vh, dk, dv), jnp.float32))
+            # one pass: the conv from zeros before position 0, silu, q's and k's L2 norms and q's ``Dk^-0.5``
+            q, k, v = delta_mixer.conv_silu_l2norm(
+                q, k, v, conv(q, "q_conv1d"), conv(k, "k_conv1d"), conv(v, "v_conv1d"), kh)
+        o, _ = gd.gated_delta_chunk(q.reshape(b, t, kh, dk), k.reshape(b, t, kh, dk), v.reshape(b, t, vh, dv),
+                                    g, beta, jnp.zeros((b, vh, dk, dv), jnp.float32))
         with jax.named_scope("linear_out"):
-            normed = RMSNorm(cfg.rms_norm_eps, jnp.float32, name="o_norm")(o)      # over Dv, one weight for all heads
-            gated = (normed * jax.nn.silu(z)).reshape(b, t, vh * dv).astype(cfg.dtype)
+            weight = _Scale(name="o_norm")(dv)                                  # over Dv, one weight for all heads
+            gated = delta_mixer.gated_rmsnorm(o.reshape(b, t, vh * dv), z, weight, cfg.rms_norm_eps, cfg.dtype)
         with jax.named_scope("linear_project"):
             return proj(cfg.hidden_size, "o_proj")(gated)
 

@@ -273,17 +273,48 @@ def _primitives(jaxpr):
     return [eqn.primitive.name for eqn in _eqns(jaxpr)]
 
 
-@pytest.mark.parametrize("t,rows,digest", [(200, 0, "bdcc9bbf7ba86d06"), (64 * (gd.SEGMENT + 2), 2, "aea06d3a8a028ce3")],
-                         ids=["a_prefill_chunk", "rows_past_a_segment"])
-def test_only_a_differentiated_call_traces_the_kernels(t, rows, digest):
+def _qwen3_next_prefill_layer(t):
+    """The jaxpr of ONE Gated DeltaNet layer of ``models/qwen3_next.py`` as a
+    prefill chunk of ``t`` tokens traces it: in-projections, the conv over the
+    slot's window, silu, the L2 norms, the chunked rule from the slot's state,
+    the gated norm, the out-projection."""
+    from accelerate_tpu.models.qwen3_next import Qwen3NextConfig, Qwen3NextGatedDeltaNet
+
+    cfg = Qwen3NextConfig.tiny()
+    layer = Qwen3NextGatedDeltaNet(cfg)
+    slots, vh, dk, dv = 3, cfg.linear_value_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    cache = {"slots": jnp.asarray([1], jnp.int32), "state": jnp.zeros((slots, vh, dk, dv), jnp.float32),
+             "conv": jnp.zeros((slots, cfg.linear_conv_kernel_dim - 1, cfg.conv_channels), cfg.dtype)}
+    x, positions = jnp.zeros((1, t, cfg.hidden_size), jnp.float32), jnp.arange(t, dtype=jnp.int32)[None]
+    live = jnp.ones((1, t), bool)
+    params = jax.eval_shape(layer.init, jax.random.key(0), x, positions, cache, live)
+    return jax.make_jaxpr(layer.apply)(params, x, positions, cache, live).jaxpr
+
+
+@pytest.mark.parametrize("traced,t,rows,digest", [
+    ("rule", 200, 0, "bdcc9bbf7ba86d06"), ("rule", 64 * (gd.SEGMENT + 2), 2, "aea06d3a8a028ce3"),
+    ("qwen3_next_prefill_layer", 200, 0, "fa2cdfa8a2e50705")],
+    ids=["a_prefill_chunk", "rows_past_a_segment", "qwen3_next_prefill_layer"])
+def test_only_a_differentiated_call_traces_the_kernels(traced, t, rows, digest):
     """The split between the rule's two users falls on the call itself: an
     undifferentiated ``gated_delta_chunk`` (Qwen3-Next's prefill) traces the
     plain-XLA primal alone - no ``pallas_call``, and the primitive list of
     the commit before the kernels, pinned by its digest - so a serving
     program lowers what it always lowered; under ``jax.grad`` the two walks
     are there, one ``linear_chunk_fwd`` and one ``linear_chunk_bwd`` a call of
-    ``_blocks``, and no scan over the blocks."""
+    ``_blocks``, and no scan over the blocks.  The third case pins the same of
+    the WHOLE linear layer of ``models/qwen3_next.py`` as a prefill chunk
+    traces it (the conv, silu, the norms and the gated norm around the rule
+    too: ``gd.causal_conv_chunk``, ``gd.l2norm``, plain ``jax.numpy``): the
+    primitive list of the commit before the training mixer's fused passes
+    (``ops/delta_mixer.py``), no ``pallas_call``, and the one ``custom_vjp_call``
+    that is the rule's own."""
     import hashlib
+    if traced == "qwen3_next_prefill_layer":
+        primal = _primitives(_qwen3_next_prefill_layer(t))
+        assert "pallas_call" not in primal and primal.count("custom_vjp_call") == 1
+        assert hashlib.sha256(",".join(primal).encode()).hexdigest()[:16] == digest, ",".join(primal)
+        return
     one = lambda seed: _rule_inputs(seed, t, 2, 8, 12, True)
     args = tuple(jnp.stack(both) for both in zip(one(1), one(2))) if rows else one(1)
     primal = _primitives(jax.make_jaxpr(gd.gated_delta_chunk)(*args).jaxpr)
@@ -314,6 +345,138 @@ def test_remat_makes_the_rules_inverse_once_a_layer():
     plain = OlmoHybridForCausalLM(dataclasses.replace(model.config, remat=False))
     again = jax.checkpoint(plain.apply, policy=jax.checkpoint_policies.nothing_saveable)
     assert square_products(again) == 3 * 31
+
+
+# -- 3b. the two passes around the rule, each against the plain composition it replaces ---------------
+
+
+def _plain_conv_pass(q, k, v, q_taps, k_taps, v_taps, heads):
+    """``conved`` as the model wrote it before the fused pass, and the L2 norms: ``gd.causal_conv_chunk``
+    from a zero window, ``jax.nn.silu``, ``gd.l2norm`` per head, q's ``Dk^-0.5``."""
+    def conved(a, taps):
+        window = jnp.zeros((taps.shape[0] - 1, a.shape[-1]), jnp.float32)
+        return jax.nn.silu(jax.vmap(lambda row: gd.causal_conv_chunk(row, window, taps, a.shape[1])[0])(a))
+
+    dk = q.shape[-1] // heads
+    per_head = lambda a: gd.l2norm(a.reshape(a.shape[:2] + (heads, dk))).reshape(a.shape)
+    return per_head(conved(q, q_taps)) * dk ** -0.5, per_head(conved(k, k_taps)), conved(v, v_taps)
+
+
+def _plain_gated_norm(o, z, weight, eps, dtype, heads):
+    from accelerate_tpu.models.llama import RMSNorm
+
+    split = lambda a: a.reshape(a.shape[:2] + (heads, -1))
+    normed = RMSNorm(eps, jnp.float32).apply({"params": {"scale": weight}}, split(o))
+    return (normed * jax.nn.silu(split(z))).reshape(o.shape).astype(dtype)
+
+
+def _conv_pass_inputs(batch, t, heads, dk, dv, seed=0):
+    keys = jax.random.split(jax.random.key(seed), 9)
+    normal = lambda i, *shape: jax.random.normal(keys[i], shape, jnp.float32)
+    x = (normal(0, batch, t, heads * dk), normal(1, batch, t, heads * dk), normal(2, batch, t, heads * dv))
+    taps = tuple(normal(3 + i, 4, a.shape[-1]) * 0.5 for i, a in enumerate(x))
+    cotangents = tuple(normal(6 + i, *a.shape) for i, a in enumerate(x))
+    return x + taps, cotangents
+
+
+def _close(got, want, limit, name=""):
+    np.testing.assert_allclose(got, want, atol=limit * max(float(jnp.max(jnp.abs(want))), 1e-30), rtol=0, err_msg=name)
+
+
+CONV_LEAVES = ("q", "k", "v", "q_taps", "k_taps", "v_taps")
+
+
+@pytest.mark.parametrize("batch,t,heads,dk,dv", [
+    (2, 37, 4, 8, 16),          # the tiny heads, two rows, T no multiple of anything: one block a lane-part wide
+    (1, 3, 4, 8, 16),           # the first three rows: nothing but the zero history
+    (1, 300, 4, 96, 192),       # one block of the published heads (four: 384, 384 and 768 lanes), three blocks of rows
+    (1, 140, 6, 96, 192),       # a block and a half of them: the last block hangs over each array's edge
+], ids=["tiny_heads_two_rows", "first_three_rows", "published_heads", "published_heads_over_the_edge"])
+def test_the_conv_pass_is_the_plain_composition(batch, t, heads, dk, dv):
+    """``delta_mixer.conv_silu_l2norm`` (its kernels interpreted) against
+    ``gd.causal_conv_chunk`` + ``jax.nn.silu`` + ``gd.l2norm``: the three
+    outputs and ``jax.vjp``'s six gradients, float32.  Row 5 of q's first
+    sequence and the three before it are zero, so that row's conv is zero and
+    its norm is the eps alone: finite, and the gradient there too.  5e-6 of
+    the largest element (read: up to 1e-6; the taps' sums over the rows 3e-7)."""
+    from accelerate_tpu.ops import delta_mixer
+
+    args, cotangents = _conv_pass_inputs(batch, t, heads, dk, dv)
+    if t > 5:
+        args = (args[0].at[0, 2:6].set(0.0),) + args[1:]
+    got, pull = jax.vjp(lambda *a: delta_mixer.conv_silu_l2norm(*a, heads), *args)
+    want, pull_plain = jax.vjp(lambda *a: _plain_conv_pass(*a, heads), *args)
+    for name, a, b in zip("qkv", got, want):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        _close(a, b, 5e-6, name)
+    if t > 5:
+        np.testing.assert_array_equal(got[0][0, 5], 0.0)
+    for name, a, b in zip(CONV_LEAVES, pull(cotangents), pull_plain(cotangents)):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        _close(a, b, 5e-6, name)
+
+
+@pytest.mark.parametrize("leaf", [3, 4, 5], ids=CONV_LEAVES[3:])
+def test_each_taps_gradient_alone_is_the_plain_compositions(leaf):
+    """``jax.grad`` in ONE of the three conv weights (the others' cotangents
+    are then symbolic zeros inside jax, the kernel's own sums still all made):
+    a loss that reads all three outputs, at the published heads over two rows."""
+    from accelerate_tpu.ops import delta_mixer
+
+    args, weights = _conv_pass_inputs(2, 70, 4, 96, 192, seed=leaf)
+    loss = lambda f: lambda *a: sum(jnp.sum(jnp.square(out) * w) for out, w in zip(f(*a, 4), weights))
+    got = jax.grad(loss(delta_mixer.conv_silu_l2norm), argnums=leaf)(*args)
+    _close(got, jax.grad(loss(_plain_conv_pass), argnums=leaf)(*args), 5e-6)
+
+
+@pytest.mark.parametrize("batch,t,heads,dv,dtype", [
+    (2, 37, 4, 16, jnp.float32), (1, 300, 2, 192, jnp.float32), (1, 300, 3, 192, jnp.bfloat16),
+], ids=["tiny_heads_two_rows", "published_heads", "published_heads_over_the_edge_bf16"])
+def test_the_gated_norm_pass_is_the_plain_composition(batch, t, heads, dv, dtype):
+    """``delta_mixer.gated_rmsnorm`` against ``RMSNorm`` x ``silu(z)`` and
+    the cast: the value (to one rounding of ``dtype``) and the gradients of
+    ``o``, ``z`` and the norm's ``scale`` (a sum over rows and heads), with a
+    cotangent in ``dtype`` as the projection behind it hands back.  A row of
+    ``o`` is zero: the eps keeps its norm finite."""
+    from accelerate_tpu.ops import delta_mixer
+
+    keys = jax.random.split(jax.random.key(t), 4)
+    o, z, ct = (jax.random.normal(k, (batch, t, heads * dv), jnp.float32) for k in keys[:3])
+    o, weight = o.at[0, 1].set(0.0), 1.0 + 0.1 * jax.random.normal(keys[3], (dv,), jnp.float32)
+    got, pull = jax.vjp(lambda *a: delta_mixer.gated_rmsnorm(*a, 1e-6, dtype), o, z, weight)
+    want, pull_plain = jax.vjp(lambda *a: _plain_gated_norm(*a, 1e-6, dtype, heads), o, z, weight)
+    assert got.dtype == dtype and bool(jnp.all(jnp.isfinite(got.astype(jnp.float32))))
+    _close(got.astype(jnp.float32), want.astype(jnp.float32), 5e-6 if dtype == jnp.float32 else 2 ** -8)
+    for name, a, b in zip(("o", "z", "scale"), pull(ct.astype(dtype)), pull_plain(ct.astype(dtype))):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        _close(a, b, 5e-6, name)
+
+
+def test_the_two_passes_gradients_under_two_shards_are_one_devices():
+    """``dp_shard`` 2 on the CPU mesh, two rows, jitted: each pass's launch
+    then goes through ``per_shard``'s ``shard_map`` (a device runs the kernel
+    on its own row), and what is summed over the rows - the taps' gradients,
+    the norm's scale's - leaves the call as one partial a row and is added up
+    outside.  Against the same call with no mesh."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from accelerate_tpu import Accelerator
+    from accelerate_tpu.ops import delta_mixer
+    from accelerate_tpu.parallelism_config import ParallelismConfig
+
+    args, cotangents = _conv_pass_inputs(2, 70, 4, 8, 16)
+    o, z, ct = cotangents[2], args[2], cotangents[2] * 0.5
+    weight = 1.0 + 0.1 * jnp.arange(16, dtype=jnp.float32)
+    conv = lambda *a: jax.vjp(lambda *x: delta_mixer.conv_silu_l2norm(*x, 4), *a[:6])[1](a[6:])
+    gate = lambda o, z, w, ct: jax.vjp(lambda *x: delta_mixer.gated_rmsnorm(*x, 1e-6, jnp.float32), o, z, w)[1](ct)
+    want = conv(*args, *cotangents) + gate(o, z, weight, ct)
+    acc = Accelerator(parallelism_config=ParallelismConfig(dp_shard_size=2, devices=jax.devices()[:2]))
+    rows, whole = NamedSharding(acc.mesh, P("dp_shard")), NamedSharding(acc.mesh, P())
+    put = lambda arrays: tuple(jax.device_put(a, rows if a.ndim == 3 else whole) for a in arrays)
+    got = jax.jit(conv)(*put(args + cotangents)) + jax.jit(gate)(*put((o, z, weight, ct)))
+    assert "dp_shard" in str(got[0].sharding.spec)                  # the rows stayed where they were
+    for name, a, b in zip(CONV_LEAVES + ("o", "z", "scale"), got, want):
+        _close(a, b, 2e-6, name)
 
 
 def test_a_batch_of_rows_is_each_row_alone():
@@ -383,7 +546,11 @@ def test_three_prepared_steps_with_lion_sr_follow_the_train_reference(model_dtyp
     times ``1 + 2**-22`` and ``1 - 2**-22``; 0.062 with the kernels of PR 44
     and 0.020 with their output times ``1 + 2**-22``; over seeds 1-4 and those
     five programs 0.005-0.075, mean 0.035, neither rule the lower (my CPU
-    runs, PR 44: ``_chunk``'s ``o`` scaled in a copy of each tree)."""
+    runs, PR 44: ``_chunk``'s ``o`` scaled in a copy of each tree); 0.022 with
+    the fused passes of PR 46 around the rule, and 0.140 - over the limit - while
+    their conv summed its taps newest first and their norm met q's scale in
+    another order than the plain composition's: the same draw (my CPU runs, PR
+    46; seeds 1-4 then 0.016-0.061 against 0.015-0.074 on the tree before)."""
     seed, hy = 1, RECIPE["optimizer_hyper"]
     acc, step, new_state = family.build_trainer(WIDER, LAYERS, dict(RECIPE, model_dtype=model_dtype))
     state = new_state(seed)

@@ -608,15 +608,29 @@ def test_olmo_hybrids_linear_layer_trains_through_the_two_walks_kernels(one_chip
     inside ``_chunk``'s loop over the four segments, all under the
     ``linear_chunk`` scope that the cell's two metrics read; no other loop is
     left there (the 32-step scans over the blocks are gone), and both kernels
-    fit the scoped VMEM (Mosaic refuses at compile time)."""
+    fit the scoped VMEM (Mosaic refuses at compile time).
+
+    Around the rule (PR 46, ``ops/delta_mixer.py``) the conv + silu + L2 norm
+    and the gated norm are ONE kernel each way each: ``linear_conv_fwd`` and
+    ``linear_out_fwd`` twice (the first pass, the recompute), ``linear_conv_bwd``
+    and ``linear_out_bwd`` once, every one under the scope word that
+    ``linear_project_device_ms.train`` reads, the gradient's too; beside them
+    the compiler leaves under those two words no fusion over the rows, only
+    the relayouts of ``o`` and ``d(o)`` between the rule and the gate's kernel.  What every start pays for them is
+    guarded without a clock: the program's StableHLO is at most 1.5 x the
+    196,725 bytes it had before the passes were kernels, and TWO such layers
+    lower each pass's body as often as one does (a ``jit`` a pass: one
+    function, called from each layer)."""
     import re
 
     from accelerate_tpu.models import OlmoHybridConfig
     from accelerate_tpu.models.olmo_hybrid import OlmoHybridGatedDeltaNet
+    from accelerate_tpu.ops import delta_mixer
     from accelerate_tpu.ops import gated_delta as gd
 
     del compile_for_chip                                # the compile cache off, as for every compile here
     monkeypatch.setattr(gd, "_on_tpu", lambda: True)
+    monkeypatch.setattr(delta_mixer, "_on_tpu", lambda: True)
     cfg = OlmoHybridConfig.olmo_hybrid_7b(dtype=BF16)
     assert (cfg.linear_num_value_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim) == (30, 96, 192)
     layer = OlmoHybridGatedDeltaNet(cfg)
@@ -626,11 +640,30 @@ def test_olmo_hybrids_linear_layer_trains_through_the_two_walks_kernels(one_chip
     params = on_chip(jax.eval_shape(layer.init, jax.random.key(0), x))
     mixed = jax.checkpoint(layer.apply, policy=jax.checkpoint_policies.save_only_these_names(gd.KEPT_ACROSS_REMAT))
     loss = lambda p, x: jnp.sum(jnp.square(mixed(p, x)))                 # the layers behind it read its output
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile()
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x)
+    bodies = lambda text: {name: text.count(f'kernel_name = "{name}"') for name in (
+        "linear_conv_fwd", "linear_conv_bwd", "linear_out_fwd", "linear_out_bwd")}
+    stablehlo = lowered.as_text()
+    assert len(stablehlo) <= 1.5 * 196_725, len(stablehlo)
+    assert bodies(stablehlo) == {"linear_conv_fwd": 2, "linear_conv_bwd": 1, "linear_out_fwd": 2, "linear_out_bwd": 1}
+    two = lambda p, q, x: jnp.sum(jnp.square(mixed(q, x + mixed(p, x))))
+    assert bodies(jax.jit(jax.grad(two, argnums=(0, 1))).lower(params, params, x).as_text()) == bodies(stablehlo)
+
+    compiled = lowered.compile()
     text = compiled.as_text()
     kernels = [line for line in text.splitlines() if "tpu_custom_call" in line]
     assert len(_custom_calls(text, "linear_chunk_fwd")) == 2 and len(_custom_calls(text, "linear_chunk_bwd")) == 1
-    assert len(kernels) == 3 and all(re.search(r"\blinear_chunk\b", line) for line in kernels)
+    for scope, counts in (("linear_conv", (2, 1)), ("linear_out", (2, 1))):
+        calls = [_custom_calls(text, f"{scope}_{way}") for way in ("fwd", "bwd")]
+        assert tuple(len(c) for c in calls) == counts, (scope, calls)
+        assert all(re.search(rf"/{scope}/", line) for c in calls for line in c)
+    assert len(kernels) == 9
+    assert sum(bool(re.search(r"\blinear_chunk\b", line)) for line in kernels) == 3
+    # beside the kernels, no fusion over the rows carries the two words (the passes' arithmetic is all inside);
+    # what does is the relayout of ``o`` into the array the gate's kernel reads, twice, and of ``d(o)`` out of it
+    named = re.findall(r'^\s+%?\S+ = \S+\[1,8192,\S+ (fusion|copy)\(.*op_name="[^"]*/(?:linear_conv|linear_out)/',
+                       text, re.M)
+    assert named.count("fusion") == 0 and named.count("copy") <= 3, named
     loops = re.findall(r' while\(.*op_name="([^"]*)"', text)
     assert len(loops) == 3 and all("linear_chunk" in name and name.count("while") == 1 for name in loops), loops
     assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2**30
@@ -662,3 +695,38 @@ def test_the_walks_kernels_under_a_four_chip_mesh(topo, compile_for_chip, monkey
     assert len(_custom_calls(text, "linear_chunk_fwd")) == 1 and len(_custom_calls(text, "linear_chunk_bwd")) == 1
     assert re.search(r"%linear_chunk_fwd\S* = \(f32\[30,4,96,192\]", text)      # one row's heads a device
     assert "all-gather" not in text and "all-to-all" not in text           # no row leaves its device
+
+
+def test_the_mixers_passes_under_a_four_chip_mesh(topo, compile_for_chip, monkeypatch):
+    """The two passes around the rule (``ops/delta_mixer.py``), forward +
+    backward over ``topo.devices`` as ``dp_shard`` 4, four rows at the cell's
+    widths: each launch goes manual over the batch axes (``per_shard``), a
+    device runs the kernels on its own row, no row leaves its device, and the
+    sums over the rows (the taps' gradients, the norm's scale's) are added up
+    outside the kernels: one all-reduce each, of ``[4, C]`` and ``[1, C]``."""
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from accelerate_tpu import Accelerator, ParallelismConfig
+    from accelerate_tpu.ops import delta_mixer
+
+    del compile_for_chip
+    monkeypatch.setattr(delta_mixer, "_on_tpu", lambda: True)
+    acc = Accelerator(parallelism_config=ParallelismConfig(dp_shard_size=4, devices=list(topo.devices)))
+    rows, whole = NamedSharding(acc.mesh, P("dp_shard")), NamedSharding(acc.mesh, P())
+    arg = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=rows if len(shape) == 3 else whole)
+    b, t, heads, dk, dv = 4, 256, 30, 96, 192
+
+    def loss(q, k, v, q_taps, k_taps, v_taps, z, weight):
+        q, k, v = delta_mixer.conv_silu_l2norm(q, k, v, q_taps, k_taps, v_taps, heads)
+        gated = delta_mixer.gated_rmsnorm(v, z, weight, 1e-6, BF16)
+        return jnp.sum(jnp.square(q)) + jnp.sum(jnp.square(k)) + jnp.sum(jnp.square(gated.astype(jnp.float32)))
+
+    text = jax.jit(jax.grad(loss, argnums=range(8))).lower(
+        arg(b, t, heads * dk), arg(b, t, heads * dk), arg(b, t, heads * dv), arg(4, heads * dk), arg(4, heads * dk),
+        arg(4, heads * dv), arg(b, t, heads * dv), arg(dv)).compile().as_text()
+    for name in ("linear_conv_fwd", "linear_conv_bwd", "linear_out_fwd", "linear_out_bwd"):
+        assert len(_custom_calls(text, name)) == 1, name
+    assert re.search(r"%linear_conv_bwd\S* = \(f32\[1,256,2880\]", text)        # one row a device
+    assert "all-gather" not in text and "all-to-all" not in text                # no row leaves its device
