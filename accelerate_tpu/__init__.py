@@ -18,7 +18,6 @@ from .utils.dataclasses import (
     DistributedOperationException,
     DistributedType,
     ExpertParallelConfig,
-    FP8RecipeKwargs,
     FullyShardedDataParallelPlugin,
     GradientAccumulationPlugin,
     GradSyncKwargs,

@@ -47,7 +47,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from .data_loader import DataLoaderDispatcher, DataLoaderShard, prepare_data_loader, skip_first_batches
 from .ops import operations as ops
-from .ops.precision import DynamicLossScale, Policy, all_finite, fp8_autocast, get_policy
+from .ops.precision import DynamicLossScale, Policy, all_finite, get_policy
 from .optimizer import AcceleratedOptimizer
 from .parallel.sharding import (
     device_plan,
@@ -69,7 +69,6 @@ from .utils.dataclasses import (
     AutocastKwargs,
     ContextParallelConfig,
     DataLoaderConfiguration,
-    FP8RecipeKwargs,
     FullyShardedDataParallelPlugin,
     GradientAccumulationPlugin,
     GradSyncKwargs,
@@ -120,11 +119,6 @@ if _HAS_FLAX:
         # scalars, resilience/guard.py) — carried in the state so they
         # survive checkpoint/resume; None unless ResiliencePlugin.nan_guard
         guard_state: Any = None
-        # fp8 delayed-scaling metas (per-kernel amax history + scale,
-        # ops/fp8.py) — None unless mixed_precision="fp8" arms the delayed
-        # recipe; rides the state comm_state-style (checkpointed, updated
-        # functionally by the jitted step)
-        fp8_state: Any = None
         apply_fn: Callable = flax.struct.field(pytree_node=False, default=None)
         tx: Any = flax.struct.field(pytree_node=False, default=None)
         # .replace(**kwargs) is provided by flax.struct.dataclass
@@ -287,7 +281,6 @@ class Accelerator:
         self.grad_sync_kwargs = GradSyncKwargs()
         self.init_process_group_kwargs: Optional[InitProcessGroupKwargs] = None
         self.profile_kwargs = ProfileKwargs()
-        self.fp8_recipe: Optional[FP8RecipeKwargs] = None
         for handler in kwargs_handlers or []:
             if isinstance(handler, AutocastKwargs):
                 self.autocast_handler = handler
@@ -297,8 +290,6 @@ class Accelerator:
                 self.init_process_group_kwargs = handler
             elif isinstance(handler, ProfileKwargs):
                 self.profile_kwargs = handler
-            elif isinstance(handler, FP8RecipeKwargs):
-                self.fp8_recipe = handler
 
         state_kwargs = {}
         if self.init_process_group_kwargs is not None:
@@ -881,25 +872,6 @@ class Accelerator:
                 qs = jax.tree_util.tree_map(lambda q: jax.device_put(q, rep), qs)
                 errs = jax.tree_util.tree_map(lambda e: jax.device_put(e, err_sh), errs)
             comm_state = (qs, errs)
-        fp8_state = None
-        if str(self.mixed_precision) == "fp8":
-            from .ops.fp8 import fp8_delayed_enabled, init_fp8_state
-
-            if fp8_delayed_enabled():
-                recipe = self.fp8_recipe
-                fp8_state = init_fp8_state(
-                    params,
-                    history_len=recipe.amax_history_len if recipe else None,
-                    margin=recipe.margin if recipe else None,
-                )
-                if fp8_state is not None and sharded:
-                    # metas are tiny (history vector + scalar scale) —
-                    # replicate them onto the mesh's device set so the
-                    # jitted step sees one device set end-to-end
-                    rep = NamedSharding(self.mesh, PartitionSpec())
-                    fp8_state = jax.tree_util.tree_map(
-                        jax.jit(lambda x: x, out_shardings=rep), fp8_state
-                    )
         state = TrainState(
             step=jnp.int32(0),
             params=params,
@@ -912,7 +884,6 @@ class Accelerator:
             guard_state=(
                 _guard.init_guard_state() if self.resilience_plugin.nan_guard else None
             ),
-            fp8_state=fp8_state,
             apply_fn=apply_fn,
             tx=tx,
         )
@@ -1050,7 +1021,6 @@ class Accelerator:
                 params, device_plan(psh),
             )
 
-        use_fp8 = str(self.mixed_precision) == "fp8"
         # DDP "sum" semantics: the GSPMD-implicit reduction produces the
         # global-mean gradient (grad of the global-mean loss), so
         # average_grads=False rescales the tree by the data-parallel world
@@ -1068,7 +1038,7 @@ class Accelerator:
                     f"mixed_precision={self.mixed_precision!r}"
                 )
 
-        def compute_grads(params, batch, rng, loss_scale, fp8_state=None):
+        def compute_grads(params, batch, rng, loss_scale):
             if compute_width_grads:
                 # differentiate wrt the compute-width copy: every grad leaf is
                 # born bf16 and the fp32 grad tree never exists in HBM — the
@@ -1078,25 +1048,8 @@ class Accelerator:
             def scaled_loss(p, mb):
                 if not compute_width_grads:
                     p = policy.cast_to_compute(p)
-                if use_fp8 and fp8_state is not None \
-                        and isinstance(p, dict) and "params" in p:
-                    # delayed scaling: the meta tree rides into the trace as
-                    # the read-only "fp8" collection (ops/fp8.py) — flax
-                    # apply ignores extra collections, so the user loss_fn
-                    # signature is untouched.  Bare param trees (no variables
-                    # wrapper) can't carry a collection and simply stay on
-                    # current scaling.
-                    from .ops.fp8 import merge_fp8_collection
-
-                    p = merge_fp8_collection(p, fp8_state)
                 mb_args = (p, mb, rng) if wants_rng else (p, mb)
-                if use_fp8:
-                    # trace the model under the fp8 region: QuantizableDense
-                    # layers route their matmuls through scaled e4m3
-                    with fp8_autocast():
-                        out = loss_fn(*mb_args)
-                else:
-                    out = loss_fn(*mb_args)
+                out = loss_fn(*mb_args)
                 loss, aux = (out if has_aux else (out, None))
                 # the scalar loss always lives in fp32 (torch-AMP keeps
                 # reductions fp32); otherwise scaling by 2^16 overflows fp16
@@ -1380,21 +1333,12 @@ class Accelerator:
                     metrics = _guard.guard_metrics(metrics, finite, new_guard_state)
                 else:
                     metrics["nan_skipped"] = jnp.logical_not(finite)
-            new_fp8_state = state.fp8_state
-            if new_fp8_state is not None:
-                # delayed-scaling tick: the history rolls against the
-                # POST-update kernels, so the scale used at step t+1 was
-                # derived from amaxes observed through step t (TE contract)
-                from .ops.fp8 import update_fp8_state
-
-                new_fp8_state = update_fp8_state(new_fp8_state, new_params)
             new_state = state.replace(
                 step=state.step + 1,
                 params=new_params,
                 opt_state=new_opt,
                 loss_scale=new_scale,
                 guard_state=new_guard_state,
-                fp8_state=new_fp8_state,
             )
             return new_state, metrics
 
@@ -1627,8 +1571,7 @@ class Accelerator:
 
                 def microbatch(carry, mb):
                     grads_acc, loss_acc, _prev_aux = carry
-                    loss, aux, grads = compute_grads(params_c, mb, use_rng, state.loss_scale,
-                                                      state.fp8_state)
+                    loss, aux, grads = compute_grads(params_c, mb, use_rng, state.loss_scale)
                     # the carry accumulates in fp32 regardless of the grad
                     # wire dtype: summing accum_steps microbatches in bf16
                     # would lose ~log2(accum_steps) mantissa bits
@@ -1684,8 +1627,7 @@ class Accelerator:
             def step_fn(state: TrainState, batch):
                 rng, use_rng = jax.random.split(state.rng)
                 loss, aux, grads = compute_grads(
-                    fetch_params(state.params), batch, use_rng,
-                    state.loss_scale, state.fp8_state)
+                    fetch_params(state.params), batch, use_rng, state.loss_scale)
                 grad_accum = jax.tree_util.tree_map(jnp.add, state.grad_accum, grads)
                 accum_step = state.accum_step + 1
                 is_boundary = accum_step >= accum_steps
@@ -1718,8 +1660,7 @@ class Accelerator:
             def step_fn(state: TrainState, batch):
                 rng, use_rng = jax.random.split(state.rng)
                 loss, aux, grads = compute_grads(
-                    fetch_params(state.params), batch, use_rng,
-                    state.loss_scale, state.fp8_state)
+                    fetch_params(state.params), batch, use_rng, state.loss_scale)
                 new_state, metrics = apply_update(state.replace(rng=rng), grads, loss)
                 if has_aux:
                     metrics["aux"] = aux
@@ -1957,13 +1898,9 @@ class Accelerator:
         casting applied (the autocast analog for eval, reference :1791).
         Host-offloaded masters are fetched to device memory first."""
         policy = self.policy
-        use_fp8 = str(self.mixed_precision) == "fp8"
 
         @jax.jit
         def jitted(params, batch):
-            if use_fp8:
-                with fp8_autocast():
-                    return eval_fn(policy.cast_to_compute(params), batch)
             return eval_fn(policy.cast_to_compute(params), batch)
 
         def step(params, batch):

@@ -357,7 +357,7 @@ def wire_schema(model_config, plugin) -> dict:
     ps = plugin.page_size
     pps = plugin.pages_per_slot
     if quantized:
-        from ..models.llama import KV_QUANT_DTYPES
+        from ..ops.paged_cache import KV_QUANT_DTYPES
 
         page_dtype = str(jnp.dtype(KV_QUANT_DTYPES[kvd]))
     else:

@@ -503,7 +503,7 @@ def _audit_fp8_scaling(closed) -> list[Finding]:
     non-multiplicative consumer with no ``mul``/``div`` anywhere in the
     chain.  fp8 codes are fixed-point residue — ``q = x * scale`` cast to
     e4m3/e5m2 — so a correct fp8 matmul ALWAYS dequantizes its accumulator
-    (``out * (1 / (x_scale * w_scale))``, the ops/fp8.py contract) before
+    (``out * (1 / (x_scale * w_scale))``) before
     downstream math sees it.  The chain is followed through value-preserving
     ops (convert/transpose/reshape/...); a result that escapes its scope
     stays quiet (conservative, the GL106 discipline) since the consumer is

@@ -146,9 +146,9 @@ RULES: dict[str, Rule] = {
             "factor — the loss still goes down, just slower, which is why "
             "nothing else catches it",
             "multiply the dot result by the combined inverse scale before "
-            "anything else consumes it (ops/fp8.fp8_delayed_dot / "
-            "fp8_current_scaled_dot are the model), or route the layer "
-            "through QuantizableDense with mixed_precision='fp8'",
+            "anything else consumes it (the quantised-page kernels of "
+            "ops/flash_attention.py, which fold each page's scale into the "
+            "scores and the weighted sum, are the model)",
         ),
         Rule(
             "GL105", "unsharded-output", Severity.WARNING, "jaxpr",

@@ -27,6 +27,7 @@ from typing import Optional
 
 from .config import LaunchConfig, load_config_or_default
 from .launch import _merge_args_into_config, _validate
+from ..utils.constants import MIXED_PRECISION_CHOICES
 from ..utils.launch import config_env
 
 # Accelerator counts per host for common TPU types (public Cloud TPU docs):
@@ -239,7 +240,7 @@ def cloud_command_parser(subparsers=None) -> argparse.ArgumentParser:
     parser.add_argument("--project", default=None)
     parser.add_argument("--num_machines", type=int, default=None)
     parser.add_argument("--num_processes", type=int, default=None)
-    parser.add_argument("--mixed_precision", default=None, choices=["no", "bf16", "fp16", "fp8"])
+    parser.add_argument("--mixed_precision", default=None, choices=MIXED_PRECISION_CHOICES)
     parser.add_argument("--gradient_accumulation_steps", type=int, default=None)
     parser.add_argument("--output", "-o", default=None, help="Write the manifest here instead of stdout.")
     parser.add_argument("--submit", action="store_true",

@@ -22,6 +22,8 @@ from typing import Optional
 
 import yaml
 
+from ..utils.constants import MIXED_PRECISION_CHOICES
+
 DEFAULT_CONFIG_DIR = Path(
     os.environ.get("ACCELERATE_TPU_CACHE", Path.home() / ".cache" / "accelerate_tpu")
 )
@@ -48,7 +50,7 @@ class LaunchConfig:
     main_process_port: Optional[int] = None
     # -- execution ---------------------------------------------------------
     use_cpu: bool = False
-    mixed_precision: str = "no"  # no | bf16 | fp16 | fp8
+    mixed_precision: str = "no"  # no | bf16 | fp16
     gradient_accumulation_steps: int = 1
     debug: bool = False
     # gang restarts after a worker crash (torchrun-elasticity analog for the
@@ -183,7 +185,7 @@ def interactive_config() -> LaunchConfig:
     cfg.use_cpu = _ask("Force CPU (debug runs without an accelerator)?", False, bool)
     cfg.debug = _ask("Enable debug mode (collective shape verification)?", False, bool)
     cfg.mixed_precision = _ask_choice(
-        "Mixed precision", ("no", "bf16", "fp16", "fp8"), "bf16"
+        "Mixed precision", tuple(MIXED_PRECISION_CHOICES), "bf16"
     )
     cfg.gradient_accumulation_steps = _ask_pos_int("Gradient accumulation steps?", 1)
 

@@ -18,8 +18,7 @@ Mapping notes:
 - ``parallelism_config_*`` keys (reference cluster.py:500-546) map 1:1 onto
   the mesh axes.
 - DeepSpeed/FSDP cpu-offload flags fold into ``fsdp_offload_params``.
-- fp8 configs import as ``mixed_precision: fp8`` (recipe details are
-  backend-specific and re-tuned on TPU).
+- ``mixed_precision: fp8`` is refused by name (``utils/constants.FP8_REFUSED``).
 """
 
 from __future__ import annotations
@@ -29,6 +28,7 @@ from pathlib import Path
 
 import yaml
 
+from ..utils.constants import FP8_REFUSED
 from .config import LaunchConfig, default_config_path
 
 # Reference keys that are deliberately dropped, with the reason shown to the
@@ -54,7 +54,6 @@ _DROPPED = {
     "dynamo_config": "torch.compile config; XLA compiles the whole step on TPU",
     "ipex_config": "Intel extension; not applicable",
     "mpirun_hostfile": "MPI launcher detail",
-    "fp8_config": "fp8 recipe is backend-specific; re-tune via precision policy on TPU",
     "sagemaker_config": "SageMaker launcher not supported",
     "additional_args": "SageMaker launcher detail",
 }
@@ -95,6 +94,8 @@ def convert(raw: dict) -> tuple[LaunchConfig, list[str]]:
     if mp == "fp16":
         notes.append("mixed_precision fp16 -> bf16 (TPU-native; fp16 loss-scaling unneeded)")
         mp = "bf16"
+    if mp == "fp8":
+        raise ValueError(f"mixed_precision: fp8 — {FP8_REFUSED}")
     cfg.mixed_precision = mp
 
     dist = str(take("distributed_type", "NO") or "NO").upper()

@@ -20,12 +20,12 @@ equal walks read it, chosen by the call's shape and by nothing else:
   sum_s p_s c_s``, ``o_h = W_UV,h u_h`` — the row is the key (whole) and the
   value (its first ``kv_lora_rank`` values); no key or value head is built.
   The walk is ONE Pallas kernel a layer, ``latent_decode``
-  (``ops/latent_attention.latent_decode_attention``): each slot reads its
+  (``ops/page_walk.latent_decode_attention``): each slot reads its
   own pages once, up to its own length, and nothing is gathered;
 - a prefill chunk ``[1, C]`` runs the EXPANDED form: each gathered block of
   latent rows is up-projected by ``W_kvb`` of the held heads to per-head keys
   and values, then scored (the chunk's own rows, written first, included),
-  through ``ops/sparse_attention.paged_masked_attention`` — the XLA walk the
+  through ``ops/page_walk.paged_masked_attention`` — the XLA walk the
   kernel is tested against.
 
 The pool is ONE array a layer, ``[P, page, 640]`` at the published sizes: a
@@ -64,9 +64,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import sparse_attention as sa
+from ..ops import page_walk as pw
 from ..ops import window_attention as wa
-from ..ops.latent_attention import latent_decode_attention
+from ..ops.paged_cache import init_paged_pools, page_writer
 from .k_exaone import KExaoneBlock, KExaoneMTP
 from .layers import Float32Out, apply_rotary, bias_free_proj, rotary_angles
 from .llama import LMHead, RMSNorm
@@ -247,27 +247,27 @@ class JoyAIFlashAttention(nn.Module):
         page, row = pool.shape[1:]
         with jax.named_scope("paged_write_kv"):
             pad = jnp.zeros((b, t, row - r - dr), cfg.dtype)
-            pool = sa.page_writer(tables, pos, live, page)(pool, jnp.concatenate([c, kr, pad], -1))
+            pool = page_writer(tables, pos, live, page)(pool, jnp.concatenate([c, kr, pad], -1))
         zero = jnp.zeros((), jnp.int32)
         if t == 1:      # absorbed: the row is the key, whole, and in its first r values the value
             with jax.named_scope("latent_project"):
                 qa = jnp.einsum("bthd,rhd->bthr", qn, w_kvb[..., :dn]).astype(cfg.dtype)
             with jax.named_scope("latent_attend"):
-                u = latent_decode_attention(qa[:, 0], qr[:, 0], pool, tables, q_pos[:, 0],
-                                            scale=scale)[:, None]
+                u = pw.latent_decode_attention(qa[:, 0], qr[:, 0], pool, tables, q_pos[:, 0],
+                                               scale=scale)[:, None]
             with jax.named_scope("latent_project"):
                 out = jnp.einsum("bthr,rhd->bthd", u, w_kvb[..., dn:]).astype(cfg.dtype)
             walked = jnp.sum((q_pos + page) // page, dtype=jnp.int32) * page  # each slot's own pages
             counts = jnp.stack([jnp.sum(q_pos + 1, dtype=jnp.int32), walked, zero])
         else:           # expanded: each gathered block up-projected to the held heads' keys, values
-            bp = sa.block_pages_for(b, t, h, page)
-            padded = sa.pad_block_tables(tables, bp)
+            bp = pw.block_pages_for(b, t, h, page)
+            padded = pw.pad_block_tables(tables, bp)
             walked = b * jnp.minimum((kv_len + bp * page - 1) // (bp * page),
                                      padded.shape[1] // bp) * (bp * page)
             with jax.named_scope("latent_prefill"):
-                out = sa.paged_masked_attention(
+                out = pw.paged_masked_attention(
                     jnp.concatenate([qn, qr], axis=-1), pool, None, padded, kv_len,
-                    sa.causal_mask(q_pos), scale=scale, value_width=dv, expand=expanded)
+                    pw.causal_mask(q_pos), scale=scale, value_width=dv, expand=expanded)
             counts = jnp.stack([zero, zero, walked.astype(jnp.int32)])
         return o_proj(out.reshape(b, t, h * dv)), {"latent_pages": pool}, counts
 
@@ -312,8 +312,6 @@ class JoyAIFlashForCausalLM(nn.Module):
     def init_paged_cache(self, num_pages: int, page_size: int, num_slots: int,
                          pages_per_slot: int, kv_dtype=None):
         """One pool of latent rows a layer under the engine's block table."""
-        from ..serving.paged_cache import init_paged_pools
-
         if kv_dtype in ("int8", "fp8"):
             raise NotImplementedError(self.serving_refuses["kv_dtype"])
         cfg = self.config

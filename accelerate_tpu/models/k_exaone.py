@@ -31,7 +31,7 @@ no collective and nothing in its place on one chip.
 ``serving/__init__.py``).  A ``full_attention`` layer keeps K and V in pages
 ``[P, page, Hkv * D]`` under the engine's block table; a decode step reads
 each slot's own pages once, to the slot's own length, inside one Pallas call a
-layer (``paged_walk_decode``, ``ops/latent_attention.py``), a prefill chunk
+layer (``paged_walk_decode``, ``ops/page_walk.py``), a prefill chunk
 walks blocks of gathered pages (``ops/sparse_attention.py``, plain XLA).  A
 ``sliding_attention`` layer keeps, per SLOT, a ring of ``sliding_window`` rows
 (``ops/window_attention.py``) outside the allocator: its bytes do not grow
@@ -51,9 +51,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops import sparse_attention as sa
+from ..ops import page_walk as pw
 from ..ops import window_attention as wa
-from ..ops.latent_attention import paged_walk_decode_attention
+from ..ops.paged_cache import init_paged_pools, page_writer
 from ..parallel.expert_parallel import grouped_ffn, held_rows_fed, route_dropless
 from .layers import Float32Dense, Float32Out, apply_rotary, bias_free_proj, rotary_angles
 from .llama import LMHead, RMSNorm
@@ -172,10 +172,10 @@ class KExaoneAttention(nn.Module):
         [2] — or None)``.
 
         A full-attention layer's decode step ``[S, 1]`` is the
-        ``paged_walk_decode`` Pallas kernel (``ops/latent_attention.py``: each
+        ``paged_walk_decode`` Pallas kernel (``ops/page_walk.py``: each
         slot's own K and V pages once, to its own length); its prefill chunk
         ``[1, C]`` walks blocks of gathered pages
-        (``ops/sparse_attention.paged_causal_attention``, plain XLA)."""
+        (``ops/page_walk.paged_causal_attention``, plain XLA)."""
         cfg = self.config
         b, t = x32.shape[:2]
         h, hkv, d, window = cfg.heads, cfg.kv_heads, cfg.head_dim, cfg.sliding_window
@@ -227,15 +227,15 @@ class KExaoneAttention(nn.Module):
         else:
             tables, page = cache["block_tables"], cache["k_pages"].shape[1]
             with jax.named_scope("paged_write_kv"):
-                write = sa.page_writer(tables, pos, live, page)
+                write = page_writer(tables, pos, live, page)
                 k_pages, v_pages = write(cache["k_pages"], flat(k)), write(cache["v_pages"], flat(v))
             if t == 1:      # each slot's own pages once, to its own length: one kernel
                 with jax.named_scope("global_attend"):
-                    out = paged_walk_decode_attention(q[:, 0], k_pages, v_pages, tables,
-                                                      q_pos[:, 0])[:, None]
+                    out = pw.paged_walk_decode_attention(q[:, 0], k_pages, v_pages, tables,
+                                                         q_pos[:, 0])[:, None]
             else:           # a chunk's rows against blocks of gathered pages
-                padded = sa.pad_block_tables(tables, sa.block_pages_for(b, t, h, page))
-                out = sa.paged_causal_attention(q, k_pages, v_pages, padded, q_pos,
+                padded = pw.pad_block_tables(tables, pw.block_pages_for(b, t, h, page))
+                out = pw.paged_causal_attention(q, k_pages, v_pages, padded, q_pos,
                                                 jnp.max(q_pos) + 1)
             state = {"k_pages": k_pages, "v_pages": v_pages}
             walked = jnp.sum((q_pos + page) // page, dtype=jnp.int32) * page  # each slot's own pages
@@ -389,8 +389,6 @@ class KExaoneForCausalLM(nn.Module):
                          pages_per_slot: int, kv_dtype=None):
         """Pages for the full-attention layers, a ring per slot for the window
         layers: the second is a slot-addressed kind of layer state."""
-        from ..serving.paged_cache import init_paged_pools
-
         if kv_dtype in ("int8", "fp8"):
             raise NotImplementedError(self.serving_refuses["kv_dtype"])
         cfg = self.config

@@ -34,7 +34,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops import page_walk as pw
 from ..ops import sparse_attention as sa
+from ..ops.paged_cache import init_paged_pools, page_writer
 from ..parallel.expert_parallel import grouped_ffn, route_dropless
 from .layers import Float32Dense, Float32Out, apply_rotary, bias_free_proj, rotary_angles
 from .llama import LMHead, RMSNorm
@@ -172,7 +174,7 @@ class KeyeVL2Attention(nn.Module):
         live = jnp.ones((b, t), bool) if cache_write_mask is None else cache_write_mask
         tables = cache["block_tables"]
         with jax.named_scope("paged_write_kv"):
-            write = sa.page_writer(tables, pos, live, page)
+            write = page_writer(tables, pos, live, page)
             k_pages = write(cache["k_pages"], k.reshape(b, t, hkv * d))
             v_pages = write(cache["v_pages"], v.reshape(b, t, hkv * d))
             lanes = cache["index_pages"].shape[2]
@@ -180,9 +182,9 @@ class KeyeVL2Attention(nn.Module):
                                 jnp.pad(k_idx, ((0, 0), (0, 0), (0, lanes - k_idx.shape[2]))))
         q_pos = jnp.where(live, pos, -1)
         kv_len = jnp.max(q_pos) + 1
-        bp = max(sa.block_pages_for(b, t, h, page),
-                 sa.block_pages_for(b, t, cfg.indexer_num_heads, page))
-        padded = sa.pad_block_tables(tables, bp)
+        bp = max(pw.block_pages_for(b, t, h, page),
+                 pw.block_pages_for(b, t, cfg.indexer_num_heads, page))
+        padded = pw.pad_block_tables(tables, bp)
         keys, threshold, ties = sa.index_keys(q_idx, w_idx, index_pages, padded, q_pos,
                                               cfg.sparse_topk, kv_len)
         out = sa.paged_selected_attention(q, k_pages, v_pages, padded, keys, threshold, ties,
@@ -257,7 +259,7 @@ class KeyeVL2ForCausalLM(nn.Module):
         "hold_finished": "page transfer (serving/transfer.py moves k_pages and v_pages only)",
     }
 
-    # a prefill chunk is written a page at a time (ops/sparse_attention.write_chunk_pages)
+    # a prefill chunk is written a page at a time (ops/paged_cache.write_chunk_pages)
     prefill_writes_whole_pages = True
 
     @property
@@ -270,8 +272,6 @@ class KeyeVL2ForCausalLM(nn.Module):
                          pages_per_slot: int, kv_dtype=None):
         """This family's kind of per-token state: K and V pages and the
         indexer's key pages, one block table for all three."""
-        from ..serving.paged_cache import init_paged_pools
-
         if kv_dtype in ("int8", "fp8"):
             raise NotImplementedError(self.serving_refuses["kv_dtype"])
 

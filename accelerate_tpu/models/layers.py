@@ -42,9 +42,7 @@ import numpy as np
 from jax import lax
 
 from ..ops.collective_matmul import dense_collective_matmul
-from ..ops.fp8 import fp8_delayed_dot, fp8_fake_quantize
 from ..ops.lora import lora_apply
-from ..ops.precision import fp8_current_scaled_dot, fp8_enabled
 from ..ops.quantized_matmul import quantized_matmul
 from ..utils.quantization import is_quantized
 
@@ -86,43 +84,14 @@ class QuantizableDense(nn.Module):
             kernel = self.param(
                 "kernel", self.kernel_init, (x.shape[-1], self.features), self.param_dtype
             )
-            if fp8_enabled():
-                # inside an fp8_autocast region (mixed_precision="fp8")
-                x_c, k_c = x.astype(dtype), kernel.astype(dtype)
-                y = None
-                if self.tp_mode is not None:
-                    # compose with the collective-matmul ring: the ring owns
-                    # its partial dots, so hand it operands already rounded
-                    # through e4m3 storage (ops/fp8.py) — fp8 numerics, ring
-                    # latency hiding, same fallback contract as bf16
-                    y = dense_collective_matmul(
-                        fp8_fake_quantize(x_c), fp8_fake_quantize(k_c),
-                        self.tp_mode, axis_name=self.tp_axis,
-                    )
-                if y is None:
-                    if self.has_variable("fp8", "w_meta"):
-                        # delayed scaling: the per-tensor amax history rides
-                        # TrainState.fp8_state and arrives as the read-only
-                        # "fp8" collection; e4m3 fwd / e5m2 bwd (HYBRID)
-                        y = fp8_delayed_dot(
-                            x_c, k_c, self.get_variable("fp8", "w_meta"),
-                            preferred_element_type=dtype,
-                        )
-                    else:
-                        # stateless current scaling: scaled-e4m3 matmul on
-                        # the MXU, bf16 straight-through bwd
-                        y = fp8_current_scaled_dot(
-                            x_c, k_c, preferred_element_type=dtype
-                        )
-            else:
-                y = None
-                if self.tp_mode is not None:
-                    y = dense_collective_matmul(
-                        x.astype(dtype), kernel.astype(dtype), self.tp_mode,
-                        axis_name=self.tp_axis,
-                    )
-                if y is None:
-                    y = jnp.dot(x.astype(dtype), kernel.astype(dtype))
+            y = None
+            if self.tp_mode is not None:
+                y = dense_collective_matmul(
+                    x.astype(dtype), kernel.astype(dtype), self.tp_mode,
+                    axis_name=self.tp_axis,
+                )
+            if y is None:
+                y = jnp.dot(x.astype(dtype), kernel.astype(dtype))
         if self.use_bias:
             bias = self.param("bias", self.bias_init, (self.features,), self.param_dtype)
             y = y + bias.astype(dtype)

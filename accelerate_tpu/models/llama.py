@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops import paged_cache
 from .layers import QuantizableDense
 
 
@@ -265,27 +266,6 @@ def init_cache(config, batch_size: int, max_len: int, dtype=None):
     ]
 
 
-# Quantized KV page dtypes (KIVI-style per-page scales; serving/paged_cache
-# kv_page_bytes carries the matching accounting).  Codes are symmetric:
-# q = round(v * QMAX / amax), dequant = q * (amax / QMAX); the per-(kv-head,
-# page) amax lives in `k_scales`/`v_scales` float32 arrays next to the pages.
-KV_QUANT_DTYPES = {"int8": jnp.int8, "fp8": jnp.float8_e4m3fn}
-KV_QUANT_QMAX = {"int8": 127.0, "fp8": 448.0}
-
-
-def resolve_kv_dtype(kv_dtype):
-    """Normalize a KV page dtype knob: ``None``/``""``/``"bf16"`` mean
-    "model dtype" (dense pages, no scales); ``"int8"``/``"fp8"`` arm the
-    quantized page layout."""
-    if kv_dtype in (None, "", "bf16"):
-        return None
-    if kv_dtype not in KV_QUANT_DTYPES:
-        raise ValueError(
-            f"kv_dtype must be '', 'bf16', 'int8' or 'fp8', got {kv_dtype!r}"
-        )
-    return kv_dtype
-
-
 def init_paged_cache(config, num_pages: int, page_size: int, num_slots: int,
                      pages_per_slot: int, dtype=None, kv_dtype=None):
     """Paged variant of :func:`init_cache` — the serving-core KV layout
@@ -301,7 +281,7 @@ def init_paged_cache(config, num_pages: int, page_size: int, num_slots: int,
       *j*-th logical page lives in physical page ``block_tables[i, j]``;
     - ``seq_lens`` ``[num_slots]`` int32 tokens written per slot (0 = dead);
     - ``free_stack``/``free_top`` — the device-side page allocator's free
-      list (``serving/paged_cache.py`` pops/pushes it functionally, so the
+      list (``ops/paged_cache.py`` pops/pushes it functionally, so the
       decode step stays jit- and donation-clean).
 
     Liveness is positional, like the dense cache: a kv index is visible to a
@@ -318,9 +298,9 @@ def init_paged_cache(config, num_pages: int, page_size: int, num_slots: int,
     (offset-0) write, so stale scales never leak across tenants.
     """
     dtype = dtype or config.dtype
-    kv_dtype = resolve_kv_dtype(kv_dtype)
+    kv_dtype = paged_cache.resolve_kv_dtype(kv_dtype)
     hkv, d = config.num_key_value_heads, config.head_dim
-    page_dtype = KV_QUANT_DTYPES[kv_dtype] if kv_dtype else dtype
+    page_dtype = paged_cache.KV_QUANT_DTYPES[kv_dtype] if kv_dtype else dtype
 
     def layer():
         entry = {
@@ -332,119 +312,8 @@ def init_paged_cache(config, num_pages: int, page_size: int, num_slots: int,
             entry["v_scales"] = jnp.zeros((hkv, num_pages), jnp.float32)
         return entry
 
-    from ..serving.paged_cache import init_paged_pools   # (serving imports this module)
-
-    return init_paged_pools([layer() for _ in range(config.num_hidden_layers)], num_pages,
-                            num_slots, pages_per_slot)
-
-
-def paged_gather_kv(k_pages, v_pages, block_tables, k_scales=None,
-                    v_scales=None, kv_dtype=None, out_dtype=None):
-    """Gather a ``[B, S, Hkv, D]`` linear KV view through the block table.
-
-    ``k_pages``/``v_pages``: ``[Hkv, P, page, D]``; ``block_tables``:
-    ``[B, n]``.  Returns ``(k, v, kv_positions)`` with ``S = n * page`` and
-    ``kv_positions`` the within-sequence token index of every gathered slot
-    — ready for :func:`cached_attention`'s positional liveness mask (stale
-    pages beyond a slot's ``seq_len`` sit at positions the causal
-    comparison never admits).
-
-    With quantized pages, pass the per-page ``k_scales``/``v_scales`` plus
-    ``kv_dtype``/``out_dtype``: the gathered codes dequantize in the linear
-    view (``codes * amax / QMAX``), so downstream attention is unchanged."""
-    hkv, _, page, d = k_pages.shape
-    b, n = block_tables.shape
-
-    def lin(pages, scales):
-        g = pages[:, block_tables]                      # [Hkv, B, n, page, D]
-        if scales is not None:
-            qmax = KV_QUANT_QMAX[kv_dtype]
-            s = (scales / qmax)[:, block_tables]        # [Hkv, B, n]
-            g = (g.astype(jnp.float32) * s[..., None, None]).astype(
-                out_dtype or jnp.float32
-            )
-        return g.transpose(1, 2, 3, 0, 4).reshape(b, n * page, hkv, d)
-
-    kv_positions = jnp.broadcast_to(jnp.arange(n * page, dtype=jnp.int32), (b, n * page))
-    return lin(k_pages, k_scales), lin(v_pages, v_scales), kv_positions
-
-
-@jax.named_scope("paged_write_kv")
-def paged_write_kv(pages, values, page_ids, offsets):
-    """Scatter per-token K or V rows into the page pool.
-
-    ``pages``: ``[Hkv, P, page, D]``; ``values``: ``[B, T, Hkv, D]``;
-    ``page_ids``/``offsets``: ``[B, T]`` int32 (masked tokens carry an
-    out-of-bounds page id and drop — the write-mask convention)."""
-    hkv, _, _, d = pages.shape
-    flat = values.reshape(-1, hkv, d).transpose(1, 0, 2)   # [Hkv, B*T, D]
-    return pages.at[:, page_ids.reshape(-1), offsets.reshape(-1)].set(
-        flat.astype(pages.dtype), mode="drop"
-    )
-
-
-@jax.named_scope("paged_write_kv")
-def paged_write_kv_quantized(pages, scales, values, page_ids, offsets,
-                             kv_dtype: str):
-    """Quantize-on-write into int8/fp8 pages with per-(kv-head, page) scales.
-
-    Same scatter contract as :func:`paged_write_kv` (OOB page ids drop), with
-    the per-page running-amax discipline layered on:
-
-    1. an **offset-0 write opens the page**: its stored amax resets, so a
-       recycled page never inherits the previous tenant's range (the reset
-       also zeroes the stale codes via the ratio rescale below);
-    2. the page amax is the **running max** over every row written so far
-       (scatter-max), monotone within a page's lifetime;
-    3. when the amax grows, the page's **existing codes rescale in place**
-       (``codes * old_amax / new_amax``) so quantization and dequantization
-       always share one scale — only the pages touched by this call are
-       gathered/rescaled/scattered, never the pool.
-
-    Every duplicate-index scatter writes identical values (all copies see
-    the final amax), so the result is order-independent — bitwise
-    deterministic run-to-run.  Returns ``(pages, scales)``.
-    """
-    hkv, num_pages, _, d = pages.shape
-    qmax = KV_QUANT_QMAX[kv_dtype]
-    page_dtype = KV_QUANT_DTYPES[kv_dtype]
-    flat_pages = page_ids.reshape(-1)                       # [N]
-    flat_off = offsets.reshape(-1)                          # [N]
-    vals = values.reshape(-1, hkv, d).transpose(1, 0, 2).astype(jnp.float32)
-    row_amax = jnp.max(jnp.abs(vals), axis=-1)              # [Hkv, N]
-    # 1. open fresh pages (at most one offset-0 row per page per call)
-    reset_ids = jnp.where(flat_off == 0, flat_pages, num_pages)
-    opened = scales.at[:, reset_ids].set(0.0, mode="drop")
-    # 2. running max over this call's rows
-    new_scales = opened.at[:, flat_pages].max(row_amax, mode="drop")
-    # 3. rescale the touched pages' existing codes to the final amax
-    safe_pages = jnp.clip(flat_pages, 0, num_pages - 1)
-    old_amax = opened[:, safe_pages]                        # [Hkv, N]
-    fin_amax = new_scales[:, safe_pages]
-    ratio = jnp.where(fin_amax > 0, old_amax / jnp.maximum(fin_amax, 1e-30), 1.0)
-    touched = pages[:, safe_pages].astype(jnp.float32)      # [Hkv, N, page, D]
-    rescaled = touched * ratio[:, :, None, None]
-    if page_dtype == jnp.int8:
-        rescaled = jnp.clip(jnp.rint(rescaled), -qmax, qmax)
-    pages = pages.at[:, flat_pages].set(
-        rescaled.astype(page_dtype), mode="drop"
-    )
-    # 4. quantize the new rows under the final page amax
-    q = vals * (qmax / jnp.maximum(fin_amax, 1e-30))[:, :, None]
-    q = jnp.where(fin_amax[:, :, None] > 0, q, 0.0)
-    if page_dtype == jnp.int8:
-        q = jnp.rint(q)
-    q = jnp.clip(q, -qmax, qmax)
-    pages = pages.at[:, flat_pages, flat_off].set(q.astype(page_dtype), mode="drop")
-    return pages, new_scales
-
-
-def dequantize_kv_pages(pages, scales, kv_dtype: str, dtype):
-    """Full-pool dequantize: ``codes * amax / QMAX`` in ``dtype``.  The
-    reference path for parity tests and the wire format's receive side."""
-    qmax = KV_QUANT_QMAX[kv_dtype]
-    return (pages.astype(jnp.float32)
-            * (scales / qmax)[:, :, None, None]).astype(dtype)
+    return paged_cache.init_paged_pools([layer() for _ in range(config.num_hidden_layers)],
+                                        num_pages, num_slots, pages_per_slot)
 
 
 def cached_attention(q, k_cache, v_cache, kv_positions, q_positions):
@@ -544,16 +413,16 @@ class LlamaAttention(nn.Module):
                 # code dtype so the trace stays argument-driven
                 kv_dtype = ("int8" if cache["k_pages"].dtype == jnp.int8
                             else "fp8")
-                k_pages, k_scales = paged_write_kv_quantized(
+                k_pages, k_scales = paged_cache.paged_write_kv_quantized(
                     cache["k_pages"], cache["k_scales"], k, page_ids, offsets,
                     kv_dtype)
-                v_pages, v_scales = paged_write_kv_quantized(
+                v_pages, v_scales = paged_cache.paged_write_kv_quantized(
                     cache["v_pages"], cache["v_scales"], v, page_ids, offsets,
                     kv_dtype)
             else:
                 kv_dtype, k_scales, v_scales = None, None, None
-                k_pages = paged_write_kv(cache["k_pages"], k, page_ids, offsets)
-                v_pages = paged_write_kv(cache["v_pages"], v, page_ids, offsets)
+                k_pages = paged_cache.paged_write_kv(cache["k_pages"], k, page_ids, offsets)
+                v_pages = paged_cache.paged_write_kv(cache["v_pages"], v, page_ids, offsets)
             if cfg.attn_implementation == "flash" and t == 1:
                 # batched single-token decode: the Pallas paged kernel walks
                 # each slot's pages through the block table (scalar-prefetch)
@@ -575,7 +444,7 @@ class LlamaAttention(nn.Module):
                     k_scales=k_scales, v_scales=v_scales,
                 )
             else:
-                k_lin, v_lin, kv_pos = paged_gather_kv(
+                k_lin, v_lin, kv_pos = paged_cache.paged_gather_kv(
                     k_pages, v_pages, cache["block_tables"],
                     k_scales, v_scales, kv_dtype, cfg.dtype,
                 )
@@ -736,31 +605,13 @@ class LMHead(nn.Module):
 
     @nn.compact
     def __call__(self, x, adapter_ids=None):
-        from ..ops.precision import fp8_enabled
-
         w = self.param(
             "kernel", nn.initializers.lecun_normal(), (x.shape[-1], self.vocab_size), jnp.float32
         )
         w_c = w.astype(self.dtype)
-        fp8_on = fp8_enabled()
 
         def head_dot(x):
-            # fp32-accumulated vocab projection; under fp8_autocast the
-            # storage rounds to e4m3 — delayed weight scale when the "fp8"
-            # collection rides in (ops/fp8.py), current scaling otherwise
-            if fp8_on:
-                if self.has_variable("fp8", "w_meta"):
-                    from ..ops.fp8 import fp8_delayed_dot
-
-                    return fp8_delayed_dot(
-                        x, w_c, self.get_variable("fp8", "w_meta"),
-                        preferred_element_type=jnp.float32,
-                    )
-                from ..ops.precision import fp8_current_scaled_dot
-
-                return fp8_current_scaled_dot(
-                    x, w_c, preferred_element_type=jnp.float32
-                )
+            # fp32-accumulated vocab projection
             return jax.lax.dot_general(
                 x, w_c, (((x.ndim - 1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -776,17 +627,11 @@ class LMHead(nn.Module):
         if x.ndim == 3:
             # column-parallel over tp (lm_head rule shards the vocab dim):
             # the ring gathers the sequence left tp-scattered by the last
-            # block's row-parallel down_proj inside the head matmul; under
-            # fp8 the ring consumes e4m3-rounded operands (ops/fp8.py)
+            # block's row-parallel down_proj inside the head matmul
             from ..ops.collective_matmul import dense_collective_matmul
 
-            x_ring, w_ring = x, w_c
-            if fp8_on:
-                from ..ops.fp8 import fp8_fake_quantize
-
-                x_ring, w_ring = fp8_fake_quantize(x), fp8_fake_quantize(w_c)
             y = dense_collective_matmul(
-                x_ring, w_ring, "column", preferred_element_type=jnp.float32
+                x, w_c, "column", preferred_element_type=jnp.float32
             )
             if y is not None:
                 return y

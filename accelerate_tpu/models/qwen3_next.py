@@ -65,9 +65,9 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import gated_delta as gd
-from ..ops import sparse_attention as sa
+from ..ops import page_walk as pw
 from ..ops import window_attention as wa
-from ..ops.latent_attention import paged_walk_decode_attention
+from ..ops.paged_cache import init_paged_pools, page_writer
 from ..parallel.expert_parallel import grouped_ffn, held_rows_fed, route_dropless
 from .k_exaone import KExaoneMLP
 from .layers import Float32Dense, Float32Out, apply_rotary, bias_free_proj, rotary_angles
@@ -341,15 +341,15 @@ class Qwen3NextAttention(nn.Module):
         tables, page = cache["block_tables"], cache["k_pages"].shape[1]
         flat = lambda a: a.reshape(b, t, hkv * d)
         with jax.named_scope("paged_write_kv"):
-            write = sa.page_writer(tables, pos, live, page)
+            write = page_writer(tables, pos, live, page)
             k_pages, v_pages = write(cache["k_pages"], flat(k)), write(cache["v_pages"], flat(v))
         state = {"k_pages": k_pages, "v_pages": v_pages}
         if t > 1:               # a chunk's rows against blocks of gathered pages
-            padded = sa.pad_block_tables(tables, sa.block_pages_for(b, t, h, page))
-            out = sa.paged_causal_attention(q, k_pages, v_pages, padded, q_pos, jnp.max(q_pos) + 1)
+            padded = pw.pad_block_tables(tables, pw.block_pages_for(b, t, h, page))
+            out = pw.paged_causal_attention(q, k_pages, v_pages, padded, q_pos, jnp.max(q_pos) + 1)
             return gated(out), state, None
         with jax.named_scope("global_attend"):      # each slot's own pages once: one kernel
-            out = paged_walk_decode_attention(q[:, 0], k_pages, v_pages, tables, q_pos[:, 0])
+            out = pw.paged_walk_decode_attention(q[:, 0], k_pages, v_pages, tables, q_pos[:, 0])
         walked = jnp.sum((q_pos + page) // page, dtype=jnp.int32) * page
         return gated(out[:, None]), state, jnp.stack([jnp.sum(q_pos + 1, dtype=jnp.int32), walked])
 
@@ -446,8 +446,6 @@ class Qwen3NextForCausalLM(nn.Module):
         """Pages for the full-attention layers; a recurrent state (float32)
         and a conv window per slot for the Gated DeltaNet layers: the second
         is a slot-addressed kind of layer state."""
-        from ..serving.paged_cache import init_paged_pools
-
         if kv_dtype in ("int8", "fp8"):
             raise NotImplementedError(self.serving_refuses["kv_dtype"])
         cfg = self.config

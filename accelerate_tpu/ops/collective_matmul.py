@@ -32,7 +32,7 @@ the ring link carry traffic concurrently).
 Fallbacks: the XLA monolithic path is used whenever the ring axis is trivial
 (size 1) or shapes do not divide the ring.  The knob rides
 ``FullyShardedDataParallelPlugin.collective_matmul``
-/ env ``ACCELERATE_COLLECTIVE_MATMUL`` and is resolved at **trace time** (like ``ops/precision.fp8_autocast``): set it
+/ env ``ACCELERATE_COLLECTIVE_MATMUL`` and is resolved at **trace time**: set it
 before the step compiles.
 """
 

@@ -710,7 +710,7 @@ def _paged_decode_body(bt_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
 
 
 # Max code magnitude per quantized page dtype (mirrors
-# models/llama.py:KV_QUANT_QMAX): symmetric int8 uses the full [-127, 127]
+# ops/paged_cache.py:KV_QUANT_QMAX): symmetric int8 uses the full [-127, 127]
 # band; fp8 pages store e4m3 codes whose saturation point is 448.
 _KV_QMAX = {"int8": 127.0, "float8_e4m3fn": 448.0}
 
@@ -769,7 +769,7 @@ def paged_decode_attention(
     without repeating K/V, like :func:`flash_attention`.  Returns
     ``[S, H, D]``.
 
-    **Quantized pages** (``serving/paged_cache.py`` int8/fp8 pools): pass
+    **Quantized pages** (``ops/paged_cache.py`` int8/fp8 pools): pass
     the per-(kv-head, page) amax arrays ``k_scales``/``v_scales``
     (``[Hkv, P]`` f32).  Each page's scale rides as its own block through
     the same block-table index map and the codes dequantize in-tile

@@ -1,4 +1,22 @@
-"""A decode step's attention over ITS OWN pages as one Pallas kernel: each slot
+"""Reading pages through a block table: everything that attends over the
+page-major pools ``[P, page, W]`` of ``ops/paged_cache.py``.  Two walks:
+
+- **the XLA walk**, :func:`paged_masked_attention` (with :func:`block_pages_for`,
+  :func:`pad_block_tables`, :func:`causal_mask`, :func:`paged_causal_attention`):
+  a block of pages at a time with a running softmax under a caller's mask, plain
+  XLA gathers of whole pages.  It serves every masked attention over paged keys
+  that is not a kernel of its own: Keye-VL-2's prefill chunks and decode steps
+  (through ``ops/sparse_attention.paged_selected_attention``), the PREFILL CHUNKS
+  of K-EXAONE's and Qwen3-Next's full causal layers and of JoyAI-Flash's latent
+  attention, and it is the oracle the kernel below is tested against;
+- **the Pallas decode walk**, ``_page_walk`` (:func:`paged_walk_decode_attention`,
+  :func:`latent_decode_attention`): a decode step ``[S, 1]``, described next.
+
+The dense family's head-major pools are read by the paged kernels of
+``ops/flash_attention.py`` (``ROADMAP.md`` C5 moves them here when A1 has
+decided which survive).
+
+A decode step's attention over ITS OWN pages as one Pallas kernel: each slot
 reads its own pages once, up to its own length, and nothing is gathered.  Two
 callers, one walk; what a row of a page holds is read off the inputs:
 
@@ -21,7 +39,7 @@ callers, one walk; what a row of a page holds is read off the inputs:
       o_h        = sum_s softmax_s(score_h) v_s[h // G]
 
 The mathematics and the precision are those of
-``ops/sparse_attention.paged_masked_attention`` under the causal mask (float32
+:func:`paged_masked_attention` under the causal mask (float32
 scores from the pool's dtype, a running maximum and sum in float32, ``p`` cast
 to the pool's dtype before it meets the values, a float32 sum divided once at
 the end), which stays the oracle this kernel is tested against.  What differs
@@ -78,13 +96,18 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import _on_tpu
 
-# pages one compute step scores: as many as fill _CHUNK_BYTES of one pool's buffer (16 pages of
+# f32 elements one loop step may hold as scores ([B, T, H or J, block]); 64M = 256 MiB
+_BLOCK_BUDGET = 1 << 26
+_MAX_BLOCK_PAGES = 64
+
+# pages one compute step of the kernel scores: as many as fill _CHUNK_BYTES of one pool's buffer (16 pages of
 # 64 rows of 640 bf16 values), no fewer than _CHUNK_PAGES (a step's fixed cost) and no more than
 # twice that (the timings are in the module's docstring)
 _CHUNK_PAGES = 16
@@ -95,6 +118,135 @@ def chunk_pages(page_bytes: int, pages_per_slot: int) -> int:
     """Pages one compute step scores, from a page's bytes in one pool."""
     fill = max(_CHUNK_PAGES, min(_CHUNK_BYTES // page_bytes, 2 * _CHUNK_PAGES))
     return min(fill, pages_per_slot)
+
+
+# ---------------------------------------------------------------------------
+# the XLA walk
+# ---------------------------------------------------------------------------
+
+
+def block_pages_for(batch: int, width: int, heads: int, page_size: int) -> int:
+    """Pages a loop step covers: as many as keep one step's float32 scores
+    ([batch, width, heads, pages * page_size]) inside the block budget."""
+    fit = _BLOCK_BUDGET // max(1, batch * width * heads * page_size)
+    return int(max(1, min(_MAX_BLOCK_PAGES, 1 << max(0, int(fit).bit_length() - 1))))
+
+
+def pad_block_tables(block_tables, block_pages: int):
+    """Block tables padded to a whole number of loop steps.  The padding's
+    page ids are 0: such keys lie past every sequence's capacity and are
+    never visible."""
+    pad = -block_tables.shape[1] % block_pages
+    return jnp.pad(block_tables, ((0, 0), (0, pad))) if pad else block_tables
+
+
+def paged_masked_attention(q, k_pages, v_pages, block_tables, kv_len, block_mask,
+                           mask_carry=lambda: (), *, scale=None, value_width=None, expand=None):
+    """The page walk every masked attention over paged keys shares (callers:
+    ``ops/sparse_attention.paged_selected_attention``, Keye's chunks and decode
+    steps; :func:`paged_causal_attention`, K-EXAONE's prefill chunks; JoyAI's
+    prefill chunks through ``expand``; and, as their oracle, the tests of the
+    decode kernels below): ``q``
+    [B, T, H, D] against the pages of ``block_tables`` [B, n] (n a whole
+    number of loop steps: ``pad_block_tables``), a block of
+    ``block_pages_for`` pages at a time with a running softmax, so that no
+    ``[T, S]`` array is held whole.  ``block_mask(i, blk, carry)`` ->
+    ``(mask [B, T, blk] bool, carry)`` says which keys of block ``i`` (the
+    positions ``i * blk + arange(blk)``) each query attends; ``mask_carry()``
+    makes its state before the first block.  ``kv_len``: scalar, the longest live
+    context (no step walks past it).  A row that attends nothing (dead slot,
+    padding) comes back zero.  Returns [B, T, H, Dv].
+
+    What a row of a page may be:
+
+    - ``k_pages`` and ``v_pages`` [P, page, Hkv * D], two pools of one head
+      width: a token's key heads in one row, its value heads in the other
+      (``Dv = D``);
+    - ``v_pages=None``: ONE pool [P, page, Hkv * D] whose row is the key and,
+      in its first ``value_width`` values, the value (a latent row ``[c ;
+      kr]``: scored whole, summed as ``c``).  The row is summed whole and the
+      sum cut to ``Dv = value_width``, so no slice of a gathered block is made;
+    - ``expand(rows [B, blk, W]) -> (k [B, blk, H, D], v [B, blk, H,
+      value_width])``: the gathered rows of that one pool are up-projected to
+      per-head keys and values before they are scored (a latent row expanded
+      by ``W_UK`` / ``W_UV`` for a prefill chunk).
+
+    ``scale`` multiplies the scores (default ``1 / sqrt(D)``)."""
+    b, t, h, d = q.shape
+    _, page, width = k_pages.shape
+    hkv = h if expand is not None else width // d
+    dv = d if value_width is None else value_width
+    g = h // hkv
+    n = block_tables.shape[1]
+    bp = block_pages_for(b, t, h, page)
+    if n % bp:
+        raise ValueError(f"block tables of {n} pages are not a whole number of {bp}-page steps")
+    if v_pages is not None and (expand is not None or value_width is not None):
+        raise ValueError("a value is a second pool, or a part of the key pool's row, not both")
+    blk = bp * page
+    qg = q.reshape(b, t, hkv, g, d)
+    if scale is None:
+        scale = 1.0 / np.sqrt(d)
+    summed = d if v_pages is None and expand is None else dv       # a whole row is summed, then cut
+
+    def gathered(pages):
+        if expand is not None:
+            return expand(k_pages[pages].reshape(b, blk, width))
+        k_blk = k_pages[pages].reshape(b, blk, hkv, d)
+        return k_blk, (k_blk if v_pages is None else v_pages[pages].reshape(b, blk, hkv, d))
+
+    def attend_block(i, carry):
+        m, l, acc, state = carry
+        pages = lax.dynamic_slice_in_dim(block_tables, i * bp, bp, axis=1)        # [B, bp]
+        k_blk, v_blk = gathered(pages)
+        s = jnp.einsum("bthgd,bshd->bhgts", qg, k_blk, preferred_element_type=jnp.float32) * scale
+        sel, state = block_mask(i, blk, state)
+        sel = sel[:, None, None]                                                  # [B,1,1,T,blk]
+        m_new = jnp.maximum(m, jnp.max(jnp.where(sel, s, -jnp.inf), axis=-1))
+        safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        p = jnp.where(sel, jnp.exp(s - safe[..., None]), 0.0)
+        alpha = jnp.where(jnp.isfinite(m), jnp.exp(m - safe), 0.0)
+        l = l * alpha + jnp.sum(p, axis=-1)
+        pv = jnp.einsum("bhgts,bshd->bhgtd", p.astype(v_blk.dtype), v_blk,
+                        preferred_element_type=jnp.float32)
+        return m_new, l, acc * alpha[..., None] + pv, state
+
+    steps = jnp.minimum((kv_len + blk - 1) // blk, n // bp)
+    init = (jnp.full((b, hkv, g, t), -jnp.inf, jnp.float32),
+            jnp.zeros((b, hkv, g, t), jnp.float32),
+            jnp.zeros((b, hkv, g, t, summed), jnp.float32), mask_carry())
+    _, l, acc, _ = lax.fori_loop(0, steps, attend_block, init)
+    out = acc / jnp.where(l > 0, l, 1.0)[..., None]
+    out = out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, summed).astype(q.dtype)
+    return out if summed == dv else out[..., :dv]
+
+
+def causal_mask(q_positions):
+    """The ``block_mask`` of full causal attention: key ``s`` is seen by the
+    query at position ``t`` iff ``s <= t``.  ``q_positions`` [B, T] int32, -1
+    for a query that sees nothing (dead slot, padding)."""
+    def causal(i, blk, state):
+        pos = i * blk + jnp.arange(blk, dtype=jnp.int32)
+        return pos[None, None, :] <= q_positions[:, :, None], state
+
+    return causal
+
+
+@jax.named_scope("global_attend")
+def paged_causal_attention(q, k_pages, v_pages, block_tables, q_positions, kv_len):
+    """Full causal attention over paged keys: the same walk with the mask
+    ``s <= t`` (:func:`causal_mask`).  It gathers EVERY row of ``q``'s batch
+    up to ``kv_len`` in whole blocks, which suits one sequence's chunk ``[1,
+    C]`` (``models/k_exaone.py``'s prefill); a decode step ``[S, 1]`` over
+    ragged contexts is :func:`paged_walk_decode_attention`, whose oracle this
+    is."""
+    return paged_masked_attention(q, k_pages, v_pages, block_tables, kv_len,
+                                  causal_mask(q_positions))
+
+
+# ---------------------------------------------------------------------------
+# the Pallas decode walk
+# ---------------------------------------------------------------------------
 
 
 def _walk_kernel(bt_ref, pos_ref, *refs, scale: float, pages_per_slot: int, chunk: int,

@@ -17,17 +17,12 @@ The threshold is EXACT, and keys that tie AT the threshold are taken in order
 of position until the row has ``topk``: the set a stable sort by descending
 score keeps.
 
-The page walk under that mask, :func:`paged_masked_attention`, is shared by
-every masked attention over paged keys that is not a kernel of its own: this
-family's prefill chunks and decode steps, and the PREFILL CHUNKS of
-``models/k_exaone.py``'s full causal layers and of ``models/joyai_flash.py``'s
-latent attention (their decode steps ``[S, 1]`` walk each slot's own pages
-inside the Pallas kernels of ``ops/latent_attention.py``, which are tested
-against this walk).  What a row of a page holds is the caller's to say: a token's key heads in one pool and its value
-heads in another, of one head width; or ONE row that is the key and, in its
-first values, the value (a latent ``[c ; kr]`` shared by every head, scored
-whole at the caller's scale); or a row that is up-projected to per-head keys
-and values a gathered block at a time before it is scored.
+The page walk under that mask is ``ops/page_walk.paged_masked_attention``,
+shared with every other masked attention over paged keys
+(:func:`paged_selected_attention` here is one caller of it); the page writes
+are ``ops/paged_cache.page_writer``.  This module keeps what is SPARSE: the
+order-preserving image of the scores, the threshold, the selection, the
+indexer's scores over paged keys, and the cache-free form.
 
 The threshold is the one Pallas kernel here (``sparse_threshold``,
 :func:`kth_largest_key`): a tile of rows of the order-preserving integer image
@@ -36,9 +31,7 @@ bits runs there, the same code for a decode step's handful of rows and a
 prefill chunk's thousands (off the TPU it runs in Pallas interpret mode, as
 the kernels of ``ops/flash_attention.py`` do).  Everything else is plain XLA:
 gathers of whole pages, dynamic slices and ``while`` loops whose trip count
-follows the longest live context.  Pages are written one page (prefill) or
-one row (decode) at a time with ``dynamic_update_slice`` on the donated pool —
-in the layout the reads use, so no relayout copy of a pool surrounds a write.
+follows the longest live context.
 """
 
 from __future__ import annotations
@@ -53,11 +46,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import _on_tpu
+from . import page_walk
 
 _INT_MIN = np.int32(-2 ** 31)
-# f32 elements one loop step may hold as scores ([B, T, H or J, block]); 64M = 256 MiB
-_BLOCK_BUDGET = 1 << 26
-_MAX_BLOCK_PAGES = 64
 # keys (rows x columns) of a row tile's running count in the threshold kernel: 4 vregs of
 # int32, and how many times as many keys one counting step folds into it (so a step reads
 # 32 vregs, and the loop's carried registers and its branch are paid once for them)
@@ -167,21 +158,6 @@ def selected(keys, threshold, ties_taken, ties_before=0):
     return mask, rank[..., -1]
 
 
-def block_pages_for(batch: int, width: int, heads: int, page_size: int) -> int:
-    """Pages a loop step covers: as many as keep one step's float32 scores
-    ([batch, width, heads, pages * page_size]) inside the block budget."""
-    fit = _BLOCK_BUDGET // max(1, batch * width * heads * page_size)
-    return int(max(1, min(_MAX_BLOCK_PAGES, 1 << max(0, int(fit).bit_length() - 1))))
-
-
-def pad_block_tables(block_tables, block_pages: int):
-    """Block tables padded to a whole number of loop steps.  The padding's
-    page ids are 0: such keys lie past every sequence's capacity and are
-    never visible."""
-    pad = -block_tables.shape[1] % block_pages
-    return jnp.pad(block_tables, ((0, 0), (0, pad))) if pad else block_tables
-
-
 @jax.named_scope("sparse_index")
 def index_keys(q_idx, w_idx, index_pages, block_tables, q_positions, topk: int, kv_len):
     """Indexer scores of every (query, key) pair against the paged indexer
@@ -200,7 +176,7 @@ def index_keys(q_idx, w_idx, index_pages, block_tables, q_positions, topk: int, 
     b, t, j, di = q_idx.shape
     page = index_pages.shape[1]
     n = block_tables.shape[1]
-    bp = block_pages_for(b, t, j, page)
+    bp = page_walk.block_pages_for(b, t, j, page)
     if n % bp:
         raise ValueError(f"block tables of {n} pages are not a whole number of {bp}-page steps")
     blk = bp * page
@@ -221,123 +197,19 @@ def index_keys(q_idx, w_idx, index_pages, block_tables, q_positions, topk: int, 
     return (keys, *kth_largest_key(keys, topk, kv_len))
 
 
-def paged_masked_attention(q, k_pages, v_pages, block_tables, kv_len, block_mask,
-                           mask_carry=lambda: (), *, scale=None, value_width=None, expand=None):
-    """The page walk every masked attention over paged keys shares (callers:
-    :func:`paged_selected_attention`, Keye's chunks and decode steps;
-    :func:`paged_causal_attention`, K-EXAONE's prefill chunks; JoyAI's
-    prefill chunks through ``expand``; and, as their oracle, the tests of the
-    decode kernels of ``ops/latent_attention.py``): ``q``
-    [B, T, H, D] against the pages of ``block_tables`` [B, n] (n a whole
-    number of loop steps: ``pad_block_tables``), a block of
-    ``block_pages_for`` pages at a time with a running softmax, so that no
-    ``[T, S]`` array is held whole.  ``block_mask(i, blk, carry)`` ->
-    ``(mask [B, T, blk] bool, carry)`` says which keys of block ``i`` (the
-    positions ``i * blk + arange(blk)``) each query attends; ``mask_carry()``
-    makes its state before the first block.  ``kv_len``: scalar, the longest live
-    context (no step walks past it).  A row that attends nothing (dead slot,
-    padding) comes back zero.  Returns [B, T, H, Dv].
-
-    What a row of a page may be:
-
-    - ``k_pages`` and ``v_pages`` [P, page, Hkv * D], two pools of one head
-      width: a token's key heads in one row, its value heads in the other
-      (``Dv = D``);
-    - ``v_pages=None``: ONE pool [P, page, Hkv * D] whose row is the key and,
-      in its first ``value_width`` values, the value (a latent row ``[c ;
-      kr]``: scored whole, summed as ``c``).  The row is summed whole and the
-      sum cut to ``Dv = value_width``, so no slice of a gathered block is made;
-    - ``expand(rows [B, blk, W]) -> (k [B, blk, H, D], v [B, blk, H,
-      value_width])``: the gathered rows of that one pool are up-projected to
-      per-head keys and values before they are scored (a latent row expanded
-      by ``W_UK`` / ``W_UV`` for a prefill chunk).
-
-    ``scale`` multiplies the scores (default ``1 / sqrt(D)``)."""
-    b, t, h, d = q.shape
-    _, page, width = k_pages.shape
-    hkv = h if expand is not None else width // d
-    dv = d if value_width is None else value_width
-    g = h // hkv
-    n = block_tables.shape[1]
-    bp = block_pages_for(b, t, h, page)
-    if n % bp:
-        raise ValueError(f"block tables of {n} pages are not a whole number of {bp}-page steps")
-    if v_pages is not None and (expand is not None or value_width is not None):
-        raise ValueError("a value is a second pool, or a part of the key pool's row, not both")
-    blk = bp * page
-    qg = q.reshape(b, t, hkv, g, d)
-    if scale is None:
-        scale = 1.0 / np.sqrt(d)
-    summed = d if v_pages is None and expand is None else dv       # a whole row is summed, then cut
-
-    def gathered(pages):
-        if expand is not None:
-            return expand(k_pages[pages].reshape(b, blk, width))
-        k_blk = k_pages[pages].reshape(b, blk, hkv, d)
-        return k_blk, (k_blk if v_pages is None else v_pages[pages].reshape(b, blk, hkv, d))
-
-    def attend_block(i, carry):
-        m, l, acc, state = carry
-        pages = lax.dynamic_slice_in_dim(block_tables, i * bp, bp, axis=1)        # [B, bp]
-        k_blk, v_blk = gathered(pages)
-        s = jnp.einsum("bthgd,bshd->bhgts", qg, k_blk, preferred_element_type=jnp.float32) * scale
-        sel, state = block_mask(i, blk, state)
-        sel = sel[:, None, None]                                                  # [B,1,1,T,blk]
-        m_new = jnp.maximum(m, jnp.max(jnp.where(sel, s, -jnp.inf), axis=-1))
-        safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.where(sel, jnp.exp(s - safe[..., None]), 0.0)
-        alpha = jnp.where(jnp.isfinite(m), jnp.exp(m - safe), 0.0)
-        l = l * alpha + jnp.sum(p, axis=-1)
-        pv = jnp.einsum("bhgts,bshd->bhgtd", p.astype(v_blk.dtype), v_blk,
-                        preferred_element_type=jnp.float32)
-        return m_new, l, acc * alpha[..., None] + pv, state
-
-    steps = jnp.minimum((kv_len + blk - 1) // blk, n // bp)
-    init = (jnp.full((b, hkv, g, t), -jnp.inf, jnp.float32),
-            jnp.zeros((b, hkv, g, t), jnp.float32),
-            jnp.zeros((b, hkv, g, t, summed), jnp.float32), mask_carry())
-    _, l, acc, _ = lax.fori_loop(0, steps, attend_block, init)
-    out = acc / jnp.where(l > 0, l, 1.0)[..., None]
-    out = out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, summed).astype(q.dtype)
-    return out if summed == dv else out[..., :dv]
-
-
 @jax.named_scope("sparse_attend")
 def paged_selected_attention(q, k_pages, v_pages, block_tables, keys, threshold, ties_taken,
                              kv_len):
     """Attention of ``q`` [B, T, H, D] over the keys its row selected:
-    :func:`paged_masked_attention` with the selection as the mask.  ``keys``
+    ``page_walk.paged_masked_attention`` with the selection as the mask.  ``keys``
     / ``threshold`` / ``ties_taken`` as :func:`index_keys` returns them.
     Returns [B, T, H, D]."""
     def selection(i, blk, ties):
         return selected(lax.dynamic_slice_in_dim(keys, i * blk, blk, axis=2), threshold,
                         ties_taken, ties)
 
-    return paged_masked_attention(q, k_pages, v_pages, block_tables, kv_len, selection,
-                                  lambda: jnp.zeros(q.shape[:2], jnp.int32))
-
-
-def causal_mask(q_positions):
-    """The ``block_mask`` of full causal attention: key ``s`` is seen by the
-    query at position ``t`` iff ``s <= t``.  ``q_positions`` [B, T] int32, -1
-    for a query that sees nothing (dead slot, padding)."""
-    def causal(i, blk, state):
-        pos = i * blk + jnp.arange(blk, dtype=jnp.int32)
-        return pos[None, None, :] <= q_positions[:, :, None], state
-
-    return causal
-
-
-@jax.named_scope("global_attend")
-def paged_causal_attention(q, k_pages, v_pages, block_tables, q_positions, kv_len):
-    """Full causal attention over paged keys: the same walk with the mask
-    ``s <= t`` (:func:`causal_mask`).  It gathers EVERY row of ``q``'s batch
-    up to ``kv_len`` in whole blocks, which suits one sequence's chunk ``[1,
-    C]`` (``models/k_exaone.py``'s prefill); a decode step ``[S, 1]`` over
-    ragged contexts is ``ops/latent_attention.paged_walk_decode_attention``,
-    whose oracle this is."""
-    return paged_masked_attention(q, k_pages, v_pages, block_tables, kv_len,
-                                  causal_mask(q_positions))
+    return page_walk.paged_masked_attention(q, k_pages, v_pages, block_tables, kv_len, selection,
+                                            lambda: jnp.zeros(q.shape[:2], jnp.int32))
 
 
 def dense_selected_attention(q, k, v, q_idx, w_idx, k_idx, positions, topk: int):
@@ -361,63 +233,3 @@ def dense_selected_attention(q, k, v, q_idx, w_idx, k_idx, positions, topk: int)
         p = jax.nn.softmax(jnp.where(chosen[:, None, None], s, -jnp.inf), axis=-1)
         out = jnp.einsum("bhgts,bshd->bthgd", p.astype(v.dtype), v)
     return out.reshape(b, t, h, d), chosen
-
-
-# ---------------------------------------------------------------------------
-# page writes: in place, in the layout the reads use
-# ---------------------------------------------------------------------------
-#
-# A pool is ``[P, page, W]``: page-major, a token's whole row (every KV head,
-# the indexer's one key, or a latent row with no head axis at all) contiguous.
-# Reads gather whole pages along the leading dim and writes update a row or a
-# page of it, so XLA keeps the pool in the layout it arrives in and puts no
-# relayout copy around either (the
-# head-major ``[Hkv, P, page, D]`` of ``models/llama.py`` is the Pallas
-# kernels' tile; under these XLA ops it costs two copies of the pool a write).
-
-
-def page_writer(block_tables, positions, live, page_size: int):
-    """``write(pages, rows [B, T, W])`` of one paged call, for every pool of a
-    layer: a decode step ``[B, 1]`` writes one row a slot at its position's
-    page and offset, a prefill chunk ``[1, C]`` its first ``sum(live)`` rows a
-    page at a time from ``positions[0, 0]`` (a page boundary)."""
-    if positions.shape[1] == 1:
-        logical = jnp.clip(positions[:, 0] // page_size, 0, block_tables.shape[1] - 1)
-        ids = jnp.take_along_axis(block_tables, logical[:, None], axis=1)[:, 0]
-        return lambda pages, rows: write_token_rows(
-            pages, rows[:, 0], ids, positions[:, 0] % page_size, live[:, 0])
-    # one chunk of one sequence: contiguous positions from a page boundary
-    length = jnp.sum(live[0].astype(jnp.int32))
-    return lambda pages, rows: write_chunk_pages(
-        pages, rows[0], block_tables[0], positions[0, 0], length)
-
-
-def write_token_rows(pages, rows, page_ids, offsets, live):
-    """Decode: one row per slot into ``pages`` [P, page, W].  rows: [B, W];
-    page_ids/offsets/live: [B].  One scatter over the two leading dims; a
-    dead slot's row goes out of bounds and is dropped (its block table may
-    name a page that is another slot's by now)."""
-    ids = jnp.where(live, page_ids, pages.shape[0])
-    return pages.at[ids, offsets].set(rows.astype(pages.dtype), mode="drop")
-
-
-def write_chunk_pages(pages, rows, page_row, start, length):
-    """Prefill: the first ``length`` of ``rows`` [C, W] into the pages
-    ``page_row`` [n] names from token ``start`` on, a page at a time.
-    ``start`` is a multiple of the page size and ``C`` a whole number of
-    pages (the engine's chunks are: ``prefill_chunk % page_size == 0``)."""
-    _, page, width = pages.shape
-    c = rows.shape[0]
-    if c % page:
-        raise ValueError(f"a prefill bucket of {c} tokens is not a whole number of {page}-token pages")
-    vals = rows.astype(pages.dtype).reshape(c // page, page, width)
-    first = start // page
-
-    def write_page(j, pages):
-        at = (page_row[first + j], 0, 0)
-        old = lax.dynamic_slice(pages, at, (1, page, width))
-        new = lax.dynamic_slice_in_dim(vals, j, 1, axis=0)
-        keep = (j * page + jnp.arange(page)) < length
-        return lax.dynamic_update_slice(pages, jnp.where(keep[None, :, None], new, old), at)
-
-    return lax.fori_loop(0, (length + page - 1) // page, write_page, pages)

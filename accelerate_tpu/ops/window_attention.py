@@ -2,7 +2,7 @@
 a sequence is its last ``ring`` tokens, whatever the context's length.
 
 A ring is ``[slots, ring, Hkv * D]`` (a token's heads in one row, as the page
-pools of ``ops/sparse_attention.py`` have them); the token at position ``p``
+pools of ``ops/paged_cache.py`` have them); the token at position ``p``
 of the sequence in slot ``s`` lives in row ``p % ring`` of ``ring[s]``, so
 after the token at ``t`` is written, row ``r`` holds position
 ``t - (t - r) % ring``.  A row is read only when that position lies in
@@ -19,7 +19,7 @@ Two paths, plain XLA, both banded — no score outside the band is computed:
   ``window`` queries against its own and the previous block of keys.
 
 Writes (:func:`ring_writer`) are in place and in the layout the reads use:
-one row a slot (:func:`~.sparse_attention.write_token_rows` with the slot as
+one row a slot (:func:`~.paged_cache.write_token_rows` with the slot as
 the page) or the chunk's last ``ring`` tokens into one slot's ring
 (:func:`write_chunk_ring`).
 """
@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from .sparse_attention import write_token_rows
+from .paged_cache import write_token_rows
 
 
 def masked_attention(qg, k, v, visible, scale):
@@ -93,7 +93,7 @@ def ring_chunk_attention(q, k, v, k_ring, v_ring, slot, q_positions, window: int
 
 def ring_writer(slots, positions, live, ring: int):
     """``write(ring_rows, rows [B, T, W])`` of one paged call, for K and V
-    alike (``sparse_attention.page_writer``'s twin for slot-addressed state):
+    alike (``paged_cache.page_writer``'s twin for slot-addressed state):
     a decode step ``[B, 1]`` writes one row a slot at ``position % ring``, a
     prefill chunk ``[1, C]`` the last ``ring`` of its first ``sum(live)`` rows."""
     if positions.shape[1] == 1:

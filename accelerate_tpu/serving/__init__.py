@@ -3,8 +3,10 @@
 The production-inference rebuild of the reference's
 ``inference.py``/``big_modeling.py`` contract — see docs/serving.md:
 
-- :mod:`.paged_cache` — functional device-side page allocator over the pools
-  the model builds (``model.init_paged_cache``, below);
+- ``ops/paged_cache.py`` (below the models) — the pools a model builds
+  (``model.init_paged_cache``, below), their functional device-side page
+  allocator and every page write; :mod:`.paged_cache` — what a page and a
+  pool take, on the host;
 - :mod:`.scheduler` — deterministic continuous-batching policy (FIFO
   admission, chunked prefill into shape buckets, youngest-first eviction);
 - :mod:`.engine` — the jitted, donation-clean prefill/decode/release
@@ -46,7 +48,7 @@ the engine imports none):
 
 - ``init_paged_cache(num_pages, page_size, num_slots, pages_per_slot,
   kv_dtype=None)`` (required) — the cache pytree of
-  :func:`.paged_cache.init_paged_pools` around one dict of arrays per
+  ``ops/paged_cache.init_paged_pools`` around one dict of arrays per
   layer: what a layer keeps per token is the layer's kind's to say.  Two
   kinds exist.  A *paged* layer's arrays are ``[num_pages, ...]`` and the
   ONE block table addresses all of them (K and V pages, scales, an
@@ -109,8 +111,8 @@ from .prefix_cache import (
     prefix_cache_accounting,
     unbounded_prefix_hit_rate,
 )
-from .paged_cache import (allocate, cache_accounting, kv_pool_accounting, pages_for,
-                          push_pages, release)
+from ..ops.paged_cache import allocate, pages_for, push_pages, release
+from .paged_cache import cache_accounting, kv_pool_accounting
 from .router import FleetRouter, fleet_chaos_replay, fleet_replay
 from .scheduler import ContinuousBatchingScheduler, Request, SlotState
 from .speculate import (

@@ -17,7 +17,7 @@ memmaps on demand:
   step, and the bounded-retry/fault hooks ride along like every other
   host transfer.
 - **pool discipline**: a free-list + one donated jitted scatter
-  (``pool.at[slot].set``) mirrors ``serving/paged_cache.py`` — the pool
+  (``pool.at[slot].set``) mirrors ``ops/paged_cache.py`` — the pool
   buffers alias in place, so the decode step stays donation-clean and
   ``ServingEngine.audit_decode_step()`` stays green.
 - **pinning**: every in-flight request holding adapter *t* keeps a

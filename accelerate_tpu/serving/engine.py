@@ -43,7 +43,7 @@ from ..resilience import faults as _faults
 from ..telemetry import HostLedger, RequestTracer
 from ..utils.dataclasses import ServingPlugin, TelemetryPlugin
 from .overload import DegradationLadder
-from .paged_cache import allocate, pages_for, push_pages, release
+from ..ops.paged_cache import allocate, pages_for, push_pages, release
 from .prefix_cache import PrefixCache
 from .scheduler import ContinuousBatchingScheduler, Request, SlotState
 from .speculate import Speculator, make_draft_provider, speculative_page_need

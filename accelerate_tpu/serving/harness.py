@@ -25,7 +25,6 @@ from typing import Callable, Optional
 
 import numpy as np
 
-from .paged_cache import pages_for
 from .scheduler import Request
 
 

@@ -39,7 +39,7 @@ from typing import Optional
 
 import numpy as np
 
-from .paged_cache import pages_for
+from ..ops.paged_cache import pages_for
 
 
 class DegradationLadder:

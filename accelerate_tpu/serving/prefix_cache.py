@@ -4,7 +4,7 @@ refcounted eviction (vLLM automatic-prefix-caching discipline).
 Millions of users share system prompts and few-shot preambles, but a plain
 paged engine re-prefills every prompt into private pages.  This module is
 the host-side spine of prefix reuse over the existing functional allocator
-(``serving/paged_cache.py``):
+(``ops/paged_cache.py``):
 
 - **Content addressing**: every FULL page of a prompt gets a chained block
   hash — ``h_j = H(adapter_id, h_{j-1}, tokens[j*page:(j+1)*page])`` — so a
@@ -45,8 +45,6 @@ from __future__ import annotations
 
 import hashlib
 from typing import Optional, Sequence
-
-from .paged_cache import pages_for
 
 
 def _block_digest(parent: bytes, tokens: Sequence[int], adapter_id: int) -> bytes:

@@ -5,7 +5,7 @@ Pure **host-side, deterministic** bookkeeping: given the same request trace
 and the same plugin knobs, every decision (admission order, chunk sizes,
 interleave, evictions) replays identically — the engine executes on device,
 this module only decides.  The scheduler mirrors the device allocator's free
-count with the same arithmetic (``paged_cache.pages_for``), so it can evict
+count with the same arithmetic (``ops/paged_cache.pages_for``), so it can evict
 *before* a device-side pop could underflow, without a per-step device->host
 sync.
 
@@ -74,7 +74,7 @@ from typing import Optional
 
 import numpy as np
 
-from .paged_cache import pages_for
+from ..ops.paged_cache import pages_for
 
 
 @dataclasses.dataclass(frozen=True)

@@ -23,7 +23,7 @@ Two draft providers:
 
 Rejected drafts cost nothing but the verify lane they rode in: the verify
 program rolls speculatively-consumed pages back onto the functional
-free-list (``paged_cache.push_pages``) and the host mirror stays exact via
+free-list (``ops/paged_cache.push_pages``) and the host mirror stays exact via
 per-slot accepted-length bookkeeping (``scheduler.note_verify``).
 
 :func:`predicted_acceptance` is the CheckFreq-style predicted twin: a
@@ -290,7 +290,7 @@ def predicted_acceptance(trace, results: dict, provider, k: int) -> dict:
 def speculative_page_need(kv_tokens: int, depth: int, page_size: int) -> int:
     """Worst-case fresh pages one slot's verify pass can consume: page
     starts among the written positions ``[kv, kv + depth]``."""
-    from .paged_cache import pages_for
+    from ..ops.paged_cache import pages_for
 
     return int(pages_for(kv_tokens + depth + 1, page_size)
                - pages_for(kv_tokens, page_size))

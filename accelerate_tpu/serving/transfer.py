@@ -43,7 +43,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from .engine import ServingEngine
-from .paged_cache import allocate, kv_page_bytes, pages_for
+from ..ops.paged_cache import allocate, pages_for
+from .paged_cache import kv_page_bytes
 from .scheduler import Request
 
 

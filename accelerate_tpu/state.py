@@ -31,6 +31,7 @@ import jax
 import numpy as np
 
 from .parallelism_config import ParallelismConfig
+from .utils.constants import FP8_REFUSED
 from .utils.dataclasses import (
     DistributedType,
     GradientAccumulationPlugin,
@@ -350,28 +351,11 @@ class AcceleratorState:
             else mixed_precision.lower()
         )
         if mixed_precision not in MixedPrecisionType:
+            no_fp8 = f" ({FP8_REFUSED})" if mixed_precision == "fp8" else ""
             raise ValueError(
-                f"mixed_precision must be one of {MixedPrecisionType.list()}, got {mixed_precision!r}"
+                f"mixed_precision must be one of {MixedPrecisionType.list()}, "
+                f"got {mixed_precision!r}{no_fp8}"
             )
-        if mixed_precision == "fp8":
-            from .ops.precision import fp8_hardware_supported
-
-            if not fp8_hardware_supported():
-                if parse_flag_from_env("ACCELERATE_FP8_FALLBACK_BF16"):
-                    logger.warning(
-                        "mixed_precision='fp8' requested but this accelerator has no "
-                        "fp8 matmul units; falling back to bf16 "
-                        "(ACCELERATE_FP8_FALLBACK_BF16 is set)."
-                    )
-                    mixed_precision = "bf16"
-                else:
-                    logger.warning(
-                        "mixed_precision='fp8' requested but this accelerator has no "
-                        "fp8 matmul units — the quantize/descale work is pure overhead "
-                        "(measured slower than bf16 on TPU v5e). Training proceeds in "
-                        "fp8 as requested; set ACCELERATE_FP8_FALLBACK_BF16=true to "
-                        "auto-fall-back to bf16 on unsupported hardware."
-                    )
         self.mixed_precision = mixed_precision
         if parallelism_config is None and os.environ.get("PARALLELISM_CONFIG_DP_SHARD_SIZE"):
             parallelism_config = ParallelismConfig.from_env()

@@ -444,7 +444,9 @@ class HostLedger:
         for g in range(_GENERATIONS):
             m[f"gc_pause_n.gen{g}"] = hook.by_gen[g] - gens0[g]
         rec = self.recorder
-        for seq, start, dt, gen, collected in reversed(hook.recent):
+        # a copy: ``rec.complete`` allocates, a collection may start inside this loop,
+        # and the listener then appends to ``recent`` on this very thread
+        for seq, start, dt, gen, collected in reversed(tuple(hook.recent)):
             if seq <= self._gc_seen:
                 break
             if dt > self._gc_max:

@@ -144,7 +144,15 @@ class SpanRecorder:
         return len(self._events)
 
     def events(self) -> list[tuple]:
-        return list(self._events)
+        """A copy of the ring, safe to read while something records: every
+        reader below goes through it.  The copy is one C call; where a writer
+        still gets in under it (another thread, or a finalizer the collector
+        runs) the deque says so and the copy is taken again."""
+        while True:
+            try:
+                return list(self._events)
+            except RuntimeError:    # "deque mutated during iteration"
+                continue
 
     def overhead_frac(self, wall_s: float) -> float:
         """Share of ``wall_s`` spent inside record calls."""
@@ -169,7 +177,7 @@ class SpanRecorder:
             "ts": 0, "args": {"name": self.process_name},
         }]
         rows: list[dict] = []
-        for ph, name, cat, track, ts, dur, args in self._events:
+        for ph, name, cat, track, ts, dur, args in self.events():
             tid = tracks.setdefault(track, len(tracks) + 1)
             ev = {
                 "ph": ph, "name": name, "pid": 0, "tid": tid,
@@ -200,7 +208,7 @@ class SpanRecorder:
         """One JSON object per event (the raw-events sink; the Chrome
         export is the human-facing one)."""
         with open(path, "w") as f:
-            for ph, name, cat, track, ts, dur, args in self._events:
+            for ph, name, cat, track, ts, dur, args in self.events():
                 f.write(json.dumps({
                     "ph": ph, "name": name, "cat": cat, "track": track,
                     "ts": ts, "dur": dur, "args": args or {},

@@ -34,7 +34,9 @@ SAFE_WEIGHTS_INDEX_NAME = "model.safetensors.index.json"
 SAFE_WEIGHTS_SHARD_PATTERN = "model-{:05d}-of-{:05d}.safetensors"
 
 # -- option lists (CLI choices / config validation / plugin env parsing) -----
-MIXED_PRECISION_CHOICES = ["no", "bf16", "fp16", "fp8"]
+MIXED_PRECISION_CHOICES = ["no", "bf16", "fp16"]
+# what a request for the reference's fourth choice is told, wherever it arrives
+FP8_REFUSED = "the chips this package runs on have no fp8 matmul units; use 'bf16'"
 SHARDING_STRATEGY_CHOICES = ["FULL_SHARD", "SHARD_GRAD_OP", "NO_SHARD", "HYBRID_SHARD"]
 REMAT_POLICY_CHOICES = ["full", "dots", "offload"]
 GRAD_ACCUM_MODE_CHOICES = ["in_step", "across_steps"]

@@ -61,12 +61,12 @@ class DistributedType(BaseEnum):
 
 
 class MixedPrecisionType(BaseEnum):
-    """reference dataclasses.py:647 — 'no'|'fp16'|'bf16'|'fp8'."""
+    """reference dataclasses.py:647 — 'no'|'fp16'|'bf16' (the reference's 'fp8' is
+    refused by name: ``state.py``)."""
 
     NO = "no"
     FP16 = "fp16"
     BF16 = "bf16"
-    FP8 = "fp8"
 
 
 class ShardingStrategy(BaseEnum):
@@ -114,14 +114,6 @@ class LoggerType(BaseEnum):
     DVCLIVE = "dvclive"
     SWANLAB = "swanlab"
     TRACKIO = "trackio"
-
-
-class FP8Format(BaseEnum):
-    """FP8 dtype pairing for matmul inputs (TE 'HYBRID' recipe analog,
-    reference dataclasses.py:359-438)."""
-
-    E4M3 = "E4M3"
-    HYBRID = "HYBRID"  # e4m3 fwd, e5m2 bwd
 
 
 # ---------------------------------------------------------------------------
@@ -967,44 +959,6 @@ class ExpertParallelConfig(KwargsHandler):
 
 
 @dataclass
-class FP8RecipeKwargs(KwargsHandler):
-    """Unified fp8 recipe (reference AO/TE/MSAMP recipes dataclasses.py:311-483).
-
-    XLA-native: matmul inputs cast to float8 with per-tensor delayed scaling;
-    amax history drives the scale like TE's DelayedScaling.
-    """
-
-    fp8_format: FP8Format = FP8Format.HYBRID
-    amax_history_len: Optional[int] = None   # env ACCELERATE_FP8_AMAX_HISTORY_LEN,
-                                             # default 16
-    amax_compute_algo: str = "max"
-    margin: Optional[int] = None             # env ACCELERATE_FP8_MARGIN, default 0
-    module_filter: Optional[Callable[[str], bool]] = None
-
-    def __post_init__(self):
-        if isinstance(self.fp8_format, str):
-            self.fp8_format = FP8Format(self.fp8_format.upper())
-        env = os.environ
-        if self.amax_history_len is None:
-            self.amax_history_len = int(
-                env.get("ACCELERATE_FP8_AMAX_HISTORY_LEN", 16)
-            )
-        if self.margin is None:
-            self.margin = int(env.get("ACCELERATE_FP8_MARGIN", 0))
-        if self.amax_history_len < 1:
-            raise ValueError(
-                f"amax_history_len must be >= 1, got {self.amax_history_len}"
-            )
-        if self.margin < 0:
-            raise ValueError(f"margin must be >= 0, got {self.margin}")
-        if self.amax_compute_algo != "max":
-            raise ValueError(
-                "amax_compute_algo: only 'max' is implemented "
-                f"(got {self.amax_compute_algo!r})"
-            )
-
-
-@dataclass
 class DataLoaderConfiguration(KwargsHandler):
     """reference dataclasses.py DataLoaderConfiguration (split_batches,
     dispatch_batches, even_batches, use_seedable_sampler...)."""
@@ -1056,5 +1010,4 @@ ALL_KWARGS_HANDLERS = (
     GradSyncKwargs,
     InitProcessGroupKwargs,
     ProfileKwargs,
-    FP8RecipeKwargs,
 )

@@ -167,14 +167,3 @@ def is_multihost_available() -> bool:
 def is_bf16_available() -> bool:
     """bf16 is native on every TPU generation we target; always true on JAX."""
     return is_jax_available()
-
-
-def is_fp8_available() -> bool:
-    """float8_e4m3fn / e5m2 dtypes exist in every supported jax/ml_dtypes."""
-    try:
-        import jax.numpy as jnp
-
-        jnp.float8_e4m3fn  # noqa: B018
-        return True
-    except (ImportError, AttributeError):
-        return False

@@ -107,11 +107,8 @@ PAGES: dict[str, tuple[str, list[str] | None]] = {
         "send_to_device", "concatenate",
     ]),
     "kernels": ("accelerate_tpu.ops.flash_attention", None),
-    "fp8": ("accelerate_tpu.ops.fp8", [
-        "init_fp8_state", "update_fp8_state", "merge_fp8_collection",
-        "fp8_delayed_dot", "fp8_fake_quantize", "fp8_delayed_enabled",
-        "amax_history_len", "fp8_margin",
-    ]),
+    "paged_cache": ("accelerate_tpu.ops.paged_cache", None),
+    "page_walk": ("accelerate_tpu.ops.page_walk", None),
     "quantization": ("accelerate_tpu.utils.quantization", [
         "QuantizationConfig", "QuantizedTensor", "quantize", "dequantize",
         "quantize_params", "quantized_apply",
@@ -152,7 +149,7 @@ PAGES: dict[str, tuple[str, list[str] | None]] = {
         "GradSyncKwargs", "ProfileKwargs", "GradientAccumulationPlugin",
         "FullyShardedDataParallelPlugin", "ResiliencePlugin", "ServingPlugin",
         "LoraPlugin", "ProjectConfiguration", "DataLoaderConfiguration",
-        "InitProcessGroupKwargs", "FP8RecipeKwargs",
+        "InitProcessGroupKwargs",
     ]),
     "memory": ("accelerate_tpu.utils.memory", None),
 }

@@ -108,8 +108,7 @@ def collective_matmul_rs_hint_step(x, w):
 
 def unscaled_fp8_dot_step(x, w):
     """GL110 fixed: the accumulator is multiplied by the combined inverse
-    scale before anything else consumes it — the ops/fp8.py contract
-    (fp8_current_scaled_dot is the model)."""
+    scale before anything else consumes it — what rule GL110 asks."""
     x_scale = 448.0 / jnp.maximum(jnp.max(jnp.abs(x)), 1e-12)
     w_scale = 448.0 / jnp.maximum(jnp.max(jnp.abs(w)), 1e-12)
     qx = (x * x_scale).astype(jnp.float8_e4m3fn)

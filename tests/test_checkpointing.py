@@ -354,3 +354,41 @@ def test_wait_for_published_checkpoint(tmp_path):
     bare = tmp_path / "checkpoint_1"
     bare.mkdir()
     wait_for_published_checkpoint(bare, verify=False, timeout_s=0.2)
+
+
+def test_a_parent_format_state_with_a_null_fp8_state_has_the_same_leaves():
+    """A checkpoint names a train state's leaves by their index in the flattened
+    state and nothing else (``save_accelerator_state``; the manifest lists
+    files).  The state of before PR 47 had one more field, ``fp8_state``, last
+    and ``None`` wherever no fp8 recipe was armed: it flattened to the leaves
+    this state flattens to, in this order, so such a checkpoint loads as it was
+    written."""
+    import flax
+
+    from accelerate_tpu.accelerator import TrainState
+
+    @flax.struct.dataclass
+    class ParentTrainState:
+        step: jax.Array
+        params: dict
+        opt_state: tuple
+        rng: jax.Array
+        loss_scale: object = None
+        grad_accum: object = None
+        accum_step: object = None
+        comm_state: object = None
+        guard_state: object = None
+        fp8_state: object = None
+        apply_fn: object = flax.struct.field(pytree_node=False, default=None)
+        tx: object = flax.struct.field(pytree_node=False, default=None)
+
+    tx = optax.adam(0.05)
+    params = regression_init_params()
+    fields = dict(step=jnp.int32(3), params=params, opt_state=tx.init(params),
+                  rng=jax.random.key(0), accum_step=jnp.int32(1),
+                  guard_state={"nan_skips": jnp.int32(0), "consecutive_nan_skips": jnp.int32(0)})
+    then = jax.tree_util.tree_flatten_with_path(ParentTrainState(fp8_state=None, **fields))[0]
+    now = jax.tree_util.tree_flatten_with_path(TrainState(**fields))[0]
+    assert [jax.tree_util.keystr(p) for p, _ in then] == [jax.tree_util.keystr(p) for p, _ in now]
+    assert all(a is b for (_, a), (_, b) in zip(then, now)) and len(now) >= 6
+    assert "fp8_state" not in TrainState.__dataclass_fields__

@@ -280,8 +280,8 @@ def test_interactive_config_full_flow(monkeypatch, capsys):
         "2",          # slices (dcn cross-slice axis)
         "",           # use_cpu
         "y",          # debug
-        "fp4",        # invalid precision -> re-prompt
-        "fp8",        # precision
+        "fp8",        # refused precision (no fp8 matmul units) -> re-prompt
+        "fp16",       # precision
         "2",          # grad accum
         "2",          # tp
         "2",          # cp
@@ -309,7 +309,7 @@ def test_interactive_config_full_flow(monkeypatch, capsys):
     assert "not one of" in out          # invalid answers were rejected
     assert "pick one" in out            # cp+sp conflict surfaced
     assert "Mesh:" in out
-    assert cfg.mixed_precision == "fp8"
+    assert cfg.mixed_precision == "fp16"
     assert cfg.tp_size == 2 and cfg.cp_size == 2 and cfg.sp_size == 1
     assert cfg.fsdp_offload_params and cfg.fsdp_activation_checkpointing
     assert cfg.debug and cfg.num_machines == 2
@@ -323,7 +323,7 @@ def test_interactive_config_full_flow(monkeypatch, capsys):
         num_cpu_devices = None
 
     env = _base_env(_Args(), cfg)
-    assert env["ACCELERATE_MIXED_PRECISION"] == "fp8"
+    assert env["ACCELERATE_MIXED_PRECISION"] == "fp16"
     assert env["FSDP_OFFLOAD_PARAMS"] == "true"
     assert env["PARALLELISM_CONFIG_TP_SIZE"] == "2"
     assert env["ACCELERATE_DEBUG_MODE"] == "true"
@@ -496,12 +496,12 @@ def test_menu_select_fallback_paths(monkeypatch):
     re-prompts on junk (the menu UI degrades to this in pipes/CI)."""
     from accelerate_tpu.commands import menu
 
-    answers = iter(["", "bf16", "3", "junk", "1"])
+    answers = iter(["", "bf16", "2", "junk", "1"])
     monkeypatch.setattr("builtins.input", lambda prompt="": next(answers))
-    choices = ("no", "bf16", "fp16", "fp8")
+    choices = ("no", "bf16", "fp16")
     assert menu.select("precision", choices, "bf16") == "bf16"   # default
     assert menu.select("precision", choices, "no") == "bf16"     # by name
-    assert menu.select("precision", choices, "no") == "fp8"      # by index
+    assert menu.select("precision", choices, "no") == "fp16"     # by index
     assert menu.select("precision", choices, "no") == "bf16"     # junk -> re-ask
 
 

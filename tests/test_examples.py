@@ -95,7 +95,6 @@ def test_complete_nlp_example(tmp_path):
         ("gradient_accumulation_for_autoregressive_models.py", "max param diff"),
         ("grad_comm_compression.py", "bf16 gradient collectives"),
         ("zero_offload.py", "targets 2, 3"),
-        ("fp8_training.py", "fp8 matmuls, bf16 activations"),
         ("bf16_master_sr.py", "x smaller with SR"),
     ],
 )

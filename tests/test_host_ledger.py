@@ -436,3 +436,32 @@ def test_a_trace_inside_a_trace_is_booked_once():
     trace_s, lower_s, backend_s, load_s, hits, misses = counter.parts()
     assert trace_s > 0.0 and lower_s > 0.0 and counter.count >= 1
     assert trace_s + lower_s + max(backend_s, 0.0) + load_s <= wall * 1.05
+
+
+def test_a_collection_inside_the_ledgers_own_gc_loop_does_not_break_it():
+    """With a tracer armed ``_read_gc`` records a span for each long pause, and
+    recording allocates: a collection may start INSIDE the loop, and the
+    process-wide listener then appends to the very deque the loop walks, on
+    the same thread (``RuntimeError: deque mutated during iteration``: a traced
+    run of ``qwen3-next.serve_assist``, ``ROADMAP.md`` C24).  Planted here by
+    a recorder that fires the listener from ``complete``."""
+    hook = install_global_gc_hook()
+    led = HostLedger({})
+
+    class CollectsWhenItRecords:
+        spans = 0
+
+        def complete(self, name, track, start, end, **args):
+            self.spans += 1
+            hook("start", {"generation": 0})
+            hook("stop", {"generation": 0, "collected": 0})
+
+    rec = CollectsWhenItRecords()
+    led.set_clock(led.clock, rec)
+    for _ in range(3):                       # three pauses the ledger has not read yet
+        hook("start", {"generation": 2})
+        hook._t0 -= 1.0                      # ... each a second long: worth a span
+        hook("stop", {"generation": 2, "collected": 7})
+    led._read_gc()
+    assert rec.spans >= 3 and led.metrics["gc_pause_n.gen2"] >= 3     # a real collection may add its own
+    assert led.metrics["gc_pause_s_max"] >= 1.0

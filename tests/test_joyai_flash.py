@@ -23,7 +23,7 @@ from accelerate_tpu.generation import GenerationConfig  # noqa: E402
 from accelerate_tpu.models import JoyAIFlashConfig, JoyAIFlashForCausalLM  # noqa: E402
 from accelerate_tpu.models.joyai_flash import JoyAIFlashAttention, deinterleave_rope  # noqa: E402
 from accelerate_tpu.models.k_exaone import KExaoneSparseMoE  # noqa: E402
-from accelerate_tpu.ops import sparse_attention as sa  # noqa: E402
+from accelerate_tpu.ops import page_walk as pw  # noqa: E402
 from accelerate_tpu.serving import (Request, ServingEngine, cache_accounting,  # noqa: E402
                                     verify_serving_invariants)
 from accelerate_tpu.utils.dataclasses import ServingPlugin  # noqa: E402
@@ -171,7 +171,7 @@ def test_one_walk_serves_every_kind_of_row():
     q = jax.random.normal(jax.random.key(0), (b, t, h, d))
     pool = jax.random.normal(jax.random.key(1), (16, page, d))
     tables = jnp.asarray(np.random.default_rng(0).permutation(16)[:b * n].reshape(b, n), jnp.int32)
-    padded = sa.pad_block_tables(tables, sa.block_pages_for(b, t, h, page))      # one 64-page step
+    padded = pw.pad_block_tables(tables, pw.block_pages_for(b, t, h, page))      # one 64-page step
     q_pos = jnp.asarray([[21], [9]], jnp.int32)
     rows = pool[tables].reshape(b, n * page, d)
     seen = jnp.arange(n * page)[None, None] <= q_pos[:, :, None]
@@ -182,8 +182,8 @@ def test_one_walk_serves_every_kind_of_row():
         return jnp.einsum("bhts,bshd->bthd", p, values)
 
     shared = jnp.broadcast_to(rows[:, :, None], (b, n * page, h, d))
-    walk = lambda *a, **kw: sa.paged_masked_attention(*a, padded, jnp.max(q_pos) + 1,
-                                                      sa.causal_mask(q_pos), **kw)
+    walk = lambda *a, **kw: pw.paged_masked_attention(*a, padded, jnp.max(q_pos) + 1,
+                                                      pw.causal_mask(q_pos), **kw)
     np.testing.assert_allclose(walk(q, pool, pool), dense(shared, shared, 0.25), **TOL)
     got = walk(q, pool, None, scale=0.1, value_width=12)
     assert got.shape == (b, t, h, 12)

@@ -20,13 +20,10 @@ import numpy as np
 import pytest
 
 from accelerate_tpu.generation import GenerationConfig, generate, generate_paged
-from accelerate_tpu.models.llama import (
+from accelerate_tpu.models.llama import LlamaConfig, LlamaForCausalLM, init_paged_cache
+from accelerate_tpu.ops.paged_cache import (
     KV_QUANT_QMAX,
-    LlamaConfig,
-    LlamaForCausalLM,
     dequantize_kv_pages,
-    init_paged_cache,
-    paged_gather_kv,
     paged_write_kv_quantized,
     resolve_kv_dtype,
 )

@@ -1,7 +1,7 @@
-"""``ops/latent_attention.latent_decode_attention`` (the ``latent_decode``
+"""``ops/page_walk.latent_decode_attention`` (the ``latent_decode``
 Pallas kernel, interpret mode here) against the XLA walk it takes the place of
 in ``models/joyai_flash.py``'s decode step:
-``ops/sparse_attention.paged_masked_attention(..., value_width=r)`` under the
+``ops/page_walk.paged_masked_attention(..., value_width=r)`` under the
 causal mask, on the same pool.
 
 Every case scatters its pages over the pool and points every block-table entry
@@ -15,8 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from accelerate_tpu.ops import sparse_attention as sa
-from accelerate_tpu.ops.latent_attention import _CHUNK_PAGES, latent_decode_attention
+from accelerate_tpu.ops import page_walk as pw
+from accelerate_tpu.ops.page_walk import _CHUNK_PAGES, latent_decode_attention
 
 TINY = dict(heads=2, r=32, dr=8, row=128, page=8)          # the CPU rehearsal's widths
 PUBLISHED = dict(heads=4, r=512, dr=64, row=640, page=64)  # the cell's
@@ -57,10 +57,10 @@ def xla_walk(qa, qr, pool, tables, pos, scale):
     s, h, r = qa.shape
     page, row = pool.shape[1:]
     q_abs = jnp.concatenate([qa, qr, jnp.zeros((s, h, row - r - qr.shape[2]), qa.dtype)], -1)
-    padded = sa.pad_block_tables(tables, sa.block_pages_for(s, 1, h, page))
-    return sa.paged_masked_attention(
+    padded = pw.pad_block_tables(tables, pw.block_pages_for(s, 1, h, page))
+    return pw.paged_masked_attention(
         q_abs[:, None], pool.at[0].set(0.0), None, padded, jnp.max(pos) + 1,
-        sa.causal_mask(pos[:, None]), scale=scale, value_width=r)[:, 0]
+        pw.causal_mask(pos[:, None]), scale=scale, value_width=r)[:, 0]
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])

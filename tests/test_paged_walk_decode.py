@@ -1,7 +1,7 @@
-"""``ops/latent_attention.paged_walk_decode_attention`` (the ``paged_walk_decode``
+"""``ops/page_walk.paged_walk_decode_attention`` (the ``paged_walk_decode``
 Pallas kernel, interpret mode here) against the XLA walk it takes the place of
 in ``models/k_exaone.py``'s decode step:
-``ops/sparse_attention.paged_causal_attention`` on the same two pools.
+``ops/page_walk.paged_causal_attention`` on the same two pools.
 
 As in ``test_latent_decode.py`` every case scatters its pages over the pools
 and points every block-table entry past a slot's last page at page 0, which
@@ -14,8 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from accelerate_tpu.ops import sparse_attention as sa
-from accelerate_tpu.ops.latent_attention import chunk_pages, paged_walk_decode_attention
+from accelerate_tpu.ops import page_walk as pw
+from accelerate_tpu.ops.page_walk import chunk_pages, paged_walk_decode_attention
 
 TINY = dict(heads=4, kv_heads=2, d=32, page=8)         # models/k_exaone.KExaoneConfig.tiny's widths
 GROUPED = dict(heads=16, kv_heads=2, d=32, page=8)     # eight query heads a KV head
@@ -62,8 +62,8 @@ def scattered(widths, pages_per_slot, positions, dtype, seed):
 
 def xla_walk(q, k_pool, v_pool, tables, pos):
     s, h, _ = q.shape
-    padded = sa.pad_block_tables(tables, sa.block_pages_for(s, 1, h, k_pool.shape[1]))
-    return sa.paged_causal_attention(q[:, None], k_pool.at[0].set(0.0), v_pool.at[0].set(0.0),
+    padded = pw.pad_block_tables(tables, pw.block_pages_for(s, 1, h, k_pool.shape[1]))
+    return pw.paged_causal_attention(q[:, None], k_pool.at[0].set(0.0), v_pool.at[0].set(0.0),
                                      padded, pos[:, None], jnp.max(pos) + 1)[:, 0]
 
 

@@ -14,9 +14,9 @@ from accelerate_tpu.models.llama import (
     LlamaConfig,
     LlamaForCausalLM,
     init_paged_cache,
-    paged_gather_kv,
     cached_attention,
 )
+from accelerate_tpu.ops.paged_cache import paged_gather_kv
 from accelerate_tpu.serving import (
     Request,
     ServingEngine,

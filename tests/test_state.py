@@ -43,6 +43,19 @@ def test_accelerator_state_invalid_precision():
         AcceleratorState(mixed_precision="int3")
 
 
+@pytest.mark.parametrize("by", ["argument", "environment"])
+def test_fp8_is_refused_by_name_and_the_message_names_bf16(by, monkeypatch):
+    """``mixed_precision="fp8"`` is not a precision of this package: the same
+    ValueError an unknown one raises, with the one sentence that says why."""
+    AcceleratorState._reset_state(reset_partial_state=True)
+    if by == "environment":
+        monkeypatch.setenv("ACCELERATE_MIXED_PRECISION", "fp8")
+    with pytest.raises(ValueError, match="no fp8 matmul units; use 'bf16'"):
+        AcceleratorState(mixed_precision="fp8" if by == "argument" else None)
+    monkeypatch.delenv("ACCELERATE_MIXED_PRECISION", raising=False)
+    assert AcceleratorState(mixed_precision="bf16").mixed_precision == "bf16"   # nothing poisoned
+
+
 def test_accelerator_state_default_mesh():
     state = AcceleratorState()
     mesh = state.mesh

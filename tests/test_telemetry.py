@@ -364,6 +364,37 @@ def test_span_recorder_ring_is_bounded():
     assert names == [f"e{i}" for i in range(12, 20)]  # oldest dropped
 
 
+def test_span_recorder_is_read_while_another_thread_records(tmp_path):
+    """``events()``, ``to_chrome_trace()`` and ``write_jsonl()`` read a copy:
+    a writer that appends meanwhile cannot break them (``RuntimeError: deque
+    mutated during iteration`` on the bare loops this replaced)."""
+    import threading
+
+    rec = SpanRecorder(capacity=512, clock=VirtualClock(1.0))
+    for i in range(512):
+        rec.instant("seed", "engine", step=i)
+    stop = threading.Event()
+
+    def writer():
+        i = 0
+        while not stop.is_set():
+            rec.instant("tick", "engine", step=i)
+            i += 1
+
+    thread = threading.Thread(target=writer, daemon=True)
+    thread.start()
+    try:
+        for _ in range(3000):
+            assert len(rec.events()) == 512
+        for _ in range(20):
+            assert len(rec.to_chrome_trace()["traceEvents"]) == 512 + 2
+            rec.write_jsonl(tmp_path / "events.jsonl")
+    finally:
+        stop.set()
+        thread.join()
+    assert rec.recorded > 512 and rec.dropped == rec.recorded - 512
+
+
 def test_span_recorder_disabled_records_nothing():
     rec = SpanRecorder(clock=VirtualClock(1.0), enabled=False)
     rec.instant("x", "t")

@@ -417,10 +417,10 @@ def test_k_exaone_serving_programs_compile_at_the_cells_shapes(one_chip, monkeyp
 
     from accelerate_tpu.generation import GenerationConfig
     from accelerate_tpu.models import KExaoneConfig, KExaoneForCausalLM
-    from accelerate_tpu.ops import latent_attention as la
+    from accelerate_tpu.ops import page_walk as pw
     from accelerate_tpu.serving.engine import fresh_engine_jits
 
-    monkeypatch.setattr(la, "_on_tpu", lambda: True)   # the kernel, not its interpreter
+    monkeypatch.setattr(pw, "_on_tpu", lambda: True)   # the kernel, not its interpreter
     model = KExaoneForCausalLM(KExaoneConfig(
         num_hidden_layers=4, experts_held=tuple(range(16)), attention_heads_held=8,
         key_value_heads_held=1, vocab_held=19200))
@@ -481,10 +481,10 @@ def test_joyai_flash_serving_programs_compile_at_the_cells_shapes(one_chip, monk
 
     from accelerate_tpu.generation import GenerationConfig
     from accelerate_tpu.models import JoyAIFlashConfig, JoyAIFlashForCausalLM
-    from accelerate_tpu.ops import latent_attention as la
+    from accelerate_tpu.ops import page_walk as pw
     from accelerate_tpu.serving.engine import fresh_engine_jits
 
-    monkeypatch.setattr(la, "_on_tpu", lambda: True)   # the kernel, not its interpreter
+    monkeypatch.setattr(pw, "_on_tpu", lambda: True)   # the kernel, not its interpreter
     model = JoyAIFlashForCausalLM(JoyAIFlashConfig(
         num_hidden_layers=2, experts_held=tuple(range(32)), attention_heads_held=4,
         vocab_held=16160))
@@ -549,10 +549,10 @@ def test_qwen3_next_serving_programs_compile_at_the_cells_shapes(one_chip, monke
     from accelerate_tpu.generation import GenerationConfig
     from accelerate_tpu.models import Qwen3NextConfig, Qwen3NextForCausalLM
     from accelerate_tpu.ops import gated_delta as gd
-    from accelerate_tpu.ops import latent_attention as la
+    from accelerate_tpu.ops import page_walk as pw
     from accelerate_tpu.serving.engine import fresh_engine_jits
 
-    monkeypatch.setattr(la, "_on_tpu", lambda: True)   # the kernels, not their interpreter
+    monkeypatch.setattr(pw, "_on_tpu", lambda: True)   # the kernels, not their interpreter
     monkeypatch.setattr(gd, "_on_tpu", lambda: True)
     model = Qwen3NextForCausalLM(Qwen3NextConfig(
         num_hidden_layers=4, experts_held=tuple(range(128)), attention_heads_held=4,
